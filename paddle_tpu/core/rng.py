@@ -16,22 +16,38 @@ import jax
 
 
 class Generator:
-    """A stateful PRNG: owns a key, hands out fresh subkeys."""
+    """A stateful PRNG: owns a key, hands out fresh subkeys.
+
+    The key is built from the seed on first use, not on construction: the
+    package builds its default generator while it is imported, and
+    `jax.random.key` initialises the default backend — a process that only
+    imports the package (a launcher, a fleet manager) must leave the chip
+    to its children."""
 
     def __init__(self, seed: int = 0):
         self._lock = threading.Lock()
         self.manual_seed(seed)
 
     def manual_seed(self, seed: int):
-        with getattr(self, "_lock", threading.Lock()):
+        with self._lock:
             self._seed = int(seed)
-            self._key = jax.random.key(int(seed))
+            self._key_ = None
         return self
 
     seed = manual_seed
 
     def initial_seed(self) -> int:
         return self._seed
+
+    @property
+    def _key(self):
+        if self._key_ is None:
+            self._key_ = jax.random.key(self._seed)
+        return self._key_
+
+    @_key.setter
+    def _key(self, key):
+        self._key_ = key
 
     def next_key(self):
         """Split and return a fresh subkey (advances state)."""
@@ -40,10 +56,12 @@ class Generator:
             return sub
 
     def get_state(self):
-        return jax.random.key_data(self._key)
+        with self._lock:
+            return jax.random.key_data(self._key)
 
     def set_state(self, state):
-        self._key = jax.random.wrap_key_data(state)
+        with self._lock:
+            self._key = jax.random.wrap_key_data(state)
 
 
 _default_generator = Generator(0)
