@@ -68,7 +68,6 @@ def psum_gather(w, uids, axis: str, mesh):
 
     Shards other than the owner contribute exact zeros, so the psum is
     bit-identical to a single-device gather."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     height = w.shape[0]
@@ -82,9 +81,9 @@ def psum_gather(w, uids, axis: str, mesh):
         rows = jnp.where(mine[:, None], rows, jnp.zeros((), rows.dtype))
         return jax.lax.psum(rows, axis)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axis, None), P()), out_specs=P(),
-                     check_rep=False)(w, uids)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axis, None), P()), out_specs=P(),
+                         check_vma=False)(w, uids)
 
 
 def _state_specs(state, height: int, axis: str):
@@ -109,7 +108,6 @@ def sharded_lazy_row_update(optimizer, p, grad: RowSparseGrad, state, lr,
     O(lookups·width) work per shard, writes strictly local, moments of
     untouched rows untouched.  The distributed half of adam_op.h lazy_mode,
     with GSPMD placement instead of a parameter server."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from ..optimizer.sparse import lazy_row_update
 
@@ -129,11 +127,11 @@ def sharded_lazy_row_update(optimizer, p, grad: RowSparseGrad, state, lr,
         return lazy_row_update(optimizer, p_l, g, state_l, lr, step_no,
                                decay_flag, lr_mult)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), st_specs, P(), P(), P(), P()),
         out_specs=(P(axis, None), st_specs),
-        check_rep=False)(p, state, grad.rows, grad.values, lr, step_no)
+        check_vma=False)(p, state, grad.rows, grad.values, lr, step_no)
 
 
 # ---------------------------------------------------------------------------

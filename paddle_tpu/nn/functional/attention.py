@@ -13,8 +13,16 @@ import jax
 import jax.numpy as jnp
 
 from ...core.op import dispatch
+from ...observability.metrics import counter
 
 _USE_FLASH = True
+
+# which form each traced attention call took: the choice is made from shapes
+# at trace time, so a compiled step's counts say what is inside it
+_PATH_TAKEN = counter(
+    "attention_path_total",
+    "attention calls traced, by the form taken (pallas flash kernel or the "
+    "O(S^2) XLA form)", ("path",))
 
 
 def set_flash_attention(enabled: bool):
@@ -52,7 +60,7 @@ def _flash_kv_bias(mask, batch, sk):
 
 def _sdpa_raw(q, k, v, mask, dropout_p, is_causal, drop_key):
     # pallas flash path: handles causal, (B,Sk) padding bias, and in-kernel
-    # dropout; falls back to the XLA naive form otherwise
+    # dropout; shapes and masks it cannot express take the XLA naive form
     if _USE_FLASH:
         from ...ops import flash_attention as fa
         try:
@@ -70,7 +78,9 @@ def _sdpa_raw(q, k, v, mask, dropout_p, is_causal, drop_key):
                 dropout_p=dropout_p if drop_key is not None else 0.0,
                 dropout_seed=seed)
             if out is not None:
+                _PATH_TAKEN.labels(path="flash").inc()
                 return out
+    _PATH_TAKEN.labels(path="xla").inc()
     scale = 1.0 / math.sqrt(q.shape[-1])
     # (b, s, h, d) -> (b, h, s, d)
     qt = jnp.swapaxes(q, 1, 2)

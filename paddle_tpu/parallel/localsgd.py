@@ -20,7 +20,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor, unwrap
@@ -100,12 +99,12 @@ class LocalSGDTrainStep:
             new_opt = jax.tree_util.tree_map(lambda s: s[None], new_opt)
             return new_params, new_opt, jax.lax.pmean(loss, "dp")
 
-        step = shard_map(
+        step = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("dp"), P("dp"), P(), P(), P(),
                       tuple(P("dp") for _ in range(n_batch))),
             out_specs=(P("dp"), P("dp"), P()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(step, donate_argnums=(0, 1))
 
     def _build_sync(self):

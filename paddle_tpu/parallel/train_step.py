@@ -163,7 +163,6 @@ class ShardedTrainStep:
             identical to the implicit global-loss gradient; a sum-reduced
             loss differs by a factor of dp (exactly as it would under the
             reference's scaled-loss + allreduce)."""
-            from jax.experimental.shard_map import shard_map
 
             def local(params, batch):
                 key = jax.random.fold_in(rng_key,
@@ -182,10 +181,10 @@ class ShardedTrainStep:
                     g)
                 return jax.lax.pmean(loss, "dp"), g
 
-            return shard_map(
+            return jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), tuple(P("dp") for _ in batch)),
-                out_specs=(P(), P()), check_rep=False)(params, batch)
+                out_specs=(P(), P()), check_vma=False)(params, batch)
 
         grads_of = (grads_of_explicit if self._fp16_allreduce
                     else grads_of_implicit)
