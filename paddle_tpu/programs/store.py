@@ -15,6 +15,10 @@ never be reused silently, and no artifact is ever invalidated in place.
 Knobs (env, read at `ensure_enabled()` / import-time bootstrap):
 
 - ``PDTPU_PROGRAM_CACHE_DIR``            base directory; unset = disabled
+- ``JAX_COMPILATION_CACHE_DIR``          where set, the cache stays where
+  JAX put it: the machine's owner placed it from outside (a directory
+  that survives the process, or the job), so the store applies its
+  thresholds and counters there and never names another directory
 - ``PDTPU_PROGRAM_CACHE_MIN_COMPILE_S``  min compile seconds to persist
   (default 0: fleet cold-start wants even the small dispatch-cache
   programs — jax's own 1s default would skip them all)
@@ -39,6 +43,7 @@ __all__ = ["ProgramStore", "get_program_store", "enable", "disable",
            "ensure_enabled", "cache_fingerprint", "store_stats"]
 
 _ENV_DIR = "PDTPU_PROGRAM_CACHE_DIR"
+_ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
 _ENV_MIN_COMPILE = "PDTPU_PROGRAM_CACHE_MIN_COMPILE_S"
 _ENV_MAX_BYTES = "PDTPU_PROGRAM_CACHE_MAX_BYTES"
 
@@ -101,16 +106,19 @@ class ProgramStore:
         """Point every XLA compile in this process at the on-disk cache
         under `cache_dir` (or ``PDTPU_PROGRAM_CACHE_DIR``).  Returns the
         fingerprinted directory actually used, or None when no directory
-        is configured.  Re-enabling with the same dir is a no-op;
-        enabling after compiles already happened works (jax's cache
-        memoization is reset)."""
+        is configured.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the
+        directory is JAX's own, whatever `cache_dir` says.  Re-enabling
+        with the same dir is a no-op; enabling after compiles already
+        happened works (jax's cache memoization is reset)."""
         with self._lock:
             base = cache_dir or os.environ.get(_ENV_DIR)
             if not base:
                 return None
             import jax
             fp = cache_fingerprint()
-            target = os.path.join(base, f"v-{fp}")
+            placed_outside = bool(os.environ.get(_ENV_JAX_DIR))
+            target = (jax.config.jax_compilation_cache_dir if placed_outside
+                      else os.path.join(base, f"v-{fp}"))
             if self._enabled and self._dir == target:
                 return self._dir
             os.makedirs(target, exist_ok=True)
@@ -123,7 +131,8 @@ class ProgramStore:
                         "jax_raise_persistent_cache_errors",
                         "jax_compilation_cache_max_size")}
             min_compile = float(os.environ.get(_ENV_MIN_COMPILE, "0") or 0)
-            jax.config.update("jax_compilation_cache_dir", target)
+            if not placed_outside:
+                jax.config.update("jax_compilation_cache_dir", target)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                               -1)
             jax.config.update("jax_persistent_cache_min_compile_time_secs",

@@ -96,6 +96,51 @@ def test_enable_uses_fingerprinted_subdir_and_stats(store_dir):
     assert st["fingerprint"] == programs.cache_fingerprint()
 
 
+def test_cache_placed_from_outside_is_never_moved(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own directory stands whatever
+    enable() is told; thresholds and counters still apply there."""
+    import jax
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    monkeypatch.setenv("PDTPU_PROGRAM_CACHE_DIR", str(tmp_path / "env"))
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", outside)  # as at import
+    try:
+        used = programs.enable(str(tmp_path / "elsewhere"))
+        assert used == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert programs.ensure_enabled()
+        assert jax.config.jax_compilation_cache_dir == outside
+        st = programs.store_stats()
+        assert st["enabled"] and st["dir"] == outside
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        miss0 = st["misses"]
+        jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+        st = programs.store_stats()
+        assert st["misses"] == miss0 + 1 and st["entries"] >= 1
+        assert not (tmp_path / "elsewhere").exists()
+        assert not (tmp_path / "env").exists()
+    finally:
+        programs.disable()
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_enable_twice_gives_the_same_directory(tmp_path, monkeypatch):
+    """No outside placement: the directory depends on the base and the
+    fingerprint alone (the path is part of the cache key — one that moved
+    between runs would never hit)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    base = str(tmp_path / "store")
+    try:
+        first = programs.enable(base)
+        programs.disable()
+        assert programs.enable(base) == first
+        assert first == os.path.join(
+            base, f"v-{programs.cache_fingerprint()}")
+    finally:
+        programs.disable()
+
+
 # ---------------------------------------------------------------------------
 # cache-key invalidation + corruption fallback
 # ---------------------------------------------------------------------------
