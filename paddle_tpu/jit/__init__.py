@@ -327,7 +327,7 @@ class TrainStep:
 
     def __init__(self, model: Layer, loss_fn: Callable, optimizer,
                  amp_level: Optional[str] = None, amp_dtype="bfloat16",
-                 mesh=None, batch_sharding=None, remat: bool = False,
+                 remat: bool = False,
                  with_outputs: bool = False, guard: bool = False,
                  accum_steps: int = 1):
         self.model = model

@@ -112,6 +112,23 @@ def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
         return None
     if (q_segment_ids is None) != (kv_segment_ids is None):
         return None
+    if dropout_seed is None:
+        dropout_seed = jnp.zeros((1,), jnp.int32)
+    local = functools.partial(_flash_bshd, causal=bool(causal),
+                              dropout_p=float(dropout_p))
+    from ..parallel.mesh import traced_mesh
+    mesh = traced_mesh()
+    if mesh is None or mesh.size == 1:
+        return local(q, k, v, bias, q_segment_ids, kv_segment_ids,
+                     dropout_seed)
+    return _partitioned(local, mesh, q, k, v, bias, q_segment_ids,
+                        kv_segment_ids, dropout_seed)
+
+
+def _flash_bshd(q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed,
+                causal, dropout_p):
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
     g = _head_group(h, d)
     # natural layout: (B, S, H, D) -> (B, S, H*D) is a free reshape
     qt = q.reshape(b, sq, h * d)
@@ -127,11 +144,36 @@ def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
         q_segment_ids = q_segment_ids.astype(jnp.int32)[:, :, None]
     if kv_segment_ids is not None:
         kv_segment_ids = kv_segment_ids.astype(jnp.int32)[:, None, :]
-    if dropout_seed is None:
-        dropout_seed = jnp.zeros((1,), jnp.int32)
     out = _flash(qt, kt, vt, bias, q_segment_ids, kv_segment_ids,
-                 dropout_seed, bool(causal), float(dropout_p), h, g)
+                 dropout_seed, causal, dropout_p, h, g)
     return out.reshape(b, sq, h, d)
+
+
+def _partitioned(local, mesh, q, k, v, bias, qseg, kseg, seed):
+    """The kernel inside a GSPMD program over `mesh`: Mosaic kernels cannot
+    be partitioned automatically, so each device runs the kernel on its own
+    shard — batch rows over "dp", heads over "tp" where they divide; any
+    other axis (and any indivisible one) sees the operands replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    def axis(name, size):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and size % n == 0 else None
+
+    b_ax, h_ax = axis("dp", q.shape[0]), axis("tp", q.shape[2])
+    qkv, per_row = P(b_ax, None, h_ax, None), P(b_ax, None)
+
+    def body(q, k, v, bias, qseg, kseg, seed):
+        # a shard must not repeat its neighbour's dropout mask
+        for ax, salt in ((b_ax, 7919), (h_ax, 104729)):
+            if ax is not None:
+                seed = seed + jax.lax.axis_index(ax) * jnp.int32(salt)
+        return local(q, k, v, bias, qseg, kseg, seed)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, per_row, per_row, per_row, P()),
+        out_specs=qkv, check_vma=False)(q, k, v, bias, qseg, kseg, seed)
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from ..core.tensor import Tensor, unwrap
 from ..jit import functional_call, state_arrays
 from ..nn.layer_base import Layer
 from . import sharding as shd
-from .mesh import get_mesh
+from .mesh import get_mesh, tracing_under
 from .strategy import DistributedStrategy
 
 
@@ -148,7 +148,8 @@ class ShardedTrainStep:
             def loss_of(tp):
                 full = dict(params)
                 full.update(tp)
-                return self._forward_loss(full, batch, rng_key)
+                with tracing_under(self.mesh):
+                    return self._forward_loss(full, batch, rng_key)
             train_params = {k: v for k, v in params.items() if k in trainable}
             fn = _recompute.checkpoint(loss_of) if self._remat else loss_of
             return jax.value_and_grad(fn)(train_params)

@@ -1,0 +1,137 @@
+"""The kernels the TPU branch can select, compiled for a described (not
+attached) v5e chip at the widths they run at.  The chip's compiler refuses
+things interpret mode accepts — a dot it cannot tile, a block that is not
+aligned, too much fast memory — and this file is where that shows without
+chip time.  Nothing runs: it says nothing about results or speed.
+
+The only file that describes the chip.  The topology is described inside a
+module-scoped fixture, never at import: one process at a time may load the
+TPU's library, and every xdist worker imports every test file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu.ops import fused_bn_act as fbn
+from paddle_tpu.ops import int8_matmul as i8
+from paddle_tpu.ops import paged_attention as pa
+
+# GPT-2-medium: 16 heads of 64, b4 s1024 under bf16 autocast
+B, S, H, D = 4, 1024, 16, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def chip_compile(one_chip):
+    """compile(fn, *(shape, dtype)) for the described chip, with the
+    persistent compilation cache off: such an executable is written to it
+    but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def compile_(fn, *avals):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in avals]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+QKV = ((B, S, H, D), jnp.bfloat16)
+SEED = ((1,), jnp.int32)
+
+
+def flash(q, k, v, seed, causal, dropout_p):
+    return fa._flash_bshd(q, k, v, None, None, None, seed, causal=causal,
+                          dropout_p=dropout_p)
+
+
+def flash_loss_grads(q, k, v, seed, causal, dropout_p):
+    return jax.grad(lambda q, k, v: flash(
+        q, k, v, seed, causal, dropout_p).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def test_flash_forward(chip_compile):
+    text = chip_compile(functools.partial(flash, causal=False,
+                                          dropout_p=0.0), QKV, QKV, QKV, SEED)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_flash_forward_backward_causal(chip_compile):
+    text = chip_compile(functools.partial(flash_loss_grads, causal=True,
+                                          dropout_p=0.0), QKV, QKV, QKV, SEED)
+    assert text.count("tpu_custom_call") == 2   # forward + merged backward
+
+
+@pytest.mark.parametrize("hw_prng", [True, False],
+                         ids=["hardware_prng", "hash_bits"])
+def test_flash_dropout_forward_backward(chip_compile, monkeypatch, hw_prng):
+    """In-kernel dropout through both bit-sources.  The program always takes
+    the hardware PRNG on a chip; the hash form is the interpreter's, held to
+    the same compiler so it stays a usable reference."""
+    monkeypatch.setattr(fa, "_HW_PRNG", hw_prng)
+    text = chip_compile(functools.partial(flash_loss_grads, causal=True,
+                                          dropout_p=0.1), QKV, QKV, QKV, SEED)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("m", [8, 512])
+def test_int8_dequant_matmul(chip_compile, m):
+    k, n = 1024, 4096                  # GPT-2-medium ffn_in
+    text = chip_compile(i8._pallas_matmul, ((m, k), jnp.bfloat16),
+                        ((k, n), jnp.int8), ((1, n), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+POOL = ((256, 16, H, D), jnp.bfloat16)  # blocks of 16 rows; 64 per slot
+
+
+def test_paged_attention_one_slot(chip_compile):
+    text = chip_compile(pa._pallas_paged_attention, ((H, D), jnp.bfloat16),
+                        POOL, POOL, ((64,), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_attention_vmapped_over_slots(chip_compile):
+    fn = jax.vmap(pa._pallas_paged_attention, in_axes=(0, None, None, 0, 0))
+    text = chip_compile(fn, ((8, H, D), jnp.bfloat16), POOL, POOL,
+                        ((8, 64), jnp.int32), ((8,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_bn_relu_forward_backward(chip_compile):
+    """ResNet-50 stage 2 at b256 in NHWC: (256*28*28, 512) rows, bf16."""
+    m, c = 256 * 28 * 28, 512
+    blk_m = fbn._block_m(m, c)
+
+    def loss_grads(x2, gamma, beta):
+        return jax.grad(lambda x2, gamma, beta: fbn._bn_act_p(
+            x2, gamma, beta, None, 1e-5, "relu", blk_m)[0].astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(x2, gamma, beta)
+
+    text = chip_compile(loss_grads, ((m, c), jnp.bfloat16),
+                        ((c,), jnp.float32), ((c,), jnp.float32))
+    assert text.count("tpu_custom_call") >= 3   # stats, apply, backward
