@@ -33,11 +33,18 @@ BATCH, SEQ, TRAIN_STEPS = 4, 1024, 6
 SLOTS, MAX_LEN, NEW_TOKENS = 8, 1024, 32
 # prompt lengths: 40 and 50 share the 64 bucket, 600 is past 512
 PROMPT_LENS = (5, 40, 50, 100, 300, 600)
-# bf16 autocast through an all-reduce: each device sums its own quarter of
-# the batch in another order than one device sums the whole, and bf16
-# activations carry 8 bits of mantissa, so the two losses (about 10.8) agree
-# to a few 1e-3 at best; 2e-2 leaves room and still fails on a wrong collective
-DP_LOSS_TOL = 2e-2
+# bf16 autocast through an all-reduce: each chip sums its own quarter of the
+# batch and the quarters are averaged, in another order than one chip sums the
+# whole, so the losses (about 11) are not bit-equal.  The chip read 5.6e-5
+# (PR 22); 1e-3 is twenty times that, and a collective that drops a shard or
+# sums where it should average moves the second loss by more than 1e-2.
+DP_LOSS_TOL = 1e-3
+
+
+def check(ok, *facts):
+    """An assert that `python -O` cannot remove."""
+    if not ok:
+        raise AssertionError(*facts)
 
 
 def emit(phase, **facts):
@@ -99,13 +106,13 @@ def train_phase(cfg, seed, batch=BATCH, seq=SEQ, steps=TRAIN_STEPS):
     before = attention_paths()
     warm = step.warmup(*data)          # compiles, applies no update
     paths = {k: v - before.get(k, 0) for k, v in attention_paths().items()}
-    assert paths.get("flash", 0) > 0 and paths.get("xla", 0) == 0, paths
-    text = compiled(step).as_text()
+    check(paths.get("flash", 0) > 0 and paths.get("xla", 0) == 0, paths)
+    kernel_in_step = "tpu_custom_call" in compiled(step).as_text()
     losses, seconds = run_steps(step, data, steps)
-    assert all(np.isfinite(losses)), losses
-    assert losses[-1] < losses[0], losses
+    check(all(np.isfinite(losses)), losses)
+    check(losses[-1] < losses[0], losses)
     for name, p in model.state_dict().items():
-        assert {d.platform for d in p._data.devices()} == {dev.platform}, name
+        check({d.platform for d in p._data.devices()} == {dev.platform}, name)
     store = programs.store_stats()
     emit("train", model="gpt2-medium", layers=cfg.num_hidden_layers,
          hidden=cfg.hidden_size, heads=cfg.num_attention_heads,
@@ -113,12 +120,14 @@ def train_phase(cfg, seed, batch=BATCH, seq=SEQ, steps=TRAIN_STEPS):
          params=int(sum(np.prod(p.shape) for p in model.parameters())),
          losses=losses, compile_seconds=warm["seconds"],
          step_seconds_smoke=seconds, attention_paths=paths,
-         pallas_kernel_in_step="tpu_custom_call" in text,
+         pallas_kernel_in_step=kernel_in_step,
          peak_bytes_in_use=(dev.memory_stats() or {}).get(
              "peak_bytes_in_use"),
          cache_dir=store["dir"], cache_hits=store["hits"],
          cache_misses=store["misses"])
-    return model, step, text
+    # the step dies here, and the optimizer state with it: serving needs
+    # the room
+    return model, kernel_in_step
 
 
 def serve_phase(model, cfg, seed, slots=SLOTS, max_len=MAX_LEN,
@@ -130,7 +139,7 @@ def serve_phase(model, cfg, seed, slots=SLOTS, max_len=MAX_LEN,
     engine = ServingEngine(model, max_slots=slots, max_len=max_len)
     warm = engine.warmup()
     bound = len(engine.buckets) + 1
-    assert engine.compile_counts()["total"] == bound, engine.compile_counts()
+    check(engine.compile_counts()["total"] == bound, engine.compile_counts())
     rng = np.random.RandomState(seed + 1)
     prompts = [rng.randint(0, cfg.vocab_size, n).astype("int32").tolist()
                for n in prompt_lens]
@@ -145,20 +154,20 @@ def serve_phase(model, cfg, seed, slots=SLOTS, max_len=MAX_LEN,
     engine.run_until_drained(timeout=600)
     total = time.perf_counter() - t0
     for r in responses:
-        assert r.done() and r.error is None and r.finish_reason == "length", (
-            r.request.id, r.finish_reason, r.error)
+        check(r.done() and r.error is None and r.finish_reason == "length", (
+            r.request.id, r.finish_reason, r.error))
     tokens = [r.tokens() for r in responses]
-    assert all(len(t) == new_tokens for t in tokens)
+    check(all(len(t) == new_tokens for t in tokens))
     counts = engine.compile_counts()
-    assert counts["total"] == bound and engine.post_warmup_compiles() == 0, (
-        counts, engine.post_warmup_compiles())
+    check(counts["total"] == bound and engine.post_warmup_compiles() == 0, (
+        counts, engine.post_warmup_compiles()))
     # the oracle: the same prompt alone through model.generate
-    check = 1
+    probe = 1
     solo, _ = model.generate(
-        paddle.to_tensor(np.asarray(prompts[check], np.int32)[None]),
+        paddle.to_tensor(np.asarray(prompts[probe], np.int32)[None]),
         max_new_tokens=new_tokens)
     solo = np.asarray(solo.numpy())[0].tolist()
-    assert tokens[check] == solo, (tokens[check], solo)
+    check(tokens[probe] == solo, (tokens[probe], solo))
     engine.close()
     paths = {k: v - before.get(k, 0) for k, v in attention_paths().items()}
     emit("serve", model="gpt2-medium", layers=cfg.num_hidden_layers,
@@ -203,19 +212,19 @@ def dp_phase(cfg, seed, n_dev=4, per_dev_batch=2, seq=SEQ, steps=2):
     model, step, dp_losses, dp_seconds = run(mesh)
     exe = compiled(step)
     ids_sharding = exe.input_shardings[0][-1][0]   # args -> batch -> ids
-    assert ids_sharding.device_set == set(devices)
-    assert ids_sharding.shard_shape((batch, seq)) == (per_dev_batch, seq)
+    check(ids_sharding.device_set == set(devices))
+    check(ids_sharding.shard_shape((batch, seq)) == (per_dev_batch, seq))
     for name, p in model.state_dict().items():
         on = {d.id for d in p._data.devices()}
-        assert on == {d.id for d in devices}, (name, on)
-        assert p._data.sharding.is_fully_replicated, name
+        check(on == {d.id for d in devices}, (name, on))
+        check(p._data.sharding.is_fully_replicated, name)
     text = exe.as_text()
-    assert "all-reduce" in text
+    check("all-reduce" in text)
     del model, step
     _, _, one_losses, one_seconds = run(None)
-    assert all(np.isfinite(dp_losses + one_losses))
+    check(all(np.isfinite(dp_losses + one_losses)))
     diffs = [abs(a - b) for a, b in zip(dp_losses, one_losses)]
-    assert max(diffs) < DP_LOSS_TOL, (dp_losses, one_losses)
+    check(max(diffs) < DP_LOSS_TOL, (dp_losses, one_losses))
     emit("dp", model="gpt2-medium", layers=cfg.num_hidden_layers,
          hidden=cfg.hidden_size, mesh={"dp": n_dev}, batch=batch, seq=seq,
          dp_losses=dp_losses, one_device_losses=one_losses,
@@ -248,9 +257,8 @@ def main():
                                         attention_probs_dropout_prob=0.0)
         dp_phase(cfg, args.seed)
     else:
-        model, step, text = train_phase(cfg, args.seed)
-        assert "tpu_custom_call" in text
-        del step, text                 # optimizer state: serving needs the room
+        model, kernel_in_step = train_phase(cfg, args.seed)
+        check(kernel_in_step, "no pallas kernel in the compiled train step")
         serve_phase(model, cfg, args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -62,7 +62,8 @@ def save_train_program(model, loss_fn, optimizer, path: str,
         opt_state)
 
     from jax import export as jax_export
-    exported = jax_export.export(compiled)(
+    # jax.export takes the jit itself, not the compile-tracking wrapper
+    exported = jax_export.export(getattr(compiled, "_jitted", compiled))(
         state_sds, opt_sds,
         jax.ShapeDtypeStruct((), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.float32),

@@ -268,13 +268,26 @@ def read_manifest(path: str) -> dict:
             "programs": sorted(body["programs"])}
 
 
-def _load_one(name: str, rec: dict, exe_ok: bool) -> LoadedProgram:
+def _engine_devices(engine) -> list:
+    """The devices the engine's programs were compiled for and run on: its
+    mesh, or the one device that holds its KV pool.  A native executable
+    must be loaded onto exactly these — jax's default is every device of
+    the backend, and an executable loaded for eight then refuses one
+    device's arguments."""
+    if engine.mesh is not None:
+        return list(engine.mesh.devices.flat)
+    return list(engine._pools[0][0].devices())
+
+
+def _load_one(name: str, rec: dict, exe_ok: bool,
+              devices: list) -> LoadedProgram:
     errors = {}
     if exe_ok and rec.get("exe") is not None:
         try:
             from jax.experimental import serialize_executable as _sx
             payload = pickle.loads(rec["exe"])
-            compiled = _sx.deserialize_and_load(*payload)
+            compiled = _sx.deserialize_and_load(
+                *payload, execution_devices=devices)
             return LoadedProgram(name, "exe", compiled)
         except Exception as e:  # noqa: BLE001 — fall through to stablehlo
             errors["exe"] = f"{type(e).__name__}: {e}"[:300]
@@ -323,9 +336,10 @@ def load_program_set(path: str, engine) -> Dict[str, LoadedProgram]:
     exe_ok = all(saved.get(k) == live.get(k) for k in _EXE_ONLY_KEYS)
     out: Dict[str, LoadedProgram] = {}
     errors = {}
+    devices = _engine_devices(engine)
     for n in wanted:
         try:
-            out[n] = _load_one(n, body["programs"][n], exe_ok)
+            out[n] = _load_one(n, body["programs"][n], exe_ok, devices)
         except ProgramSetError as e:
             errors[n] = str(e)
     if errors:

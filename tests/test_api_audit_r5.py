@@ -85,6 +85,8 @@ def test_reference_class_trees_fully_covered():
     reference files must resolve on our side."""
     import re
 
+    if not os.path.isdir(REF):
+        pytest.skip(f"the reference tree {REF} is not on this machine")
     import paddle_tpu.text as X
     import paddle_tpu.vision.datasets as D
     import paddle_tpu.vision.transforms as T

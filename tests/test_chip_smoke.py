@@ -35,9 +35,7 @@ def phase_lines(capsys):
 
 def test_train_then_serve_phases_tiny(interpret, capsys):
     cfg = tiny_cfg()
-    model, step, text = chip_smoke.train_phase(cfg, seed=0, batch=2, seq=128,
-                                               steps=6)
-    del step
+    model, _ = chip_smoke.train_phase(cfg, seed=0, batch=2, seq=128, steps=6)
     chip_smoke.serve_phase(model, cfg, seed=0, slots=4, max_len=128,
                            prompt_lens=(5, 20, 25, 40, 70, 100),
                            new_tokens=8)

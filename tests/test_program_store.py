@@ -306,8 +306,14 @@ def test_program_set_stablehlo_fallback_path(tmp_path):
     with open(path, "rb") as f:
         envelope = pickle.load(f)
     body = pickle.loads(envelope["body"])
-    for rec in body["programs"].values():
-        assert rec["exe"] is not None and rec["stablehlo"] is not None
+    assert body["programs"]["decode"]["exe"] is not None
+    for name, rec in body["programs"].items():
+        assert rec["stablehlo"] is not None
+        # the native form is optional by design, but never missing in
+        # silence: a backend that refuses one (XLA:CPU of jaxlib 0.9.0
+        # cannot serialize the prefill's sort thunk, "`LessThan` is not
+        # serializable") leaves its reason in the artifact
+        assert rec["exe"] is not None or "exe" in body["save_errors"][name]
         assert rec["donate"] == (1,)
         rec["exe"] = None
     import hashlib
