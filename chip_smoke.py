@@ -51,10 +51,12 @@ def emit(phase, **facts):
     print(json.dumps({"phase": phase, **facts}), flush=True)
 
 
-def attention_paths():
+def attention_paths(since=None):
+    """Attention calls traced so far by the form taken ({"flash": n,
+    "xla": n}), less an earlier reading."""
     from paddle_tpu.observability.metrics import get_registry
     m = get_registry().get("attention_path_total")
-    return {k[0]: v for k, v in m.samples()}
+    return {k[0]: v - (since or {}).get(k[0], 0) for k, v in m.samples()}
 
 
 def compiled(step):
@@ -63,13 +65,13 @@ def compiled(step):
     return exe
 
 
-def build(cfg, seed, lr=1e-4):
+def build(cfg, seed):
     import paddle_tpu as paddle
     from paddle_tpu import models
     paddle.seed(seed)
     model = models.GPTForPretraining(cfg)
     crit = models.GPTPretrainingCriterion()
-    opt = paddle.optimizer.AdamW(learning_rate=lr,
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
                                  parameters=model.parameters())
     return model, crit, opt
 
@@ -105,7 +107,7 @@ def train_phase(cfg, seed, batch=BATCH, seq=SEQ, steps=TRAIN_STEPS):
     data = fixed_batch(cfg, seed, batch, seq)
     before = attention_paths()
     warm = step.warmup(*data)          # compiles, applies no update
-    paths = {k: v - before.get(k, 0) for k, v in attention_paths().items()}
+    paths = attention_paths(since=before)
     check(paths.get("flash", 0) > 0 and paths.get("xla", 0) == 0, paths)
     kernel_in_step = "tpu_custom_call" in compiled(step).as_text()
     losses, seconds = run_steps(step, data, steps)
@@ -169,7 +171,7 @@ def serve_phase(model, cfg, seed, slots=SLOTS, max_len=MAX_LEN,
     solo = np.asarray(solo.numpy())[0].tolist()
     check(tokens[probe] == solo, (tokens[probe], solo))
     engine.close()
-    paths = {k: v - before.get(k, 0) for k, v in attention_paths().items()}
+    paths = attention_paths(since=before)
     emit("serve", model="gpt2-medium", layers=cfg.num_hidden_layers,
          hidden=cfg.hidden_size, slots=slots, max_len=max_len,
          buckets=list(engine.buckets), prompt_lens=list(prompt_lens),
@@ -251,12 +253,12 @@ def main():
 
     from paddle_tpu import models, programs
     programs.enable(os.path.join(HERE, ".jax_cache"))
-    cfg = models.gpt2_medium_config()
     if args.chips == 4:
-        cfg = models.gpt2_medium_config(hidden_dropout_prob=0.0,
-                                        attention_probs_dropout_prob=0.0)
-        dp_phase(cfg, args.seed)
+        dp_phase(models.gpt2_medium_config(hidden_dropout_prob=0.0,
+                                           attention_probs_dropout_prob=0.0),
+                 args.seed)
     else:
+        cfg = models.gpt2_medium_config()
         model, kernel_in_step = train_phase(cfg, args.seed)
         check(kernel_in_step, "no pallas kernel in the compiled train step")
         serve_phase(model, cfg, args.seed)
