@@ -40,8 +40,9 @@ def test_train_then_serve_phases_tiny(interpret, capsys):
                            prompt_lens=(5, 20, 25, 40, 70, 100),
                            new_tokens=8)
     train, serve = phase_lines(capsys)
-    assert train["phase"] == "train" and train["attention_paths"] == {
-        "flash": cfg.num_hidden_layers}
+    assert train["phase"] == "train"
+    assert train["attention_paths"]["flash"] == cfg.num_hidden_layers
+    assert not train["attention_paths"].get("xla")
     assert train["losses"][-1] < train["losses"][0]
     assert serve["phase"] == "serve" and serve["tokens_served"] == 6 * 8
     assert serve["compile_counts"]["total"] == len(serve["buckets"]) + 1
