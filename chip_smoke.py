@@ -211,7 +211,10 @@ def dp_phase(cfg, seed, n_dev=4, per_dev_batch=2, seq=SEQ, steps=2):
         return model, step, losses, seconds
 
     mesh = parallel.create_mesh({"dp": n_dev}, devices=devices)
+    before = attention_paths()
     model, step, dp_losses, dp_seconds = run(mesh)
+    paths = attention_paths(since=before)
+    check(paths.get("flash", 0) > 0 and paths.get("xla", 0) == 0, paths)
     exe = compiled(step)
     ids_sharding = exe.input_shardings[0][-1][0]   # args -> batch -> ids
     check(ids_sharding.device_set == set(devices))
@@ -222,6 +225,7 @@ def dp_phase(cfg, seed, n_dev=4, per_dev_batch=2, seq=SEQ, steps=2):
         check(p._data.sharding.is_fully_replicated, name)
     text = exe.as_text()
     check("all-reduce" in text)
+    kernel_in_step = "tpu_custom_call" in text
     del model, step
     _, _, one_losses, one_seconds = run(None)
     check(all(np.isfinite(dp_losses + one_losses)))
@@ -232,10 +236,11 @@ def dp_phase(cfg, seed, n_dev=4, per_dev_batch=2, seq=SEQ, steps=2):
          dp_losses=dp_losses, one_device_losses=one_losses,
          max_abs_loss_diff=max(diffs), tolerance=DP_LOSS_TOL,
          batch_shards_on_devices=n_dev, params_replicated_on_devices=n_dev,
-         all_reduce_in_step=True,
-         pallas_kernel_in_step="tpu_custom_call" in text,
+         all_reduce_in_step=True, attention_paths=paths,
+         pallas_kernel_in_step=kernel_in_step,
          dp_step_seconds_smoke=dp_seconds,
          one_device_step_seconds_smoke=one_seconds)
+    return kernel_in_step
 
 
 def main():
@@ -254,17 +259,20 @@ def main():
     from paddle_tpu import models, programs
     programs.enable(os.path.join(HERE, ".jax_cache"))
     if args.chips == 4:
-        dp_phase(models.gpt2_medium_config(hidden_dropout_prob=0.0,
-                                           attention_probs_dropout_prob=0.0),
-                 args.seed)
+        kernel_in_step = dp_phase(
+            models.gpt2_medium_config(hidden_dropout_prob=0.0,
+                                      attention_probs_dropout_prob=0.0),
+            args.seed)
+        check(kernel_in_step, "no pallas kernel in the compiled dp step")
     else:
         cfg = models.gpt2_medium_config()
         model, kernel_in_step = train_phase(cfg, args.seed)
         check(kernel_in_step, "no pallas kernel in the compiled train step")
         serve_phase(model, cfg, args.seed)
+    # count: the chips this run used, not every chip the host shows
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
-        "count": len(jax.devices())}}), flush=True)
+        "count": args.chips}}), flush=True)
 
 
 if __name__ == "__main__":

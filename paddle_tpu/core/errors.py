@@ -2,7 +2,7 @@
 
 Reference: paddle/fluid/platform/enforce.h (PADDLE_ENFORCE* macros raising
 EnforceNotMet with a typed error code) and paddle/fluid/platform/errors.h
-(the 12-code taxonomy: InvalidArgument, NotFound, OutOfRange, AlreadyExists,
+(the 12 error codes: InvalidArgument, NotFound, OutOfRange, AlreadyExists,
 ResourceExhausted, PreconditionNotMet, PermissionDenied, ExecutionTimeout,
 Unimplemented, Unavailable, Fatal, External).  TPU-native: each code is a
 Python exception that ALSO subclasses the builtin users naturally catch
@@ -56,7 +56,7 @@ class PermissionDeniedError(EnforceNotMet, PermissionError):
 
 class ExecutionTimeoutError(EnforceNotMet, TimeoutError, RuntimeError):
     # RuntimeError base kept for continuity: timeout paths (DataLoader)
-    # raised RuntimeError before the taxonomy existed
+    # raised RuntimeError before the typed codes existed
     code = "ExecutionTimeout"
 
 

@@ -327,9 +327,16 @@ class TrainStep:
 
     def __init__(self, model: Layer, loss_fn: Callable, optimizer,
                  amp_level: Optional[str] = None, amp_dtype="bfloat16",
-                 remat: bool = False,
+                 mesh=None, batch_sharding=None, remat: bool = False,
                  with_outputs: bool = False, guard: bool = False,
                  accum_steps: int = 1):
+        if mesh is not None or batch_sharding is not None:
+            # both were accepted and ignored: the step ran on one device
+            raise TypeError(
+                "TrainStep compiles for one device and never used mesh= / "
+                "batch_sharding=; for a step over a mesh use "
+                "paddle_tpu.parallel.ShardedTrainStep(model, loss_fn, "
+                "optimizer, mesh=mesh)")
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer

@@ -116,13 +116,15 @@ def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
         dropout_seed = jnp.zeros((1,), jnp.int32)
     local = functools.partial(_flash_bshd, causal=bool(causal),
                               dropout_p=float(dropout_p))
-    from ..parallel.mesh import traced_mesh
-    mesh = traced_mesh()
-    if mesh is None or mesh.size == 1:
-        return local(q, k, v, bias, q_segment_ids, kv_segment_ids,
-                     dropout_seed)
-    return _partitioned(local, mesh, q, k, v, bias, q_segment_ids,
-                        kv_segment_ids, dropout_seed)
+    args = (q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed)
+    # jax's own mesh context (`jax.set_mesh`): the axes of it that are not
+    # yet manual are the ones GSPMD would partition this program over
+    mesh = jax.sharding.get_abstract_mesh()
+    free = {a: mesh.shape[a] for a in mesh.axis_names
+            if a not in mesh.manual_axes}
+    if all(n == 1 for n in free.values()):
+        return local(*args)
+    return _per_shard(local, free, *args)
 
 
 def _flash_bshd(q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed,
@@ -149,31 +151,27 @@ def _flash_bshd(q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed,
     return out.reshape(b, sq, h, d)
 
 
-def _partitioned(local, mesh, q, k, v, bias, qseg, kseg, seed):
-    """The kernel inside a GSPMD program over `mesh`: Mosaic kernels cannot
-    be partitioned automatically, so each device runs the kernel on its own
-    shard — batch rows over "dp", heads over "tp" where they divide; any
-    other axis (and any indivisible one) sees the operands replicated."""
+def _per_shard(local, free, q, k, v, bias, qseg, kseg, seed):
+    """The kernel inside a GSPMD program over the mesh axes `free` (name ->
+    size): Mosaic kernels cannot be partitioned automatically, so each
+    device runs the kernel on its own batch rows ("dp").  Over any other
+    axis, and where the rows do not divide, every device sees the whole
+    operands."""
     from jax.sharding import PartitionSpec as P
-
-    def axis(name, size):
-        n = mesh.shape.get(name, 1)
-        return name if n > 1 and size % n == 0 else None
-
-    b_ax, h_ax = axis("dp", q.shape[0]), axis("tp", q.shape[2])
-    qkv, per_row = P(b_ax, None, h_ax, None), P(b_ax, None)
+    dp = free.get("dp", 1)
+    rows = "dp" if dp > 1 and q.shape[0] % dp == 0 else None
+    qkv, per_row = P(rows, None, None, None), P(rows, None)
 
     def body(q, k, v, bias, qseg, kseg, seed):
-        # a shard must not repeat its neighbour's dropout mask
-        for ax, salt in ((b_ax, 7919), (h_ax, 104729)):
-            if ax is not None:
-                seed = seed + jax.lax.axis_index(ax) * jnp.int32(salt)
+        if rows is not None:
+            # a shard must not repeat its neighbour's dropout mask
+            seed = seed + jax.lax.axis_index(rows) * jnp.int32(7919)
         return local(q, k, v, bias, qseg, kseg, seed)
 
     return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(qkv, qkv, qkv, per_row, per_row, per_row, P()),
-        out_specs=qkv, check_vma=False)(q, k, v, bias, qseg, kseg, seed)
+        body, in_specs=(qkv, qkv, qkv, per_row, per_row, per_row, P()),
+        out_specs=qkv, axis_names=frozenset(free),
+        check_vma=False)(q, k, v, bias, qseg, kseg, seed)
 
 
 # ---------------------------------------------------------------------------

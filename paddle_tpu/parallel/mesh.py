@@ -11,8 +11,6 @@ axis of size 1 simply disables that parallelism dimension.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -67,27 +65,6 @@ def get_mesh(create_default: bool = False) -> Optional[Mesh]:
     if _GLOBAL_MESH is None and create_default:
         _GLOBAL_MESH = create_mesh({"dp": len(jax.devices())})
     return _GLOBAL_MESH
-
-
-_traced = threading.local()
-
-
-@contextlib.contextmanager
-def tracing_under(mesh: Mesh):
-    """Name the mesh whose GSPMD program is being traced.  XLA partitions
-    its own ops from the argument shardings, but a pallas (Mosaic) kernel
-    "cannot be automatically partitioned": a kernel entry point that finds
-    a mesh here wraps itself in a `shard_map` over it."""
-    prev = traced_mesh()
-    _traced.mesh = mesh
-    try:
-        yield
-    finally:
-        _traced.mesh = prev
-
-
-def traced_mesh() -> Optional[Mesh]:
-    return getattr(_traced, "mesh", None)
 
 
 def axis_size(mesh: Mesh, axis: str) -> int:

@@ -30,7 +30,7 @@ from ..core.tensor import Tensor, unwrap
 from ..jit import functional_call, state_arrays
 from ..nn.layer_base import Layer
 from . import sharding as shd
-from .mesh import get_mesh, tracing_under
+from .mesh import get_mesh
 from .strategy import DistributedStrategy
 
 
@@ -148,8 +148,7 @@ class ShardedTrainStep:
             def loss_of(tp):
                 full = dict(params)
                 full.update(tp)
-                with tracing_under(self.mesh):
-                    return self._forward_loss(full, batch, rng_key)
+                return self._forward_loss(full, batch, rng_key)
             train_params = {k: v for k, v in params.items() if k in trainable}
             fn = _recompute.checkpoint(loss_of) if self._remat else loss_of
             return jax.value_and_grad(fn)(train_params)
@@ -275,8 +274,9 @@ class ShardedTrainStep:
         raw_batch = tuple(jax.device_put(unwrap(b), self._batch_sharding)
                           for b in batch)
         from ..jit import warm_step_program
-        did = warm_step_program(self._compiled, state, self._opt_state,
-                                self.optimizer, raw_batch)
+        with jax.set_mesh(self.mesh):
+            did = warm_step_program(self._compiled, state, self._opt_state,
+                                    self.optimizer, raw_batch)
         return {"seconds": _time.perf_counter() - t0, "compiled": did}
 
     def __call__(self, *batch):
@@ -303,8 +303,11 @@ class ShardedTrainStep:
         rng_key = _rng.next_key()
         raw_batch = tuple(jax.device_put(unwrap(b), self._batch_sharding)
                           for b in batch)
-        out = self._compiled(
-            state, self._opt_state, step_no, lr, rng_key, raw_batch)
+        # traced under jax's own mesh context: a pallas kernel in the step
+        # reads it to run per shard (GSPMD cannot partition one)
+        with jax.set_mesh(self.mesh):
+            out = self._compiled(
+                state, self._opt_state, step_no, lr, rng_key, raw_batch)
         if self._guard:
             new_state, self._opt_state, loss, gnorm, ok = out
             self.last_guard = (gnorm, ok)

@@ -152,22 +152,36 @@ def _manifest_mismatches(saved: dict, live: dict) -> list:
     return bad
 
 
-def _export_one(raw_jitted, tracked, args):
-    """(exe_blob | None, stablehlo_blob | None, errors) for one program.
-    The native executable is taken from the TrackedJit's AOT cache when
-    the program is already compiled (warmup ran), so saving a warm
-    engine recompiles nothing."""
+def _serialize_native(raw_jitted, tracked, args, donate):
+    """The pickled native executable of one program.  It is taken from the
+    TrackedJit's AOT cache when the program is already compiled (warmup
+    ran), so saving a warm engine recompiles nothing — unless the backend
+    refuses an executable that has run: XLA:CPU's sort thunk resolves its
+    comparator on first execution and from then on answers "`LessThan` is
+    not serializable".  jax hands the same executable back for the same
+    jit, so the program is compiled once more through a jit of its own
+    and that never-executed executable is serialized."""
+    import jax
+    from jax.experimental import serialize_executable as _sx
+    compiled = None
+    if tracked is not None and hasattr(tracked, "compiled_for"):
+        compiled = tracked.compiled_for(*args)
+    if compiled is None:
+        compiled = raw_jitted.lower(*args).compile()
+    try:
+        payload = _sx.serialize(compiled)
+    except jax.errors.JaxRuntimeError:
+        fresh = jax.jit(lambda *a: raw_jitted(*a), donate_argnums=donate)
+        payload = _sx.serialize(fresh.lower(*args).compile())
+    return pickle.dumps(payload, protocol=pickle.HIGHEST_PROTOCOL)
+
+
+def _export_one(raw_jitted, tracked, args, donate):
+    """(exe_blob | None, stablehlo_blob | None, errors) for one program."""
     errors = {}
     exe_blob = stablehlo_blob = None
     try:
-        from jax.experimental import serialize_executable as _sx
-        compiled = None
-        if tracked is not None and hasattr(tracked, "compiled_for"):
-            compiled = tracked.compiled_for(*args)
-        if compiled is None:
-            compiled = raw_jitted.lower(*args).compile()
-        exe_blob = pickle.dumps(_sx.serialize(compiled),
-                                protocol=pickle.HIGHEST_PROTOCOL)
+        exe_blob = _serialize_native(raw_jitted, tracked, args, donate)
     except Exception as e:  # noqa: BLE001 — representation is optional
         errors["exe"] = f"{type(e).__name__}: {e}"[:300]
     try:
@@ -198,7 +212,8 @@ def save_program_set(engine, path: str,
                     f"program {name!r} was itself loaded from a program "
                     "set — re-exporting a loaded set is not supported; "
                     "save from a traced engine")
-            exe_blob, hlo_blob, errors = _export_one(raw, fn, args)
+            exe_blob, hlo_blob, errors = _export_one(
+                raw, fn, args, tuple(donate))
             if exe_blob is None and hlo_blob is None:
                 raise ProgramSetError(
                     f"program {name!r} could not be serialized in any "

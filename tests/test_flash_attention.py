@@ -235,14 +235,14 @@ def test_sdpa_routes_through_flash():
 
 @pytest.mark.parametrize("axes", [{"dp": 4}, {"dp": 2, "tp": 2},
                                   {"dp": 3}, {"sp": 2}])
-def test_kernel_under_a_traced_mesh_matches_one_device(axes):
+def test_kernel_under_a_mesh_matches_one_device(axes):
     """Inside a GSPMD program a Mosaic kernel cannot be partitioned by XLA:
-    under `tracing_under(mesh)` the kernel wraps itself in a shard_map
-    (batch over dp, heads over tp; an axis that does not divide, or any
-    other axis, sees replicated operands).  Forward and gradients must
+    traced under jax's mesh context (`jax.set_mesh`) the kernel wraps
+    itself in a shard_map (batch rows over dp; rows that do not divide, and
+    any other axis, see the whole operands).  Forward and gradients must
     equal the unpartitioned kernel, padding bias and segments included."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from paddle_tpu.parallel.mesh import create_mesh, tracing_under
+    from paddle_tpu.parallel.mesh import create_mesh
     mesh = create_mesh(axes)
     q, k, v = rand_qkv(b=4, h=2)
     bias = jnp.where(jnp.arange(256)[None, :] < jnp.asarray(
@@ -255,36 +255,52 @@ def test_kernel_under_a_traced_mesh_matches_one_device(axes):
             q, k, v, causal=True, bias=bias, q_segment_ids=seg,
             kv_segment_ids=seg) ** 2).sum()
 
-    def meshed(q, k, v, bias):
-        with tracing_under(mesh):
-            return loss(q, k, v, bias)
-
     want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))(
         q, k, v, bias)
     rows = NamedSharding(mesh, P("dp") if axes.get("dp", 1) in (2, 4)
                          else P())
-    got = jax.jit(jax.value_and_grad(meshed, argnums=(0, 1, 2, 3)))(
-        *(jax.device_put(x, rows) for x in (q, k, v, bias)))
+    with jax.set_mesh(mesh):
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+        args = [jax.device_put(x, rows) for x in (q, k, v, bias)]
+        assert "shard_map" in str(jax.make_jaxpr(step)(*args))
+        got = step(*args)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
 
 
+def test_kernel_inside_a_manual_shard_map_is_not_wrapped_again():
+    """`ShardedTrainStep(fp16_allreduce)` runs the model inside its own
+    shard_map: every mesh axis is manual there, the kernel sees its local
+    rows and must run as it is."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.parallel.mesh import create_mesh
+    q, k, v = rand_qkv(b=4, h=2)
+
+    def attend(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+    want = jax.jit(attend)(q, k, v)
+    with jax.set_mesh(create_mesh({"dp": 4})):
+        local = jax.jit(jax.shard_map(attend, in_specs=P("dp"),
+                                      out_specs=P("dp"), check_vma=False))
+        assert str(jax.make_jaxpr(local)(q, k, v)).count("shard_map") == 1
+        got = local(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
 def test_partitioned_dropout_masks_differ_between_shards():
     """Each shard folds its mesh position into the seed: two devices that
     hold identical rows must not draw the same keep mask."""
-    from paddle_tpu.parallel.mesh import create_mesh, tracing_under
-    mesh = create_mesh({"dp": 2})
+    from paddle_tpu.parallel.mesh import create_mesh
     q0 = jnp.zeros((2, 256, 2, 64), jnp.float32)
     v1 = jnp.ones((2, 256, 2, 64), jnp.float32)
     seed = jnp.asarray([7], jnp.int32)
 
-    @jax.jit
     def run(q, v):
-        with tracing_under(mesh):
-            return fa.flash_attention_bshd(q, q, v, dropout_p=0.5,
-                                           dropout_seed=seed)
-    out = np.asarray(run(q0, v1))
+        return fa.flash_attention_bshd(q, q, v, dropout_p=0.5,
+                                       dropout_seed=seed)
+    with jax.set_mesh(create_mesh({"dp": 2})):
+        out = np.asarray(jax.jit(run)(q0, v1))
     assert abs(out.mean() - 1.0) < 0.05
     assert not np.array_equal(out[0], out[1])
 

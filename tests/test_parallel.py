@@ -120,6 +120,21 @@ def test_sharded_step_matches_single_device(axes, st_kw):
     np.testing.assert_allclose(losses, ref, rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("kw", [{"mesh": "m"}, {"batch_sharding": "dp"}])
+def test_one_device_step_refuses_a_mesh_and_names_the_sharded_step(kw):
+    """jit.TrainStep used to accept mesh= / batch_sharding= and ignore
+    them (a 'dp' run on one device); now the caller is told where to go."""
+    from paddle_tpu.jit import TrainStep
+    model, crit = _gpt_tiny()
+    opt = paddle.optimizer.Adam(learning_rate=1e-2,
+                                parameters=model.parameters())
+    if "mesh" in kw:
+        kw = {"mesh": parallel.create_mesh({"dp": 8})}
+    with pytest.raises(TypeError, match="parallel.ShardedTrainStep"):
+        TrainStep(model, lambda logits, label: crit(logits, label), opt,
+                  **kw)
+
+
 def test_fsdp_params_actually_sharded():
     paddle.seed(0)
     model, crit = _gpt_tiny()

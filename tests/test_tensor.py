@@ -169,7 +169,7 @@ def test_shape_op():
     assert paddle.rank(t).item() == 2
 
 
-def test_typed_error_taxonomy():
+def test_typed_error_codes():
     """enforce.h/errors.h parity: typed codes that also subclass the
     natural builtin (so existing `except ValueError` keeps working)."""
     from paddle_tpu.core import errors as E
