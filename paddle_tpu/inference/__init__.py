@@ -375,8 +375,7 @@ class ServingPredictor:
                 opts["draft_model"] = quantize_for_serving(draft, quantize)
         self._config = config
         try:
-            self.engine = ServingEngine(model, profile=config._profile,
-                                        **opts)
+            self.engine = ServingEngine(model, **opts)
         except Exception as e:
             from ..programs.program_set import ProgramSetError
             if not isinstance(e, ProgramSetError) or "program_set" not in opts:
@@ -395,8 +394,7 @@ class ServingPredictor:
             except Exception:
                 pass
             opts.pop("program_set", None)
-            self.engine = ServingEngine(model, profile=config._profile,
-                                        **opts)
+            self.engine = ServingEngine(model, **opts)
         if warmup:
             self.engine.warmup()
         self.gateway = None
@@ -446,9 +444,9 @@ class ServingPredictor:
         return serve_gateway(self.gateway, port=port, addr=addr)
 
     def profile_report(self) -> Dict:
-        """Config knobs + profiler spans + live serving metrics in one
-        report (enable_profile additionally records serving_prefill /
-        serving_decode spans in the profiler table)."""
+        """Config knobs + profiler spans (the engine's `serving_*` phases
+        among them, always recorded) + live serving metrics in one
+        report."""
         rep = _profile_report(self._config, self.engine.metrics())
         if self.gateway is not None:
             gm = self.gateway.metrics()

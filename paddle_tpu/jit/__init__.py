@@ -28,6 +28,7 @@ from ..core.layout import layout_policy  # noqa: F401  (public: jit.layout_polic
 from ..core.recompute import recompute_policy  # noqa: F401  (public: jit.recompute_policy)
 from ..core.tensor import Tensor, no_grad, unwrap
 from ..nn.layer_base import Layer
+from ..observability.tracer import span as _span
 
 
 # ---------------------------------------------------------------------------
@@ -846,35 +847,41 @@ class TrainStep:
         return {"seconds": _time.perf_counter() - t0, "compiled": did}
 
     def __call__(self, *batch):
-        from ..observability import span as _span
-        with _span("train_step"), _step_hist().time():
+        with _span("train_step",
+                   args={"step": self.optimizer._step_count + 1}), \
+                _step_hist().time():
             return self._call_inner(*batch)
 
     def _call_inner(self, *batch):
-        state = state_arrays(self.model)
-        if self._opt_state is None:
-            self._opt_state = self.init_opt_state(state)
-        self._ensure_compiled(state, batch)
-        self.optimizer._step_count += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        step_no = jnp.asarray(self.optimizer._step_count, jnp.int32)
-        from ..core import rng as _rng
-        rng_key = _rng.next_key()  # fresh per step: dropout masks differ
-        raw_batch = tuple(unwrap(b) for b in batch)
-        state, self._opt_state, raw_batch = self._place_for_row_shard(
-            state, self._opt_state, raw_batch)
-        out = self._compiled(
-            state, self._opt_state, step_no, lr, rng_key, raw_batch)
-        if self._guard:
-            new_state, self._opt_state, loss, outs, gnorm, ok = out
-            self.last_guard = (gnorm, ok)
-        else:
-            new_state, self._opt_state, loss, outs = out
-        self.last_outputs = (tuple(Tensor(o) for o in outs)
-                             if outs else None)
-        sd = self.model.state_dict()
-        for k, v in new_state.items():
-            sd[k]._set_data(v)
+        with _span("train_step_gather_state"):
+            state = state_arrays(self.model)
+            if self._opt_state is None:
+                self._opt_state = self.init_opt_state(state)
+        with _span("train_step_dispatch"):
+            self._ensure_compiled(state, batch)
+            self.optimizer._step_count += 1
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            step_no = jnp.asarray(self.optimizer._step_count, jnp.int32)
+            from ..core import rng as _rng
+            rng_key = _rng.next_key()  # fresh per step: dropout masks differ
+            raw_batch = tuple(unwrap(b) for b in batch)
+            # after the build, which probes with the state as the model
+            # holds it; a no-op unless a table is row-sharded over a mesh
+            state, self._opt_state, raw_batch = self._place_for_row_shard(
+                state, self._opt_state, raw_batch)
+            out = self._compiled(
+                state, self._opt_state, step_no, lr, rng_key, raw_batch)
+        with _span("train_step_write_back"):
+            if self._guard:
+                new_state, self._opt_state, loss, outs, gnorm, ok = out
+                self.last_guard = (gnorm, ok)
+            else:
+                new_state, self._opt_state, loss, outs = out
+            self.last_outputs = (tuple(Tensor(o) for o in outs)
+                                 if outs else None)
+            sd = self.model.state_dict()
+            for k, v in new_state.items():
+                sd[k]._set_data(v)
         return Tensor(loss)
 
     # -- checkpointing (single-device variant of ShardedTrainStep's) ---------

@@ -11,9 +11,14 @@ Replaces `utils/profiler.py`'s module-global `_records`/`_events` (which
 were mutated without a lock from serving-engine threads); that module is
 now a lock-correct compat shim over this tracer.
 
-The device half stays jax.profiler: `span(..., annotate=True)` opens a
-`jax.profiler.TraceAnnotation` alongside the host span so host spans line
-up with the XLA device timeline in TensorBoard/perfetto.
+The device half stays jax.profiler: every span also opens a
+`jax.profiler.TraceAnnotation`, so in any profiler session, whoever
+started it, the program's spans lie in the host plane of the `.xplane.pb`
+on the device events' clock.  With no session active an annotation checks
+one flag (a few hundred nanoseconds: PERF.md, PR 27), so there is no
+switch to remember.  `Tracer.record` takes a span whose start lies in the
+past (a queue wait is known only when it ends); such a span is in the ring
+only, an annotation cannot be back-dated.
 """
 from __future__ import annotations
 
@@ -24,6 +29,11 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+try:  # the tracer itself is pure host; without jax it records the ring only
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except ImportError:  # pragma: no cover
+    _TraceAnnotation = None
 
 __all__ = ["Span", "Tracer", "get_tracer", "span"]
 
@@ -38,7 +48,7 @@ class Span:
                  "dur", "args", "_annotation", "_ended")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 parent: Optional["Span"] = None, annotate: bool = False,
+                 parent: Optional["Span"] = None,
                  args: Optional[dict] = None):
         self.tracer = tracer
         self.name = name
@@ -54,13 +64,10 @@ class Span:
         self.parent_id = parent.span_id if parent is not None else None
         stack.append(self)
         self._annotation = None
-        if annotate:
-            try:  # jax optional here: the tracer itself is pure host
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+        if _TraceAnnotation is not None:
+            self._annotation = (_TraceAnnotation(name, **args) if args
+                                else _TraceAnnotation(name))
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
 
     def end(self):
@@ -70,10 +77,7 @@ class Span:
         now = time.perf_counter()
         self.dur = now - self.t0
         if self._annotation is not None:
-            try:
-                self._annotation.__exit__(None, None, None)
-            except Exception:
-                pass
+            self._annotation.__exit__(None, None, None)
         stack = self.tracer._stack()
         if self in stack:  # pop through abandoned children
             while stack and stack[-1] is not self:
@@ -137,20 +141,35 @@ class Tracer:
             s = self._tls.stack = []
         return s
 
-    def _record(self, sp: Span):
+    def _append(self, name, t0, dur, tid, span_id, parent_id, args):
         with self._lock:
-            rec = self._agg.get(sp.name)
+            rec = self._agg.get(name)
             if rec is None:
-                rec = self._agg[sp.name] = [0, 0.0]
+                rec = self._agg[name] = [0, 0.0]
             rec[0] += 1
-            rec[1] += sp.dur
-            self._ring.append((sp.name, sp.t0, sp.dur, sp.tid, sp.span_id,
-                               sp.parent_id, sp.args))
+            rec[1] += dur
+            self._ring.append((name, t0, dur, tid, span_id, parent_id, args))
+
+    def _record(self, sp: Span):
+        self._append(sp.name, sp.t0, sp.dur, sp.tid, sp.span_id,
+                     sp.parent_id, sp.args)
 
     # -- recording -----------------------------------------------------------
     def span(self, name: str, parent: Optional[Span] = None,
-             annotate: bool = False, args: Optional[dict] = None) -> Span:
-        return Span(self, name, parent=parent, annotate=annotate, args=args)
+             args: Optional[dict] = None) -> Span:
+        return Span(self, name, parent=parent, args=args)
+
+    def record(self, name: str, t0: float, t1: float,
+               parent: Optional[Span] = None,
+               args: Optional[dict] = None) -> int:
+        """A closed span whose start lies in the past, on this tracer's
+        clock (`time.perf_counter`).  It takes no parent from the thread's
+        stack (it need not lie inside what is open now) and is no
+        annotation; returns its id."""
+        sid = next(self._ids)
+        self._append(name, t0, t1 - t0, threading.get_ident(), sid,
+                     parent.span_id if parent is not None else None, args)
+        return sid
 
     def light_span(self, name: str) -> _LightSpan:
         """Minimal-overhead span for per-op hot paths (see _LightSpan)."""
@@ -219,8 +238,7 @@ def get_tracer() -> Tracer:
     return _default_tracer
 
 
-def span(name: str, parent: Optional[Span] = None, annotate: bool = False,
+def span(name: str, parent: Optional[Span] = None,
          args: Optional[dict] = None) -> Span:
     """Open a span on the default tracer (context manager)."""
-    return _default_tracer.span(name, parent=parent, annotate=annotate,
-                                args=args)
+    return Span(_default_tracer, name, parent=parent, args=args)

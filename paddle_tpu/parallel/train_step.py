@@ -29,6 +29,7 @@ from ..core import recompute as _recompute
 from ..core.tensor import Tensor, unwrap
 from ..jit import functional_call, state_arrays
 from ..nn.layer_base import Layer
+from ..observability.tracer import span as _span
 from . import sharding as shd
 from .mesh import get_mesh
 from .strategy import DistributedStrategy
@@ -281,41 +282,48 @@ class ShardedTrainStep:
 
     def __call__(self, *batch):
         from ..jit import _step_hist
-        from ..observability import span as _span
-        with _span("sharded_train_step"), _step_hist().time():
+        # the phases carry TrainStep's names: one reduction and one set of
+        # metrics read both steps (the program registry name stays)
+        with _span("train_step",
+                   args={"step": self.optimizer._step_count + 1}), \
+                _step_hist().time():
             return self._call_inner(*batch)
 
     def _call_inner(self, *batch):
-        if not self._placed:
-            self.place_params()
-        state = state_arrays(self.model)
-        if self._opt_state is None:
-            raw = self.init_opt_state(state)
-            shardings = self._ensure_opt_shardings()
-            self._opt_state = jax.device_put(raw, shardings)
-        if self._compiled is None:
-            self._n_batch = len(batch)
-            self._compiled = self._build(self._opt_state_shardings)
-        self.optimizer._step_count += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        step_no = jnp.asarray(self.optimizer._step_count, jnp.int32)
-        from ..core import rng as _rng
-        rng_key = _rng.next_key()
-        raw_batch = tuple(jax.device_put(unwrap(b), self._batch_sharding)
-                          for b in batch)
-        # traced under jax's own mesh context: a pallas kernel in the step
-        # reads it to run per shard (GSPMD cannot partition one)
-        with jax.set_mesh(self.mesh):
-            out = self._compiled(
-                state, self._opt_state, step_no, lr, rng_key, raw_batch)
-        if self._guard:
-            new_state, self._opt_state, loss, gnorm, ok = out
-            self.last_guard = (gnorm, ok)
-        else:
-            new_state, self._opt_state, loss = out
-        sd = self.model.state_dict()
-        for k, v in new_state.items():
-            sd[k]._set_data(v)
+        with _span("train_step_gather_state"):
+            if not self._placed:
+                self.place_params()
+            state = state_arrays(self.model)
+            if self._opt_state is None:
+                raw = self.init_opt_state(state)
+                shardings = self._ensure_opt_shardings()
+                self._opt_state = jax.device_put(raw, shardings)
+        with _span("train_step_dispatch"):
+            if self._compiled is None:
+                self._n_batch = len(batch)
+                self._compiled = self._build(self._opt_state_shardings)
+            self.optimizer._step_count += 1
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            step_no = jnp.asarray(self.optimizer._step_count, jnp.int32)
+            from ..core import rng as _rng
+            rng_key = _rng.next_key()
+            raw_batch = tuple(
+                jax.device_put(unwrap(b), self._batch_sharding)
+                for b in batch)
+            # traced under jax's own mesh context: a pallas kernel in the
+            # step reads it to run per shard (GSPMD cannot partition one)
+            with jax.set_mesh(self.mesh):
+                out = self._compiled(
+                    state, self._opt_state, step_no, lr, rng_key, raw_batch)
+        with _span("train_step_write_back"):
+            if self._guard:
+                new_state, self._opt_state, loss, gnorm, ok = out
+                self.last_guard = (gnorm, ok)
+            else:
+                new_state, self._opt_state, loss = out
+            sd = self.model.state_dict()
+            for k, v in new_state.items():
+                sd[k]._set_data(v)
         return Tensor(loss)
 
     # -- checkpointing -------------------------------------------------------

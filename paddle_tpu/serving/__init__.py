@@ -128,7 +128,7 @@ the family as one AOT artifact, and ``ServingEngine(program_set=path)`` /
 see `paddle_tpu.programs` and the README "Program lifecycle" section.
 
 Metrics (all live under `metrics()`, the STAT_serving_* monitor counters,
-and — with profiling enabled — the profiler report): ttft_p50_ms,
+and the predictor's profile report): ttft_p50_ms,
 inter_token_ms, tokens_per_sec, queue_depth, slot_occupancy,
 requests_completed/errored, STAT_serving_{requests,rejects,tokens,
 prefills,decode_steps,compiles,queue_depth,slots_active,cancelled,

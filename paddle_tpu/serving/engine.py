@@ -92,6 +92,7 @@ import numpy as np
 
 from ..core.errors import FatalError, InvalidArgumentError, UnavailableError
 from ..generation import process_logits_dynamic
+from ..observability.tracer import span as _span
 from ..utils import faults
 from ..utils.monitor import stat_add
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
@@ -297,7 +298,7 @@ class _SlotRun:
         self.pos = pos              # kv length so far (write offset)
         self.produced = 1           # first token came from prefill
         self.last_token = first_token
-        self.last_token_at = time.monotonic()
+        self.last_token_at = time.perf_counter()
         self.key = key
         self.aid = aid              # pinned adapter slot id (0 = base)
 
@@ -370,7 +371,7 @@ class ServingEngine:
 
     def __init__(self, model, max_slots: int = 8, max_len: int = 256,
                  prefill_buckets=None, max_queue_depth: int = 64,
-                 pad_token_id: int = 0, dtype=None, profile: bool = False,
+                 pad_token_id: int = 0, dtype=None,
                  decode_chunk: int = 4, draft_model=None,
                  spec_tokens: int = 4, kv: str = "fixed",
                  block_size: int = 16, num_blocks: Optional[int] = None,
@@ -388,7 +389,6 @@ class ServingEngine:
                 f"prefill bucket {self.buckets[-1]} exceeds max_len "
                 f"{self.max_len}")
         self._dtype = dtype
-        self._profile = bool(profile)
         # tokens decoded per compiled decode call (an internal lax.scan):
         # amortizes the per-call host+dispatch cost across chunk tokens per
         # slot.  Tokens stream in bursts of `chunk`; admission, deadline
@@ -572,6 +572,7 @@ class ServingEngine:
         self._donate = (1,)
         self._compiles = {"decode": 0, "prefill": {b: 0 for b in self.buckets}}
         self._decode_calls = 0  # slow_decode fault stride counter
+        self._steps = 0  # steps that had work: the `serving_step` span's id
         # speculative decoding: a draft model swaps the decode program for
         # the single verify program and adds a draft slot pool + draft
         # prefill folded into the per-bucket prefill programs — the
@@ -650,9 +651,6 @@ class ServingEngine:
             "gap between consecutive tokens of one request")
         # metrics accumulators
         self._m_lock = threading.Lock()
-        self._ttfts: List[float] = []
-        self._itl_sum = 0.0
-        self._itl_n = 0
         self._tokens_out = 0
         self._completed = 0
         self._errored = 0
@@ -1568,18 +1566,38 @@ class ServingEngine:
         """One engine iteration: sweep deadlines/cancels, admit waiting
         requests into free slots (one bucketed prefill each), then advance
         every occupied slot one token with the single decode program.
-        Returns whether any work was done."""
+        Returns whether any work was done.
+
+        Each phase is a span of the one tracer and an annotation in any
+        profiler session (names in PERF.md section 3, a contract with the
+        benchmark): `serving_step` > `serving_sweep`, `serving_admit` >
+        (`serving_prefill_dispatch`, `serving_prefill_wait`),
+        `serving_decode` | `serving_verify` > (`serving_batch_rebuild`,
+        `serving_decode_dispatch`, `serving_token_pull`,
+        `serving_deliver`).  None per token and none per slot: counts
+        ride in the spans' `args`."""
+        if not self.has_work():
+            # an idle loop polls every 2 ms: it does nothing below, and
+            # must not fill the tracer's ring with empty steps
+            return False
+        self._steps += 1
+        with _span("serving_step", args={"step": self._steps}):
+            return self._step()
+
+    def _step(self) -> bool:
         did = False
-        self._sweep()
-        dropped = self.scheduler.sweep_pending(
-            drop=((self._queued_never_fits, self._queued_exhausted_exc)
-                  if self.kv == "paged" else None))
-        if dropped:
-            with self._m_lock:
-                self._errored += dropped
+        with _span("serving_sweep"):
+            self._sweep()
+            dropped = self.scheduler.sweep_pending(
+                drop=((self._queued_never_fits, self._queued_exhausted_exc)
+                      if self.kv == "paged" else None))
+            if dropped:
+                with self._m_lock:
+                    self._errored += dropped
+            if self.kv == "paged":
+                did = self._sweep_oom_paused()
         gate = None
         if self.kv == "paged":
-            did = self._sweep_oom_paused() or did
             # OOM-parked runs hold progress and arrived earlier: they get
             # first claim on freed slots + blocks, before new admissions
             did = self._restore_oom_paused() or did
@@ -1744,159 +1762,159 @@ class ServingEngine:
                           ).astype(np.uint32)
 
     def _admit(self, req: Request, resp: Response, slot: int):
-        if self.prefix_cache is not None:
-            return self._admit_prefix(req, resp, slot)
-        span = self._span("serving_prefill")
-        try:
-            plen = req.prompt.shape[0]
-            bucket = self._bucket_for(plen)
-            aid = 0
-            if self.lora is not None:
-                # resolve + PIN the adapter for the life of the slot (the
-                # registry cannot evict a pinned adapter).  The request
-                # was validated at make_request, but the adapter may have
-                # been evicted while it queued — typed terminal failure,
-                # never a hung consumer.
-                try:
-                    aid = self._lora_reg.acquire(req.adapter)
-                except Exception as e:
-                    stat_add("STAT_lora_rejects")
-                    with self._m_lock:
-                        self._errored += 1
-                    resp._fail(e)
-                    self.scheduler.release(slot)
-                    return
-            if self.kv == "paged":
-                # claim the prompt's blocks; only reachable without them
-                # when PDTPU_FAULT_KV_EXHAUST moved the cap between the
-                # admission gate and here — typed terminal, never a hang
-                if not self.kv_pool.alloc(slot, bucket):
-                    stat_add("STAT_serving_kv_exhausted")
-                    with self._m_lock:
-                        self._errored += 1
-                    resp._fail(KVPoolExhaustedError(
-                        f"request {req.id}: KV block pool exhausted at "
-                        f"admission ({self.kv_pool.free_blocks()} free of "
-                        f"{self.kv_pool.capacity()} usable)"))
-                    self.scheduler.release(slot)
-                    if self._lora_reg is not None and aid:
-                        self._lora_reg.release(aid)
-                    return
-                slot_arg = jnp.asarray(self.kv_pool.table_array(slot))
-            else:
-                slot_arg = jnp.int32(slot)
-            ids = np.full((1, bucket), self.pad_token_id, np.int32)
-            ids[0, :plen] = req.prompt
-            key = self._request_key(req)
-            if self.draft_model is not None:
-                (tok, logp, finite, self._pools,
-                 self._draft_pools) = self._prefill_fns[bucket](
-                    self._state, self._dstate, self._pools,
-                    self._draft_pools, jnp.asarray(ids), slot_arg,
-                    jnp.int32(plen), jnp.asarray(key),
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jnp.float32(req.top_p), jnp.asarray(req.greedy))
-            elif self.lora is not None:
-                # the adapter id is an ordinary dynamic input: a new
-                # adapter NEVER means a new program
-                tok, logp, finite, self._pools = self._prefill_fns[bucket](
-                    self._state, self._pools, self._lora_reg.device_args(),
-                    jnp.asarray(ids), slot_arg, jnp.int32(plen),
-                    jnp.int32(aid), jnp.asarray(key),
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jnp.float32(req.top_p), jnp.asarray(req.greedy))
-            else:
-                tok, logp, finite, self._pools = self._prefill_fns[bucket](
-                    self._state, self._pools, jnp.asarray(ids),
-                    slot_arg, jnp.int32(plen), jnp.asarray(key),
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jnp.float32(req.top_p), jnp.asarray(req.greedy))
+        """One admission: the bucketed prefill enqueued, its first token
+        pulled (the pull blocks on the chip), the run seated in its slot."""
+        plen = int(req.prompt.shape[0])
+        with _span("serving_admit", args={"request": req.id, "slot": slot,
+                                          "plen": plen}) as sp:
+            with _span("serving_prefill_dispatch"):
+                out = (self._prefill_cached(req, resp, slot, plen, sp.args)
+                       if self.prefix_cache is not None
+                       else self._prefill(req, resp, slot, plen, sp.args))
+            if out is None:  # failed terminally before any program ran
+                return
+            tok, logp, finite, key, aid = out
             stat_add("STAT_serving_prefills")
-            if not bool(finite):
+            with _span("serving_prefill_wait"):
+                ok = bool(finite)
+                if ok:
+                    tok = int(tok)
+            if not ok:
                 # the run is not in _slots yet — _release won't see the
                 # pin, drop it here
                 if self._lora_reg is not None and aid:
                     self._lora_reg.release(aid)
                 self._fail_slot(slot, resp, "prefill")
                 return
-            tok = int(tok)
+            if self.prefix_cache is not None:
+                self.prefix_cache.insert(
+                    self._share_key(req), req.prompt,
+                    self.kv_pool.block_ids(slot)[:plen // self.block_size])
             run = _SlotRun(req, resp, pos=plen, first_token=tok, key=key,
                            aid=aid)
             self._slots[slot] = run
             self._batch_dirty = True
             self._emit(run, tok, float(logp))
-            stat_add("STAT_serving_tokens")
+            self._count_tokens(1)
             self._maybe_finish(slot, run, tok)
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
 
-    def _admit_prefix(self, req: Request, resp: Response, slot: int):
-        """Warm-path admission: adopt the longest cached prefix chain
-        into the slot's table, COW the final block when the whole prompt
-        is cached, and prefill ONLY the uncached suffix (per-slot
-        dynamic `cached_len` into the same per-bucket program family —
-        `cached_len == 0` IS the cold path, so a miss costs nothing
-        extra and the compile bound is unchanged)."""
-        span = self._span("serving_prefill")
-        try:
-            plen = int(req.prompt.shape[0])
-            share_key = self._share_key(req)
-            plan = self._cached_plan(req, record=True)
-
-            def exhausted(stage):
+    def _prefill(self, req: Request, resp: Response, slot: int, plen: int,
+                 span_args: dict):
+        """Host preparation and the enqueue of the cold prefill program.
+        -> (tok, logp, finite, key, adapter id), all still on the device,
+        or None when the request failed terminally before the call."""
+        bucket = span_args["bucket"] = self._bucket_for(plen)
+        aid = 0
+        if self.lora is not None:
+            # resolve + PIN the adapter for the life of the slot (the
+            # registry cannot evict a pinned adapter).  The request
+            # was validated at make_request, but the adapter may have
+            # been evicted while it queued — typed terminal failure,
+            # never a hung consumer.
+            try:
+                aid = self._lora_reg.acquire(req.adapter)
+            except Exception as e:
+                stat_add("STAT_lora_rejects")
+                with self._m_lock:
+                    self._errored += 1
+                resp._fail(e)
+                self.scheduler.release(slot)
+                return None
+        if self.kv == "paged":
+            # claim the prompt's blocks; only reachable without them
+            # when PDTPU_FAULT_KV_EXHAUST moved the cap between the
+            # admission gate and here — typed terminal, never a hang
+            if not self.kv_pool.alloc(slot, bucket):
                 stat_add("STAT_serving_kv_exhausted")
                 with self._m_lock:
                     self._errored += 1
                 resp._fail(KVPoolExhaustedError(
                     f"request {req.id}: KV block pool exhausted at "
-                    f"admission/{stage} ({self.kv_pool.free_blocks()} "
-                    f"free of {self.kv_pool.capacity()} usable)"))
+                    f"admission ({self.kv_pool.free_blocks()} free of "
+                    f"{self.kv_pool.capacity()} usable)"))
                 self.scheduler.release(slot)
-
-            if plan.chain and not self.kv_pool.adopt(slot, plan.chain):
-                return exhausted("adopt")
-            if plan.cow:
-                pair = self.kv_pool.cow_last(slot)
-                if pair is None:
-                    self.kv_pool.free(slot)
-                    return exhausted("cow")
-                src, dst = pair
-                # device copy BEFORE any program can write the new block
-                self._pools = self._cow_fn(self._pools, jnp.int32(src),
-                                           jnp.int32(dst))
-                self.prefix_cache.note_cow()
-            if not self.kv_pool.ensure(slot, plan.cached_len + plan.bucket):
-                self.kv_pool.free(slot)
-                return exhausted("suffix")
+                if self._lora_reg is not None and aid:
+                    self._lora_reg.release(aid)
+                return None
             slot_arg = jnp.asarray(self.kv_pool.table_array(slot))
-            suffix = plen - plan.cached_len
-            ids = np.full((1, plan.bucket), self.pad_token_id, np.int32)
-            ids[0, :suffix] = req.prompt[plan.cached_len:]
-            key = self._request_key(req)
-            tok, logp, finite, self._pools = self._prefill_fns[plan.bucket](
-                self._state, self._pools, jnp.asarray(ids), slot_arg,
-                jnp.int32(plen), jnp.int32(plan.cached_len),
-                jnp.asarray(key), jnp.float32(req.temperature),
-                jnp.int32(req.top_k), jnp.float32(req.top_p),
-                jnp.asarray(req.greedy))
-            stat_add("STAT_serving_prefills")
-            if not bool(finite):
-                self._fail_slot(slot, resp, "prefill")
-                return
-            self.prefix_cache.insert(
-                share_key, req.prompt,
-                self.kv_pool.block_ids(slot)[:plen // self.block_size])
-            tok = int(tok)
-            run = _SlotRun(req, resp, pos=plen, first_token=tok, key=key)
-            self._slots[slot] = run
-            self._batch_dirty = True
-            self._emit(run, tok, float(logp))
-            stat_add("STAT_serving_tokens")
-            self._maybe_finish(slot, run, tok)
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
+        else:
+            slot_arg = jnp.int32(slot)
+        ids = np.full((1, bucket), self.pad_token_id, np.int32)
+        ids[0, :plen] = req.prompt
+        key = self._request_key(req)
+        if self.draft_model is not None:
+            (tok, logp, finite, self._pools,
+             self._draft_pools) = self._prefill_fns[bucket](
+                self._state, self._dstate, self._pools,
+                self._draft_pools, jnp.asarray(ids), slot_arg,
+                jnp.int32(plen), jnp.asarray(key),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p), jnp.asarray(req.greedy))
+        elif self.lora is not None:
+            # the adapter id is an ordinary dynamic input: a new
+            # adapter NEVER means a new program
+            tok, logp, finite, self._pools = self._prefill_fns[bucket](
+                self._state, self._pools, self._lora_reg.device_args(),
+                jnp.asarray(ids), slot_arg, jnp.int32(plen),
+                jnp.int32(aid), jnp.asarray(key),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p), jnp.asarray(req.greedy))
+        else:
+            tok, logp, finite, self._pools = self._prefill_fns[bucket](
+                self._state, self._pools, jnp.asarray(ids),
+                slot_arg, jnp.int32(plen), jnp.asarray(key),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p), jnp.asarray(req.greedy))
+        return tok, logp, finite, key, aid
+
+    def _prefill_cached(self, req: Request, resp: Response, slot: int,
+                        plen: int, span_args: dict):
+        """Warm-path `_prefill`: adopt the longest cached prefix chain
+        into the slot's table, COW the final block when the whole prompt
+        is cached, and prefill ONLY the uncached suffix (per-slot
+        dynamic `cached_len` into the same per-bucket program family —
+        `cached_len == 0` IS the cold path, so a miss costs nothing
+        extra and the compile bound is unchanged)."""
+        plan = self._cached_plan(req, record=True)
+        span_args["bucket"] = plan.bucket
+
+        def exhausted(stage):
+            stat_add("STAT_serving_kv_exhausted")
+            with self._m_lock:
+                self._errored += 1
+            resp._fail(KVPoolExhaustedError(
+                f"request {req.id}: KV block pool exhausted at "
+                f"admission/{stage} ({self.kv_pool.free_blocks()} "
+                f"free of {self.kv_pool.capacity()} usable)"))
+            self.scheduler.release(slot)
+
+        if plan.chain and not self.kv_pool.adopt(slot, plan.chain):
+            return exhausted("adopt")
+        if plan.cow:
+            pair = self.kv_pool.cow_last(slot)
+            if pair is None:
+                self.kv_pool.free(slot)
+                return exhausted("cow")
+            src, dst = pair
+            # device copy BEFORE any program can write the new block
+            self._pools = self._cow_fn(self._pools, jnp.int32(src),
+                                       jnp.int32(dst))
+            self.prefix_cache.note_cow()
+        if not self.kv_pool.ensure(slot, plan.cached_len + plan.bucket):
+            self.kv_pool.free(slot)
+            return exhausted("suffix")
+        slot_arg = jnp.asarray(self.kv_pool.table_array(slot))
+        suffix = plen - plan.cached_len
+        ids = np.full((1, plan.bucket), self.pad_token_id, np.int32)
+        ids[0, :suffix] = req.prompt[plan.cached_len:]
+        key = self._request_key(req)
+        tok, logp, finite, self._pools = self._prefill_fns[plan.bucket](
+            self._state, self._pools, jnp.asarray(ids), slot_arg,
+            jnp.int32(plen), jnp.int32(plan.cached_len),
+            jnp.asarray(key), jnp.float32(req.temperature),
+            jnp.int32(req.top_k), jnp.float32(req.top_p),
+            jnp.asarray(req.greedy))
+        return tok, logp, finite, key, 0
 
     # ------------------------------------------------------------------
     # gateway admission: direct placement, preemption, restore
@@ -2332,8 +2350,9 @@ class ServingEngine:
         if self.draft_model is not None:
             self._spec_step()
             return
-        span = self._span("serving_decode")
-        try:
+        with _span("serving_decode", args={
+                "active": len(self._slots),
+                "calls": self._decode_calls + 1}) as sp:
             if self.kv == "paged":
                 # grow block tables for this chunk's writes (may preempt
                 # or fail runs under pool pressure — membership can
@@ -2341,83 +2360,90 @@ class ServingEngine:
                 self._ensure_decode_blocks()
                 if not self._slots:
                     return
+                sp.args["active"] = len(self._slots)
             if self._batch_dirty:
-                self._rebuild_batch()
-            # PDTPU_FAULT_SLOW_DECODE: host-side latency injection, read
-            # live per call — overload/SLO-miss paths become testable on
-            # CPU without a big model
-            faults.maybe_slow_decode(self._decode_calls)
-            self._decode_calls += 1
-            keys, temp, top_k, top_p, greedy, poison, _ = self._dev_params
-            if self.kv == "paged":
-                tables, active = self._paged_batch()
-                if self.lora is not None:
+                with _span("serving_batch_rebuild"):
+                    self._rebuild_batch()
+            with _span("serving_decode_dispatch"):
+                # PDTPU_FAULT_SLOW_DECODE: host-side latency injection,
+                # read live per call — overload/SLO-miss paths become
+                # testable on CPU without a big model
+                faults.maybe_slow_decode(self._decode_calls)
+                self._decode_calls += 1
+                keys, temp, top_k, top_p, greedy, poison, _ = \
+                    self._dev_params
+                if self.kv == "paged":
+                    tables, active = self._paged_batch()
+                    if self.lora is not None:
+                        (toks, logps, finites, ntok, npos,
+                         self._pools) = self._decode_fn(
+                            self._state, self._pools,
+                            self._lora_reg.device_args(), tables, active,
+                            self._dev_tokens, self._dev_pos, self._dev_aids,
+                            keys, temp, top_k, top_p, greedy, poison)
+                    else:
+                        (toks, logps, finites, ntok, npos,
+                         self._pools) = self._decode_fn(
+                            self._state, self._pools, tables, active,
+                            self._dev_tokens, self._dev_pos, keys, temp,
+                            top_k, top_p, greedy, poison)
+                elif self.lora is not None:
                     (toks, logps, finites, ntok, npos,
                      self._pools) = self._decode_fn(
                         self._state, self._pools,
-                        self._lora_reg.device_args(), tables, active,
-                        self._dev_tokens, self._dev_pos, self._dev_aids,
-                        keys, temp, top_k, top_p, greedy, poison)
+                        self._lora_reg.device_args(), self._dev_tokens,
+                        self._dev_pos, self._dev_aids, keys, temp, top_k,
+                        top_p, greedy, poison)
                 else:
                     (toks, logps, finites, ntok, npos,
                      self._pools) = self._decode_fn(
-                        self._state, self._pools, tables, active,
-                        self._dev_tokens, self._dev_pos, keys, temp, top_k,
-                        top_p, greedy, poison)
-            elif self.lora is not None:
-                (toks, logps, finites, ntok, npos,
-                 self._pools) = self._decode_fn(
-                    self._state, self._pools, self._lora_reg.device_args(),
-                    self._dev_tokens, self._dev_pos, self._dev_aids, keys,
-                    temp, top_k, top_p, greedy, poison)
-            else:
-                (toks, logps, finites, ntok, npos,
-                 self._pools) = self._decode_fn(
-                    self._state, self._pools, self._dev_tokens,
-                    self._dev_pos, keys, temp, top_k, top_p, greedy,
-                    poison)
-            self._dev_tokens, self._dev_pos = ntok, npos
-            # one device->host pull for the whole (chunk, slots) burst
-            toks, logps, finites = jax.device_get((toks, logps, finites))
+                        self._state, self._pools, self._dev_tokens,
+                        self._dev_pos, keys, temp, top_k, top_p, greedy,
+                        poison)
+                self._dev_tokens, self._dev_pos = ntok, npos
+            with _span("serving_token_pull"):
+                # one device->host pull for the whole (chunk, slots) burst
+                toks, logps, finites = jax.device_get(
+                    (toks, logps, finites))
             stat_add("STAT_serving_decode_steps")
-            emitted = 0
-            for slot in list(self._slots):
-                run = self._slots[slot]
-                for j in range(toks.shape[0]):
-                    # deadline enforcement on the decode tick itself, not
-                    # only at the next sweep: a budget that expired while
-                    # the chunk was computing stops the stream here — no
-                    # post-expiry tokens are delivered, the slot recycles
-                    # now (regression: deadline shorter than one chunk)
-                    if (run.req.deadline is not None
-                            and run.req.deadline.expired()):
-                        stat_add("STAT_serving_deadline_expired")
-                        run.resp._fail(DeadlineExceededError(
-                            f"request {run.req.id} deadline "
-                            f"({run.req.deadline.seconds}s) expired "
-                            "mid-decode"))
-                        self._release(slot)
-                        break
-                    if not finites[j, slot]:
-                        self._fail_slot(slot, run.resp, "decode")
-                        break
-                    t = int(toks[j, slot])
-                    run.pos += 1
-                    run.produced += 1
-                    run.last_token = t
-                    self._emit(run, t, float(logps[j, slot]))
-                    emitted += 1
-                    self._maybe_finish(slot, run, t)
-                    if slot not in self._slots:
-                        # finished mid-chunk: the tail iterations of this
-                        # slot are discarded (their KV garbage dies with
-                        # the slot's next prefill)
-                        break
-            if emitted:
-                stat_add("STAT_serving_tokens", emitted)
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
+            with _span("serving_deliver") as deliver:
+                emitted, seated = 0, len(self._slots)
+                for slot in list(self._slots):
+                    run = self._slots[slot]
+                    for j in range(toks.shape[0]):
+                        # deadline enforcement on the decode tick itself,
+                        # not only at the next sweep: a budget that expired
+                        # while the chunk was computing stops the stream
+                        # here — no post-expiry tokens are delivered, the
+                        # slot recycles now (regression: deadline shorter
+                        # than one chunk)
+                        if (run.req.deadline is not None
+                                and run.req.deadline.expired()):
+                            stat_add("STAT_serving_deadline_expired")
+                            run.resp._fail(DeadlineExceededError(
+                                f"request {run.req.id} deadline "
+                                f"({run.req.deadline.seconds}s) expired "
+                                "mid-decode"))
+                            self._release(slot)
+                            break
+                        if not finites[j, slot]:
+                            self._fail_slot(slot, run.resp, "decode")
+                            break
+                        t = int(toks[j, slot])
+                        run.pos += 1
+                        run.produced += 1
+                        run.last_token = t
+                        self._emit(run, t, float(logps[j, slot]))
+                        emitted += 1
+                        self._maybe_finish(slot, run, t)
+                        if slot not in self._slots:
+                            # finished mid-chunk: the tail iterations of
+                            # this slot are discarded (their KV garbage
+                            # dies with the slot's next prefill)
+                            break
+                self._count_tokens(emitted)
+                deliver.args = {"tokens": emitted,
+                                "finished": seated - len(self._slots)}
 
     def _spec_step(self):
         """One speculative tick: K draft proposals + one batched target
@@ -2426,94 +2452,101 @@ class ServingEngine:
         commit up to K+1 tokens, and a deadline that expired while the
         tick was computing stops the stream BEFORE the next commit — no
         post-expiry token is ever delivered."""
-        span = self._span("serving_verify")
-        try:
+        with _span("serving_verify", args={
+                "active": len(self._slots),
+                "calls": self._decode_calls + 1}) as sp:
             if self.kv == "paged":
                 self._ensure_decode_blocks()
                 if not self._slots:
                     return
+                sp.args["active"] = len(self._slots)
             if self._batch_dirty:
-                self._rebuild_batch()
-            tick_no = self._decode_calls  # lifetime stride counter: the
-            # diverge fault keys off it, NOT _spec_ticks, which is a
-            # metrics-window counter reset_metrics() zeroes
-            faults.maybe_slow_decode(tick_no)
-            self._decode_calls += 1
-            keys, temp, top_k, top_p, greedy, poison, spec_on = \
-                self._dev_params
-            diverge = bool(self._diverge_every is not None
-                           and tick_no % self._diverge_every == 0)
-            self._spec_ticks += 1
-            if self.kv == "paged":
-                tables, active = self._paged_batch()
-                (toks, logps, finites, counts, accepts, last, npos,
-                 self._pools, self._draft_pools) = self._decode_fn(
-                    self._state, self._dstate, self._pools,
-                    self._draft_pools, tables, active, self._dev_tokens,
-                    self._dev_pos, keys, temp, top_k, top_p, greedy,
-                    spec_on, poison, jnp.asarray(diverge))
-            else:
-                (toks, logps, finites, counts, accepts, last, npos,
-                 self._pools, self._draft_pools) = self._decode_fn(
-                    self._state, self._dstate, self._pools,
-                    self._draft_pools, self._dev_tokens, self._dev_pos,
-                    keys, temp, top_k, top_p, greedy, spec_on, poison,
-                    jnp.asarray(diverge))
-            self._dev_tokens, self._dev_pos = last, npos
-            # one device->host pull for the whole (slots, K+1) tick
-            toks, logps, finites, counts, accepts = jax.device_get(
-                (toks, logps, finites, counts, accepts))
+                with _span("serving_batch_rebuild"):
+                    self._rebuild_batch()
+            with _span("serving_decode_dispatch"):
+                tick_no = self._decode_calls  # lifetime stride counter:
+                # the diverge fault keys off it, NOT _spec_ticks, which is
+                # a metrics-window counter reset_metrics() zeroes
+                faults.maybe_slow_decode(tick_no)
+                self._decode_calls += 1
+                keys, temp, top_k, top_p, greedy, poison, spec_on = \
+                    self._dev_params
+                diverge = bool(self._diverge_every is not None
+                               and tick_no % self._diverge_every == 0)
+                self._spec_ticks += 1
+                if self.kv == "paged":
+                    tables, active = self._paged_batch()
+                    (toks, logps, finites, counts, accepts, last, npos,
+                     self._pools, self._draft_pools) = self._decode_fn(
+                        self._state, self._dstate, self._pools,
+                        self._draft_pools, tables, active,
+                        self._dev_tokens, self._dev_pos, keys, temp, top_k,
+                        top_p, greedy, spec_on, poison,
+                        jnp.asarray(diverge))
+                else:
+                    (toks, logps, finites, counts, accepts, last, npos,
+                     self._pools, self._draft_pools) = self._decode_fn(
+                        self._state, self._dstate, self._pools,
+                        self._draft_pools, self._dev_tokens, self._dev_pos,
+                        keys, temp, top_k, top_p, greedy, spec_on, poison,
+                        jnp.asarray(diverge))
+                self._dev_tokens, self._dev_pos = last, npos
+            with _span("serving_token_pull"):
+                # one device->host pull for the whole (slots, K+1) tick
+                toks, logps, finites, counts, accepts = jax.device_get(
+                    (toks, logps, finites, counts, accepts))
             stat_add("STAT_serving_decode_steps")
             stat_add("STAT_spec_ticks")
             k_spec = self.spec_tokens
-            emitted = proposed = accepted_n = 0
-            for slot in list(self._slots):
-                run = self._slots[slot]
-                if not finites[slot]:
-                    self._fail_slot(slot, run.resp, "verify")
-                    continue
-                if run.req.spec:
-                    proposed += k_spec
-                    accepted_n += int(accepts[slot])
-                    self._h_accept.observe(int(accepts[slot]) / k_spec)
-                for j in range(int(counts[slot])):
-                    # deadline enforcement on the tick itself (PR-6 rule):
-                    # a speculative tick may hold K+1 ready tokens, but a
-                    # budget that expired mid-tick delivers none of the
-                    # remainder — the slot recycles now (regression:
-                    # deadline shorter than one speculative tick)
-                    if (run.req.deadline is not None
-                            and run.req.deadline.expired()):
-                        stat_add("STAT_serving_deadline_expired")
-                        run.resp._fail(DeadlineExceededError(
-                            f"request {run.req.id} deadline "
-                            f"({run.req.deadline.seconds}s) expired "
-                            "mid-decode"))
-                        self._release(slot)
-                        break
-                    t = int(toks[slot, j])
-                    run.pos += 1
-                    run.produced += 1
-                    run.last_token = t
-                    self._emit(run, t, float(logps[slot, j]))
-                    emitted += 1
-                    self._maybe_finish(slot, run, t)
-                    if slot not in self._slots:
-                        # finished mid-tick: the tail commits are
-                        # discarded (their KV garbage dies with the
-                        # slot's next prefill)
-                        break
-            if emitted:
-                stat_add("STAT_serving_tokens", emitted)
-            if proposed:
-                stat_add("STAT_spec_proposed", proposed)
-                stat_add("STAT_spec_accepted", accepted_n)
-                with self._m_lock:
-                    self._spec_proposed += proposed
-                    self._spec_accepted += accepted_n
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
+            with _span("serving_deliver") as deliver:
+                emitted = proposed = accepted_n = 0
+                seated = len(self._slots)
+                for slot in list(self._slots):
+                    run = self._slots[slot]
+                    if not finites[slot]:
+                        self._fail_slot(slot, run.resp, "verify")
+                        continue
+                    if run.req.spec:
+                        proposed += k_spec
+                        accepted_n += int(accepts[slot])
+                        self._h_accept.observe(int(accepts[slot]) / k_spec)
+                    for j in range(int(counts[slot])):
+                        # deadline enforcement on the tick itself (PR-6
+                        # rule): a speculative tick may hold K+1 ready
+                        # tokens, but a budget that expired mid-tick
+                        # delivers none of the remainder — the slot
+                        # recycles now (regression: deadline shorter than
+                        # one speculative tick)
+                        if (run.req.deadline is not None
+                                and run.req.deadline.expired()):
+                            stat_add("STAT_serving_deadline_expired")
+                            run.resp._fail(DeadlineExceededError(
+                                f"request {run.req.id} deadline "
+                                f"({run.req.deadline.seconds}s) expired "
+                                "mid-decode"))
+                            self._release(slot)
+                            break
+                        t = int(toks[slot, j])
+                        run.pos += 1
+                        run.produced += 1
+                        run.last_token = t
+                        self._emit(run, t, float(logps[slot, j]))
+                        emitted += 1
+                        self._maybe_finish(slot, run, t)
+                        if slot not in self._slots:
+                            # finished mid-tick: the tail commits are
+                            # discarded (their KV garbage dies with the
+                            # slot's next prefill)
+                            break
+                self._count_tokens(emitted)
+                if proposed:
+                    stat_add("STAT_spec_proposed", proposed)
+                    stat_add("STAT_spec_accepted", accepted_n)
+                    with self._m_lock:
+                        self._spec_proposed += proposed
+                        self._spec_accepted += accepted_n
+                deliver.args = {"tokens": emitted,
+                                "finished": seated - len(self._slots)}
 
     def _fail_slot(self, slot: int, resp: Response, phase: str):
         stat_add("STAT_serving_nonfinite")
@@ -2525,21 +2558,22 @@ class ServingEngine:
         self._release(slot)
 
     def _emit(self, run: _SlotRun, tok: int, logp: float):
-        now = time.monotonic()
+        now = time.perf_counter()
         first = run.resp.first_token_at is None
         run.resp._push_token(tok, logp)
-        with self._m_lock:
-            self._tokens_out += 1
-            if first:
-                self._ttfts.append(run.resp.ttft)
-            else:
-                self._itl_sum += now - run.last_token_at
-                self._itl_n += 1
         if first:
             self._h_ttft.observe(run.resp.ttft)
         else:
             self._h_itl.observe(now - run.last_token_at)
         run.last_token_at = now
+
+    def _count_tokens(self, n: int):
+        """Tokens delivered by one admission or one decode call: counted
+        once a call, not under a lock a token."""
+        if n:
+            stat_add("STAT_serving_tokens", n)
+            with self._m_lock:
+                self._tokens_out += n
 
     def _maybe_finish(self, slot: int, run: _SlotRun, tok: int):
         eos = run.req.eos_token_id
@@ -2553,12 +2587,6 @@ class ServingEngine:
             self._completed += 1
         run.resp._finish(reason)
         self._release(slot)
-
-    def _span(self, name: str):
-        if not self._profile:
-            return None
-        from ..utils.profiler import RecordEvent
-        return RecordEvent(name).__enter__()
 
     # ------------------------------------------------------------------
     # driving
@@ -2858,12 +2886,14 @@ class ServingEngine:
 
     def metrics(self) -> Dict:
         """Serving metrics snapshot (also published as STAT_serving_*
-        monitor counters and, under enable_profile, in the profiler
-        report)."""
+        monitor counters and in the predictor's profile report)."""
+        # the two latency figures come from the registry's histograms, the
+        # one account `_emit` keeps: a bucket quantile and a mean over
+        # every engine of the process since the registry was last reset
+        p50 = self._h_ttft.quantile(0.5)
+        gaps = self._h_itl.snapshot()
+        itl = gaps["sum"] / gaps["count"] if gaps["count"] else None
         with self._m_lock:
-            ttfts = sorted(self._ttfts)
-            p50 = ttfts[len(ttfts) // 2] if ttfts else None
-            itl = self._itl_sum / self._itl_n if self._itl_n else None
             elapsed = time.monotonic() - self._started_at
             return {
                 "requests_completed": self._completed,
@@ -2917,9 +2947,6 @@ class ServingEngine:
 
     def reset_metrics(self):
         with self._m_lock:
-            self._ttfts = []
-            self._itl_sum = 0.0
-            self._itl_n = 0
             self._tokens_out = 0
             self._completed = 0
             self._errored = 0

@@ -16,6 +16,7 @@ from typing import List, Optional
 import numpy as np
 
 from ..core.errors import EnforceNotMet
+from ..observability.tracer import get_tracer
 from ..utils.retry import Deadline
 
 __all__ = ["Request", "Response", "RequestCancelled"]
@@ -106,6 +107,11 @@ class Response:
     engine produces them.  Terminal state is exactly one of: finished
     (`finish_reason` in {"eos", "length"}), or errored (`error` set —
     rejection, cancellation, deadline expiry, non-finite logits).
+
+    Its times (`submitted_at`, `admitted_at` when it left the queue for a
+    slot, `first_token_at`, `finished_at`) are on `time.perf_counter`,
+    the tracer's clock; reaching the terminal state records the
+    `serving_request` span from submission to there.
     """
 
     def __init__(self, request: Request):
@@ -114,7 +120,8 @@ class Response:
         self._lock = threading.Lock()
         self._tokens: List[int] = []
         self._done = threading.Event()
-        self.submitted_at = time.monotonic()
+        self.submitted_at = time.perf_counter()
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.finish_reason: Optional[str] = None
@@ -124,7 +131,7 @@ class Response:
 
     # -- engine side --------------------------------------------------------
     def _push_token(self, tok: int, logp: float = 0.0):
-        now = time.monotonic()
+        now = time.perf_counter()
         with self._lock:
             if self.first_token_at is None:
                 self.first_token_at = now
@@ -132,24 +139,35 @@ class Response:
             self.logprob += float(logp)
         self._q.put((_TOK, int(tok)))
 
-    def _finish(self, reason: str):
+    def _end(self, reason: str, exc: Optional[BaseException]) -> bool:
+        """Enter the terminal state once; False if already there."""
         with self._lock:
             if self._done.is_set():
-                return
-            self.finished_at = time.monotonic()
+                return False
+            self.finished_at = time.perf_counter()
             self.finish_reason = reason
+            self.error = exc
+            tokens = len(self._tokens)
             self._done.set()
-        self._q.put((_END, reason))
+        if isinstance(self.request, Request):
+            # (the gateway fails a stub for a submission that never became
+            # a request: that has no timeline)
+            args = {"request": self.request.id,
+                    "prompt": int(self.request.prompt.shape[0]),
+                    "tokens": tokens, "finish": reason}
+            if exc is not None:
+                args["error"] = type(exc).__name__
+            get_tracer().record("serving_request", self.submitted_at,
+                                self.finished_at, args=args)
+        return True
+
+    def _finish(self, reason: str):
+        if self._end(reason, None):
+            self._q.put((_END, reason))
 
     def _fail(self, exc: BaseException):
-        with self._lock:
-            if self._done.is_set():
-                return
-            self.finished_at = time.monotonic()
-            self.finish_reason = "error"
-            self.error = exc
-            self._done.set()
-        self._q.put((_ERR, exc))
+        if self._end("error", exc):
+            self._q.put((_ERR, exc))
 
     # -- caller side --------------------------------------------------------
     def cancel(self):

@@ -14,10 +14,12 @@ exactly like a retry loop's backoff does.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Optional, Tuple
 
 from ..core.errors import ResourceExhaustedError, ExecutionTimeoutError
+from ..observability.tracer import get_tracer
 from ..utils.monitor import stat_add
 from .request import Request, Response, RequestCancelled
 
@@ -51,6 +53,14 @@ class QueueFullError(ResourceExhaustedError):
 class DeadlineExceededError(ExecutionTimeoutError):
     """The request's wall-clock deadline passed before it finished."""
     code = "ExecutionTimeout"
+
+
+def _note_admitted(req: Request, resp: Response):
+    """The request leaves the queue for a slot: stamp it and record its
+    wait, from submission, as a span that carries its id."""
+    resp.admitted_at = time.perf_counter()
+    get_tracer().record("serving_queue_wait", resp.submitted_at,
+                        resp.admitted_at, args={"request": req.id})
 
 
 class RequestScheduler:
@@ -147,6 +157,7 @@ class RequestScheduler:
                 self._active[slot] = (req, resp)
                 stat_add("STAT_serving_slots_active")
                 occ_g.set(len(self._active))
+                _note_admitted(req, resp)
                 return req, resp, slot
             return None
 
@@ -162,6 +173,8 @@ class RequestScheduler:
             self._active[slot] = (req, resp)
             stat_add("STAT_serving_slots_active")
             _obs()[0].set(len(self._active))
+            if resp.admitted_at is None:  # not a preempted run's return
+                _note_admitted(req, resp)
             return slot
 
     def release(self, slot: int):

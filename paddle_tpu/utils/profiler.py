@@ -20,7 +20,7 @@ import contextlib
 import jax
 
 from ..core import op as _op
-from ..observability.tracer import get_tracer
+from ..observability.tracer import get_tracer, span
 
 _enabled = False
 # aggregates snapshot taken at start_profiler: the profiler reports the
@@ -106,27 +106,10 @@ def profiler(state="All", sorted_key="total", profile_path=None, log_dir=None):
         stop_profiler(sorted_key, profile_path)
 
 
-class RecordEvent:
-    """RAII host span (reference: platform/profiler.h:127) — an
-    observability span with the jax TraceAnnotation passthrough, so host
-    spans line up with the XLA device timeline."""
-
-    def __init__(self, name):
-        self.name = name
-        self._span = None
-
-    def __enter__(self):
-        self._span = get_tracer().span(self.name, annotate=True)
-        return self
-
-    def __exit__(self, *exc):
-        if self._span is not None:
-            self._span.end()
-            self._span = None
-        return False
-
-    def end(self):
-        self.__exit__(None, None, None)
+# RAII host span (reference: platform/profiler.h:127): the observability
+# span itself, which is also a jax TraceAnnotation, so host spans line up
+# with the XLA device timeline.  `with RecordEvent(name):` or `.end()`.
+RecordEvent = span
 
 
 def summary():

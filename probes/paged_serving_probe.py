@@ -113,7 +113,7 @@ def main():
     def one_rep(eng, rec):
         eng.reset_metrics()
         resps = [eng.submit(r["prompt"], r["max_new"]) for r in reqs]
-        t0 = time.monotonic()
+        t0 = time.perf_counter()    # the clock of Response.finished_at
         while eng.has_work():                      # saturated drive
             eng.step()
             rec["peak_resident_slots"] = max(

@@ -132,11 +132,11 @@ def main():
     engine.reset_metrics()
     engine.start()
     resps = [None] * n_req
-    t0 = time.monotonic()
+    t0 = time.perf_counter()        # the clock of Response.finished_at
 
     def submitter():
         for i, r in enumerate(reqs):
-            now = time.monotonic() - t0
+            now = time.perf_counter() - t0
             if now < arrivals[i]:
                 time.sleep(arrivals[i] - now)
             resps[i] = engine.submit(r["prompt"], r["max_new"])
