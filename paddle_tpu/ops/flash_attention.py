@@ -16,18 +16,55 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   (s, d) matmuls at d=64 run at MXU row-rate, so amortizing every load/store
   across a head group is worth ~1.8x over a head-per-step grid (measured on
   v5e at BERT-large shapes).
+- **Scores are computed transposed**, keys down the sublanes and queries
+  along the lanes: the softmax's running max and sum are then (1, blk_q)
+  rows, lane-dense, the reductions over keys are plain elementwise ops
+  between vregs, and the output accumulator (d, blk_q) is rescaled at full
+  width.  (With queries down the sublanes the state is a (blk_q, 1) column
+  that costs half a score block's vector work per operation.)
+- **One q block a grid step, the keys resident**: K and V of a head group
+  stay in VMEM (their block index does not move with the q block, so they
+  are loaded once per (batch, group)) and the kernel itself walks the k
+  sub-blocks.  Under the causal mask the walk ends at the diagonal: blocks
+  above it are neither loaded nor computed, and only the blocks the
+  diagonal crosses pay for the mask (iota, compare, select).  Up to
+  `_WRITTEN_OUT` rows the walk is written out, one branch a q block with
+  static bounds, the whole blocks of a row taken as ONE tall block and the
+  heads side by side, so that no chain of rescales and no loop boundary
+  stands between a block's products and its neighbour's softmax; longer
+  sequences are walked by `fori_loop`s.  A sequence of one block is the
+  same code with nothing to walk and nothing to rescale.
 - The backward is the FlashAttention-2 recompute scheme: the forward saves
-  only O and the per-row logsumexp; one merged backward kernel recomputes the
-  score blocks and produces dQ partials, dK and dV in a single pass.
+  only O and the per-row logsumexp; one merged backward kernel owns a k
+  block per grid step, holds Q, dO, lse and delta of the head group
+  resident, walks the q sub-blocks from the diagonal down, and produces dK,
+  dV and dQ in a single pass.  dQ accumulates in VMEM across the k blocks
+  and leaves once, in the input dtype.  Its time goes with the area it
+  computes, so under the causal mask it cuts the scores finer (256) than
+  the forward, which at 256 query lanes takes 1.7 times as long a tile as
+  at 512.
 - Dropout is applied *inside* the kernel from the TPU hardware PRNG re-seeded
-  per (head, q-block, k-block), so the keep mask is bit-identical between
-  forward and backward regardless of grid order.  Under `interpret=True`
-  (CPU CI) a murmur-style hash of absolute coordinates replaces the PRNG.
+  per (head, tile of the scores), the tile being the backward's block, so
+  the keep mask is bit-identical between forward and backward whatever
+  blocks and order each walks in.  Under `interpret=True` (CPU CI) a
+  murmur-style hash of absolute coordinates replaces the PRNG.
 - Masking: `causal`, an additive per-key bias (B, Sk) covering padding masks,
   and q/kv segment ids (packed-sequence masking) are fused into the kernel.
 
+What the chip reads (v5e, PERF.md section 6, PR 29) at GPT-2-medium's call,
+(b4, s1024, 16 heads of 64) causal in bfloat16: forward 0.146 ms, backward
+0.286 ms a call (0.407 and 0.384 before), 30% of the roofline the benchmark
+counts (half of the full product's operations at 197 TFLOP/s).  A 128 x 128
+tile of scores costs the forward 0.043-0.047 us and the backward 0.107-0.111
+us, twice what its products take at peak (0.021 and 0.053): with d = 64 every
+product has a 64-wide dimension and fills half of the 128-wide array, so the
+bound that applies is the MXU at half rate, not the vector unit and not HBM.
+What is left above it is area: the forward computes 3/4 and the backward 5/8
+of the full product where the mask keeps 1/2 plus the diagonal.
+
 `flash_attention_bshd` returns None when the kernel doesn't apply (wrong
-platform/shape); callers fall back to the XLA-fused naive path.
+platform/shape, or a sequence too long for its resident blocks); callers
+fall back to the XLA-fused naive path.
 """
 from __future__ import annotations
 
@@ -39,10 +76,30 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import jaxpr_as_fun
+
+from ..observability.metrics import counter
 
 _INTERPRET = False  # tests flip this to run the kernels via the interpreter
 
 _NEG_INF = -1e30
+
+# what a kernel may hold in VMEM: the compiler's default scope is 16 MiB of
+# the v5e's 128; a kernel whose resident blocks need more asks for it, and
+# a sequence too long for that takes the XLA form
+_VMEM_DEFAULT = 12 << 20
+_VMEM_MOST = 96 << 20
+
+# which form each traced kernel call took, chosen from the shapes at trace
+# time like `attention_path_total`: `one_block` (the keys are one block: no
+# loop, no rescale), `blocks` (a loop over all k sub-blocks), `causal_blocks`
+# (the loop ends at the diagonal and only its blocks are masked)
+_FORM_TAKEN = counter(
+    "flash_attention_form_total",
+    "flash kernel calls traced, by the form the shapes chose", ("form",))
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
 def _available() -> bool:
@@ -60,11 +117,26 @@ def _env_int(name):
         return None  # tuning knob: garbage falls back to the heuristic
 
 
-def _block(size: int) -> int:
-    override = _env_int("PDTPU_FLASH_BLOCK")
-    if override in (128, 256, 512) and size % override == 0:
-        return override
-    return next(b for b in (512, 256, 128) if size % b == 0)
+# the longest sequence whose walk over the blocks is written out (its bounds
+# static, the kernel one branch a grid block); longer ones are walked by
+# `fori_loop`s whose bounds the kernel computes from its grid index
+_WRITTEN_OUT = 1024
+
+
+def _block(size: int, fine: bool = False) -> int:
+    """Rows of a q or k sub-block: 512 where it divides, else 256 or 128
+    (the forward read 0.080 us a 128 x 128 tile at 256 query lanes against
+    0.047 at 512, the backward 0.11 at both).  `fine`: the causal backward,
+    whose time goes with the area computed, is cut at 256 where its walk is
+    written out: 10 of 16 blocks at S = 1024 where 512 gives 3 of 4."""
+    cands = (256, 128) if fine and size <= _WRITTEN_OUT else (512, 256, 128)
+    return next(b for b in cands if size % b == 0)
+
+
+def _form(causal: bool, sk: int) -> str:
+    if sk == _block(sk):
+        return "one_block"
+    return "causal_blocks" if causal else "blocks"
 
 
 def _head_group(h: int, d: int):
@@ -86,6 +158,21 @@ def _head_group(h: int, d: int):
     if not cands:
         return h  # full fold: block last dim == array last dim is allowed
     return min(cands, key=lambda g: (abs(g * d - 256), -g))
+
+
+def _resident_bytes(sq, sk, gd, itemsize):
+    """VMEM the larger of the two kernels holds for one head group: the
+    backward's Q, dO and dQ of the whole sequence (double-buffered) and its
+    float32 dQ accumulator; the forward's K and V."""
+    bwd = sq * gd * (3 * 2 * itemsize + 4)
+    fwd = sk * gd * 2 * 2 * itemsize
+    return max(bwd, fwd)
+
+
+def _compiler_params(semantics, resident):
+    limit = None if resident <= _VMEM_DEFAULT else resident + (16 << 20)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
@@ -112,8 +199,12 @@ def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
         return None
     if (q_segment_ids is None) != (kv_segment_ids is None):
         return None
+    if _resident_bytes(sq, sk, _head_group(h, d) * d,
+                       q.dtype.itemsize) > _VMEM_MOST:
+        return None
     if dropout_seed is None:
         dropout_seed = jnp.zeros((1,), jnp.int32)
+    _FORM_TAKEN.labels(form=_form(bool(causal), sk)).inc()
     local = functools.partial(_flash_bshd, causal=bool(causal),
                               dropout_p=float(dropout_p))
     args = (q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed)
@@ -136,16 +227,15 @@ def _flash_bshd(q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed,
     qt = q.reshape(b, sq, h * d)
     kt = k.reshape(b, sk, h * d)
     vt = v.reshape(b, sk, h * d)
-    # reshape mask inputs so every pallas block satisfies the TPU tiling
-    # rule (last two dims divisible by (8,128) or equal to the array's):
-    # per-key vectors ride the lane axis as (B, 1, Sk), per-query ids the
-    # sublane axis as (B, Sq, 1)
+    # the mask inputs as the transposed scores want them: per-key vectors
+    # ride the sublane axis as (B, Sk, 1), per-query ids the lane axis as
+    # (B, 1, Sq)
     if bias is not None:
-        bias = bias.astype(jnp.float32)[:, None, :]
+        bias = bias.astype(jnp.float32)[:, :, None]
     if q_segment_ids is not None:
-        q_segment_ids = q_segment_ids.astype(jnp.int32)[:, :, None]
+        q_segment_ids = q_segment_ids.astype(jnp.int32)[:, None, :]
     if kv_segment_ids is not None:
-        kv_segment_ids = kv_segment_ids.astype(jnp.int32)[:, None, :]
+        kv_segment_ids = kv_segment_ids.astype(jnp.int32)[:, :, None]
     out = _flash(qt, kt, vt, bias, q_segment_ids, kv_segment_ids,
                  dropout_seed, causal, dropout_p, h, g)
     return out.reshape(b, sq, h, d)
@@ -177,9 +267,10 @@ def _per_shard(local, free, q, k, v, bias, qseg, kseg, seed):
 # ---------------------------------------------------------------------------
 # in-kernel dropout.
 #
-# On TPU: the hardware PRNG, re-seeded per (seed, bh, qi, ki) block so the
-# keep mask is identical wherever the block is recomputed (fwd kernel and the
-# merged bwd kernel iterate blocks in different grid orders).
+# On TPU: the hardware PRNG, re-seeded per (seed, bh) and tile of the scores
+# (the backward's block), so the keep mask is identical wherever and in
+# whatever blocks the scores are recomputed (the forward walks the k blocks
+# of a q block, the backward the q blocks of a k block, in smaller blocks).
 # Under interpret=True (CPU CI): a murmur3-style hash of absolute
 # coordinates — the TPU PRNG primitives don't run in the interpreter.
 
@@ -191,19 +282,30 @@ def _per_shard(local, free, q, k, v, bias, qseg, kseg, seed):
 _HW_PRNG = True
 
 
-def _keep_mask(seed_ref, bh, qi, ki, blk_q, blk_k, dropout_p):
+def _keep_mask(seed_ref, bh, q0, k0, blk_q, blk_k, dropout_p, tile):
+    """(blk_k, blk_q) keep mask of head bh's scores from key k0 and query
+    q0 on, drawn in tiles of `tile` = (queries, keys)."""
     thresh = min(int(dropout_p * 4294967296.0), 4294967295)
     if _HW_PRNG and not _INTERPRET:
-        # hardware seeding takes at most 2 words: pack (seed, bh) and
-        # (qi, ki) — grid coords are far below 2^15 so the pair is unique.
-        # -1640531615 == 0x9E3779B1 as int32
-        pltpu.prng_seed(seed_ref[0] + bh * jnp.int32(-1640531615),
-                        qi * jnp.int32(0x10001) + ki)
-        bits = pltpu.prng_random_bits((blk_q, blk_k))
+        # drawn tile by tile, each seeded by its own position: forward and
+        # backward cut the scores into different blocks and must see one
+        # mask, so the tile is the smaller, the backward's, block.
+        # Hardware seeding takes at most 2 words: pack (seed, bh) and the
+        # tile's (q, k) — tile coords are far below 2^15 so the pair is
+        # unique.  -1640531615 == 0x9E3779B1 as int32
+        tq, tk = tile
+
+        def draw(i, j):
+            pltpu.prng_seed(seed_ref[0] + bh * jnp.int32(-1640531615),
+                            (q0 // tq + j) * jnp.int32(0x10001) + k0 // tk + i)
+            return pltpu.prng_random_bits((tk, tq))
+        bits = jnp.concatenate(
+            [jnp.concatenate([draw(i, j) for j in range(blk_q // tq)], axis=1)
+             for i in range(blk_k // tk)], axis=0)
         return bits.astype(jnp.uint32) >= jnp.uint32(thresh)
-    rows, cols = _coords(qi, ki, blk_q, blk_k)
-    x = (rows.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)
-         ^ cols.astype(jnp.uint32) * jnp.uint32(0x85EBCA77))
+    kpos, qpos = _coords(q0, k0, blk_q, blk_k)
+    x = (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)
+         ^ kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA77))
     x = x ^ (seed_ref[0].astype(jnp.uint32)
              + bh.astype(jnp.uint32) * jnp.uint32(0xC2B2AE35))
     x = x ^ (x >> 16)
@@ -214,52 +316,105 @@ def _keep_mask(seed_ref, bh, qi, ki, blk_q, blk_k, dropout_p):
     return x >= jnp.uint32(thresh)
 
 
-def _coords(qi, ki, blk_q, blk_k):
-    rows = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-    cols = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return rows, cols
+def _coords(q0, k0, blk_q, blk_k):
+    """Key and query positions of a transposed (blk_k, blk_q) block."""
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (blk_k, blk_q), 0)
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (blk_k, blk_q), 1)
+    return kpos, qpos
 
 
-def _mask_specs(has_bias, has_seg, blk_q, blk_k, q_pos):
-    """BlockSpecs for the optional [bias, qseg, kseg] inputs (in that order).
-    `q_pos` says which of the two non-(batch/group) grid axes (0 or 1) walks
-    the q blocks. Per-key inputs are (B, 1, Sk), per-query ones (B, Sq, 1)."""
-    k_pos = 1 - q_pos
-
-    def spec_k(pos):
-        return pl.BlockSpec(
-            (1, 1, blk_k),
-            lambda b, g, a1, a2, s, _p=pos: (b, 0, (a1, a2)[_p]))
-
-    def spec_q(pos):
-        return pl.BlockSpec(
-            (1, blk_q, 1),
-            lambda b, g, a1, a2, s, _p=pos: (b, (a1, a2)[_p], 0))
-
-    out = []
-    if has_bias:
-        out.append(spec_k(k_pos))
-    if has_seg:
-        out.append(spec_q(q_pos))
-        out.append(spec_k(k_pos))
-    return out
-
-
-def _masked_scores(q_hd, k_hd, bias_ref, qseg_ref, kseg_ref, qi, ki,
-                   blk_q, blk_k, scale, causal, causal_off):
-    """One (blk_q, blk_k) score block for one head with all masks (f32)."""
-    s = jax.lax.dot_general(q_hd, k_hd, (((1,), (1,)), ((), ())),
+def _scores(k_hd, q_hd, bias, qseg, kseg, q0, k0, scale, diagonal,
+            causal_off):
+    """One transposed (blk_k, blk_q) score block of one head (f32) with its
+    masks.  bias, kseg: (blk_k, 1) or None; qseg: (1, blk_q) or None;
+    `diagonal`: the causal diagonal may cross this block."""
+    s = jax.lax.dot_general(k_hd, q_hd, _NT,
                             preferred_element_type=jnp.float32) * scale
-    if bias_ref is not None:
-        s = s + bias_ref[0]  # (1, blk_k) broadcast over rows
-    if causal or qseg_ref is not None:
-        rows, cols = _coords(qi, ki, blk_q, blk_k)
-        if causal:
-            s = jnp.where(rows + causal_off >= cols, s, _NEG_INF)
-        if qseg_ref is not None:
-            # (blk_q, 1) == (1, blk_k) -> (blk_q, blk_k)
-            s = jnp.where(qseg_ref[0] == kseg_ref[0], s, _NEG_INF)
+    if bias is not None:
+        s = s + bias
+    if diagonal:
+        kpos, qpos = _coords(q0, k0, q_hd.shape[0], k_hd.shape[0])
+        s = jnp.where(qpos + causal_off >= kpos, s, _NEG_INF)
+    if qseg is not None:
+        s = jnp.where(kseg == qseg, s, _NEG_INF)
     return s
+
+
+def _rows(i, blk, n=1):
+    """Rows of sub-blocks [i, i+n) of a resident block; i a loop index (then
+    n is 1) or an int."""
+    if isinstance(i, int):
+        return slice(i * blk, (i + n) * blk)
+    return pl.ds(pl.multiple_of(i * blk, blk), blk)
+
+
+def _clip(x, lo, hi):
+    if all(isinstance(t, int) for t in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _diagonal_span(first, reach, blk, n):
+    """Blocks of size `blk` (n of them) against a causal edge: those below
+    index `lo` lie wholly on the kept side, [lo, hi) are crossed by the
+    diagonal, those from `hi` on are wholly masked.  `first` is the last
+    position the edge keeps for the nearest row, `reach` for the farthest."""
+    hi = _clip(reach // blk + 1, 1, n)
+    lo = _clip((first + 1) // blk, 0, hi)
+    return lo, hi
+
+
+def _loop(lo, hi, body, carry, written_out):
+    """`fori_loop`, or written out (the bounds are static then): the blocks
+    of a short sequence lie in one stretch of straight-line code, and the
+    scheduler starts a block's products under its neighbour's softmax where
+    a loop's iterations would run one after the other."""
+    if written_out:
+        for i in range(lo, hi):
+            carry = body(i, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _per_block(index, n, written_out, fn):
+    """fn(i) for the grid step's block `index` of `n`: where the walk is
+    written out, one branch a block with i an int, so that the bounds of the
+    causal walk, which depend on i, are static too."""
+    if written_out:
+        for i in range(n):
+            pl.when(index == i)(functools.partial(fn, i))
+    else:
+        fn(index)
+
+
+def _traced_once(n_arrays):
+    """`impl(*arrays, *static)`, bound from a jaxpr traced once a signature.
+    A model calls the kernels once a layer, all with one signature, and a
+    step is traced more than once; pallas traces a kernel's body anew at
+    every call, and the written-out walk is some fifty blocks of it a
+    layer: 24 layers took the step's set-up from 14 s to 57 (chip's host,
+    PR 29).  The equations are bound again where the call stands, so names
+    and transformations see what they saw."""
+    def wrap(impl):
+        @functools.lru_cache(maxsize=32)
+        def jaxpr_of(avals, static, flags):
+            args = [a and jax.ShapeDtypeStruct(*a) for a in avals]
+            return jax.make_jaxpr(lambda *xs: impl(*xs, *static),
+                                  return_shape=True)(*args)
+
+        @functools.wraps(impl)
+        def bound(*args):
+            arrays, static = args[:n_arrays], args[n_arrays:]
+            if not jax.sharding.get_abstract_mesh().empty:
+                return impl(*args)   # avals carry the mesh: trace in place
+            closed, out = jaxpr_of(
+                tuple(x if x is None else (x.shape, x.dtype) for x in arrays),
+                static, (_INTERPRET, _HW_PRNG, _WRITTEN_OUT))
+            flat = jaxpr_as_fun(closed)(*(x for x in arrays if x is not None))
+            return jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(out), flat)
+        return bound
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -267,92 +422,118 @@ def _masked_scores(q_hd, k_hd, bias_ref, qseg_ref, kseg_ref, qi, ki,
 
 
 def _fwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
-                blk_q, blk_k, n_k, scale, causal_off, heads, hg):
+                drop_tile, written_out, blk_q, blk_k, n_q, n_k, scale,
+                causal_off, heads, hg):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     bias_ref = next(it) if has_bias else None
     qseg_ref = next(it) if has_seg else None
     kseg_ref = next(it) if has_seg else None
     o_ref, lse_ref = next(it), next(it)
-    if n_k > 1:
-        acc_ref, m_ref, l_ref = next(it), next(it), next(it)
 
-    b, g, qi, ki = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                    pl.program_id(3))
+    b, g = pl.program_id(0), pl.program_id(1)
     d = q_ref.shape[-1] // hg
+    qseg = qseg_ref[0, 0] if has_seg else None
 
-    if n_k > 1:
-        @pl.when(ki == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-
-    def _head(h):
+    def block(h, qi, ki, n, carry, diagonal):
+        """Online-softmax step of head h over the k sub-blocks [ki, ki+n);
+        `carry` None: the first step, nothing to rescale."""
         sl = slice(h * d, (h + 1) * d)
-        s = _masked_scores(q_ref[0][:, sl], k_ref[0][:, sl], bias_ref,
-                           qseg_ref, kseg_ref, qi, ki, blk_q, blk_k,
-                           scale, causal, causal_off)
-        bh = b * jnp.int32(heads) + g * jnp.int32(hg) + jnp.int32(h)
-        if n_k == 1:
-            m = jnp.max(s, axis=1, keepdims=True)
-            p = jnp.exp(s - m)
-            l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
-            if dropout_p > 0.0:
-                keep = _keep_mask(seed_ref, bh, qi, ki, blk_q, blk_k,
-                                  dropout_p)
-                p = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
-            o = jax.lax.dot(p.astype(v_ref.dtype), v_ref[0][:, sl],
-                            preferred_element_type=jnp.float32) / l
-            return o.astype(o_ref.dtype), m + jnp.log(l)
-        # online-softmax path (multiple k blocks)
-        hsl = slice(h, h + 1)
-        m_prev = m_ref[:, hsl]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[:, hsl] = l_ref[:, hsl] * alpha + jnp.sum(p, axis=1,
-                                                        keepdims=True)
-        m_ref[:, hsl] = m_cur
+        ks = _rows(ki, blk_k, n)
+        s = _scores(k_ref[0, ks, sl], q_ref[0, :, sl],
+                    bias_ref[0, ks, :] if has_bias else None,
+                    qseg, kseg_ref[0, ks, :] if has_seg else None,
+                    qi * blk_q, ki * blk_k, scale, diagonal, causal_off)
+        m = jnp.max(s, axis=0, keepdims=True)          # (1, blk_q)
+        if carry is not None:
+            m_prev, l_prev, acc = carry
+            m = jnp.maximum(m_prev, m)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=0, keepdims=True)
         if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref, bh, qi, ki, blk_q, blk_k, dropout_p)
+            bh = b * jnp.int32(heads) + g * jnp.int32(hg) + jnp.int32(h)
+            keep = _keep_mask(seed_ref, bh, qi * blk_q, ki * blk_k, blk_q,
+                              n * blk_k, dropout_p, drop_tile)
             p = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
-        acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0][:, sl],
+        pv = jax.lax.dot_general(                       # (d, blk_q)
+            v_ref[0, ks, sl], p.astype(v_ref.dtype), _TN,
             preferred_element_type=jnp.float32)
-        return None, None
+        if carry is None:
+            return m, l, pv
+        alpha = jnp.exp(m_prev - m)
+        return m, alpha * l_prev + l, alpha * acc + pv
 
-    def _compute():
-        if n_k == 1:
-            outs, lses = [], []
-            for h in range(hg):
-                o, lse = _head(h)
-                outs.append(o)
-                lses.append(lse)
-            o_ref[0] = jnp.concatenate(outs, axis=1)
-            lse_ref[0, 0] = jnp.concatenate(lses, axis=1)
+    def q_block(qi):
+        """q block qi (static or the grid's index) against its k blocks:
+        the whole ones, then those the diagonal crosses.  The heads go
+        side by side: their chains (product, softmax, product) are
+        independent, so one's latency hides behind the others' work."""
+        if causal:
+            n_whole, n_seen = _diagonal_span(
+                qi * blk_q + causal_off, qi * blk_q + blk_q - 1 + causal_off,
+                blk_k, n_k)
         else:
-            for h in range(hg):
-                _head(h)
+            n_whole = n_seen = n_k
 
-    if causal and n_k > 1:
-        @pl.when(qi * blk_q + blk_q - 1 + causal_off >= ki * blk_k)
-        def _go():
-            _compute()
+        def blocks(ki, n, carry, diagonal):
+            return tuple(block(h, qi, ki, n, c, diagonal)
+                         for h, c in enumerate(carry))
+        if written_out:
+            # the whole blocks as ONE tall block: no chain of rescales
+            carry = (None,) * hg
+            if n_whole:
+                carry = blocks(0, n_whole, carry, False)
+        else:
+            carry = ((jnp.full((1, blk_q), _NEG_INF, jnp.float32),
+                      jnp.zeros((1, blk_q), jnp.float32),
+                      jnp.zeros((d, blk_q), jnp.float32)),) * hg
+            carry = jax.lax.fori_loop(
+                0, n_whole, lambda ki, c: blocks(ki, 1, c, False), carry)
+        carry = _loop(n_whole, n_seen, lambda ki, c: blocks(ki, 1, c, True),
+                      carry, written_out)
+        outs, lses = [], []
+        for m, l, acc in carry:
+            l = jnp.maximum(l, 1e-30)
+            outs.append(acc / l)
+            lses.append(m + jnp.log(l))
+        # (hg*d, blk_q) -> (blk_q, hg*d): one transpose a q block
+        o_ref[0] = jnp.concatenate(outs, axis=0).T.astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.concatenate(lses, axis=0)
+
+    if causal:
+        _per_block(pl.program_id(2), n_q, written_out, q_block)
     else:
-        _compute()
-
-    if n_k > 1:
-        @pl.when(ki == n_k - 1)
-        def _finish():
-            l = jnp.maximum(l_ref[...], 1e-30)
-            d_ = q_ref.shape[-1] // hg
-            parts = [(acc_ref[:, h * d_:(h + 1) * d_] / l[:, h:h + 1])
-                     for h in range(hg)]
-            o_ref[0] = jnp.concatenate(parts, axis=1).astype(o_ref.dtype)
-            lse_ref[0, 0] = m_ref[...] + jnp.log(l)
+        q_block(pl.program_id(2))
 
 
+def _mask_inputs(bias, qseg, kseg, blk_q, blk_k, q_index, k_index):
+    """Inputs and BlockSpecs of the optional [bias, qseg, kseg].  Per-key
+    inputs are (B, Sk, 1) in blocks of `blk_k` rows at `k_index(i)`, i the
+    grid's last index; the q ids (B, 1, Sq) go in as (B, n_q, 1, blk_q),
+    block `q_index(i)` of them, or all of them resident (`q_index` None)."""
+    inputs, specs = [], []
+
+    def per_key(x):
+        inputs.append(x)
+        specs.append(pl.BlockSpec(
+            (1, blk_k, 1), lambda b, g, i, s: (b, k_index(i), 0)))
+
+    if bias is not None:
+        per_key(bias)
+    if qseg is not None:
+        n_q = qseg.shape[-1] // blk_q
+        inputs.append(qseg.reshape(-1, n_q, 1, blk_q))
+        if q_index is None:
+            specs.append(pl.BlockSpec((1, n_q, 1, blk_q),
+                                      lambda b, g, i, s: (b, 0, 0, 0)))
+        else:
+            specs.append(pl.BlockSpec(
+                (1, 1, 1, blk_q), lambda b, g, i, s: (b, q_index(i), 0, 0)))
+        per_key(kseg)
+    return inputs, specs
+
+
+@_traced_once(7)
 def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
     b, sq, hd = q.shape
     sk = k.shape[1]
@@ -361,56 +542,47 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
     n_hg = heads // hg
     blk_q, blk_k = _block(sq), _block(sk)
     n_q, n_k = sq // blk_q, sk // blk_k
-    scale = 1.0 / math.sqrt(d)
 
+    # grid (b, head group, q block); K and V whole: their block does not
+    # move with the q block, so they are fetched once per (b, head group)
     in_specs = [
-        pl.BlockSpec((1, blk_q, gd), lambda b, g, i, j, s: (b, i, g)),
-        pl.BlockSpec((1, blk_k, gd), lambda b, g, i, j, s: (b, j, g)),
-        pl.BlockSpec((1, blk_k, gd), lambda b, g, i, j, s: (b, j, g)),
+        pl.BlockSpec((1, blk_q, gd), lambda b, g, i, s: (b, i, g)),
+        pl.BlockSpec((1, sk, gd), lambda b, g, i, s: (b, 0, g)),
+        pl.BlockSpec((1, sk, gd), lambda b, g, i, s: (b, 0, g)),
     ]
-    inputs = [q, k, v]
-    in_specs += _mask_specs(bias is not None, qseg is not None,
-                            blk_q, blk_k, q_pos=0)
-    if bias is not None:
-        inputs.append(bias)
-    if qseg is not None:
-        inputs.extend([qseg, kseg])
-
+    extra, extra_specs = _mask_inputs(bias, qseg, kseg, blk_q, sk,
+                                      q_index=lambda i: i,
+                                      k_index=lambda i: 0)
     kernel = functools.partial(
         _fwd_kernel, has_bias=bias is not None, has_seg=qseg is not None,
-        causal=causal, dropout_p=dropout_p, blk_q=blk_q, blk_k=blk_k,
-        n_k=n_k, scale=scale, causal_off=sk - sq, heads=heads, hg=hg)
-
-    scratch = []
-    if n_k > 1:
-        scratch = [
-            pltpu.VMEM((blk_q, gd), jnp.float32),
-            pltpu.VMEM((blk_q, hg), jnp.float32),
-            pltpu.VMEM((blk_q, hg), jnp.float32),
-        ]
+        causal=causal, dropout_p=dropout_p,
+        drop_tile=(_block(sq, fine=causal), _block(sk, fine=causal)),
+        written_out=max(sq, sk) <= _WRITTEN_OUT, blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k, scale=1.0 / math.sqrt(d),
+        causal_off=sk - sq, heads=heads, hg=hg)
 
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, n_hg, n_q, n_k),
-            in_specs=in_specs,
+            grid=(b, n_hg, n_q),
+            in_specs=in_specs + extra_specs,
             out_specs=[
-                pl.BlockSpec((1, blk_q, gd), lambda b, g, i, j, s: (b, i, g)),
-                pl.BlockSpec((1, 1, blk_q, hg),
-                             lambda b, g, i, j, s: (b, g, i, 0)),
+                pl.BlockSpec((1, blk_q, gd), lambda b, g, i, s: (b, i, g)),
+                pl.BlockSpec((1, 1, hg, blk_q),
+                             lambda b, g, i, s: (b, g, 0, i)),
             ],
-            scratch_shapes=scratch,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, n_hg, sq, hg), jnp.float32),
+            # per-row logsumexp, the rows along the lanes as the transposed
+            # scores of both kernels want them
+            jax.ShapeDtypeStruct((b, n_hg, hg, sq), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "parallel"),
+            _resident_bytes(sq, sk, gd, q.dtype.itemsize)),
         interpret=_INTERPRET,
-    )(seed, *inputs)
+    )(seed, q, k, v, *extra)
     return o, lse
 
 
@@ -419,96 +591,111 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
 #
 # The score block s and p = exp(s - lse) are recomputed once per (k,q) block
 # and feed dq, dk AND dv — half the exp/mask/dropout recompute of the classic
-# two-kernel (dq grid / dkv grid) split.  dk/dv accumulate in VMEM across the
-# inner q axis; dq cannot (its output block is revisited non-consecutively on
-# TPU), so each grid step writes a per-k-block dq partial and XLA sums the
-# n_k partials afterwards — free when n_k == 1, O(n_k · |dq|) HBM otherwise,
-# still far cheaper than a second score recompute pass.
+# two-kernel (dq grid / dkv grid) split.  A grid step owns one k block: dk/dv
+# accumulate in VMEM over the loop of q sub-blocks, which under the causal
+# mask starts at the diagonal.  dq accumulates, transposed like the scores,
+# in a VMEM scratch that stays put while the k blocks of a (batch, head
+# group) go by, and is written once after the last of them.
 
 
 def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
-                blk_q, blk_k, n_q, scale, causal_off, heads, hg):
+                written_out, blk_q, blk_k, n_q, n_k, scale, causal_off, heads,
+                hg):
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
         next(it), next(it), next(it), next(it), next(it), next(it))
     bias_ref = next(it) if has_bias else None
     qseg_ref = next(it) if has_seg else None
     kseg_ref = next(it) if has_seg else None
-    dqp_ref, dk_ref, dv_ref = next(it), next(it), next(it)
+    dq_ref, dk_ref, dv_ref = next(it), next(it), next(it)
     dbias_ref = next(it) if has_bias else None
-    dk_acc, dv_acc = next(it), next(it)
+    dq_acc, dk_acc, dv_acc = next(it), next(it), next(it)
     db_acc = next(it) if has_bias else None
 
-    b, g, ki, qi = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                    pl.program_id(3))
+    b, g = pl.program_id(0), pl.program_id(1)
     d = q_ref.shape[-1] // hg
+    bias = bias_ref[0] if has_bias else None
+    kseg = kseg_ref[0] if has_seg else None
 
-    @pl.when(qi == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def block(h, qi, ki, diagonal):
+        sl = slice(h * d, (h + 1) * d)
+        hs = slice(h, h + 1)
+        k_hd, v_hd = k_ref[0, :, sl], v_ref[0, :, sl]
+        qs = _rows(qi, blk_q)
+        q_hd, do_hd = q_ref[0, qs, sl], do_ref[0, qs, sl]
+        s = _scores(k_hd, q_hd, bias, qseg_ref[0, qi] if has_seg else None,
+                    kseg, qi * blk_q, ki * blk_k, scale, diagonal,
+                    causal_off)
+        p = jnp.exp(s - lse_ref[0, 0, qi, hs, :])           # (blk_k, blk_q)
+        dpd = jax.lax.dot_general(v_hd, do_hd, _NT,
+                                  preferred_element_type=jnp.float32)
+        if dropout_p > 0.0:
+            bh = b * jnp.int32(heads) + g * jnp.int32(hg) + jnp.int32(h)
+            keep = _keep_mask(seed_ref, bh, qi * blk_q, ki * blk_k, blk_q,
+                              blk_k, dropout_p, (blk_q, blk_k))
+            inv = 1.0 / (1.0 - dropout_p)
+            pd = jnp.where(keep, p * inv, 0.0)
+            dp = jnp.where(keep, dpd * inv, 0.0)
+        else:
+            pd, dp = p, dpd
+        dv_acc[:, sl] += jax.lax.dot(pd.astype(do_ref.dtype), do_hd,
+                                     preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, 0, qi, hs, :])
+        if has_bias:  # d(bias_k) = sum over q rows of dS (heads summed)
+            db_acc[...] += jnp.sum(ds, axis=1, keepdims=True)
+        ds = ds.astype(q_ref.dtype)
+        dk_acc[:, sl] += jax.lax.dot(
+            ds, q_hd, preferred_element_type=jnp.float32) * scale
+        dq_acc[qi, sl, :] += jax.lax.dot_general(           # (d, blk_q)
+            k_hd, ds, _TN, preferred_element_type=jnp.float32) * scale
+
+    def k_block(ki):
+        """k block ki (static or the grid's index) against its q blocks,
+        the heads side by side: first those the diagonal crosses, then the
+        whole ones below it."""
+        if causal:
+            # seen from the keys' side: q blocks below `first_seen` are
+            # wholly masked, those from `first_whole` on wholly kept
+            edge = ki * blk_k - causal_off   # first row that sees the block
+            first_seen = _clip(edge // blk_q, 0, n_q - 1)
+            first_whole = _clip((edge + blk_k - 1 + blk_q - 1) // blk_q,
+                                first_seen, n_q)
+        else:
+            first_seen = first_whole = 0
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
         if has_bias:
             db_acc[...] = jnp.zeros_like(db_acc)
 
-    def _compute():
-        dq_parts = []
-        for h in range(hg):
-            sl = slice(h * d, (h + 1) * d)
-            s = _masked_scores(q_ref[0][:, sl], k_ref[0][:, sl], bias_ref,
-                               qseg_ref, kseg_ref, qi, ki, blk_q, blk_k,
-                               scale, causal, causal_off)
-            p = jnp.exp(s - lse_ref[0, 0][:, h:h + 1])
-            dpd = jax.lax.dot_general(
-                do_ref[0][:, sl], v_ref[0][:, sl], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if dropout_p > 0.0:
-                bh = (b * jnp.int32(heads) + g * jnp.int32(hg)
-                      + jnp.int32(h))
-                keep = _keep_mask(seed_ref, bh, qi, ki, blk_q, blk_k,
-                                  dropout_p)
-                inv = 1.0 / (1.0 - dropout_p)
-                pd = jnp.where(keep, p * inv, 0.0)
-                dp = jnp.where(keep, dpd * inv, 0.0)
-            else:
-                pd, dp = p, dpd
-            dv_acc[:, sl] += jax.lax.dot_general(
-                pd.astype(do_ref.dtype), do_ref[0][:, sl],
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, 0][:, h:h + 1])
-            dk_acc[:, sl] += jax.lax.dot_general(
-                ds.astype(q_ref.dtype), q_ref[0][:, sl],
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if has_bias:  # d(bias_k) = sum over q rows of dS (heads summed)
-                db_acc[...] += jnp.sum(ds, axis=0, keepdims=True)
-            dq_parts.append((jax.lax.dot(
-                ds.astype(k_ref.dtype), k_ref[0][:, sl],
-                preferred_element_type=jnp.float32) * scale))
-        dqp_ref[0, 0] = jnp.concatenate(dq_parts, axis=1).astype(
-            dqp_ref.dtype)
-
-    if causal:
-        cond = qi * blk_q + blk_q - 1 + causal_off >= ki * blk_k
-
-        @pl.when(cond)
-        def _go():
-            _compute()
-
-        @pl.when(jnp.logical_not(cond))
-        def _zero():  # this (k,q) partial must still be defined
-            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
-    else:
-        _compute()
-
-    @pl.when(qi == n_q - 1)
-    def _finish():
+        def blocks(qi, _, diagonal):
+            for h in range(hg):
+                block(h, qi, ki, diagonal)
+        _loop(first_seen, first_whole, lambda qi, c: blocks(qi, c, True),
+              None, written_out)
+        _loop(first_whole, n_q, lambda qi, c: blocks(qi, c, False), None,
+              written_out)
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
         if has_bias:
             dbias_ref[0, 0] = db_acc[...]
 
+    if causal:
+        _per_block(pl.program_id(2), n_k, written_out, k_block)
+    else:
+        k_block(pl.program_id(2))
 
+    @pl.when(pl.program_id(2) == n_k - 1)
+    def _finish():
+        for qi in range(n_q):
+            dq_ref[0, qi * blk_q:(qi + 1) * blk_q, :] = dq_acc[qi].T.astype(
+                dq_ref.dtype)
+
+
+@_traced_once(10)
 def _bwd_impl(q, k, v, bias, qseg, kseg, seed, o, lse, do,
               causal, dropout_p, heads, hg):
     b, sq, hd = q.shape
@@ -516,77 +703,61 @@ def _bwd_impl(q, k, v, bias, qseg, kseg, seed, o, lse, do,
     d = hd // heads
     gd = hg * d
     n_hg = heads // hg
-    blk_q, blk_k = _block(sq), _block(sk)
+    blk_q, blk_k = _block(sq, fine=causal), _block(sk, fine=causal)
     n_q, n_k = sq // blk_q, sk // blk_k
-    scale = 1.0 / math.sqrt(d)
-    causal_off = sk - sq
 
-    # delta[b, s, h] = sum_d do*o, laid out (B, n_hg, Sq, hg) like lse
+    # lse and delta[b, s, h] = sum_d do*o as (B, n_hg, n_q, hg, blk_q): a
+    # (hg, blk_q) tile a q sub-block, found by a leading index in the loop
+    lse = lse.reshape(b, n_hg, hg, n_q, blk_q).transpose(0, 1, 3, 2, 4)
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        b, sq, heads, d).sum(-1).reshape(b, sq, n_hg, hg).transpose(
-        0, 2, 1, 3)
+        b, n_q, blk_q, n_hg, hg, d).sum(-1).transpose(0, 3, 1, 4, 2)
 
-    # grid (b, head group, k block, q block): dk/dv owned per outer k step,
-    # dq written as per-k partials summed below
-    kv_specs = [
-        pl.BlockSpec((1, blk_q, gd), lambda b, g, j, i, s: (b, i, g)),  # q
-        pl.BlockSpec((1, blk_k, gd), lambda b, g, j, i, s: (b, j, g)),  # k
-        pl.BlockSpec((1, blk_k, gd), lambda b, g, j, i, s: (b, j, g)),  # v
-        pl.BlockSpec((1, blk_q, gd), lambda b, g, j, i, s: (b, i, g)),  # do
-        pl.BlockSpec((1, 1, blk_q, hg),
-                     lambda b, g, j, i, s: (b, g, i, 0)),               # lse
-        pl.BlockSpec((1, 1, blk_q, hg),
-                     lambda b, g, j, i, s: (b, g, i, 0)),               # delta
-    ]
-    kv_extra = _mask_specs(bias is not None, qseg is not None,
-                           blk_q, blk_k, q_pos=1)
-    inputs = [q, k, v, do, lse, delta] + \
-        ([] if bias is None else [bias]) + \
-        ([] if qseg is None else [qseg, kseg])
+    # grid (b, head group, k block); Q, dO, lse and delta whole
+    whole = pl.BlockSpec((1, sq, gd), lambda b, g, j, s: (b, 0, g))
+    k_block = pl.BlockSpec((1, blk_k, gd), lambda b, g, j, s: (b, j, g))
+    rows = pl.BlockSpec((1, 1, n_q, hg, blk_q),
+                        lambda b, g, j, s: (b, g, 0, 0, 0))
+    extra, extra_specs = _mask_inputs(bias, qseg, kseg, blk_q, blk_k,
+                                      q_index=None, k_index=lambda j: j)
 
-    dqp_dtype = q.dtype if n_k == 1 else jnp.float32
     outs = pl.pallas_call(
         functools.partial(
             _bwd_kernel, has_bias=bias is not None,
             has_seg=qseg is not None, causal=causal, dropout_p=dropout_p,
-            blk_q=blk_q, blk_k=blk_k, n_q=n_q, scale=scale,
-            causal_off=causal_off, heads=heads, hg=hg),
+            written_out=max(sq, sk) <= _WRITTEN_OUT,
+            blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
+            scale=1.0 / math.sqrt(d), causal_off=sk - sq, heads=heads,
+            hg=hg),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, n_hg, n_k, n_q),
-            in_specs=kv_specs + kv_extra,
-            out_specs=[
-                pl.BlockSpec((1, 1, blk_q, gd),
-                             lambda b, g, j, i, s: (j, b, i, g)),   # dq part
-                pl.BlockSpec((1, blk_k, gd),
-                             lambda b, g, j, i, s: (b, j, g)),
-                pl.BlockSpec((1, blk_k, gd),
-                             lambda b, g, j, i, s: (b, j, g)),
-            ] + ([pl.BlockSpec((1, 1, 1, blk_k),
-                               lambda b, g, j, i, s: (b, g, 0, j))]
-                 if bias is not None else []),
+            grid=(b, n_hg, n_k),
+            in_specs=[whole, k_block, k_block, whole, rows, rows]
+            + extra_specs,
+            out_specs=[whole, k_block, k_block]
+            + ([pl.BlockSpec((1, 1, blk_k, 1),
+                             lambda b, g, j, s: (b, g, j, 0))]
+               if bias is not None else []),
             scratch_shapes=[
+                pltpu.VMEM((n_q, gd, blk_q), jnp.float32),
                 pltpu.VMEM((blk_k, gd), jnp.float32),
                 pltpu.VMEM((blk_k, gd), jnp.float32),
-            ] + ([pltpu.VMEM((1, blk_k), jnp.float32)]
+            ] + ([pltpu.VMEM((blk_k, 1), jnp.float32)]
                  if bias is not None else []),
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n_k, b, sq, hd), dqp_dtype),
+            jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
             jax.ShapeDtypeStruct((b, sk, hd), k.dtype),
             jax.ShapeDtypeStruct((b, sk, hd), v.dtype),
-        ] + ([jax.ShapeDtypeStruct((b, n_hg, 1, sk), jnp.float32)]
+        ] + ([jax.ShapeDtypeStruct((b, n_hg, sk, 1), jnp.float32)]
              if bias is not None else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            _resident_bytes(sq, sk, gd, q.dtype.itemsize)),
         interpret=_INTERPRET,
-    )(seed, *inputs)
-    dqp, dk, dv = outs[0], outs[1], outs[2]
-    dq = dqp[0].astype(q.dtype) if n_k == 1 else \
-        dqp.sum(axis=0).astype(q.dtype)
+    )(seed, q, k, v, do, lse, delta, *extra)
+    dq, dk, dv = outs[:3]
     dbias = None
-    if bias is not None:  # per-(batch, head-group) key sums -> (B, 1, Sk)
+    if bias is not None:  # per-(batch, head-group) key sums -> (B, Sk, 1)
         dbias = outs[3].sum(axis=1)
     return dq, dk, dv, dbias
 

@@ -70,9 +70,11 @@ def check_compat(saved: Dict[str, int], strict: bool = False):
 
 # -- current semantic versions ----------------------------------------------
 # r1 -> r2 changes that altered serialized numerics/layout:
-register_op_version("flash_attention", 2,
+register_op_version("flash_attention", 3,
                     "natural-layout head-folded kernels; in-kernel "
-                    "dropout/mask (r1 was transpose-layout, fwd-only)")
+                    "dropout/mask (r1 was transpose-layout, fwd-only); v3 "
+                    "walks the k blocks inside the kernel and seeds dropout "
+                    "per backward tile: another mask for the same seed")
 register_op_version("scaled_dot_product_attention", 2,
                     "routes masks/dropout through the flash kernel")
 register_op_version("fake_quantize", 1, "QAT/PTQ fake-quant family")

@@ -9,6 +9,9 @@ module-scoped fixture, never at import: one process at a time may load the
 TPU's library, and every xdist worker imports every test file.
 """
 import functools
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,10 +83,32 @@ def test_flash_forward(chip_compile):
     assert text.count("tpu_custom_call") == 1
 
 
-def test_flash_forward_backward_causal(chip_compile):
+def flash_roofline_patterns():
+    """The patterns by which the benchmark's `flash_attn_roofline` finds the
+    two kernels' events in a trace: an event is named like its instruction."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", "flash_attn_roofline.json")
+    with open(path) as f:
+        params = json.load(f)["params"]
+    return re.compile(params["forward"]), re.compile(params["backward"])
+
+
+@pytest.mark.parametrize("b,s", [(B, S), (2, 2048)],
+                         ids=["s1024_written_out", "s2048_loop"])
+def test_flash_forward_backward_causal(chip_compile, b, s):
+    """gpt2m-train's call (4 x 1024, 16 heads of 64: the walk to the
+    diagonal written out) and one past `_WRITTEN_OUT` (the loop).  The
+    compiled text holds exactly the custom calls the metric's two patterns
+    name: the forward kernel and the merged backward."""
+    qkv = ((b, s, H, D), jnp.bfloat16)
     text = chip_compile(functools.partial(flash_loss_grads, causal=True,
-                                          dropout_p=0.0), QKV, QKV, QKV, SEED)
-    assert text.count("tpu_custom_call") == 2   # forward + merged backward
+                                          dropout_p=0.0), qkv, qkv, qkv, SEED)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    forward, backward = flash_roofline_patterns()
+    assert len(calls) == 2
+    assert sum(bool(forward.search(c)) for c in calls) == 1
+    assert sum(bool(backward.search(c)) for c in calls) == 1
 
 
 @pytest.mark.parametrize("hw_prng", [True, False],
