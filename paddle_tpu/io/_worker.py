@@ -138,8 +138,10 @@ def _maybe_crash(seq, raw):
     os._exit(17)  # hard crash: no result, no cleanup — the real thing
 
 
-def worker_loop(dataset, collate_fn, task_q, result_q, worker_id,
+def worker_loop(dataset, collate_fn, task_q, result_conn, worker_id,
                 use_shm, worker_init_fn, num_workers=0, crash_cfg=None):
+    """`result_conn` is this worker's own pipe to the parent: `send` writes
+    in this thread and takes no lock another worker could die holding."""
     global _worker_info
     _worker_info = WorkerInfo(worker_id, num_workers, dataset)
     if worker_init_fn is not None:
@@ -152,6 +154,7 @@ def worker_loop(dataset, collate_fn, task_q, result_q, worker_id,
         try:
             _maybe_crash(seq, crash_cfg)
             batch = encode(fetch(dataset, indices, collate_fn), use_shm)
-            result_q.put((epoch, seq, batch, None))
+            result_conn.send((epoch, seq, batch, None))
         except Exception as e:  # surface worker errors to the parent
-            result_q.put((epoch, seq, None, f"{type(e).__name__}: {e}"))
+            result_conn.send((epoch, seq, None,
+                              f"{type(e).__name__}: {e}"))

@@ -118,30 +118,60 @@ class _WorkerPool:
         self._worker_args = (dataset, collate_fn, use_shm, worker_init_fn,
                              num_workers)
         self._task_q = ctx.Queue()
-        self._result_q = ctx.Queue()
-        self._procs = [self._spawn_worker(wid) for wid in range(num_workers)]
+        # results come back on a pipe a worker.  One queue for all of them
+        # has one write lock, and a worker that dies (os._exit, the OOM
+        # killer) while its feeder thread holds that lock blocks every
+        # surviving worker's put for ever, with all workers alive.
+        self._readers = [None] * num_workers
+        self._procs = []
         try:
-            for p in self._procs:
-                p.start()
+            for wid in range(num_workers):
+                self._procs.append(self._spawn_worker(wid))
         except Exception:
             for p in self._procs:
                 if p.is_alive():
                     p.terminate()
             self._procs = []
+            self._close_readers()
             raise
 
     def _spawn_worker(self, wid):
+        """Start worker `wid` on a result pipe of its own (a dead
+        predecessor's pipe is closed: what it held is lost and its tasks
+        are resubmitted)."""
         dataset, collate_fn, use_shm, worker_init_fn, nw = self._worker_args
         # fault config is read HERE (parent, spawn time) and passed as an
         # arg: a forkserver's cached environment must not decide whether
         # the injection is armed — and a respawned worker picks up the
         # config current at respawn time (disarmed once the test clears it)
         from ..utils import faults as _faults
-        return self._ctx.Process(
-            target=_worker_loop,
-            args=(dataset, collate_fn, self._task_q, self._result_q, wid,
-                  use_shm, worker_init_fn, nw, _faults.get("worker_crash")),
-            daemon=True)
+        reader, writer = self._ctx.Pipe(duplex=False)
+        try:
+            p = self._ctx.Process(
+                target=_worker_loop,
+                args=(dataset, collate_fn, self._task_q, writer, wid,
+                      use_shm, worker_init_fn, nw,
+                      _faults.get("worker_crash")),
+                daemon=True)
+            p.start()
+        except Exception:
+            reader.close()
+            raise
+        finally:
+            # the worker holds the only write end: its death reads as EOF
+            writer.close()
+        self._drop_reader(wid)
+        self._readers[wid] = reader
+        return p
+
+    def _drop_reader(self, wid):
+        if self._readers[wid] is not None:
+            self._readers[wid].close()
+            self._readers[wid] = None
+
+    def _close_readers(self):
+        for wid in range(len(self._readers)):
+            self._drop_reader(wid)
 
     def respawn_dead(self):
         """Replace every dead worker process; returns how many were
@@ -155,34 +185,45 @@ class _WorkerPool:
         for i, p in enumerate(self._procs):
             if p.is_alive():
                 continue
-
-            def start_one(wid=i):
-                q = self._spawn_worker(wid)
-                q.start()
-                return q
-            self._procs[i] = policy.call(start_one)
+            self._procs[i] = policy.call(self._spawn_worker, i)
             replaced += 1
         if replaced:
             stat_add("STAT_dataloader_worker_respawns", replaced)
         return replaced
 
+    def _recv(self, wid):
+        """One result off worker `wid`'s pipe, or None when the pipe has
+        ended (the worker died, maybe in the middle of a message)."""
+        try:
+            return self._readers[wid].recv()
+        except (EOFError, OSError):
+            self._drop_reader(wid)
+            return None
+
     def _get_result(self):
         """Blocking result fetch that detects dead workers and honors the
         user timeout with a meaningful error (reference: reader.py raises on
         worker exit; torch detects OOM-killed workers the same way)."""
+        from multiprocessing.connection import wait
         waited = 0.0
         while True:
-            try:
-                return self._result_q.get(timeout=1.0)
-            except queue.Empty:
-                if not self.alive():
-                    raise _WorkerDied()
-                waited += 1.0
-                if self._timeout is not None and waited >= self._timeout:
-                    from ..core.errors import ExecutionTimeoutError
-                    raise ExecutionTimeoutError(
-                        f"[ExecutionTimeout] DataLoader worker timed out "
-                        f"after {waited:.0f}s")
+            live = {r: wid for wid, r in enumerate(self._readers)
+                    if r is not None}
+            ready = wait(list(live), timeout=1.0)
+            for r in ready:
+                result = self._recv(live[r])
+                if result is not None:
+                    return result
+            if not self.alive() or not live:
+                raise _WorkerDied()
+            if ready:       # a pipe ended at once: no second was waited
+                continue
+            waited += 1.0
+            if self._timeout is not None and waited >= self._timeout:
+                from ..core.errors import ExecutionTimeoutError
+                raise ExecutionTimeoutError(
+                    f"[ExecutionTimeout] DataLoader worker timed out "
+                    f"after {waited:.0f}s")
 
     def run(self, index_batches, max_in_flight):
         """Yield collated numpy batches in order.
@@ -254,17 +295,17 @@ class _WorkerPool:
             self._drain()
 
     def _drain(self):
-        """Decode-and-discard everything currently in the result queue
+        """Decode-and-discard everything currently in the result pipes
         (frees shared-memory blocks whose ownership passed to this side)."""
-        while True:
+        for wid in range(len(self._readers)):
             try:
-                _, _, batch, _ = self._result_q.get_nowait()
-            except queue.Empty:
-                return
+                while (self._readers[wid] is not None
+                       and self._readers[wid].poll()):
+                    result = self._recv(wid)
+                    if result is not None and result[2] is not None:
+                        _decode(result[2])
             except Exception:
-                return
-            if batch is not None:
-                _decode(batch)
+                continue
 
     def shutdown(self):
         import time as _time
@@ -287,6 +328,7 @@ class _WorkerPool:
             p.terminate()
         self._procs = []
         self._drain()  # workers have exited: anything left is ours to free
+        self._close_readers()
 
     def alive(self):
         return bool(self._procs) and all(p.is_alive() for p in self._procs)

@@ -1,5 +1,6 @@
 """Flagship model zoo: BERT / GPT-2 / ERNIE pretraining models for the
-BASELINE.md benchmark configs (#3 BERT DP, #4 ERNIE sharding, #5 GPT-2 PP)."""
+BASELINE.md benchmark configs (#3 BERT DP, #4 ERNIE sharding, #5 GPT-2 PP),
+and the Cohere2-MoE decoder the serving benchmark runs."""
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertPretrainingCriterion,
                    BertForSequenceClassification,
@@ -13,3 +14,5 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForPretraining,  # noqa: F401
                     ernie_base_config, ernie_large_config)
 from .dlrm import (DLRMConfig, DLRM, DLRMCriterion,  # noqa: F401
                    dlrm_tiny_config)
+from .cohere_moe import (CohereMoEConfig, CohereMoEBlock,  # noqa: F401
+                         CohereMoEForCausalLM)

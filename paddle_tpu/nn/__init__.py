@@ -38,7 +38,7 @@ from .layer.rnn import (RNNCellBase, SimpleRNNCell, LSTMCell, GRUCell, RNN,  # n
 from .layer.transformer import (MultiHeadAttention, TransformerEncoderLayer,  # noqa: F401
                                 TransformerEncoder, TransformerDecoderLayer,
                                 TransformerDecoder, Transformer)
-from .layer.moe import MoELayer  # noqa: F401
+from .layer.moe import MoELayer, HeldExperts  # noqa: F401
 from .decode import (Decoder, BeamSearchDecoder, dynamic_decode,  # noqa: F401
                      gather_tree)
 from . import utils  # noqa: F401,E402

@@ -14,7 +14,7 @@ from .norm import (batch_norm, fused_bn_act, fused_dual_bn_act,  # noqa: F401
                    layer_norm, instance_norm, group_norm,
                    local_response_norm, normalize, rms_norm)
 from .pooling import *  # noqa: F401,F403
-from .moe import moe_ffn  # noqa: F401
+from .moe import moe_ffn, moe_ffn_held  # noqa: F401
 from .vision import affine_grid, grid_sample, temporal_shift  # noqa: F401
 from .crf import linear_chain_crf, crf_decoding, hsigmoid_loss  # noqa: F401
 

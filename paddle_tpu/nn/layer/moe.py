@@ -12,9 +12,11 @@ Usage:
 """
 from __future__ import annotations
 
+import jax
+
 from ..layer_base import Layer
 from .. import initializer as I
-from ..functional.moe import moe_ffn
+from ..functional.moe import moe_ffn, moe_ffn_held
 
 
 class MoELayer(Layer):
@@ -54,3 +56,52 @@ class MoELayer(Layer):
     def extra_repr(self):
         return (f"d_model={self.d_model}, d_hidden={self.d_hidden}, "
                 f"num_experts={self.num_experts}, top_k={self.top_k}")
+
+
+class HeldExperts(Layer):
+    """The routed experts of one layer as ONE chip of an expert-parallel
+    deployment holds them: the router over all `num_experts`, the gated
+    (silu) weights of the experts in `experts_held` alone, no token dropped.
+
+        y, picks_here, experts_hit = layer(x)          # x: (T, d_model)
+
+    `y` is the part of the routed sum that the held experts give
+    (`functional.moe_ffn_held`); the two int32 counts are what a serving
+    engine's spans and counters report.  Expert weights are created in
+    `dtype`; the router stays float32 (its scores pick the experts).
+    """
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 top_k: int, experts_held=None, dtype=None,
+                 std: float = 0.02):
+        super().__init__()
+        self.experts_held = tuple(range(num_experts) if experts_held is None
+                                  else (int(e) for e in experts_held))
+        if (len(set(self.experts_held)) != len(self.experts_held)
+                or not all(0 <= e < num_experts for e in self.experts_held)):
+            raise ValueError(f"experts_held {self.experts_held} must be "
+                             f"distinct ids below {num_experts}")
+        self.num_experts, self.top_k = num_experts, top_k
+        init, n = I.Normal(std=std), len(self.experts_held)
+
+        def leaf(shape, dtype):
+            # one leaf at a time: the initializer draws in float32, and the
+            # draws of three such leaves in flight do not fit beside a
+            # chip's share of a large model (PERF.md, PR 30)
+            p = self.create_parameter(shape, dtype=dtype,
+                                      default_initializer=init)
+            jax.block_until_ready(p._data)
+            return p
+
+        self.router = leaf((d_model, num_experts), "float32")
+        self.gate = leaf((n, d_model, d_hidden), dtype)
+        self.up = leaf((n, d_model, d_hidden), dtype)
+        self.down = leaf((n, d_hidden, d_model), dtype)
+
+    def forward(self, x, valid=None):
+        return moe_ffn_held(x, self.router, self.gate, self.up, self.down,
+                            self.experts_held, top_k=self.top_k, valid=valid)
+
+    def extra_repr(self):
+        return (f"num_experts={self.num_experts}, top_k={self.top_k}, "
+                f"held={len(self.experts_held)}")

@@ -73,6 +73,26 @@ GSPMD inserts the collectives).  The 8-virtual-device CPU mesh makes the
 whole thing tier-1 testable: streams match the single-device engine
 token-for-token for the same seeds.
 
+**Models that decode the whole batch** (``model.serving_batch_decode``,
+e.g. `models.CohereMoEForCausalLM`): the same constructor and the same
+two program families, but prefill calls `model.forward_prefill(ids,
+prompt_len)` (the last position's logits alone) and the decode step
+hands `model.forward_decode(tokens, pools, pos, active)` ALL slots at
+once instead of vmapping a batch of one — a routed layer routes once a
+step and its experts see every slot's token in one grouped product;
+rows of empty slots are routed nowhere.  `gen_fixed_cache` may give
+layers different lengths: a leaf shorter than the pool is a window
+layer's RING, written at ``pos % rows``; `write_slot` overwrites each
+leaf over its own length, and a bucket longer than the ring leaves the
+prompt's last ``rows`` positions in it.  Both programs return the
+model's int32 counts ``[picks on held experts, picks in all, held
+experts hit, grouped products made]`` with the tokens; they ride in the
+`serving_admit` / `serving_decode` spans' args (`routed_here`,
+`routed_all`, `experts_hit`, `expert_products`) and the counters `moe_routed_picks_total{where}`,
+`moe_experts_hit_total`, and `serving_kv_rows{kind}` gauges the rows
+held.  ``kv="paged"``, ``prefix_cache``, ``draft_model``, ``mesh``,
+``lora``, `preempt_slot` and `restore_run` raise for such a model.
+
 Greedy requests are bit-identical to a solo
 `generation.generate(decode_strategy='greedy_search')` run of the same
 prompt: prefill logits at the prompt's last position are unaffected by
@@ -389,6 +409,33 @@ class ServingEngine:
                 f"prefill bucket {self.buckets[-1]} exceeds max_len "
                 f"{self.max_len}")
         self._dtype = dtype
+        # a model that asks for it (`serving_batch_decode`) gets the WHOLE
+        # batch of slots in one `forward_decode` call, a position a slot,
+        # and a pool whose leaves differ by layer (`gen_fixed_cache` gives a
+        # window layer a ring); what is not built for such a model refuses
+        self._batched = bool(getattr(model, "serving_batch_decode", False))
+        if self._batched:
+            for given, what, missing in (
+                    (kv == "paged", "kv='paged'",
+                     "the paged pool has one block table for every layer "
+                     "(kv_pool.py) and no ring of a window's rows"),
+                    (prefix_cache, "prefix_cache=True",
+                     "prefix reuse shares blocks of the paged pool, and a "
+                     "ring overwrites the rows a later request would share"),
+                    (draft_model is not None, "draft_model=",
+                     "the verify program scores K+1 positions a slot "
+                     "through `forward_fixed`, which this model has not"),
+                    (mesh is not None, "mesh=",
+                     "`_init_mesh` lays out dense layers by name and "
+                     "shards no expert axis"),
+                    (lora is not None, "lora=",
+                     "adapter hooks attach to `Linear` sublayers and enter "
+                     "per vmapped row; the batched program has neither")):
+                if given:
+                    raise InvalidArgumentError(
+                        f"{what} is not built for a model that decodes the "
+                        f"whole batch ({type(model).__name__}): {missing}. "
+                        f"Drop {what} on this engine.")
         # tokens decoded per compiled decode call (an internal lax.scan):
         # amortizes the per-call host+dispatch cost across chunk tokens per
         # slot.  Tokens stream in bursts of `chunk`; admission, deadline
@@ -607,6 +654,9 @@ class ServingEngine:
             self._decode_fn = (self._build_verify_paged()
                                if self.kv == "paged"
                                else self._build_verify())
+        elif self._batched:
+            self._init_batched()
+            self._decode_fn = self._build_decode_batched()
         else:
             self._decode_fn = (self._build_decode_paged()
                                if self.kv == "paged"
@@ -832,21 +882,35 @@ class ServingEngine:
         per bucket."""
         apply_fixed = self._apply
         model, draft = self.model, self.draft_model
-        pool_len, dtype = self._pool_len, self._dtype
+        dtype = self._dtype
         dapply = self._dapply if draft is not None else None
 
-        def write_slot(pools, kv, slot):
+        def write_slot(pools, kv, slot, prompt_len=None):
             new_pools = []
             for (kp, vp), (kc, vc) in zip(pools, kv):
-                # full-range overwrite: bucket KV + zeros to pool_len, so
-                # a recycled slot keeps no stale KV from its previous
-                # tenant
-                krow = jnp.zeros((1, pool_len) + kp.shape[2:], kp.dtype)
-                vrow = jnp.zeros((1, pool_len) + vp.shape[2:], vp.dtype)
-                krow = jax.lax.dynamic_update_slice(
-                    krow, kc.astype(kp.dtype), (0, 0, 0, 0))
-                vrow = jax.lax.dynamic_update_slice(
-                    vrow, vc.astype(vp.dtype), (0, 0, 0, 0))
+                # full-range overwrite: bucket KV + zeros to the leaf's own
+                # length (pool_len, or a window layer's ring), so a
+                # recycled slot keeps no stale KV from its previous tenant
+                rows = kp.shape[1]
+                if kc.shape[1] > rows:
+                    # a bucket longer than the ring leaves the prompt's
+                    # last `rows` positions in it: row r holds the one
+                    # position p in [plen - rows, plen) with p % rows == r
+                    first = prompt_len - rows
+                    p = first + (jnp.arange(rows) - first) % rows
+                    held = (p >= 0)[None, :, None, None]
+                    at = jnp.maximum(p, 0)
+                    krow = jnp.where(held, jnp.take(kc, at, axis=1),
+                                     0).astype(kp.dtype)
+                    vrow = jnp.where(held, jnp.take(vc, at, axis=1),
+                                     0).astype(vp.dtype)
+                else:
+                    krow = jnp.zeros((1, rows) + kp.shape[2:], kp.dtype)
+                    vrow = jnp.zeros((1, rows) + vp.shape[2:], vp.dtype)
+                    krow = jax.lax.dynamic_update_slice(
+                        krow, kc.astype(kp.dtype), (0, 0, 0, 0))
+                    vrow = jax.lax.dynamic_update_slice(
+                        vrow, vc.astype(vp.dtype), (0, 0, 0, 0))
                 new_pools.append((
                     jax.lax.dynamic_update_slice(kp, krow, (slot, 0, 0, 0)),
                     jax.lax.dynamic_update_slice(vp, vrow, (slot, 0, 0, 0))))
@@ -858,7 +922,23 @@ class ServingEngine:
             self._compiles["prefill"][bucket] += 1  # trace-count (host)
             stat_add("STAT_serving_compiles")
 
-        if draft is None and self.lora is not None:
+        if self._batched:
+            apply_prefill = self._apply_prefill
+
+            def prefill(state, pools, ids, slot, prompt_len, key, temp,
+                        top_k, top_p, greedy):
+                count_trace()
+                # the model returns the last position's logits alone (a
+                # bucket's logits over the whole vocabulary are not needed)
+                logits, kv, counts = apply_prefill(state, ids, prompt_len)
+                new_pools = write_slot(pools, kv, slot, prompt_len)
+                tok, logp, finite = _first_token_at(
+                    logits, 0, prompt_len - 1, key, temp, top_k, top_p,
+                    greedy)
+                return tok, logp, finite, new_pools, counts
+
+            name, donate = f"serving_prefill_b{bucket}", self._donate
+        elif draft is None and self.lora is not None:
             def prefill(state, pools, lora, ids, slot, prompt_len, aid,
                         key, temp, top_k, top_p, greedy):
                 count_trace()
@@ -976,6 +1056,102 @@ class ServingEngine:
             (tokens, pos, pools), (toks, logps, finites) = jax.lax.scan(
                 one, (tokens, pos, pools), None, length=chunk)
             return toks, logps, finites, tokens, pos, pools
+
+        from ..observability import track
+        return track("serving_decode",
+                     jax.jit(decode, donate_argnums=self._donate))
+
+    def _init_batched(self):
+        """What an engine over a `serving_batch_decode` model adds: the two
+        entries of the model's protocol, which pool leaves are rings, and
+        the routed layers' counters."""
+        from ..jit import functional_call
+        from ..observability import metrics as _obs_m
+        model = self.model
+
+        def apply_prefill(state, ids, prompt_len):
+            return functional_call(model, state, ids, prompt_len,
+                                   training=False, method="forward_prefill")
+
+        def apply_decode(state, tokens, caches, pos, active):
+            return functional_call(model, state, tokens, caches, pos, active,
+                                   training=False, method="forward_decode")
+
+        self._apply_prefill, self._apply_decode = apply_prefill, apply_decode
+        # rows of each layer's leaf: shorter than the pool = a window's ring
+        self._leaf_rows = [int(k.shape[1]) for k, _ in self._pools]
+        self._c_picks = _obs_m.counter(
+            "moe_routed_picks_total",
+            "picks of the routed layers (tokens x experts a token x "
+            "layers), by whether the expert is held here", ("where",))
+        self._c_hit = _obs_m.counter(
+            "moe_experts_hit_total",
+            "held experts that got at least one token, a layer a step")
+        self._g_kv_rows = _obs_m.gauge(
+            "serving_kv_rows",
+            "cache rows the running requests hold, summed over layers, by "
+            "kind of layer", ("kind",))
+
+    def _count_routed(self, span_args: dict, counts):
+        """A program's routed counts [here, all, experts hit, grouped
+        products made] into its span's args and the counters."""
+        here, total, hit, products = (int(c) for c in counts)
+        span_args.update(routed_here=here, routed_all=total,
+                         experts_hit=hit, expert_products=products)
+        self._c_picks.labels(where="here").inc(here)
+        self._c_picks.labels(where="elsewhere").inc(total - here)
+        self._c_hit.inc(hit)
+
+    def _refuse_batched(self, what: str):
+        if self._batched:
+            raise InvalidArgumentError(
+                f"{what} is not built for a model that decodes the whole "
+                f"batch ({type(self.model).__name__}): a snapshot takes "
+                "rows [0, pos) of every leaf, and a window layer's ring "
+                "holds positions pos - rows .. pos at pos % rows")
+
+    def _gauge_kv_rows(self):
+        """Rows the running requests hold as the decode call read them,
+        summed over layers: a ring holds at most its own length."""
+        held = {"window": 0, "full": 0}
+        for run in self._slots.values():
+            for rows in self._leaf_rows:
+                kind = "window" if rows < self._pool_len else "full"
+                held[kind] += min(run.pos, rows)
+        for kind, n in held.items():
+            self._g_kv_rows.labels(kind=kind).set(n)
+
+    def _build_decode_batched(self):
+        """The decode program of a `serving_batch_decode` model: one
+        `forward_decode` over all slots a step (no vmap: a routed layer
+        routes once and its experts see every slot's token), rows of empty
+        slots routed nowhere.  Returns the model's counts summed over the
+        chunk's steps with the tokens."""
+        apply_decode = self._apply_decode
+        poison_armed = self._poison_target is not None
+        chunk = self.decode_chunk
+
+        def decode(state, pools, tokens, pos, active, keys, temp, top_k,
+                   top_p, greedy, poison):
+            self._compiles["decode"] += 1  # trace-count (host side effect)
+            stat_add("STAT_serving_compiles")
+
+            def one(carry, _):
+                tokens, pos, pools, counts = carry
+                last, pools, c = apply_decode(state, tokens, pools, pos,
+                                              active)
+                if poison_armed:
+                    last = faults.poison_logits(last, poison)
+                finite = jnp.isfinite(last).all(axis=-1)
+                tok, logp = _sample_step(last, keys, pos, temp, top_k,
+                                         top_p, greedy)
+                return (tok, pos + 1, pools, counts + c), (tok, logp, finite)
+
+            (tokens, pos, pools, counts), (toks, logps, finites) = \
+                jax.lax.scan(one, (tokens, pos, pools,
+                                   jnp.zeros((4,), jnp.int32)),
+                             None, length=chunk)
+            return toks, logps, finites, tokens, pos, counts, pools
 
         from ..observability import track
         return track("serving_decode",
@@ -1773,12 +1949,14 @@ class ServingEngine:
                        else self._prefill(req, resp, slot, plen, sp.args))
             if out is None:  # failed terminally before any program ran
                 return
-            tok, logp, finite, key, aid = out
+            tok, logp, finite, key, aid = out[:5]
             stat_add("STAT_serving_prefills")
             with _span("serving_prefill_wait"):
                 ok = bool(finite)
                 if ok:
                     tok = int(tok)
+                if self._batched:   # the program has ended: no new wait
+                    self._count_routed(sp.args, np.asarray(out[5]))
             if not ok:
                 # the run is not in _slots yet — _release won't see the
                 # pin, drop it here
@@ -1860,11 +2038,14 @@ class ServingEngine:
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jnp.float32(req.top_p), jnp.asarray(req.greedy))
         else:
-            tok, logp, finite, self._pools = self._prefill_fns[bucket](
-                self._state, self._pools, jnp.asarray(ids),
-                slot_arg, jnp.int32(plen), jnp.asarray(key),
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p), jnp.asarray(req.greedy))
+            tok, logp, finite, self._pools, *counts = \
+                self._prefill_fns[bucket](
+                    self._state, self._pools, jnp.asarray(ids),
+                    slot_arg, jnp.int32(plen), jnp.asarray(key),
+                    jnp.float32(req.temperature), jnp.int32(req.top_k),
+                    jnp.float32(req.top_p), jnp.asarray(req.greedy))
+            # a batched model's program also returns its routed counts
+            return (tok, logp, finite, key, aid, *counts)
         return tok, logp, finite, key, aid
 
     def _prefill_cached(self, req: Request, resp: Response, slot: int,
@@ -1953,6 +2134,7 @@ class ServingEngine:
         programs — and slicing `[slot, :pos]` before the device_get
         would compile one tiny gather per distinct pos, which is worse.
         Must be called between engine steps from the driving thread."""
+        self._refuse_batched("preempt_slot")
         run = self._slots.get(slot)
         if run is None:
             raise InvalidArgumentError(f"slot {slot} holds no active run")
@@ -2018,6 +2200,7 @@ class ServingEngine:
         that was never preempted.  Returns False when no slot is free —
         or, paged, when the block pool cannot hold the saved rows yet
         (the caller retries as it drains)."""
+        self._refuse_batched("restore_run")
         slot = self.scheduler.acquire(paused.req, paused.resp)
         if slot is None:
             return False
@@ -2342,6 +2525,10 @@ class ServingEngine:
         self._dev_pos = jnp.asarray(pos)
         if self.lora is not None:
             self._dev_aids = jnp.asarray(aids)
+        if self._batched:
+            active = np.zeros((s,), bool)
+            active[list(self._slots)] = True
+            self._dev_active = jnp.asarray(active)
         self._dev_params = tuple(jnp.asarray(a) for a in (
             keys, temp, top_k, top_p, greedy, poison, spec_on))
         self._batch_dirty = False
@@ -2394,6 +2581,12 @@ class ServingEngine:
                         self._lora_reg.device_args(), self._dev_tokens,
                         self._dev_pos, self._dev_aids, keys, temp, top_k,
                         top_p, greedy, poison)
+                elif self._batched:
+                    (toks, logps, finites, ntok, npos, counts,
+                     self._pools) = self._decode_fn(
+                        self._state, self._pools, self._dev_tokens,
+                        self._dev_pos, self._dev_active, keys, temp, top_k,
+                        top_p, greedy, poison)
                 else:
                     (toks, logps, finites, ntok, npos,
                      self._pools) = self._decode_fn(
@@ -2403,8 +2596,16 @@ class ServingEngine:
                 self._dev_tokens, self._dev_pos = ntok, npos
             with _span("serving_token_pull"):
                 # one device->host pull for the whole (chunk, slots) burst
-                toks, logps, finites = jax.device_get(
-                    (toks, logps, finites))
+                # (and a batched model's routed counts with it)
+                if self._batched:
+                    toks, logps, finites, counts = jax.device_get(
+                        (toks, logps, finites, counts))
+                else:
+                    toks, logps, finites = jax.device_get(
+                        (toks, logps, finites))
+            if self._batched:
+                self._count_routed(sp.args, counts)
+                self._gauge_kv_rows()
             stat_add("STAT_serving_decode_steps")
             with _span("serving_deliver") as deliver:
                 emitted, seated = 0, len(self._slots)
@@ -2728,6 +2929,9 @@ class ServingEngine:
         if self.lora is not None:
             # per-slot adapter ids slide in right after `pos`
             base.insert(2, jnp.zeros((s,), jnp.int32))
+        if self._batched:
+            # so does the batched program's mask of occupied slots
+            base.insert(2, jnp.zeros((s,), bool))
         if self.draft_model is not None:
             args = pre + base + [jnp.ones((s,), bool),
                                  jnp.zeros((s,), bool), jnp.asarray(False)]

@@ -160,3 +160,28 @@ def test_fused_bn_relu_forward_backward(chip_compile):
     text = chip_compile(loss_grads, ((m, c), jnp.bfloat16),
                         ((c,), jnp.float32), ((c,), jnp.float32))
     assert text.count("tpu_custom_call") >= 3   # stats, apply, backward
+
+
+def test_held_experts_grouped_product_keeps_its_name(chip_compile):
+    """command-a-plus's routed layer in a decode step (16 slots, 16 of 128
+    experts held, 4096 wide, bfloat16): the chip's compiler takes
+    `jax.lax.ragged_dot` as its own grouped kernel, three a layer, and their
+    instructions carry the name by which `moe_expert_product_roofline` finds
+    their events in a trace (and the metadata kernel beside each does not)."""
+    from paddle_tpu.nn.functional.moe import GROUPED_PRODUCTS, moe_ffn_held
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", "moe_expert_product_roofline.json")
+    with open(path) as f:
+        kernel = re.compile(json.load(f)["params"]["kernel"])
+    held, h = tuple(range(16)), 4096
+    text = chip_compile(
+        lambda x, r, g, u, d, valid: moe_ffn_held.raw(
+            x, r, g, u, d, held, top_k=8, valid=valid),
+        ((16, h), jnp.bfloat16), ((h, 128), jnp.float32),
+        ((16, h, h), jnp.bfloat16), ((16, h, h), jnp.bfloat16),
+        ((16, h, h), jnp.bfloat16), ((16,), jnp.bool_))
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    # as many as the program's spans say it made (`expert_products`)
+    assert sum(bool(kernel.search(c)) for c in calls) == GROUPED_PRODUCTS
+    assert len(calls) > GROUPED_PRODUCTS   # the group metadata: other names

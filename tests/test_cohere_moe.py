@@ -1,0 +1,376 @@
+"""The Cohere2-MoE decoder (`models/cohere_moe.py`), the expert layer that is
+told which experts it holds (`nn.HeldExperts`, `F.moe_ffn_held`) and the
+serving engine's batched decode over a pool whose leaves differ by layer,
+against the benchmark's plain reference (`benchmark/reference/
+cohere2_moe.py`), at a tiny size on the CPU in float32 with seeded weights.
+"""
+import collections
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import models, nn, observability as obs
+from paddle_tpu.core.errors import InvalidArgumentError
+from paddle_tpu.nn import functional as F
+from paddle_tpu.serving import ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import weights as W  # noqa: E402
+from benchmark.arch import cohere2_moe as A  # noqa: E402
+
+pytestmark = [pytest.mark.serving]
+
+REF = A.reference
+WINDOW = 8
+TINY = dict(vocab_size=128, hidden_size=64, intermediate_size=32,
+            num_hidden_layers=4, num_attention_heads=8,
+            num_key_value_heads=2, head_dim=16, sliding_window=WINDOW,
+            num_experts=8, num_experts_per_tok=2, num_shared_experts=2,
+            dtype="float32")
+NAME, T0, DUR, TID, ID, PARENT, ARGS = range(7)
+
+
+def dims(held):
+    cfg = dict(TINY, layer_types=["sliding_attention"] * 3
+               + ["full_attention"], rope_theta=50000, layer_norm_eps=1e-5,
+               logit_scale=1, initializer_range=0.125,
+               experts_held=list(held))
+    return A.dims(cfg)
+
+
+def weights(d, seed):
+    """(top, [one layer's leaves]) made the way the benchmark makes them."""
+    return (dict(A.make_leaves(W.make, d, seed, -1)),
+            [dict(A.make_leaves(W.make, d, seed, i)) for i in range(d["L"])])
+
+
+def build(held=(1, 4, 6), seed=2147483659):
+    d = dims(held)
+    model = models.CohereMoEForCausalLM(models.CohereMoEConfig(
+        **TINY, experts_held=held))
+    model.eval()
+    top, layers = weights(d, seed)
+    state = model.state_dict()
+    for i, leaves in enumerate([top] + layers):
+        for name, leaf in leaves.items():
+            state[A.program_name(name, i - 1)]._set_data(leaf)
+    return model, d, top, layers
+
+
+MAX_LEN = 40
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return build()
+
+
+@pytest.fixture(scope="module")
+def ref_logits(tiny):
+    """The reference's logits over MAX_LEN positions, compiled once: under
+    the causal mask what follows a position does not move it, so every
+    comparison pads its ids to that length."""
+    _, d, top, layers = tiny
+    fn = jax.jit(lambda ids: REF.logits(top, layers, ids, d))
+
+    def padded(ids):
+        ids = np.asarray(ids, np.int32)
+        row = np.zeros((MAX_LEN,), np.int32)
+        row[:len(ids)] = ids
+        return np.asarray(fn(jnp.asarray(row)))[:len(ids)]
+
+    return padded
+
+
+@pytest.fixture(scope="module")
+def eng(tiny):
+    """One warmed engine for the module: three slots, buckets of 4 and 16
+    (the second longer than the window of 8)."""
+    e = ServingEngine(tiny[0], max_slots=3, max_len=MAX_LEN,
+                      prefill_buckets=(4, 16), decode_chunk=4,
+                      max_queue_depth=16)
+    e.warmup()
+    yield e
+    e.close()
+
+
+# ------------------------------------------------------------------ model
+
+def test_logits_agree_with_the_reference(tiny, ref_logits):
+    model, d, top, layers = tiny
+    assert model.config.layer_types == d["kinds"]
+    ids = np.random.RandomState(0).randint(0, 128, (1, 24)).astype(np.int32)
+    got = np.asarray(model(paddle.to_tensor(ids)).numpy())[0]
+    want = ref_logits(ids[0])
+    assert np.max(np.abs(got - want)) < 2e-5
+    # 24 positions pass the window of 8, and window layers rotate where
+    # full layers do not: the reference reads both
+    for other in (dict(d, window=64), dict(d, kinds=["full_attention"] * 4)):
+        moved = np.asarray(jax.jit(lambda ids: REF.logits(
+            top, layers, ids, other))(jnp.asarray(ids[0])))
+        assert np.max(np.abs(moved - want)) > 1e-3
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_prefill_attention_in_blocks_and_chunks_is_the_references(
+        tiny, monkeypatch, kind):
+    """Queries a block, keys a chunk under a running maximum and sum, with
+    blocks and chunks that divide neither the length nor the window."""
+    from paddle_tpu.models import cohere_moe
+    monkeypatch.setattr(cohere_moe, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(cohere_moe, "_KEY_CHUNK", 5)
+    blk = [b for b in tiny[0].layers if b.kind == kind][0]
+    rng = np.random.RandomState(4)
+    q, k, v = (jnp.asarray(rng.randn(37, heads, 16), jnp.float32)
+               for heads in (8, 2, 2))
+    want = REF.attention(q, k, v, WINDOW if kind == "sliding_attention"
+                         else None, "float32")
+    np.testing.assert_allclose(jax.jit(blk._attend_seq)(q, k, v), want,
+                               atol=2e-6)
+
+
+def test_rope_turns_adjacent_pairs_and_leaves_position_0():
+    q = jnp.asarray(np.random.RandomState(1).randn(5, 2, 16), jnp.float32)
+    rot = REF.rope_gptj(q, jnp.arange(5), 50000.0)
+    pair = lambda x: np.asarray(x).reshape(5, 2, 8, 2)  # noqa: E731
+    np.testing.assert_allclose(np.linalg.norm(pair(rot), axis=-1),
+                               np.linalg.norm(pair(q), axis=-1), rtol=1e-5)
+    np.testing.assert_allclose(rot[0], q[0], rtol=1e-6)
+    # pair i of position 1 turns by theta ** (-2i / 16)
+    ang = 50000.0 ** (-np.arange(8) / 8.0)
+    a, b = pair(q)[1, 0, :, 0], pair(q)[1, 0, :, 1]
+    np.testing.assert_allclose(pair(rot)[1, 0, :, 0],
+                               a * np.cos(ang) - b * np.sin(ang), atol=1e-6)
+
+
+# ------------------------------------------------------- the expert layer
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference():
+    """The guide's share test: the routed parts of all 4 shares of 2
+    experts, plus the shared experts once, are the uncut layer's FFN."""
+    d_all = dims(range(8))
+    _, layers = weights(d_all, 11)
+    l = layers[0]
+    h = jnp.asarray(np.random.RandomState(2).randn(40, 64), jnp.float32)
+    want = np.asarray(REF.ffn(h, l, d_all, "float32"))
+    routed, here, hit = [], 0, 0
+    for share in ((0, 1), (2, 3), (4, 5), (6, 7)):
+        take = jnp.asarray(share)
+        y, n_here, n_hit = F.moe_ffn_held(
+            h, l["router"], l["eg"][take], l["eu"][take], l["ed"][take],
+            share, top_k=2)
+        routed.append(np.asarray(y))
+        here, hit = here + int(n_here), hit + int(n_hit)
+        # each share alone is the reference's share
+        cut = {**l, "eg": l["eg"][take], "eu": l["eu"][take],
+               "ed": l["ed"][take]}
+        shared_too = np.asarray(REF.ffn(h, cut, dims(share), "float32"))
+        if share == (0, 1):
+            shared = shared_too - routed[-1]
+        np.testing.assert_allclose(routed[-1] + shared, shared_too,
+                                   atol=2e-6)
+    # every pick fell on exactly one share, and nothing was dropped
+    assert here == 40 * 2 and 0 < hit <= 8
+    np.testing.assert_allclose(sum(routed) + shared, want, atol=3e-6)
+
+
+def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
+    """The capacity-and-drop layer loses tokens here; this one must not."""
+    rng = np.random.RandomState(3)
+    t, h, i = 48, 16, 8
+    x = jnp.asarray(np.abs(rng.randn(t, h)), jnp.float32)
+    router = np.zeros((h, 6), np.float32)
+    router[:, 3], router[:, 5] = 1.0, 0.5          # every token: 3, then 5
+    gate, up, down = (jnp.asarray(rng.randn(*s), jnp.float32) * 0.3
+                      for s in ((2, h, i), (2, h, i), (2, i, h)))
+    layer = nn.HeldExperts(h, i, 6, 2, experts_held=(5, 3), dtype="float32")
+    for p, v in zip((layer.router, layer.gate, layer.up, layer.down),
+                    (jnp.asarray(router), gate, up, down)):
+        p._set_data(v)
+    y, here, hit = layer(paddle.to_tensor(x))
+    assert (int(here.numpy()), int(hit.numpy())) == (t * 2, 2)
+    s = jax.nn.sigmoid(x @ router)
+    w = s[:, [5, 3]] / jnp.sum(s[:, [5, 3]], -1, keepdims=True)
+    want = sum(w[:, k:k + 1] * ((jax.nn.silu(x @ gate[k]) * (x @ up[k]))
+                                @ down[k]) for k in range(2))
+    np.testing.assert_allclose(np.asarray(y.numpy()), np.asarray(want),
+                               atol=1e-5)
+    assert np.all(np.abs(np.asarray(y.numpy())).sum(-1) > 0)
+    # rows marked not valid are routed nowhere
+    valid = jnp.arange(t) < 10
+    y2, here2, _ = F.moe_ffn_held(x, jnp.asarray(router), gate, up, down,
+                                  (5, 3), top_k=2, valid=valid)
+    assert int(here2) == 20 and not np.any(np.asarray(y2)[10:])
+    with pytest.raises(ValueError):
+        nn.HeldExperts(h, i, 6, 2, experts_held=(5, 5))
+
+
+@pytest.mark.parametrize("key, value", [("expert_selection_fn", "softmax"),
+                                        ("norm_topk_prob", False)])
+def test_the_one_form_of_routing_that_is_built_is_the_one_accepted(key,
+                                                                    value):
+    """The source's keys stay in the config; a value the routed layer and
+    the reference do not compute raises and is not silently ignored."""
+    cfg = models.CohereMoEConfig(**TINY)
+    assert (cfg.expert_selection_fn, cfg.norm_topk_prob) == ("sigmoid", True)
+    with pytest.raises(InvalidArgumentError, match=key):
+        models.CohereMoEConfig(**TINY, **{key: value})
+
+
+def test_a_layer_says_how_many_grouped_products_it_made(monkeypatch, tiny):
+    """What the spans carry as `expert_products`: three a call of the routed
+    layer, and a long prompt makes a call a block of tokens."""
+    from paddle_tpu.models import cohere_moe
+    from paddle_tpu.nn.functional.moe import GROUPED_PRODUCTS
+    assert GROUPED_PRODUCTS == 3
+    block = tiny[0].layers[0]
+    h = jnp.asarray(np.random.RandomState(0).randn(16, 64), jnp.float32)
+    whole, counts = block._ffn(h, None)
+    assert int(counts[3]) == GROUPED_PRODUCTS
+    monkeypatch.setattr(cohere_moe, "_MOE_BLOCK", 4)
+    blocks, counts4 = block._ffn(h, None)
+    assert int(counts4[3]) == 4 * GROUPED_PRODUCTS
+    assert [int(c) for c in counts4[:2]] == [int(c) for c in counts[:2]]
+    # an expert is read once a block that routes a token to it
+    assert int(counts[2]) <= int(counts4[2]) <= 4 * int(counts[2])
+    np.testing.assert_allclose(np.asarray(blocks), np.asarray(whole),
+                               atol=1e-5)
+
+
+# ------------------------------------------------------------- the engine
+
+REQUESTS = ((3, 20), (12, 18), (5, 6), (14, 10), (2, 25), (9, 12))
+
+
+def serve(eng, reqs, seed=0):
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(0, 128, plen).astype(np.int32)
+               for plen, _ in reqs]
+    resps = [eng.submit(p, out) for p, (_, out) in zip(prompts, reqs)]
+    eng.run_until_drained(timeout=120)
+    return prompts, resps
+
+
+@pytest.fixture(scope="module")
+def served(eng):
+    """Six requests through three slots: prompts shorter (3, 5, 2) and
+    longer (12, 14, 9) than the window of 8, a bucket of 16 that leaves its
+    last 8 rows in the ring, rings that wrap during decode, and slots
+    reused after a longer tenant; the ring of spans it left."""
+    tracer = obs.get_tracer()
+    tracer.clear()
+    prompts, resps = serve(eng, REQUESTS)
+    return {"prompts": prompts, "resps": resps, "events": tracer.events()}
+
+
+def test_prefill_then_decode_follow_the_reference_at_every_position(
+        eng, served, ref_logits):
+    assert eng._leaf_rows == [WINDOW] * 3 + [MAX_LEN]
+    assert eng.post_warmup_compiles() == 0
+    assert eng.compile_counts()["total"] == eng.compile_counts()["bound"] == 3
+    for prompt, resp, (_, out) in zip(served["prompts"], served["resps"],
+                                      REQUESTS):
+        toks = resp.tokens()
+        assert len(toks) == out and resp.finish_reason == "length"
+        full = np.concatenate([prompt, toks])
+        want = np.asarray(jax.nn.log_softmax(
+            ref_logits(full[:-1]), axis=-1))[len(prompt) - 1:]
+        # the served token is the reference's choice, at its probability
+        assert [int(np.argmax(row)) for row in want] == list(toks)
+        assert abs(resp.logprob - want[np.arange(out), toks].sum()) < 1e-4
+
+
+def test_a_batch_of_slots_at_different_positions_is_each_slot_alone(
+        eng, served):
+    for k in (1, 4):
+        resp = eng.submit(served["prompts"][k], REQUESTS[k][1])
+        eng.run_until_drained(timeout=120)
+        assert resp.tokens() == served["resps"][k].tokens()
+        assert abs(resp.logprob - served["resps"][k].logprob) < 1e-5
+
+
+def test_what_is_not_built_for_such_a_model_refuses(tiny, eng):
+    for kw, what in ((dict(kv="paged"), "kv='paged'"),
+                     (dict(prefix_cache=True), "prefix_cache"),
+                     (dict(draft_model=tiny[0]), "draft_model"),
+                     (dict(mesh=object()), "mesh=")):
+        with pytest.raises(InvalidArgumentError, match=what):
+            ServingEngine(tiny[0], max_slots=2, max_len=16, **kw)
+    resp = eng.submit([1, 2, 3], 20)
+    eng.step()
+    with pytest.raises(InvalidArgumentError, match="ring"):
+        eng.preempt_slot(0)
+    with pytest.raises(InvalidArgumentError, match="ring"):
+        eng.restore_run(None)
+    eng.run_until_drained(timeout=60)
+    assert len(resp.tokens()) == 20
+
+
+# ------------------------------------------------------ spans and counters
+
+def test_the_spans_a_step_are_unchanged_and_carry_the_routed_counts(served):
+    events = served["events"]
+    names = collections.Counter(ev[NAME] for ev in events)
+    steps = names["serving_step"]
+    # what `tests/test_step_phases.py` finds of a GPT-2 engine: one of each
+    # phase a step, five a request, nothing a token, a slot or a layer
+    assert names == dict(
+        serving_step=steps, serving_sweep=steps, serving_decode=steps,
+        serving_decode_dispatch=steps, serving_token_pull=steps,
+        serving_deliver=steps,
+        serving_batch_rebuild=names["serving_batch_rebuild"],
+        **{n: len(REQUESTS) for n in (
+            "serving_queue_wait", "serving_admit", "serving_request",
+            "serving_prefill_dispatch", "serving_prefill_wait")})
+    layers, k, chunk = 4, 2, 4
+    for ev in events:
+        if ev[NAME] == "serving_admit":
+            args = ev[ARGS]
+            assert args["routed_all"] == args["plen"] * k * layers
+        elif ev[NAME] == "serving_decode":
+            args = ev[ARGS]
+            assert args["routed_all"] == args["active"] * k * layers * chunk
+        else:
+            assert not (ev[ARGS] and "routed_here" in ev[ARGS])
+            continue
+        assert 0 <= args["routed_here"] <= args["routed_all"]
+        # three experts held: each hit counts once a layer a step
+        assert 0 <= args["experts_hit"] <= min(
+            args["routed_here"], 3 * layers * chunk)
+        # three grouped products a layer a step (a prompt: a block)
+        assert args["expert_products"] == 3 * layers * (
+            chunk if ev[NAME] == "serving_decode" else 1)
+
+
+def test_the_counters_add_up(eng):
+    reg = obs.metrics.get_registry()
+    picks, hit = reg.get("moe_routed_picks_total"), reg.get(
+        "moe_experts_hit_total")
+    before = (picks.value(where="here"), picks.value(where="elsewhere"),
+              hit.value())
+    tracer = obs.get_tracer()
+    tracer.clear()
+    serve(eng, REQUESTS[:3], seed=5)
+    events = tracer.events()
+    last = [ev[ARGS] for ev in events if ev[NAME] == "serving_decode"][-1]
+    # the last decode call read its requests' rows (all past the window by
+    # then): three rings and one full layer a request
+    rows = reg.get("serving_kv_rows")
+    assert rows.value(kind="window") == 3 * WINDOW * last["active"]
+    assert (WINDOW * last["active"] < rows.value(kind="full")
+            <= MAX_LEN * last["active"])
+    spans = [ev[ARGS] for ev in events
+             if ev[ARGS] and "routed_here" in ev[ARGS]]
+    here = sum(a["routed_here"] for a in spans)
+    total = sum(a["routed_all"] for a in spans)
+    assert picks.value(where="here") - before[0] == here > 0
+    assert picks.value(where="elsewhere") - before[1] == total - here > 0
+    assert hit.value() - before[2] == sum(a["experts_hit"] for a in spans)

@@ -373,7 +373,23 @@ class _DetDataset:
                 np.asarray([i], "int64"))
 
 
-def test_worker_crash_respawns_and_epoch_completes(tmp_path):
+@pytest.fixture
+def hard_timeout():
+    """The fleet tests' wedge guard (`tests/test_autoscale.py`): SIGALRM
+    fails this one test if it hangs (now and then a killed `DataLoader`
+    worker leaves the producer thread polling: the probe's run, and the
+    in-process worker-crash tests here), where the hang used to cost a
+    whole run its time limit."""
+    def handler(signum, frame):
+        raise TimeoutError("hard per-test timeout (120 s)")
+    old = signal.signal(signal.SIGALRM, handler)
+    signal.alarm(120)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, old)
+
+
+def test_worker_crash_respawns_and_epoch_completes(tmp_path, hard_timeout):
     """A worker hard-killed (os._exit) mid-epoch is respawned and its lost
     batch redelivered: the epoch yields every batch, in order."""
     from paddle_tpu.io import DataLoader
@@ -390,7 +406,7 @@ def test_worker_crash_respawns_and_epoch_completes(tmp_path):
     assert os.path.exists(once)  # the fault actually fired
 
 
-def test_worker_crash_budget_exhausted_raises(tmp_path):
+def test_worker_crash_budget_exhausted_raises(tmp_path, hard_timeout):
     """A poison task that kills every worker that touches it (no `once`
     sentinel) exhausts the respawn budget and surfaces UnavailableError."""
     from paddle_tpu.core.errors import UnavailableError
@@ -510,18 +526,25 @@ def test_preemption_checkpoint_and_exit_then_resume(tmp_path):
 # the full probe, smoke mode
 # ---------------------------------------------------------------------------
 
-def test_resilience_probe_smoke():
+def test_resilience_probe_smoke(hard_timeout):
     """End-to-end acceptance: NaN-injected + worker-killed + SIGTERM-
     preempted run resumes to the baseline's exact final loss, and async
     saves stall the loop less than sync saves."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
+    proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "probes",
                                       "resilience_probe.py"), "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env, cwd=REPO)
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESIL")]
-    assert line, (proc.stdout, proc.stderr)
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
+        cwd=REPO, start_new_session=True)
+    try:
+        out, err = proc.communicate()
+    finally:
+        if proc.poll() is None:     # the alarm fired: leave nothing behind
+            os.killpg(proc.pid, signal.SIGKILL)
+            proc.wait()
+    line = [l for l in out.splitlines() if l.startswith("RESIL")]
+    assert line, (out, err)
     rec = json.loads(line[0][len("RESIL"):])
     parity = rec["chaos_parity"]
     assert parity["ok"], parity
