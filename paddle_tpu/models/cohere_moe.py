@@ -10,9 +10,12 @@ max_length)` rows, written at `pos % rows`, and a full layer `max_length`
 rows; `forward_prefill` runs one padded prompt and `forward_decode` the
 WHOLE batch of slots at once, a position a slot, so a layer routes once a
 step and its experts see every slot's token in one grouped product
-(`serving_batch_decode`: the engine asks for it).  Both return, beside
-their outputs, int32 counts `[picks on held experts, picks in all, held
-experts hit, grouped products made]` summed over layers.
+(`serving_batch_decode`: the engine asks for it).  A prompt of any bucket
+goes through the routed layer once a layer too: `F.moe_ffn_held` walks the
+picks held here in chunks and makes nothing as wide as a row for the rest.
+Both return, beside their outputs, int32 counts `[picks on held experts,
+picks in all, held experts hit, grouped products made, rows those products
+went over]` summed over layers.
 
 Weights and cache are held in `config.dtype`; norm statistics, the router,
 softmax and every sum into the residual stream are float32.
@@ -27,7 +30,7 @@ import jax.numpy as jnp
 from ..core.errors import InvalidArgumentError
 from ..core.tensor import Tensor, unwrap
 from ..nn import initializer as I
-from ..nn.functional.moe import GROUPED_PRODUCTS, moe_ffn_held
+from ..nn.functional.moe import moe_ffn_held
 from ..nn.layer.container import LayerList
 from ..nn.layer.moe import HeldExperts
 from ..nn.layer_base import Layer
@@ -35,7 +38,6 @@ from ..nn.layer_base import Layer
 SLIDING, FULL = "sliding_attention", "full_attention"
 _QUERY_BLOCK = 256      # prefill attention: queries a block (scores fit)
 _KEY_CHUNK = 2048       # and keys a pass of the running softmax
-_MOE_BLOCK = 2048       # prefill experts: tokens a grouped product
 
 
 class CohereMoEConfig:
@@ -154,34 +156,21 @@ class CohereMoEBlock(Layer):
     def _ffn(self, h, valid):
         """Routed part of the held experts + the mean of the shared ones,
         float32; counts [picks here, picks in all, held experts hit,
-        grouped products made]."""
+        grouped products made, rows they went over]."""
         cfg = self.cfg
         ex = self.experts
-        args = (unwrap(ex.router), unwrap(ex.gate), unwrap(ex.up),
-                unwrap(ex.down), ex.experts_held, ex.top_k)
-        t = h.shape[0]
         if valid is None:
-            valid = jnp.ones((t,), bool)
-        if t > _MOE_BLOCK and t % _MOE_BLOCK == 0:
-            # a long prompt's tokens go through the experts a block at a
-            # time: the sorted copies of a block fit, of 8192 x 8 not
-            def block(hv):
-                return moe_ffn_held.raw(hv[0], *args, valid=hv[1])
-            y, here, hit = jax.lax.map(block, (
-                h.reshape(-1, _MOE_BLOCK, h.shape[-1]),
-                valid.reshape(-1, _MOE_BLOCK)))
-            y, here, hit = y.reshape(t, -1), jnp.sum(here), jnp.sum(hit)
-            products = GROUPED_PRODUCTS * (t // _MOE_BLOCK)
-        else:
-            y, here, hit = moe_ffn_held.raw(h, *args, valid=valid)
-            products = GROUPED_PRODUCTS
+            valid = jnp.ones((h.shape[0],), bool)
+        y, here, hit, products, rows = moe_ffn_held.raw(
+            h, unwrap(ex.router), unwrap(ex.gate), unwrap(ex.up),
+            unwrap(ex.down), ex.experts_held, ex.top_k, valid=valid)
         g = jnp.einsum("th,shi->tsi", h, unwrap(self.shared_gate))
         u = jnp.einsum("th,shi->tsi", h, unwrap(self.shared_up))
         a = (jax.nn.silu(g.astype(jnp.float32)) * u).astype(h.dtype)
         shared = jnp.einsum("tsi,sih->th", a, unwrap(self.shared_down),
                             preferred_element_type=jnp.float32)
         counts = jnp.stack([here, jnp.sum(valid, dtype=jnp.int32) * ex.top_k,
-                            hit, products]).astype(jnp.int32)
+                            hit, products, rows]).astype(jnp.int32)
         return (y.astype(jnp.float32)
                 + shared / cfg.num_shared_experts), counts
 
@@ -309,7 +298,7 @@ class CohereMoEForCausalLM(Layer):
     def _seq(self, ids, valid=None):
         """ids (S,) -> hidden (S, H), [(k, v)] a layer, counts."""
         x = unwrap(self.embed_tokens)[ids]
-        kv, counts = [], jnp.zeros((4,), jnp.int32)
+        kv, counts = [], 0
         for blk in self.layers:
             x, k, v, c = blk.forward_seq(x, valid)
             kv.append((k, v))
@@ -352,7 +341,7 @@ class CohereMoEForCausalLM(Layer):
         position -> (logits (B, V) float32, caches, counts)."""
         pos, active = unwrap(pos), unwrap(active)
         x = unwrap(self.embed_tokens)[unwrap(tokens)]
-        new, counts = [], jnp.zeros((4,), jnp.int32)
+        new, counts = [], 0
         for blk, (kbuf, vbuf) in zip(self.layers, caches):
             x, kbuf, vbuf, c = blk.forward_decode(x, unwrap(kbuf),
                                                   unwrap(vbuf), pos, active)

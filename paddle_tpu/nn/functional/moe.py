@@ -6,6 +6,13 @@ einsums with static shapes — under jit with the expert dim of the weights
 sharded P("ep", ...) and tokens sharded P("dp"), GSPMD lowers the dispatch
 einsum to the all-to-all the reference would have hand-written, and the
 per-expert FFN einsum runs fully expert-parallel on the MXU.
+
+`moe_ffn_held` is the other form, for ONE chip's share of an
+expert-parallel deployment: no capacity and nothing dropped; it sorts the
+picks, walks those that fell on the experts held here in chunks through
+grouped products, and says what that cost (picks here, experts hit,
+grouped products made, rows they went over) as device values a serving
+program returns with its tokens.
 """
 from __future__ import annotations
 
@@ -87,16 +94,21 @@ def _raw_moe_ffn(x, gate_w, w1, b1, w2, b2, top_k=2, capacity_factor=1.25,
 moe_ffn = defop("moe_ffn")(_raw_moe_ffn)
 
 
-# grouped products one `moe_ffn_held` call makes (gate, up, down): what a
-# serving span reports as `expert_products`, so that a reader of the device
-# trace knows how many events of the product a program call holds
+# grouped products one chunk of `moe_ffn_held` makes (gate, up, down): a call
+# returns how many it made, a serving span reports them as `expert_products`,
+# and a reader of the device trace knows how many events of the product a
+# program call holds
 GROUPED_PRODUCTS = 3
+# rows of sorted picks a chunk's grouped products go over: a call whose
+# picks (tokens x top_k) fit makes one chunk, a longer one walks the picks
+# held here in chunks of this many (chosen on the chip: PERF.md, PR 31)
+_CHUNK_ROWS = 2048
 
 
 def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
                       top_k=8, valid=None):
     """The part of a routed FFN that the experts HELD HERE give; nothing is
-    dropped.  Returns (y, picks_here, experts_hit).
+    dropped.  Returns (y, picks_here, experts_hit, products, rows).
 
     x: (T, d_model); router_w: (d_model, E) over ALL E experts; w_gate,
     w_up: (n_held, d_model, d_hidden), w_down: (n_held, d_hidden, d_model):
@@ -106,21 +118,31 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     router's score (float32) and weighs them by it over the sum of the k
     (the one form a configuration and a reference ask for so far).  Picks
     that fall on an expert held elsewhere add nothing here (that chip adds
-    them; on one chip the layer runs without its exchange).  The picks held
-    here are sorted by expert and go through one grouped product a matrix
-    (`jax.lax.ragged_dot`, rows past the last group untouched), so an
-    expert costs what its tokens cost and an expert no token picked is not
-    read.  `valid` (T,) bool: rows that are routed nowhere (an empty
-    serving slot).  picks_here: int32, picks that fell on held experts;
-    experts_hit: int32, held experts with at least one token.
+    them; on one chip the layer runs without its exchange), and nothing as
+    wide as a row of `x` is made for them: a stable sort of the T x top_k
+    picks by expert puts those held here first, and only that prefix is
+    walked, `_CHUNK_ROWS` rows at a time and as many chunks as it takes (a
+    trip count the device computes; no capacity, so no routing overflows).
+    A chunk gathers its rows of `x`, takes them through one grouped product
+    a matrix (`jax.lax.ragged_dot` with the chunk's part of each expert's
+    group, rows past the last group zeroed), weighs each row by its pick's
+    share in float32 and adds it to its token's row of `y`.  So an expert
+    costs what its tokens cost, an expert no token picked is not read and
+    one that straddles two chunks is read twice.  Where T x top_k fits one
+    chunk (a decode step) there is no loop.  `valid` (T,) bool: rows that
+    are routed nowhere (an empty serving slot, a prompt's padding).
+    int32 counts: picks_here, picks that fell on held experts;
+    experts_hit, held experts with at least one token; products, grouped
+    products made (`GROUPED_PRODUCTS` a chunk); rows, rows they went over
+    (a chunk's rows x chunks).
     """
-    t, _ = x.shape
+    t, d_model = x.shape
     n_experts = router_w.shape[-1]
     n_held = len(experts_held)
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)        # (T, K)
-    top = top / jnp.sum(top, axis=-1, keepdims=True)
+    share = (top / jnp.sum(top, axis=-1, keepdims=True)).reshape(-1)
     # expert id -> its place among the held leaves, n_held = held elsewhere
     lut = [n_held] * n_experts
     for place, e in enumerate(experts_held):
@@ -129,25 +151,44 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     if valid is not None:
         place = jnp.where(valid[:, None], place, n_held)
     place = place.reshape(-1)                                      # (T*K,)
-    order = jnp.argsort(place, stable=True)
     sizes = jnp.sum(place[:, None] == jnp.arange(n_held)[None, :],
                     axis=0, dtype=jnp.int32)                       # (n_held,)
-    here = jnp.sum(sizes)
-    with jax.named_scope("moe_expert_product"):
-        xs = x[order // top_k]
-        live = (jnp.arange(t * top_k) < here)[:, None]
-        dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
-            a, w.astype(a.dtype), sizes,
-            preferred_element_type=jnp.float32)
-        h = jnp.where(live, jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up),
-                      0.0).astype(x.dtype)
-        out = jnp.where(live, dot(h, w_down), 0.0)                 # sorted
-    # back to (token, pick) order: a gather, then the weighted sum over k
-    back = jnp.zeros((t * top_k,), jnp.int32).at[order].set(
-        jnp.arange(t * top_k, dtype=jnp.int32))
-    y = jnp.einsum("tk,tkd->td", top, out[back].reshape(t, top_k, -1))
+    ends = jnp.cumsum(sizes)
+    here = ends[-1]
+    rows = min(_CHUNK_ROWS, t * top_k)
+    chunks = -(-(t * top_k) // rows)
+    # the picks held here first, by expert; padded so that a chunk's slice
+    # never runs off the end (rows past `here` add nothing)
+    order = jnp.pad(jnp.argsort(place, stable=True).astype(jnp.int32),
+                    (0, chunks * rows - t * top_k))
+
+    def add_chunk(c, y):
+        lo = c * rows
+        picks = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        token = picks // top_k
+        live = (lo + jnp.arange(rows) < here)[:, None]
+        part = (jnp.clip(ends, lo, lo + rows)
+                - jnp.clip(ends - sizes, lo, lo + rows))
+        with jax.named_scope("moe_expert_product"):
+            xs = x[token]
+            dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                a, w.astype(a.dtype), part,
+                preferred_element_type=jnp.float32)
+            h = jnp.where(live, jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up),
+                          0.0).astype(x.dtype)
+            out = jnp.where(live, dot(h, w_down) * share[picks][:, None], 0.0)
+        return y.at[token].add(out)
+
+    y = jnp.zeros((t, d_model), jnp.float32)
+    if chunks == 1:
+        y, walked = add_chunk(0, y), jnp.int32(1)
+    else:
+        walked = (here + rows - 1) // rows
+        y = jax.lax.fori_loop(0, walked, add_chunk, y)
     return (y.astype(x.dtype), here.astype(jnp.int32),
-            jnp.sum(sizes > 0, dtype=jnp.int32))
+            jnp.sum(sizes > 0, dtype=jnp.int32),
+            (GROUPED_PRODUCTS * walked).astype(jnp.int32),
+            (rows * walked).astype(jnp.int32))
 
 
 moe_ffn_held = defop("moe_ffn_held")(_raw_moe_ffn_held)

@@ -63,10 +63,11 @@ class HeldExperts(Layer):
     deployment holds them: the router over all `num_experts`, the gated
     (silu) weights of the experts in `experts_held` alone, no token dropped.
 
-        y, picks_here, experts_hit = layer(x)          # x: (T, d_model)
+        y, picks_here, experts_hit, products, rows = layer(x)   # x: (T, d)
 
     `y` is the part of the routed sum that the held experts give
-    (`functional.moe_ffn_held`); the two int32 counts are what a serving
+    (`functional.moe_ffn_held`); the four int32 counts (the last two: the
+    grouped products made and the rows they went over) are what a serving
     engine's spans and counters report.  Expert weights are created in
     `dtype`; the router stays float32 (its scores pick the experts).
     """

@@ -86,12 +86,18 @@ layer's RING, written at ``pos % rows``; `write_slot` overwrites each
 leaf over its own length, and a bucket longer than the ring leaves the
 prompt's last ``rows`` positions in it.  Both programs return the
 model's int32 counts ``[picks on held experts, picks in all, held
-experts hit, grouped products made]`` with the tokens; they ride in the
+experts hit, grouped products made, rows those products went over]``
+with the tokens, summed over layers (and a decode call's steps) on the
+device: a prompt's routed layer walks the picks held here in chunks, so
+how many products it made is known only there.  They ride in the
 `serving_admit` / `serving_decode` spans' args (`routed_here`,
-`routed_all`, `experts_hit`, `expert_products`) and the counters `moe_routed_picks_total{where}`,
-`moe_experts_hit_total`, and `serving_kv_rows{kind}` gauges the rows
-held.  ``kv="paged"``, ``prefix_cache``, ``draft_model``, ``mesh``,
-``lora``, `preempt_slot` and `restore_run` raise for such a model.
+`routed_all`, `experts_hit`, `expert_products`, `expert_rows`) and the
+counters `moe_routed_picks_total{where}`, `moe_experts_hit_total` and
+`moe_expert_rows_total` (rows through the grouped products, to set
+against the picks held here and in all), and `serving_kv_rows{kind}`
+gauges the rows held.  ``kv="paged"``, ``prefix_cache``, ``draft_model``,
+``mesh``, ``lora``, `preempt_slot` and `restore_run` raise for such a
+model.
 
 Greedy requests are bit-identical to a solo
 `generation.generate(decode_strategy='greedy_search')` run of the same
@@ -1087,6 +1093,10 @@ class ServingEngine:
         self._c_hit = _obs_m.counter(
             "moe_experts_hit_total",
             "held experts that got at least one token, a layer a step")
+        self._c_rows = _obs_m.counter(
+            "moe_expert_rows_total",
+            "rows the grouped expert products went over (a chunk's rows x "
+            "chunks walked, a layer a step)")
         self._g_kv_rows = _obs_m.gauge(
             "serving_kv_rows",
             "cache rows the running requests hold, summed over layers, by "
@@ -1094,13 +1104,16 @@ class ServingEngine:
 
     def _count_routed(self, span_args: dict, counts):
         """A program's routed counts [here, all, experts hit, grouped
-        products made] into its span's args and the counters."""
-        here, total, hit, products = (int(c) for c in counts)
+        products made, rows they went over] into its span's args and the
+        counters."""
+        here, total, hit, products, rows = (int(c) for c in counts)
         span_args.update(routed_here=here, routed_all=total,
-                         experts_hit=hit, expert_products=products)
+                         experts_hit=hit, expert_products=products,
+                         expert_rows=rows)
         self._c_picks.labels(where="here").inc(here)
         self._c_picks.labels(where="elsewhere").inc(total - here)
         self._c_hit.inc(hit)
+        self._c_rows.inc(rows)
 
     def _refuse_batched(self, what: str):
         if self._batched:
@@ -1137,7 +1150,7 @@ class ServingEngine:
             stat_add("STAT_serving_compiles")
 
             def one(carry, _):
-                tokens, pos, pools, counts = carry
+                tokens, pos, pools = carry
                 last, pools, c = apply_decode(state, tokens, pools, pos,
                                               active)
                 if poison_armed:
@@ -1145,13 +1158,12 @@ class ServingEngine:
                 finite = jnp.isfinite(last).all(axis=-1)
                 tok, logp = _sample_step(last, keys, pos, temp, top_k,
                                          top_p, greedy)
-                return (tok, pos + 1, pools, counts + c), (tok, logp, finite)
+                return (tok, pos + 1, pools), (tok, logp, finite, c)
 
-            (tokens, pos, pools, counts), (toks, logps, finites) = \
-                jax.lax.scan(one, (tokens, pos, pools,
-                                   jnp.zeros((4,), jnp.int32)),
-                             None, length=chunk)
-            return toks, logps, finites, tokens, pos, counts, pools
+            (tokens, pos, pools), (toks, logps, finites, counts) = \
+                jax.lax.scan(one, (tokens, pos, pools), None, length=chunk)
+            return (toks, logps, finites, tokens, pos,
+                    jnp.sum(counts, axis=0), pools)
 
         from ..observability import track
         return track("serving_decode",

@@ -185,3 +185,34 @@ def test_held_experts_grouped_product_keeps_its_name(chip_compile):
     # as many as the program's spans say it made (`expert_products`)
     assert sum(bool(kernel.search(c)) for c in calls) == GROUPED_PRODUCTS
     assert len(calls) > GROUPED_PRODUCTS   # the group metadata: other names
+
+
+def test_held_experts_walk_a_prompts_picks_in_chunks(chip_compile):
+    """The same layer over a prompt's 2048 tokens (16,384 picks, an eighth
+    of them held here): one `while` walks the held picks a chunk at a time,
+    its body holds the three grouped products under the metric's name (an
+    event a product a chunk, as the spans' `expert_products` count them),
+    and nothing as wide as a row is left for all 16,384 picks."""
+    from paddle_tpu.nn.functional.moe import GROUPED_PRODUCTS, moe_ffn_held
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", "moe_expert_product_roofline.json")
+    with open(path) as f:
+        kernel = re.compile(json.load(f)["params"]["kernel"])
+    held, h, tokens, top_k = tuple(range(16)), 4096, 2048, 8
+    text = chip_compile(
+        lambda x, r, g, u, d, valid: moe_ffn_held.raw(
+            x, r, g, u, d, held, top_k=top_k, valid=valid),
+        ((tokens, h), jnp.bfloat16), ((h, 128), jnp.float32),
+        ((16, h, h), jnp.bfloat16), ((16, h, h), jnp.bfloat16),
+        ((16, h, h), jnp.bfloat16), ((tokens,), jnp.bool_))
+    bodies = re.findall(r" while\(.*body=(%[\w.\-]+)", text)
+    assert len(bodies) == 1
+    body = text[text.index(f"\n{bodies[0]} ("):]
+    body = body[:body.index("\n}\n")]
+    calls = [line.strip() for line in body.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sum(bool(kernel.search(c)) for c in calls) == GROUPED_PRODUCTS
+    assert sum(bool(kernel.search(line.strip()))
+               for line in text.splitlines()) == GROUPED_PRODUCTS
+    assert f"[{tokens * top_k},{h}]" not in text
+    assert f"[{tokens},{top_k},{h}]" not in text

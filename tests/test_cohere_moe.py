@@ -17,6 +17,7 @@ import paddle_tpu as paddle
 from paddle_tpu import models, nn, observability as obs
 from paddle_tpu.core.errors import InvalidArgumentError
 from paddle_tpu.nn import functional as F
+from paddle_tpu.nn.functional import moe
 from paddle_tpu.serving import ServingEngine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -104,7 +105,15 @@ def eng(tiny):
 
 # ------------------------------------------------------------------ model
 
-def test_logits_agree_with_the_reference(tiny, ref_logits):
+@pytest.mark.parametrize("chunk_rows", [None, 16],
+                         ids=["one_chunk", "chunks_of_16"])
+def test_logits_agree_with_the_reference(tiny, ref_logits, monkeypatch,
+                                         chunk_rows):
+    """24 tokens x 2 picks a layer: one chunk as `_CHUNK_ROWS` stands, and
+    the walk in chunks (a trip count the device computes) with it patched
+    under the picks."""
+    if chunk_rows:
+        monkeypatch.setattr(moe, "_CHUNK_ROWS", chunk_rows)
     model, d, top, layers = tiny
     assert model.config.layer_types == d["kinds"]
     ids = np.random.RandomState(0).randint(0, 128, (1, 24)).astype(np.int32)
@@ -164,7 +173,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference():
     routed, here, hit = [], 0, 0
     for share in ((0, 1), (2, 3), (4, 5), (6, 7)):
         take = jnp.asarray(share)
-        y, n_here, n_hit = F.moe_ffn_held(
+        y, n_here, n_hit, _, _ = F.moe_ffn_held(
             h, l["router"], l["eg"][take], l["eu"][take], l["ed"][take],
             share, top_k=2)
         routed.append(np.asarray(y))
@@ -195,8 +204,10 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     for p, v in zip((layer.router, layer.gate, layer.up, layer.down),
                     (jnp.asarray(router), gate, up, down)):
         p._set_data(v)
-    y, here, hit = layer(paddle.to_tensor(x))
+    y, here, hit, products, rows = layer(paddle.to_tensor(x))
     assert (int(here.numpy()), int(hit.numpy())) == (t * 2, 2)
+    assert (int(products.numpy()), int(rows.numpy())) == (
+        moe.GROUPED_PRODUCTS, t * 2)
     s = jax.nn.sigmoid(x @ router)
     w = s[:, [5, 3]] / jnp.sum(s[:, [5, 3]], -1, keepdims=True)
     want = sum(w[:, k:k + 1] * ((jax.nn.silu(x @ gate[k]) * (x @ up[k]))
@@ -206,8 +217,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     assert np.all(np.abs(np.asarray(y.numpy())).sum(-1) > 0)
     # rows marked not valid are routed nowhere
     valid = jnp.arange(t) < 10
-    y2, here2, _ = F.moe_ffn_held(x, jnp.asarray(router), gate, up, down,
-                                  (5, 3), top_k=2, valid=valid)
+    y2, here2, *_ = F.moe_ffn_held(x, jnp.asarray(router), gate, up, down,
+                                   (5, 3), top_k=2, valid=valid)
     assert int(here2) == 20 and not np.any(np.asarray(y2)[10:])
     with pytest.raises(ValueError):
         nn.HeldExperts(h, i, 6, 2, experts_held=(5, 5))
@@ -225,24 +236,78 @@ def test_the_one_form_of_routing_that_is_built_is_the_one_accepted(key,
         models.CohereMoEConfig(**TINY, **{key: value})
 
 
-def test_a_layer_says_how_many_grouped_products_it_made(monkeypatch, tiny):
-    """What the spans carry as `expert_products`: three a call of the routed
-    layer, and a long prompt makes a call a block of tokens."""
-    from paddle_tpu.models import cohere_moe
-    from paddle_tpu.nn.functional.moe import GROUPED_PRODUCTS
-    assert GROUPED_PRODUCTS == 3
-    block = tiny[0].layers[0]
-    h = jnp.asarray(np.random.RandomState(0).randn(16, 64), jnp.float32)
-    whole, counts = block._ffn(h, None)
-    assert int(counts[3]) == GROUPED_PRODUCTS
-    monkeypatch.setattr(cohere_moe, "_MOE_BLOCK", 4)
-    blocks, counts4 = block._ffn(h, None)
-    assert int(counts4[3]) == 4 * GROUPED_PRODUCTS
-    assert [int(c) for c in counts4[:2]] == [int(c) for c in counts[:2]]
-    # an expert is read once a block that routes a token to it
-    assert int(counts[2]) <= int(counts4[2]) <= 4 * int(counts[2])
-    np.testing.assert_allclose(np.asarray(blocks), np.asarray(whole),
-                               atol=1e-5)
+def plain_held(x, router, gate, up, down, held, top_k, valid):
+    """A loop over the tokens and over each token's picks that fell on a
+    held expert, in float64: (y, picks here, held experts hit)."""
+    x, router, gate, up, down = (np.asarray(a, np.float64)
+                                 for a in (x, router, gate, up, down))
+    y, here, hit = np.zeros_like(x), 0, set()
+    for t in np.flatnonzero(valid):
+        score = 1.0 / (1.0 + np.exp(-(x[t] @ router)))
+        picks = np.argsort(-score, kind="stable")[:top_k]
+        for e in picks:
+            if e not in held:
+                continue
+            k = held.index(e)
+            g = x[t] @ gate[k]
+            y[t] += (score[e] / score[picks].sum()) * (
+                (g / (1.0 + np.exp(-g)) * (x[t] @ up[k])) @ down[k])
+            here += 1
+            hit.add(e)
+    return y, here, len(hit)
+
+
+# tokens, held experts (of 6, two a token), rows a chunk, experts every
+# token is steered to (None: wherever the router's draw sends it), tokens
+# that are valid (None: all)
+HELD_CASES = {
+    "picks_below_a_chunk": (6, (1, 4), 16, None, None),
+    "one_chunk_exactly_full": (8, (3,), 8, (3, 5), None),
+    "an_expert_straddles_two_chunks": (12, (5, 3), 8, (3, 5), None),
+    "every_pick_held_here": (10, (0, 1, 2, 3, 4, 5), 8, None, None),
+    "no_pick_held_here": (9, (0, 2), 8, (3, 5), None),
+    "a_padded_tail": (12, (0, 1, 2, 3, 4, 5), 4, None, 5),
+    "a_tail_and_a_part_filled_chunk": (16, (1, 4, 5), 8, None, 11),
+}
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_the_picks_held_here_in_chunks_are_a_loop_over_each_tokens_picks(
+        monkeypatch, case):
+    """The compacted form (sort, walk the held prefix a chunk at a time,
+    add rows to their tokens) against the plain loop, with what it says of
+    itself: `GROUPED_PRODUCTS` a chunk walked and a chunk's rows a chunk,
+    so rows that are held elsewhere or padding cost no row."""
+    t, held, chunk_rows, steer, n_valid = HELD_CASES[case]
+    monkeypatch.setattr(moe, "_CHUNK_ROWS", chunk_rows)
+    rng = np.random.RandomState(len(case))
+    h, i, top_k = 16, 8, 2
+    x = np.abs(rng.randn(t, h)).astype(np.float32)
+    router = (rng.randn(h, 6) * 0.3).astype(np.float32)
+    if steer:           # positive x: these two columns win for every token
+        router[:, steer[0]], router[:, steer[1]] = 1.0, 0.5
+    gate, up, down = ((rng.randn(*s) * 0.3).astype(np.float32) for s in (
+        (len(held), h, i), (len(held), h, i), (len(held), i, h)))
+    valid = np.arange(t) < (t if n_valid is None else n_valid)
+    y, here, hit, products, rows = F.moe_ffn_held(
+        jnp.asarray(x), jnp.asarray(router), jnp.asarray(gate),
+        jnp.asarray(up), jnp.asarray(down), held, top_k=top_k,
+        valid=jnp.asarray(valid))
+    want, want_here, want_hit = plain_held(x, router, gate, up, down, held,
+                                           top_k, valid)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5)
+    assert (int(here), int(hit)) == (want_here, want_hit)
+    if t * top_k <= chunk_rows:       # one chunk, no loop: all the picks
+        assert (int(products), int(rows)) == (moe.GROUPED_PRODUCTS,
+                                              t * top_k)
+    else:
+        chunks = -(-want_here // chunk_rows)
+        assert (int(products), int(rows)) == (
+            moe.GROUPED_PRODUCTS * chunks, chunk_rows * chunks)
+    if steer:
+        assert want_here == t * len(set(steer) & set(held))
+    if n_valid is not None:           # the tail adds no row and gets none
+        assert int(rows) < t * top_k and not np.any(np.asarray(y)[n_valid:])
 
 
 # ------------------------------------------------------------- the engine
@@ -345,17 +410,20 @@ def test_the_spans_a_step_are_unchanged_and_carry_the_routed_counts(served):
         # three experts held: each hit counts once a layer a step
         assert 0 <= args["experts_hit"] <= min(
             args["routed_here"], 3 * layers * chunk)
-        # three grouped products a layer a step (a prompt: a block)
-        assert args["expert_products"] == 3 * layers * (
-            chunk if ev[NAME] == "serving_decode" else 1)
+        # three grouped products a layer a step over all the picks of the
+        # batch of 3 slots or of the prompt's bucket: one chunk each here
+        steps = chunk if ev[NAME] == "serving_decode" else 1
+        assert args["expert_products"] == 3 * layers * steps
+        assert args["expert_rows"] == k * layers * steps * (
+            3 if ev[NAME] == "serving_decode" else args["bucket"])
 
 
 def test_the_counters_add_up(eng):
     reg = obs.metrics.get_registry()
-    picks, hit = reg.get("moe_routed_picks_total"), reg.get(
-        "moe_experts_hit_total")
+    picks, hit, rows_through = (reg.get("moe_routed_picks_total"), reg.get(
+        "moe_experts_hit_total"), reg.get("moe_expert_rows_total"))
     before = (picks.value(where="here"), picks.value(where="elsewhere"),
-              hit.value())
+              hit.value(), rows_through.value())
     tracer = obs.get_tracer()
     tracer.clear()
     serve(eng, REQUESTS[:3], seed=5)
@@ -374,3 +442,5 @@ def test_the_counters_add_up(eng):
     assert picks.value(where="here") - before[0] == here > 0
     assert picks.value(where="elsewhere") - before[1] == total - here > 0
     assert hit.value() - before[2] == sum(a["experts_hit"] for a in spans)
+    assert rows_through.value() - before[3] == sum(
+        a["expert_rows"] for a in spans) >= here
