@@ -47,7 +47,10 @@ __all__ = ["ProgramSetError", "save_program_set", "load_program_set",
            "read_manifest", "LoadedProgram", "engine_manifest",
            "PROGRAM_SET_SUFFIX"]
 
-PROGRAM_SET_FORMAT = 1
+# 2: every program is `fn(weights, pools, inputs) -> dict` (ServingEngine.
+# _program_args); a format-1 artifact holds positional signatures and must
+# never be called with these arguments
+PROGRAM_SET_FORMAT = 2
 PROGRAM_SET_SUFFIX = ".pdprograms"
 
 

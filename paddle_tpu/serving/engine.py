@@ -1,25 +1,53 @@
 """ServingEngine: continuous batching over a slot-based KV-cache pool.
 
-Exactly two compiled program families serve every request mix:
+Three program bodies serve every engine configuration and request mix:
 
-- **bucketed prefill** (one trace per prompt-length bucket): the prompt,
-  right-padded to the bucket, runs through `model.forward_fixed` against a
-  bucket-sized scratch cache; the resulting KV is written into the assigned
-  slot of the engine-lifetime pool via `dynamic_update_slice`, overwriting
-  the slot's FULL [0, max_len) range (stale KV from the slot's previous
-  occupant can never leak).  The first generated token is sampled inside
-  the same program from the prompt's last-position logits.
-- **one decode step** (a single trace, ever): `model.forward_fixed` is
-  vmapped over the slot axis so every slot advances one token per call with
-  its OWN write position, and every sampling knob — temperature, top-k,
-  top-p, greedy flag, RNG key — is a per-slot dynamic input
-  (`generation.process_logits_dynamic`), so heterogeneous requests share
-  the trace.  Requests join and leave the resident batch between
-  iterations; nobody owns a compilation.
+- **prefill** (one trace per prompt-length bucket): the prompt,
+  right-padded to the bucket, runs through `model.forward_fixed` against
+  a bucket-sized scratch cache; the resulting KV is written into the
+  assigned slot of the engine-lifetime pool, overwriting the slot's FULL
+  [0, max_len) range (stale KV from the slot's previous occupant can
+  never leak).  The first generated token is sampled inside the same
+  program from the prompt's last-position logits.
+- **decode** (a single trace, ever): `model.forward_fixed` is vmapped
+  over the slot axis so every slot advances one token per step with its
+  OWN write position, `decode_chunk` steps a call, and every sampling
+  knob — temperature, top-k, top-p, greedy flag, RNG key — is a per-slot
+  dynamic input (`generation.process_logits_dynamic`), so heterogeneous
+  requests share the trace.  Requests join and leave the resident batch
+  between calls; nobody owns a compilation.
+- **verify** (``draft_model=``, in decode's place): the speculative tick,
+  below.
 
 Compilation count is therefore bounded by len(prefill_buckets) + 1 per
 engine, regardless of how many (prompt_len, max_new, sampling-param)
 combinations the traffic mixes — asserted by `compile_counts()`.
+
+What an engine is configured with never copies a body.  Each decision is
+taken once, at trace time, by a piece the bodies share:
+
+- **the cache view** (`self._cache`; `kv_pool.FixedKVView` /
+  `PagedKVView`) decides the KV layout.  `open` turns the pools and the
+  call's tables into the contiguous view the model runs against,
+  `publish` writes the rows a call produced back, `prompt_cache` /
+  `write_prompt` say what a prompt runs against and where its rows go,
+  and `prompt_inputs` / `batch_inputs` name the host-side inputs that go
+  with them.  For the fixed pool `open` and `publish` are the identity.
+- **the row forward** (`_row_forward`; for a prompt `_prompt_forward`)
+  decides what runs against the view: `forward_fixed` on one slot's
+  cache leaves at its own position, under `jax.vmap`, for the model or a
+  draft, inside the adapter context when the engine has adapters (the
+  adapter id is then one more vmapped operand); a model that decodes the
+  whole batch gets its `forward_decode` / `forward_prefill` in its
+  place.  The decode chunk, the verify tick's draft scan and its target
+  forward are all this one function.
+- **one signature**: every program is ``fn(weights, pools, inputs) ->
+  dict``.  `weights` holds the trees the engine has (``model``, and
+  ``draft`` / ``lora`` only when configured), `pools` the DONATED KV
+  pools (``model``, ``draft``), `inputs` the call's arrays by name
+  (`_prefill_inputs`, `_decode_inputs`: the live call, `warmup()` and the
+  program-set exporter all assemble them there), and the result names
+  what it returns (``out["pools"]``, ``out["toks"]``, ...).
 
 **Speculative decoding** (``draft_model=``): the decode program is
 replaced by ONE verify program per engine that (a) runs ``spec_tokens``
@@ -43,15 +71,19 @@ step token-for-token (same key folds, same distributions).
 **Paged KV pool** (``kv="paged"``): the slot-row pool is replaced by ONE
 block pool per layer (``[num_blocks, block_size, heads, head_dim]`` —
 serving/kv_pool.py) with a host-side allocator and per-slot block-table
-indirection.  The compiled programs change shape but not count or
-semantics: prefill writes the prompt's blocks through the slot's table
-(full-block overwrite — no stale KV survives re-serving), the
-decode/verify step gathers each slot's table into the contiguous view
-ONCE per call (the batched form of
+indirection, and the engine holds the paged cache view.  The compiled
+programs change shape but not count or semantics: prefill writes the
+prompt's blocks through the slot's table (full-block overwrite — no
+stale KV survives re-serving), the decode/verify step gathers each
+slot's table into the contiguous view ONCE per call (the batched form of
 `ops.paged_attention.gather_block_rows` — on CPU this reconstruction
 keeps every float op identical to the fixed engine, so streams stay
 bit-identical to solo generate) and scatters the tick's freshly written
 rows back in one pass, zeroing any block it enters (scrub-on-recycle).
+With ``prefix_cache=True`` a prompt runs at `cached_len` against its
+slot's own gathered view and only the uncached suffix is computed and
+written (`cached_len` is a dynamic input of the same per-bucket prefill;
+0 IS the cold path).
 Honest cost note: the gathered view is a TRANSIENT per-call working set
 of up to fixed-pool size, so on an accelerator the density win is in
 the PERSISTENT pool only until the pallas block-table kernel
@@ -75,29 +107,29 @@ token-for-token for the same seeds.
 
 **Models that decode the whole batch** (``model.serving_batch_decode``,
 e.g. `models.CohereMoEForCausalLM`): the same constructor and the same
-two program families, but prefill calls `model.forward_prefill(ids,
+bodies, but the prompt's forward is `model.forward_prefill(ids,
 prompt_len)` (the last position's logits alone) and the decode step
 hands `model.forward_decode(tokens, pools, pos, active)` ALL slots at
 once instead of vmapping a batch of one — a routed layer routes once a
 step and its experts see every slot's token in one grouped product;
 rows of empty slots are routed nowhere.  `gen_fixed_cache` may give
 layers different lengths: a leaf shorter than the pool is a window
-layer's RING, written at ``pos % rows``; `write_slot` overwrites each
-leaf over its own length, and a bucket longer than the ring leaves the
-prompt's last ``rows`` positions in it.  Both programs return the
-model's int32 counts ``[picks on held experts, picks in all, held
-experts hit, grouped products made, rows those products went over]``
-with the tokens, summed over layers (and a decode call's steps) on the
-device: a prompt's routed layer walks the picks held here in chunks, so
-how many products it made is known only there.  They ride in the
-`serving_admit` / `serving_decode` spans' args (`routed_here`,
-`routed_all`, `experts_hit`, `expert_products`, `expert_rows`) and the
-counters `moe_routed_picks_total{where}`, `moe_experts_hit_total` and
-`moe_expert_rows_total` (rows through the grouped products, to set
-against the picks held here and in all), and `serving_kv_rows{kind}`
-gauges the rows held.  ``kv="paged"``, ``prefix_cache``, ``draft_model``,
-``mesh``, ``lora``, `preempt_slot` and `restore_run` raise for such a
-model.
+layer's RING, written at ``pos % rows``; the fixed view's `write_prompt`
+overwrites each leaf over its own length, and a bucket longer than the
+ring leaves the prompt's last ``rows`` positions in it.  Both programs
+return the model's int32 counts ``[picks on held experts, picks in all,
+held experts hit, grouped products made, rows those products went
+over]`` with the tokens (``out["counts"]``), summed over layers (and a
+decode call's steps) on the device: a prompt's routed layer walks the
+picks held here in chunks, so how many products it made is known only
+there.  They ride in the `serving_admit` / `serving_decode` spans' args
+(`routed_here`, `routed_all`, `experts_hit`, `expert_products`,
+`expert_rows`) and the counters `moe_routed_picks_total{where}`,
+`moe_experts_hit_total` and `moe_expert_rows_total` (rows through the
+grouped products, to set against the picks held here and in all), and
+`serving_kv_rows{kind}` gauges the rows held.  ``kv="paged"``,
+``prefix_cache``, ``draft_model``, ``mesh``, ``lora``, `preempt_slot`
+and `restore_run` raise for such a model.
 
 Greedy requests are bit-identical to a solo
 `generation.generate(decode_strategy='greedy_search')` run of the same
@@ -108,6 +140,7 @@ loop runs.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -118,10 +151,12 @@ import numpy as np
 
 from ..core.errors import FatalError, InvalidArgumentError, UnavailableError
 from ..generation import process_logits_dynamic
+from ..observability import metrics as _obs_m, track
 from ..observability.tracer import span as _span
 from ..utils import faults
 from ..utils.monitor import stat_add
-from .kv_pool import KVPoolExhaustedError, PagedKVPool
+from .kv_pool import (FixedKVView, KVPoolExhaustedError, PagedKVPool,
+                      PagedKVView)
 from .request import Request, Response, RequestCancelled
 from .scheduler import RequestScheduler, DeadlineExceededError
 
@@ -135,15 +170,25 @@ class NonFiniteLogitsError(FatalError):
     code = "Fatal"
 
 
+# a decode call's per-slot sampling inputs, in `_sample_step`'s order (a
+# prefill's are one request's: `key`, then the same names)
+_SAMPLING = ("keys", "temp", "top_k", "top_p", "greedy")
+# what a decode call returns that stays on the device: the pools and the
+# next call's carry
+_RESIDENT = ("pools", "tokens", "pos")
+
+
 def _first_token_at(logits, idx, fold_pos, key, temp, top_k, top_p,
                     greedy):
-    """Sample the first generated token from the logits row at `idx`,
-    folding the key at the ABSOLUTE position `fold_pos` — the general
-    form behind `_first_token`.  The cached-prefix prefill computes only
-    the prompt's uncached suffix, so its last-position logits sit at the
-    RELATIVE index (prompt_len - 1 - cached_len) while the key must
-    still fold at the absolute (prompt_len - 1) for stream parity with
-    the cold path."""
+    """Sample the first generated token from the logits row at `idx` —
+    the prompt's last position, which right padding never touches (causal
+    mask), so this matches the solo generate prefill — folding the key at
+    the ABSOLUTE position `fold_pos` = prompt_len - 1 (decode step j
+    folds at prompt_len + j — counters never collide).  The cached-prefix
+    prefill computes only the prompt's uncached suffix, so its
+    last-position logits sit at the RELATIVE index (prompt_len - 1 -
+    cached_len) while the key must still fold at the absolute position
+    for stream parity with the cold path."""
     last = jax.lax.dynamic_index_in_dim(
         logits[0].astype(jnp.float32), idx, axis=0, keepdims=False)
     finite = jnp.isfinite(last).all()
@@ -157,20 +202,9 @@ def _first_token_at(logits, idx, fold_pos, key, temp, top_k, top_p,
     return tok, logp, finite
 
 
-def _first_token(logits, prompt_len, key, temp, top_k, top_p, greedy):
-    """Sample the first generated token from the prompt's last-position
-    logits (shared by the fixed and paged prefill programs).  Right
-    padding never touches that position (causal mask), so this matches
-    the solo generate prefill; the key is folded at (prompt_len - 1) and
-    decode step j folds at prompt_len + j — counters never collide."""
-    return _first_token_at(logits, prompt_len - 1, prompt_len - 1, key,
-                           temp, top_k, top_p, greedy)
-
-
-def _sample_step(last, keys, pos, temp, top_k, top_p, greedy):
-    """One per-slot sampling decision over (S, V) logits — shared by the
-    fixed and paged decode steps so the bit-identical-stream contract has
-    a single implementation site.  All-greedy fast path: the full dynamic
+def _sample_step(last, pos, keys, temp, top_k, top_p, greedy):
+    """One per-slot sampling decision over (S, V) logits — the single
+    implementation site of the bit-identical-stream contract.  All-greedy fast path: the full dynamic
     sampling pipeline (two (S, V) sorts + threefry draw) costs real time
     per iteration; a pure-greedy batch — the common serving mix — skips
     it at runtime via lax.cond, INSIDE the single decode trace (no extra
@@ -198,10 +232,10 @@ def _sample_step(last, keys, pos, temp, top_k, top_p, greedy):
     return jax.lax.cond(jnp.all(greedy), all_greedy, mixed, last)
 
 
-def _draft_propose(dlast, keys, pos, temp, top_k, top_p, greedy, i):
-    """One per-slot draft proposal from (S, V) draft logits — shared by
-    the fixed and paged verify steps (same single-site rationale and
-    all-greedy fast path as _sample_step).  Returns (proposal, q)."""
+def _draft_propose(dlast, pos, i, keys, temp, top_k, top_p, greedy):
+    """Draft step `i`'s per-slot proposal from (S, V) draft logits (same
+    single-site rationale and all-greedy fast path as _sample_step).
+    Returns (proposal, q)."""
     from ..generation.speculative import draft_proposal_key
 
     def mixed(dlast):
@@ -220,39 +254,6 @@ def _draft_propose(dlast, keys, pos, temp, top_k, top_p, greedy, i):
     return jax.lax.cond(jnp.all(greedy), all_greedy, mixed, dlast)
 
 
-def _extract_rows(ctx, start, n):
-    """Per-slot (n,) row windows from gathered (S, T, ...) KV views —
-    the write-back side of the paged decode/verify builders."""
-    return [
-        (jax.vmap(lambda c, p: jax.lax.dynamic_slice_in_dim(
-            c, p, n))(kc, start),
-         jax.vmap(lambda c, p: jax.lax.dynamic_slice_in_dim(
-             c, p, n))(vc, start))
-        for (kc, vc) in ctx]
-
-
-def _gather_ctx(pool, tables):
-    """Batched `ops.paged_attention.gather_block_rows` (ONE
-    implementation site for the clip/sentinel contract): (S, nb_max)
-    block tables over a (num_blocks, block_size, ...) pool -> every
-    slot's contiguous (T, ...) KV view — the SAME length the fixed
-    engine's slot row would have (to the block boundary), so the paged
-    attention pays nothing extra.  Shared by the paged decode and verify
-    builders."""
-    from ..ops.paged_attention import gather_block_rows
-    return jax.vmap(gather_block_rows, in_axes=(None, 0))(pool, tables)
-
-
-def _window_start(pos, n_rows, total_rows):
-    """Write-back window start for extracting `n_rows` rows from a
-    (*, total_rows, ...) gathered view: `pos` clamped so the window
-    never runs off the end.  A clamped window re-writes up to
-    (pos - start) rows BELOW pos with the values the gather read for
-    them — idempotent by construction — instead of paying a permanently
-    longer view just to keep dynamic_slice from clamping."""
-    return jnp.maximum(0, jnp.minimum(pos, total_rows - n_rows))
-
-
 class _CachedPlan:
     """Host-side warm-admission plan (see `_cached_plan`)."""
 
@@ -266,40 +267,6 @@ class _CachedPlan:
         self.cached_len = cached_len  # dynamic prefill input
         self.bucket = bucket          # SUFFIX bucket (plen - cached_len)
         self.new_live = new_live      # fresh live blocks this admit costs
-
-
-def _paged_row_writer(block_size, sentinel, pool_len):
-    """Builds the traced write-back for paged decode/verify: scatter
-    `n_rows` freshly produced KV rows per slot (positions pos..pos+n-1)
-    through the block tables, zeroing every block a slot ENTERS (write
-    offset 0) before the rows land — the scrub-on-recycle guarantee.
-    Inactive slots and rows past pool_len route through the sentinel id
-    and are dropped."""
-    from ..ops.paged_attention import scatter_block_rows, scrub_blocks
-
-    def write(pools, tables, pos, rows_list, active, n_rows):
-        pvals = pos[:, None] + jnp.arange(n_rows)[None, :]      # (S, R)
-        bidx = jnp.clip(pvals // block_size, 0, tables.shape[1] - 1)
-        blk = jnp.take_along_axis(tables, bidx, axis=1)
-        off = (pvals % block_size).reshape(-1)
-        ok = active[:, None] & (pvals < pool_len)
-        blk_w = jnp.where(ok, blk, sentinel).reshape(-1)
-        # a block's first row IS the entering position, so every already
-        # committed row of the entering slot lives in earlier blocks —
-        # zeroing here can only erase recycled/stale speculative rows
-        scrub = jnp.where(ok & (pvals % block_size == 0), blk,
-                          sentinel).reshape(-1)
-        new_pools = []
-        for (kp, vp), (kr, vr) in zip(pools, rows_list):
-            kr = kr.reshape((-1,) + kr.shape[2:])               # (S*R, ...)
-            vr = vr.reshape((-1,) + vr.shape[2:])
-            kp = scrub_blocks(kp, scrub)
-            vp = scrub_blocks(vp, scrub)
-            new_pools.append((scatter_block_rows(kp, blk_w, off, kr),
-                              scatter_block_rows(vp, blk_w, off, vr)))
-        return new_pools
-
-    return write
 
 
 def _default_buckets(max_len: int):
@@ -572,12 +539,12 @@ class ServingEngine:
                                        self._pool_len)
             self._pools = self.kv_pool.build_pools(model, dtype,
                                                    put=self._kv_put)
+            self._cache = PagedKVView(self.kv_pool, self.max_slots)
             # OOM preemption state: runs parked when the block pool runs
             # dry mid-decode, resumed as it drains (bounded — overflow is
             # the typed KVPoolExhaustedError path)
             self._oom_paused: List[PreemptedRun] = []
             self._max_oom_paused = max(2, 2 * self.max_slots)
-            self._paged_cache = None  # (allocator version, tables, active)
             self._oom_preempts = 0
             self._oom_failed = 0
             if prefix_cache:
@@ -596,6 +563,7 @@ class ServingEngine:
                 self._cow_fn = jax.jit(_cow, donate_argnums=(0,))
         else:
             self.kv_pool = None
+            self._cache = FixedKVView()
             # THE pool: one gen_fixed_cache(max_slots, pool_len)
             # allocation, reused for the engine's lifetime
             self._pools = model.gen_fixed_cache(self.max_slots,
@@ -606,11 +574,8 @@ class ServingEngine:
         self._assert_kv_sharded(self._pools, "KV pool")
         self._warm = False
         self._slots: Dict[int, _SlotRun] = {}
-        # device-resident decode batch state; rebuilt from host _SlotRun
-        # state only when membership changes (admission / slot release)
-        self._dev_tokens = None
-        self._dev_pos = None
-        self._dev_params = None
+        # device-resident decode batch (`_rebuild_batch`)
+        self._dev = None
         self._batch_dirty = True
         self._rid = 0
         self._submit_lock = threading.Lock()
@@ -618,11 +583,6 @@ class ServingEngine:
         # decode program carries zero fault branches
         self._poison_target = faults.nan_logits_request()
         self._key_width = len(np.asarray(jax.random.PRNGKey(0)))
-        # the pool is DONATED to every prefill/decode call and replaced by
-        # the returned buffers: XLA updates the slots in place instead of
-        # copying max_slots * max_len of KV per call (measured 166x on a
-        # CPU pool-passthrough update; the same aliasing TPU donation does)
-        self._donate = (1,)
         self._compiles = {"decode": 0, "prefill": {b: 0 for b in self.buckets}}
         self._decode_calls = 0  # slow_decode fault stride counter
         self._steps = 0  # steps that had work: the `serving_step` span's id
@@ -651,32 +611,17 @@ class ServingEngine:
             # per-tick flag is a dynamic input
             self._diverge_every = faults.draft_diverge_every()
             self._spec_ticks = 0
-            from ..observability import metrics as _obs_m2
-            self._h_accept = _obs_m2.histogram(
+            self._h_accept = _obs_m.histogram(
                 "serving_spec_accept_rate",
                 "accepted draft proposals / spec_tokens, per slot per tick")
             self._spec_proposed = 0
             self._spec_accepted = 0
-            self._decode_fn = (self._build_verify_paged()
-                               if self.kv == "paged"
-                               else self._build_verify())
-        elif self._batched:
-            self._init_batched()
-            self._decode_fn = self._build_decode_batched()
+            self._decode_fn = self._build_verify()
         else:
-            self._decode_fn = (self._build_decode_paged()
-                               if self.kv == "paged"
-                               else self._build_decode())
-        if self.kv == "paged":
-            # with a prefix cache every bucket's prefill is the cached
-            # variant (cached_len=0 IS the cold path) — the program
-            # family stays one prefill per bucket, bound unchanged
-            build = (self._build_prefill_cached if self.prefix_cache
-                     is not None else self._build_prefill_paged)
-            self._prefill_fns = {b: build(b) for b in self.buckets}
-        else:
-            self._prefill_fns = {b: self._build_prefill(b)
-                                 for b in self.buckets}
+            if self._batched:
+                self._init_batched()
+            self._decode_fn = self._build_decode()
+        self._prefill_fns = {b: self._build_prefill(b) for b in self.buckets}
         # AOT program set (paddle_tpu.programs.program_set): swap the
         # freshly built — but never yet traced — program family for
         # deserialized ones.  'exe' programs are already-compiled native
@@ -699,7 +644,6 @@ class ServingEngine:
         # observability: latency histograms shared with the unified
         # report / Prometheus endpoint (handles cached; registry.reset()
         # zeroes values in place)
-        from ..observability import metrics as _obs_m
         self._h_ttft = _obs_m.histogram(
             "serving_ttft_seconds", "submit -> first streamed token")
         self._h_itl = _obs_m.histogram(
@@ -862,217 +806,180 @@ class ServingEngine:
                 for k, v in state.items()}
 
     # ------------------------------------------------------------------
-    # compiled programs
+    # compiled programs: three bodies over the cache view and the row
+    # forward, one signature (module docstring)
     # ------------------------------------------------------------------
-    def _lora_ctx(self, lora_args, aid):
-        """Trace-time adapter context for program bodies: rebinds the
-        positional lora program argument ((A,B) per key + scales) to the
-        engine's static key tuple and scopes the (traced) adapter id so
-        the forward hooks installed by `attach_serving_lora` see it.
+    def _lora_ctx(self, weights, aid=None):
+        """Trace-time adapter context for program bodies: on an engine
+        with adapters it rebinds the `lora` weights ((A,B) per key +
+        scales) to the engine's static key tuple and scopes the (traced)
+        adapter id so the forward hooks installed by
+        `attach_serving_lora` see it; any other engine enters nothing.
         Entered per vmapped row in decode (aid is the row's scalar) and
         once per prefill (aid is the request's scalar)."""
+        if self.lora is None:
+            return contextlib.nullcontext()
         from ..lora.layers import adapter_context
-        pairs, scales = lora_args
+        pairs, scales = weights["lora"]
         return adapter_context(dict(zip(self._lora_keys, pairs)),
                                scales, aid)
 
+    def _row_forward(self, who, weights, inputs, every=False):
+        """`step(ids, view, pos) -> (logits, view)` over all slots, for
+        the model or the draft (`who`): `forward_fixed` on ONE slot's
+        cache leaves at the slot's own position, under `jax.vmap` over
+        the slot axis, giving the last position's logits (with `every`,
+        all of them) in float32 and the slot's new leaves.  The decode
+        chunk, the verify tick's draft scan and its target forward are
+        all this one function.  On an engine with adapters each row
+        enters the adapter context under its own id (one more vmapped
+        operand): heterogeneous adapters batch in ONE tick, one program.
+        A model that decodes the whole batch (`serving_batch_decode`)
+        gets its `forward_decode` over all slots in its place — no vmap:
+        a routed layer routes once and its experts see every slot's
+        token, rows of empty slots routed nowhere — which returns the
+        model's counts as a third value."""
+        state = weights[who]
+        if self._batched:
+            return lambda tokens, view, pos: self._apply_decode(
+                state, tokens, view, pos, inputs["active"])
+        apply = self._apply if who == "model" else self._dapply
+        aids = (inputs["aids"],) if self.lora is not None else ()
+
+        def row(ids, caches, p, *aid):
+            c = [(k[None], v[None]) for (k, v) in caches]
+            with self._lora_ctx(weights, *aid):
+                # a token or a slot's K+1 of them -> (1, n)
+                logits, new = apply(state, ids[(None,) * (2 - ids.ndim)],
+                                    c, p)
+            logits = logits[0] if every else logits[0, -1]
+            return (logits.astype(jnp.float32),
+                    [(k[0], v[0]) for (k, v) in new])
+
+        return lambda ids, view, pos: jax.vmap(row)(ids, view, pos, *aids)
+
+    def _prompt_forward(self, who, weights, pools, inputs):
+        """The prompt's forward for the model or the draft (`who`) and
+        its rows written into that pool: -> (logits, the new pool).  The
+        prompt, right-padded to the bucket, runs through `forward_fixed`
+        against what the cache view gives it — a bucket-sized scratch
+        cache at 0, or with a prefix cache the slot's own view at
+        `cached_len` — under the request's adapter where the engine has
+        adapters.  A model that decodes the whole batch gets its
+        `forward_prefill` in its place, which returns the last position's
+        logits alone (a bucket's logits over the whole vocabulary are not
+        needed) and the model's counts as a third value."""
+        state, ids = weights[who], inputs["ids"]
+        if self._batched:
+            logits, kv, counts = self._apply_prefill(state, ids,
+                                                     inputs["prompt_len"])
+            return (logits,
+                    self._cache.write_prompt(pools[who], kv, inputs), counts)
+        model, apply = ((self.model, self._apply) if who == "model"
+                        else (self.draft_model, self._dapply))
+        caches, at = self._cache.prompt_cache(
+            pools[who], inputs,
+            lambda: model.gen_fixed_cache(1, ids.shape[1], self._dtype))
+        with self._lora_ctx(weights, inputs.get("aid")):
+            logits, kv = apply(state, ids, caches, at)
+        return logits, self._cache.write_prompt(pools[who], kv, inputs)
+
     def _build_prefill(self, bucket: int):
-        """One per-bucket prefill program.  On a speculative engine the
+        """One per-bucket prefill program: the prompt's forward, its rows
+        written into the slot (the fixed view overwrites the slot's FULL
+        range, the paged one every block the bucket covers: no stale KV
+        survives re-serving), and the first generated token sampled from
+        the prompt's last-position logits.  On a speculative engine the
         SAME program additionally prefills the draft pool (one draft
-        forward over the same padded ids, slot row written with the same
-        full-range overwrite) — the first token still comes from the
-        target's last-prompt-position logits, so greedy parity is
-        identical with and without a draft.  On a LoRA engine the same
-        program takes the factor stacks + a scalar adapter id as EXTRA
-        dynamic inputs (adapter id 0 = base model) — still one program
-        per bucket."""
-        apply_fixed = self._apply
-        model, draft = self.model, self.draft_model
-        dtype = self._dtype
-        dapply = self._dapply if draft is not None else None
-
-        def write_slot(pools, kv, slot, prompt_len=None):
-            new_pools = []
-            for (kp, vp), (kc, vc) in zip(pools, kv):
-                # full-range overwrite: bucket KV + zeros to the leaf's own
-                # length (pool_len, or a window layer's ring), so a
-                # recycled slot keeps no stale KV from its previous tenant
-                rows = kp.shape[1]
-                if kc.shape[1] > rows:
-                    # a bucket longer than the ring leaves the prompt's
-                    # last `rows` positions in it: row r holds the one
-                    # position p in [plen - rows, plen) with p % rows == r
-                    first = prompt_len - rows
-                    p = first + (jnp.arange(rows) - first) % rows
-                    held = (p >= 0)[None, :, None, None]
-                    at = jnp.maximum(p, 0)
-                    krow = jnp.where(held, jnp.take(kc, at, axis=1),
-                                     0).astype(kp.dtype)
-                    vrow = jnp.where(held, jnp.take(vc, at, axis=1),
-                                     0).astype(vp.dtype)
-                else:
-                    krow = jnp.zeros((1, rows) + kp.shape[2:], kp.dtype)
-                    vrow = jnp.zeros((1, rows) + vp.shape[2:], vp.dtype)
-                    krow = jax.lax.dynamic_update_slice(
-                        krow, kc.astype(kp.dtype), (0, 0, 0, 0))
-                    vrow = jax.lax.dynamic_update_slice(
-                        vrow, vc.astype(vp.dtype), (0, 0, 0, 0))
-                new_pools.append((
-                    jax.lax.dynamic_update_slice(kp, krow, (slot, 0, 0, 0)),
-                    jax.lax.dynamic_update_slice(vp, vrow, (slot, 0, 0, 0))))
-            return new_pools
-
-        first_token = _first_token
-
-        def count_trace():
+        forward over the same padded ids) — the first token still comes
+        from the target's logits, so greedy parity is identical with and
+        without a draft.  Adapters and a cached prefix add dynamic inputs
+        (`aid`, adapter id 0 = base model; `cached_len`, 0 IS the cold
+        path), never a program: the bound stays one per bucket."""
+        def prefill(weights, pools, inputs):
             self._compiles["prefill"][bucket] += 1  # trace-count (host)
             stat_add("STAT_serving_compiles")
+            prompt_len = inputs["prompt_len"]
+            new_pools = {}
+            logits, new_pools["model"], *counts = self._prompt_forward(
+                "model", weights, pools, inputs)
+            if "draft" in pools:
+                _, new_pools["draft"] = self._prompt_forward(
+                    "draft", weights, pools, inputs)
+            # where the prompt's last position lies in the logits the
+            # forward gave: a batched model returns that position alone,
+            # and a cached prefix was not computed
+            if self._batched:
+                idx = 0
+            else:
+                idx = prompt_len - 1
+                if "cached_len" in inputs:
+                    idx = idx - inputs["cached_len"]
+            tok, logp, finite = _first_token_at(
+                logits, idx, prompt_len - 1,
+                *(inputs[k] for k in ("key",) + _SAMPLING[1:]))
+            out = {"tok": tok, "logp": logp, "finite": finite,
+                   "pools": new_pools}
+            if counts:
+                out["counts"] = counts[0]
+            return out
 
-        if self._batched:
-            apply_prefill = self._apply_prefill
-
-            def prefill(state, pools, ids, slot, prompt_len, key, temp,
-                        top_k, top_p, greedy):
-                count_trace()
-                # the model returns the last position's logits alone (a
-                # bucket's logits over the whole vocabulary are not needed)
-                logits, kv, counts = apply_prefill(state, ids, prompt_len)
-                new_pools = write_slot(pools, kv, slot, prompt_len)
-                tok, logp, finite = _first_token_at(
-                    logits, 0, prompt_len - 1, key, temp, top_k, top_p,
-                    greedy)
-                return tok, logp, finite, new_pools, counts
-
-            name, donate = f"serving_prefill_b{bucket}", self._donate
-        elif draft is None and self.lora is not None:
-            def prefill(state, pools, lora, ids, slot, prompt_len, aid,
-                        key, temp, top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                with self._lora_ctx(lora, aid):
-                    logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_slot(pools, kv, slot)
-                tok, logp, finite = first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools
-
-            name, donate = f"serving_prefill_b{bucket}", self._donate
-        elif draft is None:
-            def prefill(state, pools, ids, slot, prompt_len, key, temp,
-                        top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_slot(pools, kv, slot)
-                tok, logp, finite = first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools
-
-            name, donate = f"serving_prefill_b{bucket}", self._donate
-        else:
-            def prefill(state, dstate, pools, dpools, ids, slot,
-                        prompt_len, key, temp, top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_slot(pools, kv, slot)
-                dscratch = draft.gen_fixed_cache(1, bucket, dtype)
-                _, dkv = dapply(dstate, ids, dscratch, 0)
-                new_dpools = write_slot(dpools, dkv, slot)
-                tok, logp, finite = first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools, new_dpools
-
-            name, donate = f"serving_prefill_spec_b{bucket}", (2, 3)
-
-        from ..observability import track
-        return track(name, jax.jit(prefill, donate_argnums=donate))
+        kind = ("prefill_spec" if self.draft_model is not None
+                else "prefill_cached" if self.prefix_cache is not None
+                else "prefill")
+        return track(f"serving_{kind}_b{bucket}",
+                     jax.jit(prefill, donate_argnums=(1,)))
 
     def _build_decode(self):
-        apply_fixed = self._apply
+        """THE decode step: `decode_chunk` iterations of the row forward
+        and `_sample_step` in one `lax.scan` over the cache view — the
+        pool itself, or every slot's block table gathered ONCE per call,
+        the chunk's rows scattered back in one pass after it.  The
+        per-call host+dispatch cost is amortized across chunk * slots
+        tokens.  The final (tokens, pos) carry is exactly the next call's
+        input while batch membership is unchanged: the engine feeds the
+        device arrays straight back, so a steady-state decode call
+        uploads nothing."""
         poison_armed = self._poison_target is not None
-
         chunk = self.decode_chunk
+        cache = self._cache
 
-        if self.lora is not None:
-            # LoRA decode: per-slot adapter ids ride next to the sampling
-            # params as one more dynamic input and each vmapped row
-            # gathers its own factors — heterogeneous adapters batch in
-            # ONE tick, one program (the PR-4 dynamic-sampling pattern)
-            def decode(state, pools, lora, tokens, pos, aids, keys, temp,
-                       top_k, top_p, greedy, poison):
-                self._compiles["decode"] += 1  # trace-count (host)
-                stat_add("STAT_serving_compiles")
-
-                def one(carry, _):
-                    tokens, pos, pools = carry
-
-                    def row(tok, caches, p, aid):
-                        c = [(k[None], v[None]) for (k, v) in caches]
-                        with self._lora_ctx(lora, aid):
-                            logits, new = apply_fixed(state,
-                                                      tok[None, None], c, p)
-                        return (logits[0, -1].astype(jnp.float32),
-                                [(k[0], v[0]) for (k, v) in new])
-
-                    last, pools = jax.vmap(row)(tokens, pools, pos, aids)
-                    if poison_armed:
-                        last = faults.poison_logits(last, poison)
-                    finite = jnp.isfinite(last).all(axis=-1)
-                    tok, logp = _sample_step(last, keys, pos, temp, top_k,
-                                             top_p, greedy)
-                    return (tok, pos + 1, pools), (tok, logp, finite)
-
-                (tokens, pos, pools), (toks, logps, finites) = jax.lax.scan(
-                    one, (tokens, pos, pools), None, length=chunk)
-                return toks, logps, finites, tokens, pos, pools
-
-            from ..observability import track
-            return track("serving_decode",
-                         jax.jit(decode, donate_argnums=self._donate))
-
-        def decode(state, pools, tokens, pos, keys, temp, top_k, top_p,
-                   greedy, poison):
+        def decode(weights, pools, inputs):
             self._compiles["decode"] += 1  # trace-count (host side effect)
             stat_add("STAT_serving_compiles")
+            forward = self._row_forward("model", weights, inputs)
+            sampling = [inputs[k] for k in _SAMPLING]
+            pos0 = inputs["pos"]
 
             def one(carry, _):
-                tokens, pos, pools = carry
-
-                def row(tok, caches, p):
-                    c = [(k[None], v[None]) for (k, v) in caches]
-                    logits, new = apply_fixed(state, tok[None, None], c, p)
-                    return (logits[0, -1].astype(jnp.float32),
-                            [(k[0], v[0]) for (k, v) in new])
-
-                last, pools = jax.vmap(row)(tokens, pools, pos)
+                tokens, pos, view = carry
+                last, view, *counts = forward(tokens, view, pos)
                 if poison_armed:
-                    last = faults.poison_logits(last, poison)
+                    last = faults.poison_logits(last, inputs["poison"])
                 finite = jnp.isfinite(last).all(axis=-1)
-                tok, logp = _sample_step(last, keys, pos, temp, top_k,
-                                         top_p, greedy)
-                return (tok, pos + 1, pools), (tok, logp, finite)
+                tok, logp = _sample_step(last, pos, *sampling)
+                return (tok, pos + 1, view), (tok, logp, finite, *counts)
 
-            # chunked decode: `chunk` iterations per compiled call, the
-            # per-call host+dispatch cost amortized across chunk * slots
-            # tokens.  The final (tokens, pos) carry is exactly the next
-            # call's input while batch membership is unchanged: the engine
-            # feeds the device arrays straight back, so a steady-state
-            # decode call uploads nothing.
-            (tokens, pos, pools), (toks, logps, finites) = jax.lax.scan(
-                one, (tokens, pos, pools), None, length=chunk)
-            return toks, logps, finites, tokens, pos, pools
+            (tokens, pos, view), (toks, logps, finites, *counts) = \
+                jax.lax.scan(one, (inputs["tokens"], pos0,
+                                   cache.open(pools["model"], inputs)),
+                             None, length=chunk)
+            out = {"toks": toks, "logps": logps, "finites": finites,
+                   "tokens": tokens, "pos": pos,
+                   "pools": {"model": cache.publish(
+                       pools["model"], view, inputs, pos0, chunk)}}
+            if counts:   # the model's, summed over the chunk's steps
+                out["counts"] = jnp.sum(counts[0], axis=0)
+            return out
 
-        from ..observability import track
         return track("serving_decode",
-                     jax.jit(decode, donate_argnums=self._donate))
+                     jax.jit(decode, donate_argnums=(1,)))
 
     def _init_batched(self):
         """What an engine over a `serving_batch_decode` model adds: the two
         entries of the model's protocol, which pool leaves are rings, and
         the routed layers' counters."""
         from ..jit import functional_call
-        from ..observability import metrics as _obs_m
         model = self.model
 
         def apply_prefill(state, ids, prompt_len):
@@ -1134,79 +1041,42 @@ class ServingEngine:
         for kind, n in held.items():
             self._g_kv_rows.labels(kind=kind).set(n)
 
-    def _build_decode_batched(self):
-        """The decode program of a `serving_batch_decode` model: one
-        `forward_decode` over all slots a step (no vmap: a routed layer
-        routes once and its experts see every slot's token), rows of empty
-        slots routed nowhere.  Returns the model's counts summed over the
-        chunk's steps with the tokens."""
-        apply_decode = self._apply_decode
-        poison_armed = self._poison_target is not None
-        chunk = self.decode_chunk
-
-        def decode(state, pools, tokens, pos, active, keys, temp, top_k,
-                   top_p, greedy, poison):
-            self._compiles["decode"] += 1  # trace-count (host side effect)
-            stat_add("STAT_serving_compiles")
-
-            def one(carry, _):
-                tokens, pos, pools = carry
-                last, pools, c = apply_decode(state, tokens, pools, pos,
-                                              active)
-                if poison_armed:
-                    last = faults.poison_logits(last, poison)
-                finite = jnp.isfinite(last).all(axis=-1)
-                tok, logp = _sample_step(last, keys, pos, temp, top_k,
-                                         top_p, greedy)
-                return (tok, pos + 1, pools), (tok, logp, finite, c)
-
-            (tokens, pos, pools), (toks, logps, finites, counts) = \
-                jax.lax.scan(one, (tokens, pos, pools), None, length=chunk)
-            return (toks, logps, finites, tokens, pos,
-                    jnp.sum(counts, axis=0), pools)
-
-        from ..observability import track
-        return track("serving_decode",
-                     jax.jit(decode, donate_argnums=self._donate))
-
-    # ------------------------------------------------------------------
-    # speculative verify program (draft_model engines)
-    # ------------------------------------------------------------------
     def _build_verify(self):
-        """THE speculative tick: K sequential draft proposals, one batched
-        target forward over [last_committed, d_1..d_K] (K+1 positions),
-        in-program accept/reject + commit (generation.speculative).  One
-        trace, ever: sampling params, spec on/off, poison and diverge are
-        all dynamic per-slot/per-tick inputs."""
+        """THE speculative tick (draft_model engines): K sequential draft
+        proposals, one batched target forward over [last_committed,
+        d_1..d_K] (K+1 positions), in-program accept/reject + commit
+        (generation.speculative).  Draft and target each run against
+        their pool's cache view and publish the K+1 rows they wrote (a
+        paged draft pool pages through the SAME tables).  One trace,
+        ever: sampling params, spec on/off, poison and diverge are all
+        dynamic per-slot/per-tick inputs."""
         from ..generation.speculative import (commit_speculative_greedy,
                                               commit_speculative_sampled)
-        apply_fixed, dapply = self._apply, self._dapply
         poison_armed = self._poison_target is not None
         diverge_armed = self._diverge_every is not None
         k_spec = self.spec_tokens
         pad = self.pad_token_id
+        cache = self._cache
 
-        def verify(state, dstate, pools, dpools, tokens, pos, keys, temp,
-                   top_k, top_p, greedy, spec_on, poison, diverge):
+        def verify(weights, pools, inputs):
             self._compiles["decode"] += 1  # trace-count (host side effect)
             stat_add("STAT_serving_compiles")
-
-            def drow(tok, caches, p):
-                c = [(kb[None], vb[None]) for (kb, vb) in caches]
-                logits, new = dapply(dstate, tok[None, None], c, p)
-                return (logits[0, -1].astype(jnp.float32),
-                        [(kb[0], vb[0]) for (kb, vb) in new])
+            drow = self._row_forward("draft", weights, inputs)
+            trow = self._row_forward("model", weights, inputs, every=True)
+            tokens, pos, spec_on = (inputs["tokens"], inputs["pos"],
+                                    inputs["spec_on"])
+            sampling = keys, temp, top_k, top_p, greedy = [
+                inputs[k] for k in _SAMPLING]
 
             def dstep(carry, i):
-                cur, dp = carry
-                dlast, dp = jax.vmap(drow)(cur, dp, pos + i)
+                cur, dview = carry
+                dlast, dview = drow(cur, dview, pos + i)
                 if diverge_armed:
-                    dlast = faults.poison_draft_logits(dlast, diverge)
+                    dlast = faults.poison_draft_logits(dlast,
+                                                       inputs["diverge"])
                 dfin = jnp.isfinite(dlast).all(axis=-1)
-
-                prop, q = _draft_propose(dlast, keys, pos, temp, top_k,
-                                         top_p, greedy, i)
-                return (prop, dp), (prop, q, dfin)
+                prop, q = _draft_propose(dlast, pos, i, *sampling)
+                return (prop, dview), (prop, q, dfin)
 
             # K+1 draft steps, not K: step K feeds the LAST proposal d_K
             # at pos+K so a fully-accepted tick leaves the draft pool
@@ -1217,8 +1087,11 @@ class ServingEngine:
             # Step K's proposal/q outputs are discarded; on a rejection
             # its KV row is beyond the committed prefix and the next
             # tick overwrites it before any query can attend it.
-            (_, dpools), (props, qs, dfins) = jax.lax.scan(
-                dstep, (tokens, dpools), jnp.arange(k_spec + 1))
+            (_, dview), (props, qs, dfins) = jax.lax.scan(
+                dstep, (tokens, cache.open(pools["draft"], inputs)),
+                jnp.arange(k_spec + 1))
+            dpools = cache.publish(pools["draft"], dview, inputs, pos,
+                                   k_spec + 1)
             props = props[:k_spec].T             # (S, K)
             qs = jnp.swapaxes(qs[:k_spec], 0, 1)  # (S, K, V)
             dfin = dfins.all(axis=0)             # (S,)
@@ -1226,16 +1099,13 @@ class ServingEngine:
             # target scores all K proposals + the bonus position in ONE
             # forward of K+1 tokens per slot
             ids = jnp.concatenate([tokens[:, None], props], axis=1)
-
-            def trow(row_ids, caches, p):
-                c = [(kb[None], vb[None]) for (kb, vb) in caches]
-                logits, new = apply_fixed(state, row_ids[None], c, p)
-                return (logits[0].astype(jnp.float32),
-                        [(kb[0], vb[0]) for (kb, vb) in new])
-
-            tlog, pools = jax.vmap(trow)(ids, pools, pos)  # (S, K+1, V)
+            tlog, tview = trow(ids, cache.open(pools["model"], inputs),
+                               pos)              # (S, K+1, V)
+            tpools = cache.publish(pools["model"], tview, inputs, pos,
+                                   k_spec + 1)
             if poison_armed:
-                factor = jnp.where(poison, jnp.float32(float("nan")),
+                factor = jnp.where(inputs["poison"],
+                                   jnp.float32(float("nan")),
                                    jnp.float32(1.0))
                 tlog = tlog * factor[:, None, None]
             # draft non-finiteness only matters for slots actually
@@ -1260,353 +1130,40 @@ class ServingEngine:
                 jnp.all(greedy),
                 lambda o: commit_speculative_greedy(*o, pad),
                 lambda o: commit_speculative_sampled(*o, pad), ops)
-            return (out, logps, finite, count, accepted, last, pos + count,
-                    pools, dpools)
+            return {"toks": out, "logps": logps, "finites": finite,
+                    "commits": count, "accepts": accepted, "tokens": last,
+                    "pos": pos + count,
+                    "pools": {"model": tpools, "draft": dpools}}
 
-        from ..observability import track
         return track("serving_verify",
-                     jax.jit(verify, donate_argnums=(2, 3)))
+                     jax.jit(verify, donate_argnums=(1,)))
 
-    # ------------------------------------------------------------------
-    # paged programs (kv="paged"): same count, same contracts — blocks
-    # gathered/scattered through per-slot tables instead of slot rows
-    # ------------------------------------------------------------------
-    def _build_prefill_paged(self, bucket: int):
-        """Per-bucket prefill against the block pool: the prompt runs
-        through the same bucket-sized scratch cache, then every block the
-        slot's table covers for the bucket is overwritten END-TO-END
-        (prompt KV + zeros to the block boundary) — scrub-on-recycle for
-        prompt blocks is the overwrite itself.  Sentinel table entries
-        (warmup) drop the write."""
-        apply_fixed = self._apply
-        model, draft = self.model, self.draft_model
-        dtype = self._dtype
-        bs = self.block_size
-        nb_b = -(-bucket // bs)
-        dapply = self._dapply if draft is not None else None
-
-        def write_blocks(pools, kv, table):
-            ids = table[:nb_b]
-            new_pools = []
-            for (kp, vp), (kc, vc) in zip(pools, kv):
-                def as_blocks(chunk, pool):
-                    rows = chunk[0].astype(pool.dtype)      # (bucket, ...)
-                    padn = nb_b * bs - bucket
-                    if padn:
-                        rows = jnp.concatenate(
-                            [rows, jnp.zeros((padn,) + rows.shape[1:],
-                                             pool.dtype)])
-                    return rows.reshape((nb_b, bs) + rows.shape[1:])
-                new_pools.append(
-                    (kp.at[ids].set(as_blocks(kc, kp), mode="drop"),
-                     vp.at[ids].set(as_blocks(vc, vp), mode="drop")))
-            return new_pools
-
-        def count_trace():
-            self._compiles["prefill"][bucket] += 1  # trace-count (host)
-            stat_add("STAT_serving_compiles")
-
-        if draft is None and self.lora is not None:
-            def prefill(state, pools, lora, ids, table, prompt_len, aid,
-                        key, temp, top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                with self._lora_ctx(lora, aid):
-                    logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_blocks(pools, kv, table)
-                tok, logp, finite = _first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools
-
-            name, donate = f"serving_prefill_b{bucket}", (1,)
-        elif draft is None:
-            def prefill(state, pools, ids, table, prompt_len, key, temp,
-                        top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_blocks(pools, kv, table)
-                tok, logp, finite = _first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools
-
-            name, donate = f"serving_prefill_b{bucket}", (1,)
-        else:
-            def prefill(state, dstate, pools, dpools, ids, table,
-                        prompt_len, key, temp, top_k, top_p, greedy):
-                count_trace()
-                scratch = model.gen_fixed_cache(1, bucket, dtype)
-                logits, kv = apply_fixed(state, ids, scratch, 0)
-                new_pools = write_blocks(pools, kv, table)
-                dscratch = draft.gen_fixed_cache(1, bucket, dtype)
-                _, dkv = dapply(dstate, ids, dscratch, 0)
-                new_dpools = write_blocks(dpools, dkv, table)
-                tok, logp, finite = _first_token(
-                    logits, prompt_len, key, temp, top_k, top_p, greedy)
-                return tok, logp, finite, new_pools, new_dpools
-
-            name, donate = f"serving_prefill_spec_b{bucket}", (2, 3)
-
-        from ..observability import track
-        return track(name, jax.jit(prefill, donate_argnums=donate))
-
-    def _build_prefill_cached(self, bucket: int):
-        """Per-bucket prefill for prefix-cache engines: the slot's table
-        is gathered into its contiguous KV view (exactly like decode —
-        the cached prefix blocks already mapped in by admission supply
-        rows [0, cached_len)), the prompt's uncached SUFFIX runs through
-        the model at the dynamic offset `cached_len` (same traced-scalar
-        position the decode/verify programs use), and only the suffix
-        rows scatter back through the table.  cached_len=0 IS the cold
-        path: the gathered view is all-fresh blocks and the full bucket
-        computes — so cold and warm requests share one program per
-        bucket and the compile bound stays len(buckets)+1.  Buckets are
-        chosen by SUFFIX length, so a warm prefix pays a near-zero
-        prefill.  Suffix writes start at cached_len — a block boundary
-        for non-COW admissions, so shared blocks are never entered; a
-        clamped window near the pool's end re-writes gathered rows
-        value-identically, and any block it scrubs lies entirely inside
-        the window (fully rewritten), preserving shared content
-        bit-exactly."""
-        apply_fixed = self._apply
-        write_rows = _paged_row_writer(self.block_size,
-                                       self.kv_pool.num_blocks,
-                                       self._pool_len)
-        from ..ops.paged_attention import gather_block_rows
-
-        def count_trace():
-            self._compiles["prefill"][bucket] += 1  # trace-count (host)
-            stat_add("STAT_serving_compiles")
-
-        def prefill(state, pools, ids, table, prompt_len, cached_len,
-                    key, temp, top_k, top_p, greedy):
-            count_trace()
-            ctx = [(gather_block_rows(kp, table)[None],
-                    gather_block_rows(vp, table)[None])
-                   for kp, vp in pools]
-            logits, kv = apply_fixed(state, ids, ctx, cached_len)
-            total = kv[0][0].shape[1]
-            start = _window_start(cached_len, bucket, total)
-            rows = [
-                (jax.lax.dynamic_slice_in_dim(kc[0], start, bucket)[None],
-                 jax.lax.dynamic_slice_in_dim(vc[0], start, bucket)[None])
-                for kc, vc in kv]
-            new_pools = write_rows(pools, table[None], start[None],
-                                   rows, jnp.ones((1,), bool), bucket)
-            tok, logp, finite = _first_token_at(
-                logits, prompt_len - 1 - cached_len, prompt_len - 1, key,
-                temp, top_k, top_p, greedy)
-            return tok, logp, finite, new_pools
-
-        from ..observability import track
-        return track(f"serving_prefill_cached_b{bucket}",
-                     jax.jit(prefill, donate_argnums=(1,)))
-
-    def _build_decode_paged(self):
-        """THE paged decode step: gather every slot's block table into its
-        contiguous KV view ONCE per compiled call (value-identical to the
-        fixed slot row — streams stay bit-identical), run the whole
-        decode chunk against the gathered view exactly as the fixed step
-        runs against its pool rows, then scatter the chunk's freshly
-        written rows back through the tables in one pass (entering blocks
-        zeroed first).  One gather + one scatter per call amortizes the
-        indirection across chunk * slots tokens.  Sampling, the
-        all-greedy fast path, chunking and fault branches are the fixed
-        decode step verbatim."""
-        apply_fixed = self._apply
-        poison_armed = self._poison_target is not None
-        chunk = self.decode_chunk
-        write_rows = _paged_row_writer(self.block_size,
-                                       self.kv_pool.num_blocks,
-                                       self._pool_len)
-
-        gather_ctx = _gather_ctx
-
+    def _program_args(self, inputs: Dict):
+        """`(weights, pools, inputs)`, the arguments of every program of
+        the engine: `weights` the trees it has (`model`, and `draft` /
+        `lora` only when configured; passed on every call, never closed
+        over, so `swap_weights` and `load_adapter` never retrace),
+        `pools` the donated KV pools (`model`, `draft`), `inputs` the
+        call's arrays from `_prefill_inputs` / `_decode_inputs`."""
+        weights, pools = {"model": self._state}, {"model": self._pools}
+        if self.draft_model is not None:
+            weights["draft"], pools["draft"] = (self._dstate,
+                                                self._draft_pools)
         if self.lora is not None:
-            def decode(state, pools, lora, tables, active, tokens, pos,
-                       aids, keys, temp, top_k, top_p, greedy, poison):
-                self._compiles["decode"] += 1  # trace-count (host)
-                stat_add("STAT_serving_compiles")
-                ctx = [(gather_ctx(kp, tables), gather_ctx(vp, tables))
-                       for (kp, vp) in pools]
-                pos0 = pos
+            weights["lora"] = self._lora_reg.device_args()
+        return weights, pools, inputs
 
-                def one(carry, _):
-                    tokens, pos, ctx = carry
-
-                    def row(tok, caches, p, aid):
-                        c = [(k[None], v[None]) for (k, v) in caches]
-                        with self._lora_ctx(lora, aid):
-                            logits, new = apply_fixed(state,
-                                                      tok[None, None], c, p)
-                        return (logits[0, -1].astype(jnp.float32),
-                                [(k[0], v[0]) for (k, v) in new])
-
-                    last, ctx = jax.vmap(row)(tokens, ctx, pos, aids)
-                    if poison_armed:
-                        last = faults.poison_logits(last, poison)
-                    finite = jnp.isfinite(last).all(axis=-1)
-                    tok, logp = _sample_step(last, keys, pos, temp, top_k,
-                                             top_p, greedy)
-                    return (tok, pos + 1, ctx), (tok, logp, finite)
-
-                (tokens, pos, ctx), (toks, logps, finites) = jax.lax.scan(
-                    one, (tokens, pos0, ctx), None, length=chunk)
-                start = _window_start(pos0, chunk, ctx[0][0].shape[1])
-                pools = write_rows(pools, tables, start,
-                                   _extract_rows(ctx, start, chunk),
-                                   active, chunk)
-                return toks, logps, finites, tokens, pos, pools
-
-            from ..observability import track
-            return track("serving_decode",
-                         jax.jit(decode, donate_argnums=(1,)))
-
-        def decode(state, pools, tables, active, tokens, pos, keys, temp,
-                   top_k, top_p, greedy, poison):
-            self._compiles["decode"] += 1  # trace-count (host side effect)
-            stat_add("STAT_serving_compiles")
-            ctx = [(gather_ctx(kp, tables), gather_ctx(vp, tables))
-                   for (kp, vp) in pools]
-            pos0 = pos
-
-            def one(carry, _):
-                tokens, pos, ctx = carry
-
-                def row(tok, caches, p):
-                    c = [(k[None], v[None]) for (k, v) in caches]
-                    logits, new = apply_fixed(state, tok[None, None], c, p)
-                    return (logits[0, -1].astype(jnp.float32),
-                            [(k[0], v[0]) for (k, v) in new])
-
-                last, ctx = jax.vmap(row)(tokens, ctx, pos)
-                if poison_armed:
-                    last = faults.poison_logits(last, poison)
-                finite = jnp.isfinite(last).all(axis=-1)
-                tok, logp = _sample_step(last, keys, pos, temp, top_k,
-                                         top_p, greedy)
-                return (tok, pos + 1, ctx), (tok, logp, finite)
-
-            (tokens, pos, ctx), (toks, logps, finites) = jax.lax.scan(
-                one, (tokens, pos0, ctx), None, length=chunk)
-            # one scatter publishes the chunk's written rows back into
-            # the block pool; near the end of the view the window clamps
-            # and harmlessly re-writes a few already-published rows
-            start = _window_start(pos0, chunk, ctx[0][0].shape[1])
-            pools = write_rows(pools, tables, start,
-                               _extract_rows(ctx, start, chunk), active,
-                               chunk)
-            return toks, logps, finites, tokens, pos, pools
-
-        from ..observability import track
-        return track("serving_decode",
-                     jax.jit(decode, donate_argnums=(1,)))
-
-    def _build_verify_paged(self):
-        """The speculative tick over the block pool: draft and target
-        contexts are gathered from the per-slot tables ONCE per call, the
-        draft proposal scan and batched target verify run against the
-        gathered views exactly as the fixed verify runs against its pool
-        rows, and each side's freshly written rows scatter back in one
-        pass — the commit math is the fixed verify verbatim.  The draft
-        pool pages with the SAME tables."""
-        from ..generation.speculative import (commit_speculative_greedy,
-                                              commit_speculative_sampled)
-        apply_fixed, dapply = self._apply, self._dapply
-        poison_armed = self._poison_target is not None
-        diverge_armed = self._diverge_every is not None
-        k_spec = self.spec_tokens
-        pad = self.pad_token_id
-        write_rows = _paged_row_writer(self.block_size,
-                                       self.kv_pool.num_blocks,
-                                       self._pool_len)
-
-        gather_ctx = _gather_ctx
-        extract_rows = _extract_rows
-
-        def verify(state, dstate, pools, dpools, tables, active, tokens,
-                   pos, keys, temp, top_k, top_p, greedy, spec_on, poison,
-                   diverge):
-            self._compiles["decode"] += 1  # trace-count (host side effect)
-            stat_add("STAT_serving_compiles")
-            dctx = [(gather_ctx(kb, tables), gather_ctx(vb, tables))
-                    for (kb, vb) in dpools]
-
-            def dstep(carry, i):
-                cur, dp = carry
-
-                def drow(tok, caches, p):
-                    c = [(kb[None], vb[None]) for (kb, vb) in caches]
-                    logits, new = dapply(dstate, tok[None, None], c, p)
-                    return (logits[0, -1].astype(jnp.float32),
-                            [(kb[0], vb[0]) for (kb, vb) in new])
-
-                dlast, dp = jax.vmap(drow)(cur, dp, pos + i)
-                if diverge_armed:
-                    dlast = faults.poison_draft_logits(dlast, diverge)
-                dfin = jnp.isfinite(dlast).all(axis=-1)
-
-                prop, q = _draft_propose(dlast, keys, pos, temp, top_k,
-                                         top_p, greedy, i)
-                return (prop, dp), (prop, q, dfin)
-
-            # K+1 draft steps for the same density reason as the fixed
-            # verify: step K feeds d_K at pos+K so an all-accept tick
-            # leaves the draft blocks dense
-            (_, dctx), (props, qs, dfins) = jax.lax.scan(
-                dstep, (tokens, dctx), jnp.arange(k_spec + 1))
-            # window clamped at the view's end (re-writes are idempotent)
-            start = _window_start(pos, k_spec + 1, dctx[0][0].shape[1])
-            dpools = write_rows(dpools, tables, start,
-                                extract_rows(dctx, start, k_spec + 1),
-                                active, k_spec + 1)
-            props = props[:k_spec].T             # (S, K)
-            qs = jnp.swapaxes(qs[:k_spec], 0, 1)  # (S, K, V)
-            dfin = dfins.all(axis=0)             # (S,)
-
-            ids = jnp.concatenate([tokens[:, None], props], axis=1)
-            tctx = [(gather_ctx(kb, tables), gather_ctx(vb, tables))
-                    for (kb, vb) in pools]
-
-            def trow(row_ids, caches, p):
-                c = [(kb[None], vb[None]) for (kb, vb) in caches]
-                logits, new = apply_fixed(state, row_ids[None], c, p)
-                return (logits[0].astype(jnp.float32),
-                        [(kb[0], vb[0]) for (kb, vb) in new])
-
-            tlog, tctx = jax.vmap(trow)(ids, tctx, pos)  # (S, K+1, V)
-            pools = write_rows(pools, tables, start,
-                               extract_rows(tctx, start, k_spec + 1),
-                               active, k_spec + 1)
-            if poison_armed:
-                factor = jnp.where(poison, jnp.float32(float("nan")),
-                                   jnp.float32(1.0))
-                tlog = tlog * factor[:, None, None]
-            finite = (jnp.isfinite(tlog).all(axis=(1, 2))
-                      & (dfin | ~spec_on))
-
-            def proc_all(t):
-                flat = t.reshape(-1, t.shape[-1])
-
-                def rep(a):
-                    return jnp.repeat(a, k_spec + 1, axis=0)
-                return process_logits_dynamic(
-                    flat, rep(temp), rep(top_k), rep(top_p),
-                    rep(greedy)).reshape(t.shape)
-
-            plog = jax.lax.cond(jnp.all(greedy), lambda t: t, proc_all,
-                                tlog)
-            ops = (props, qs, plog, keys, pos, greedy, spec_on)
-            out, count, accepted, last, logps = jax.lax.cond(
-                jnp.all(greedy),
-                lambda o: commit_speculative_greedy(*o, pad),
-                lambda o: commit_speculative_sampled(*o, pad), ops)
-            return (out, logps, finite, count, accepted, last, pos + count,
-                    pools, dpools)
-
-        from ..observability import track
-        return track("serving_verify",
-                     jax.jit(verify, donate_argnums=(2, 3)))
+    def _run(self, fn, inputs: Dict) -> Dict:
+        """Call a program and take the pools it returns in place of the
+        ones it was handed.  They are DONATED to every call: XLA updates
+        the slots in place instead of copying max_slots * max_len of KV
+        per call (measured 166x on a CPU pool-passthrough update; the
+        same aliasing TPU donation does)."""
+        out = fn(*self._program_args(inputs))
+        self._pools = out["pools"]["model"]
+        if self.draft_model is not None:
+            self._draft_pools = out["pools"]["draft"]
+        return out
 
     # ------------------------------------------------------------------
     # submission
@@ -1916,12 +1473,20 @@ class ServingEngine:
                 run.resp._fail(RequestCancelled(
                     f"request {run.req.id} cancelled mid-decode"))
                 self._release(slot)
-            elif run.req.deadline is not None and run.req.deadline.expired():
-                stat_add("STAT_serving_deadline_expired")
-                run.resp._fail(DeadlineExceededError(
-                    f"request {run.req.id} deadline "
-                    f"({run.req.deadline.seconds}s) expired mid-decode"))
-                self._release(slot)
+            else:
+                self._expired(slot, run)
+
+    def _expired(self, slot: int, run: _SlotRun) -> bool:
+        """Fail and release a seated run whose deadline has passed."""
+        deadline = run.req.deadline
+        if deadline is None or not deadline.expired():
+            return False
+        stat_add("STAT_serving_deadline_expired")
+        run.resp._fail(DeadlineExceededError(
+            f"request {run.req.id} deadline ({deadline.seconds}s) expired "
+            "mid-decode"))
+        self._release(slot)
+        return True
 
     def _release(self, slot: int):
         run = self._slots.pop(slot, None)
@@ -1956,19 +1521,19 @@ class ServingEngine:
         with _span("serving_admit", args={"request": req.id, "slot": slot,
                                           "plen": plen}) as sp:
             with _span("serving_prefill_dispatch"):
-                out = (self._prefill_cached(req, resp, slot, plen, sp.args)
-                       if self.prefix_cache is not None
-                       else self._prefill(req, resp, slot, plen, sp.args))
-            if out is None:  # failed terminally before any program ran
+                called = (self._prefill_cached(req, resp, slot, sp.args)
+                          if self.prefix_cache is not None
+                          else self._prefill(req, resp, slot, sp.args))
+            if called is None:  # failed terminally before any program ran
                 return
-            tok, logp, finite, key, aid = out[:5]
+            out, key, aid = called
             stat_add("STAT_serving_prefills")
             with _span("serving_prefill_wait"):
-                ok = bool(finite)
+                ok = bool(out["finite"])
                 if ok:
-                    tok = int(tok)
+                    tok = int(out["tok"])
                 if self._batched:   # the program has ended: no new wait
-                    self._count_routed(sp.args, np.asarray(out[5]))
+                    self._count_routed(sp.args, np.asarray(out["counts"]))
             if not ok:
                 # the run is not in _slots yet — _release won't see the
                 # pin, drop it here
@@ -1984,16 +1549,53 @@ class ServingEngine:
                            aid=aid)
             self._slots[slot] = run
             self._batch_dirty = True
-            self._emit(run, tok, float(logp))
+            self._emit(run, tok, float(out["logp"]))
             self._count_tokens(1)
             self._maybe_finish(slot, run, tok)
 
-    def _prefill(self, req: Request, resp: Response, slot: int, plen: int,
+    def _prefill_inputs(self, req: Request, key, bucket: int,
+                        slot: Optional[int], aid: int = 0,
+                        cached_len: int = 0) -> Dict:
+        """A prefill call's `inputs` — the one place they are assembled,
+        for a live admission, for warm-up and for the program-set
+        exporter (`slot=None`: the cache view's sentinel form, so the
+        call writes nothing that lasts).  The prompt past `cached_len`
+        is right-padded to the bucket; `aid` and `cached_len` exist only
+        on an engine with adapters / a prefix cache, as ordinary dynamic
+        inputs: a new adapter or a warm prefix NEVER means a new
+        program."""
+        plen = int(req.prompt.shape[0])
+        ids = np.full((1, bucket), self.pad_token_id, np.int32)
+        ids[0, :plen - cached_len] = req.prompt[cached_len:]
+        inputs = {"ids": jnp.asarray(ids), "prompt_len": jnp.int32(plen),
+                  "key": jnp.asarray(key),
+                  "temp": jnp.float32(req.temperature),
+                  "top_k": jnp.int32(req.top_k),
+                  "top_p": jnp.float32(req.top_p),
+                  "greedy": jnp.asarray(req.greedy),
+                  **self._cache.prompt_inputs(slot)}
+        if self.lora is not None:
+            inputs["aid"] = jnp.int32(aid)
+        if self.prefix_cache is not None:
+            inputs["cached_len"] = jnp.int32(cached_len)
+        return inputs
+
+    def _call_prefill(self, req: Request, bucket: int, slot: int,
+                      aid: int = 0, cached_len: int = 0):
+        """Enqueue the bucket's prefill program for the request.
+        -> (the program's result, still on the device; the request's key;
+        its adapter id)."""
+        key = self._request_key(req)
+        out = self._run(self._prefill_fns[bucket], self._prefill_inputs(
+            req, key, bucket, slot, aid, cached_len))
+        return out, key, aid
+
+    def _prefill(self, req: Request, resp: Response, slot: int,
                  span_args: dict):
-        """Host preparation and the enqueue of the cold prefill program.
-        -> (tok, logp, finite, key, adapter id), all still on the device,
-        or None when the request failed terminally before the call."""
-        bucket = span_args["bucket"] = self._bucket_for(plen)
+        """Host preparation and the enqueue of the cold prefill program
+        (`_call_prefill`'s result), or None when the request failed
+        terminally before the call."""
+        bucket = span_args["bucket"] = self._bucket_for(req.prompt.shape[0])
         aid = 0
         if self.lora is not None:
             # resolve + PIN the adapter for the life of the slot (the
@@ -2010,76 +1612,43 @@ class ServingEngine:
                 resp._fail(e)
                 self.scheduler.release(slot)
                 return None
-        if self.kv == "paged":
-            # claim the prompt's blocks; only reachable without them
-            # when PDTPU_FAULT_KV_EXHAUST moved the cap between the
-            # admission gate and here — typed terminal, never a hang
-            if not self.kv_pool.alloc(slot, bucket):
-                stat_add("STAT_serving_kv_exhausted")
-                with self._m_lock:
-                    self._errored += 1
-                resp._fail(KVPoolExhaustedError(
-                    f"request {req.id}: KV block pool exhausted at "
-                    f"admission ({self.kv_pool.free_blocks()} free of "
-                    f"{self.kv_pool.capacity()} usable)"))
-                self.scheduler.release(slot)
-                if self._lora_reg is not None and aid:
-                    self._lora_reg.release(aid)
-                return None
-            slot_arg = jnp.asarray(self.kv_pool.table_array(slot))
-        else:
-            slot_arg = jnp.int32(slot)
-        ids = np.full((1, bucket), self.pad_token_id, np.int32)
-        ids[0, :plen] = req.prompt
-        key = self._request_key(req)
-        if self.draft_model is not None:
-            (tok, logp, finite, self._pools,
-             self._draft_pools) = self._prefill_fns[bucket](
-                self._state, self._dstate, self._pools,
-                self._draft_pools, jnp.asarray(ids), slot_arg,
-                jnp.int32(plen), jnp.asarray(key),
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p), jnp.asarray(req.greedy))
-        elif self.lora is not None:
-            # the adapter id is an ordinary dynamic input: a new
-            # adapter NEVER means a new program
-            tok, logp, finite, self._pools = self._prefill_fns[bucket](
-                self._state, self._pools, self._lora_reg.device_args(),
-                jnp.asarray(ids), slot_arg, jnp.int32(plen),
-                jnp.int32(aid), jnp.asarray(key),
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p), jnp.asarray(req.greedy))
-        else:
-            tok, logp, finite, self._pools, *counts = \
-                self._prefill_fns[bucket](
-                    self._state, self._pools, jnp.asarray(ids),
-                    slot_arg, jnp.int32(plen), jnp.asarray(key),
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jnp.float32(req.top_p), jnp.asarray(req.greedy))
-            # a batched model's program also returns its routed counts
-            return (tok, logp, finite, key, aid, *counts)
-        return tok, logp, finite, key, aid
+        # a block pool: claim the prompt's blocks; only reachable without
+        # them when PDTPU_FAULT_KV_EXHAUST moved the cap between the
+        # admission gate and here — typed terminal, never a hang
+        if self.kv_pool is not None and not self.kv_pool.alloc(slot, bucket):
+            if self._lora_reg is not None and aid:
+                self._lora_reg.release(aid)
+            return self._fail_exhausted(req, resp, slot, "admission")
+        return self._call_prefill(req, bucket, slot, aid)
+
+    def _fail_exhausted(self, req: Request, resp: Response, slot: int,
+                        stage: str):
+        """The block pool could not seat an admitted request: typed
+        terminal failure, the scheduler's slot given back."""
+        stat_add("STAT_serving_kv_exhausted")
+        with self._m_lock:
+            self._errored += 1
+        resp._fail(KVPoolExhaustedError(
+            f"request {req.id}: KV block pool exhausted at {stage} "
+            f"({self.kv_pool.free_blocks()} free of "
+            f"{self.kv_pool.capacity()} usable)"))
+        self.scheduler.release(slot)
 
     def _prefill_cached(self, req: Request, resp: Response, slot: int,
-                        plen: int, span_args: dict):
+                        span_args: dict):
         """Warm-path `_prefill`: adopt the longest cached prefix chain
         into the slot's table, COW the final block when the whole prompt
         is cached, and prefill ONLY the uncached suffix (per-slot
         dynamic `cached_len` into the same per-bucket program family —
         `cached_len == 0` IS the cold path, so a miss costs nothing
-        extra and the compile bound is unchanged)."""
+        extra and the compile bound is unchanged).  Buckets are chosen
+        by SUFFIX length, so a warm prefix pays a near-zero prefill."""
         plan = self._cached_plan(req, record=True)
         span_args["bucket"] = plan.bucket
 
         def exhausted(stage):
-            stat_add("STAT_serving_kv_exhausted")
-            with self._m_lock:
-                self._errored += 1
-            resp._fail(KVPoolExhaustedError(
-                f"request {req.id}: KV block pool exhausted at "
-                f"admission/{stage} ({self.kv_pool.free_blocks()} "
-                f"free of {self.kv_pool.capacity()} usable)"))
-            self.scheduler.release(slot)
+            return self._fail_exhausted(req, resp, slot,
+                                        f"admission/{stage}")
 
         if plan.chain and not self.kv_pool.adopt(slot, plan.chain):
             return exhausted("adopt")
@@ -2096,18 +1665,8 @@ class ServingEngine:
         if not self.kv_pool.ensure(slot, plan.cached_len + plan.bucket):
             self.kv_pool.free(slot)
             return exhausted("suffix")
-        slot_arg = jnp.asarray(self.kv_pool.table_array(slot))
-        suffix = plen - plan.cached_len
-        ids = np.full((1, plan.bucket), self.pad_token_id, np.int32)
-        ids[0, :suffix] = req.prompt[plan.cached_len:]
-        key = self._request_key(req)
-        tok, logp, finite, self._pools = self._prefill_fns[plan.bucket](
-            self._state, self._pools, jnp.asarray(ids), slot_arg,
-            jnp.int32(plen), jnp.int32(plan.cached_len),
-            jnp.asarray(key), jnp.float32(req.temperature),
-            jnp.int32(req.top_k), jnp.float32(req.top_p),
-            jnp.asarray(req.greedy))
-        return tok, logp, finite, key, 0
+        return self._call_prefill(req, plan.bucket, slot,
+                                  cached_len=plan.cached_len)
 
     # ------------------------------------------------------------------
     # gateway admission: direct placement, preemption, restore
@@ -2171,36 +1730,24 @@ class ServingEngine:
                     jnp.take(leaf, ids_dev, axis=0)))
                 return np.array(r.reshape((-1,) + r.shape[2:])[:run.pos])
 
-            kv_rows = [(rows_of(k), rows_of(v)) for k, v in self._pools]
-            draft_rows = None
-            if self.draft_model is not None:
-                draft_rows = [(rows_of(k), rows_of(v))
-                              for k, v in self._draft_pools]
+            def snapshot(pools):
+                return [(rows_of(k), rows_of(v)) for k, v in pools]
         else:
-            host = jax.device_get(self._pools)
-            kv_rows = [(np.array(k[slot, :run.pos]),
-                        np.array(v[slot, :run.pos]))
-                       for k, v in host]
-            draft_rows = None
-            if self.draft_model is not None:
-                dhost = jax.device_get(self._draft_pools)
-                draft_rows = [(np.array(k[slot, :run.pos]),
-                               np.array(v[slot, :run.pos]))
-                              for k, v in dhost]
-        paused = PreemptedRun(run, kv_rows, draft_rows)
+            def snapshot(pools):
+                return [(np.array(k[slot, :run.pos]),
+                         np.array(v[slot, :run.pos]))
+                        for k, v in jax.device_get(pools)]
+        paused = PreemptedRun(
+            run, snapshot(self._pools),
+            snapshot(self._draft_pools) if self.draft_model is not None
+            else None)
         from .transfer import engine_config_hash
         paused.source_config_hash = engine_config_hash(self)
         run.req.preempts += 1
-        self._slots.pop(slot, None)
-        self.scheduler.release(slot)
-        if self.kv == "paged":
-            self.kv_pool.free(slot)
-        if self._lora_reg is not None and run.aid:
-            # unpin while parked: the adapter NAME travels with the
-            # request; restore re-resolves (and may fail typed if the
-            # adapter was evicted meanwhile)
-            self._lora_reg.release(run.aid)
-        self._batch_dirty = True
+        # the slot, its blocks and the adapter's pin (unpinned while
+        # parked: the adapter NAME travels with the request; restore
+        # re-resolves, and may fail typed if it was evicted meanwhile)
+        self._release(slot)
         stat_add("STAT_serving_preemptions")
         return paused
 
@@ -2225,43 +1772,39 @@ class ServingEngine:
             if not self.kv_pool.alloc(slot, paused.pos):
                 self.scheduler.release(slot)
                 return False
-            self._pools = self._paged_upload(self._pools, slot,
-                                             paused.kv_rows, paused.pos)
-            if (self.draft_model is not None
-                    and paused.draft_kv_rows is not None):
-                self._draft_pools = self._paged_upload(
-                    self._draft_pools, slot, paused.draft_kv_rows,
-                    paused.pos)
-            return self._finish_restore(slot, paused)
-
-        def write_rows(pools, rows):
-            new_pools = []
-            for (hk, hv), (rk, rv) in zip(jax.device_get(pools), rows):
-                # device_get may alias backend memory on CPU: copy before
-                # the in-place row write, then re-upload (rows beyond
-                # `pos` may hold garbage from the slot's idle decode
-                # passes — the model protocol guarantees positions > pos
-                # never influence output, and decode overwrites them as
-                # it advances)
-                hk = np.array(hk)
-                hv = np.array(hv)
-                hk[slot, :paused.pos] = rk
-                hv[slot, :paused.pos] = rv
-                nk, nv = jnp.asarray(hk), jnp.asarray(hv)
-                if self._kv_put is not None:
-                    # mesh engines must re-place the uploaded pool with
-                    # its heads sharding — a default-device array here
-                    # would silently de-shard the pool and retrace the
-                    # decode program on the next call
-                    nk, nv = self._kv_put(nk), self._kv_put(nv)
-                new_pools.append((nk, nv))
-            return new_pools
-
-        self._pools = write_rows(self._pools, paused.kv_rows)
+            upload = self._paged_upload
+        else:
+            upload = self._fixed_upload
+        self._pools = upload(self._pools, slot, paused.kv_rows, paused.pos)
         if self.draft_model is not None and paused.draft_kv_rows is not None:
-            self._draft_pools = write_rows(self._draft_pools,
-                                           paused.draft_kv_rows)
+            self._draft_pools = upload(self._draft_pools, slot,
+                                       paused.draft_kv_rows, paused.pos)
         return self._finish_restore(slot, paused)
+
+    def _fixed_upload(self, pools, slot: int, rows, pos: int):
+        """A fixed pool with a snapshot's rows [0, pos) written back into
+        `slot`."""
+        new_pools = []
+        for (hk, hv), (rk, rv) in zip(jax.device_get(pools), rows):
+            # device_get may alias backend memory on CPU: copy before
+            # the in-place row write, then re-upload (rows beyond
+            # `pos` may hold garbage from the slot's idle decode
+            # passes — the model protocol guarantees positions > pos
+            # never influence output, and decode overwrites them as
+            # it advances)
+            hk = np.array(hk)
+            hv = np.array(hv)
+            hk[slot, :pos] = rk
+            hv[slot, :pos] = rv
+            nk, nv = jnp.asarray(hk), jnp.asarray(hv)
+            if self._kv_put is not None:
+                # mesh engines must re-place the uploaded pool with
+                # its heads sharding — a default-device array here
+                # would silently de-shard the pool and retrace the
+                # decode program on the next call
+                nk, nv = self._kv_put(nk), self._kv_put(nv)
+            new_pools.append((nk, nv))
+        return new_pools
 
     def _restore_paged_prefix(self, slot: int, paused: PreemptedRun) -> bool:
         """Re-pin a restored run's shared prefix instead of re-uploading
@@ -2308,10 +1851,7 @@ class ServingEngine:
                 with self._m_lock:
                     self._errored += 1
                 paused.resp._fail(e)
-                self.scheduler.release(slot)
-                if self.kv == "paged":
-                    self.kv_pool.free(slot)
-                self._batch_dirty = True
+                self._release(slot)
                 return True
         run = _SlotRun(paused.req, paused.resp, pos=paused.pos,
                        first_token=paused.last_token, key=paused.key,
@@ -2489,28 +2029,12 @@ class ServingEngine:
             did = True
         return did
 
-    def _paged_batch(self):
-        """(tables, active) dynamic inputs for the paged decode/verify
-        call: per-slot block tables (sentinel everywhere a slot is
-        unoccupied, so its writes drop) + the occupancy mask.  Cached
-        against the allocator's mutation version — tables only change
-        when a slot crosses a block boundary or membership churns, so
-        steady-state ticks re-upload nothing."""
-        ver = self.kv_pool.version
-        if self._paged_cache is not None and self._paged_cache[0] == ver:
-            return self._paged_cache[1], self._paged_cache[2]
-        s = self.max_slots
-        sentinel = self.kv_pool.num_blocks
-        tables = np.full((s, self.kv_pool.max_blocks_per_slot), sentinel,
-                         np.int32)
-        active = np.zeros((s,), bool)
-        for slot in self._slots:
-            tables[slot] = self.kv_pool.table_array(slot)
-            active[slot] = True
-        self._paged_cache = (ver, jnp.asarray(tables), jnp.asarray(active))
-        return self._paged_cache[1], self._paged_cache[2]
-
-    def _rebuild_batch(self):
+    def _batch_inputs(self, slots: Dict[int, _SlotRun]) -> Dict:
+        """The per-slot inputs of a decode or verify call for the runs
+        seated in `slots`, uploaded: each run's last token, position and
+        sampling knobs, and only where the program has them `aids`
+        (idle slots decode as adapter 0), `active` (a batched model's
+        mask of occupied slots) and `spec_on`."""
         s = self.max_slots
         tokens = np.zeros((s,), np.int32)
         pos = np.zeros((s,), np.int32)
@@ -2521,8 +2045,9 @@ class ServingEngine:
         greedy = np.ones((s,), bool)
         poison = np.zeros((s,), bool)
         spec_on = np.zeros((s,), bool)
-        aids = np.zeros((s,), np.int32)  # idle slots decode as adapter 0
-        for slot, run in self._slots.items():
+        aids = np.zeros((s,), np.int32)
+        active = np.zeros((s,), bool)
+        for slot, run in slots.items():
             tokens[slot] = run.last_token
             pos[slot] = run.pos
             keys[slot] = run.key
@@ -2533,142 +2058,50 @@ class ServingEngine:
             poison[slot] = run.req.poison
             spec_on[slot] = run.req.spec
             aids[slot] = run.aid
-        self._dev_tokens = jnp.asarray(tokens)
-        self._dev_pos = jnp.asarray(pos)
+            active[slot] = True
+        batch = {"tokens": tokens, "pos": pos, "keys": keys, "temp": temp,
+                 "top_k": top_k, "top_p": top_p, "greedy": greedy,
+                 "poison": poison}
         if self.lora is not None:
-            self._dev_aids = jnp.asarray(aids)
+            batch["aids"] = aids
         if self._batched:
-            active = np.zeros((s,), bool)
-            active[list(self._slots)] = True
-            self._dev_active = jnp.asarray(active)
-        self._dev_params = tuple(jnp.asarray(a) for a in (
-            keys, temp, top_k, top_p, greedy, poison, spec_on))
+            batch["active"] = active
+        if self.draft_model is not None:
+            batch["spec_on"] = spec_on
+        return {name: jnp.asarray(a) for name, a in batch.items()}
+
+    def _rebuild_batch(self):
+        """The device-resident decode batch, rebuilt from host _SlotRun
+        state only when membership changes (admission / slot release)."""
+        self._dev = self._batch_inputs(self._slots)
         self._batch_dirty = False
 
-    def _decode_step(self):
+    def _decode_inputs(self, batch: Dict, slots, diverge: bool = False):
+        """A decode (or verify) call's `inputs` — the one place they are
+        assembled: the device-resident `batch`, what the cache view adds
+        for the runs in `slots` (nothing, or block tables and the
+        occupancy mask) and a verify tick's fault flag.  The live tick
+        hands it the resident batch; warm-up and the program-set exporter
+        a batch over no runs, for which the view gives its sentinel
+        forms."""
+        inputs = dict(batch, **self._cache.batch_inputs(slots))
         if self.draft_model is not None:
-            self._spec_step()
-            return
-        with _span("serving_decode", args={
+            inputs["diverge"] = jnp.asarray(diverge)
+        return inputs
+
+    def _decode_step(self):
+        """One tick of the resident batch: the decode program's chunk of
+        tokens a slot or, on a speculative engine, the verify program's
+        K draft proposals + one batched target verify, committing 1..K+1
+        tokens a slot."""
+        spec = self.draft_model is not None
+        with _span("serving_verify" if spec else "serving_decode", args={
                 "active": len(self._slots),
                 "calls": self._decode_calls + 1}) as sp:
-            if self.kv == "paged":
-                # grow block tables for this chunk's writes (may preempt
+            if self.kv_pool is not None:
+                # grow block tables for this tick's writes (may preempt
                 # or fail runs under pool pressure — membership can
                 # change, so this runs before the batch rebuild)
-                self._ensure_decode_blocks()
-                if not self._slots:
-                    return
-                sp.args["active"] = len(self._slots)
-            if self._batch_dirty:
-                with _span("serving_batch_rebuild"):
-                    self._rebuild_batch()
-            with _span("serving_decode_dispatch"):
-                # PDTPU_FAULT_SLOW_DECODE: host-side latency injection,
-                # read live per call — overload/SLO-miss paths become
-                # testable on CPU without a big model
-                faults.maybe_slow_decode(self._decode_calls)
-                self._decode_calls += 1
-                keys, temp, top_k, top_p, greedy, poison, _ = \
-                    self._dev_params
-                if self.kv == "paged":
-                    tables, active = self._paged_batch()
-                    if self.lora is not None:
-                        (toks, logps, finites, ntok, npos,
-                         self._pools) = self._decode_fn(
-                            self._state, self._pools,
-                            self._lora_reg.device_args(), tables, active,
-                            self._dev_tokens, self._dev_pos, self._dev_aids,
-                            keys, temp, top_k, top_p, greedy, poison)
-                    else:
-                        (toks, logps, finites, ntok, npos,
-                         self._pools) = self._decode_fn(
-                            self._state, self._pools, tables, active,
-                            self._dev_tokens, self._dev_pos, keys, temp,
-                            top_k, top_p, greedy, poison)
-                elif self.lora is not None:
-                    (toks, logps, finites, ntok, npos,
-                     self._pools) = self._decode_fn(
-                        self._state, self._pools,
-                        self._lora_reg.device_args(), self._dev_tokens,
-                        self._dev_pos, self._dev_aids, keys, temp, top_k,
-                        top_p, greedy, poison)
-                elif self._batched:
-                    (toks, logps, finites, ntok, npos, counts,
-                     self._pools) = self._decode_fn(
-                        self._state, self._pools, self._dev_tokens,
-                        self._dev_pos, self._dev_active, keys, temp, top_k,
-                        top_p, greedy, poison)
-                else:
-                    (toks, logps, finites, ntok, npos,
-                     self._pools) = self._decode_fn(
-                        self._state, self._pools, self._dev_tokens,
-                        self._dev_pos, keys, temp, top_k, top_p, greedy,
-                        poison)
-                self._dev_tokens, self._dev_pos = ntok, npos
-            with _span("serving_token_pull"):
-                # one device->host pull for the whole (chunk, slots) burst
-                # (and a batched model's routed counts with it)
-                if self._batched:
-                    toks, logps, finites, counts = jax.device_get(
-                        (toks, logps, finites, counts))
-                else:
-                    toks, logps, finites = jax.device_get(
-                        (toks, logps, finites))
-            if self._batched:
-                self._count_routed(sp.args, counts)
-                self._gauge_kv_rows()
-            stat_add("STAT_serving_decode_steps")
-            with _span("serving_deliver") as deliver:
-                emitted, seated = 0, len(self._slots)
-                for slot in list(self._slots):
-                    run = self._slots[slot]
-                    for j in range(toks.shape[0]):
-                        # deadline enforcement on the decode tick itself,
-                        # not only at the next sweep: a budget that expired
-                        # while the chunk was computing stops the stream
-                        # here — no post-expiry tokens are delivered, the
-                        # slot recycles now (regression: deadline shorter
-                        # than one chunk)
-                        if (run.req.deadline is not None
-                                and run.req.deadline.expired()):
-                            stat_add("STAT_serving_deadline_expired")
-                            run.resp._fail(DeadlineExceededError(
-                                f"request {run.req.id} deadline "
-                                f"({run.req.deadline.seconds}s) expired "
-                                "mid-decode"))
-                            self._release(slot)
-                            break
-                        if not finites[j, slot]:
-                            self._fail_slot(slot, run.resp, "decode")
-                            break
-                        t = int(toks[j, slot])
-                        run.pos += 1
-                        run.produced += 1
-                        run.last_token = t
-                        self._emit(run, t, float(logps[j, slot]))
-                        emitted += 1
-                        self._maybe_finish(slot, run, t)
-                        if slot not in self._slots:
-                            # finished mid-chunk: the tail iterations of
-                            # this slot are discarded (their KV garbage
-                            # dies with the slot's next prefill)
-                            break
-                self._count_tokens(emitted)
-                deliver.args = {"tokens": emitted,
-                                "finished": seated - len(self._slots)}
-
-    def _spec_step(self):
-        """One speculative tick: K draft proposals + one batched target
-        verify, committing 1..K+1 tokens per slot.  Host side mirrors the
-        chunked decode step — including the PR-6 deadline rule: a tick can
-        commit up to K+1 tokens, and a deadline that expired while the
-        tick was computing stops the stream BEFORE the next commit — no
-        post-expiry token is ever delivered."""
-        with _span("serving_verify", args={
-                "active": len(self._slots),
-                "calls": self._decode_calls + 1}) as sp:
-            if self.kv == "paged":
                 self._ensure_decode_blocks()
                 if not self._slots:
                     return
@@ -2680,86 +2113,96 @@ class ServingEngine:
                 tick_no = self._decode_calls  # lifetime stride counter:
                 # the diverge fault keys off it, NOT _spec_ticks, which is
                 # a metrics-window counter reset_metrics() zeroes
+                # PDTPU_FAULT_SLOW_DECODE: host-side latency injection,
+                # read live per call — overload/SLO-miss paths become
+                # testable on CPU without a big model
                 faults.maybe_slow_decode(tick_no)
                 self._decode_calls += 1
-                keys, temp, top_k, top_p, greedy, poison, spec_on = \
-                    self._dev_params
-                diverge = bool(self._diverge_every is not None
-                               and tick_no % self._diverge_every == 0)
-                self._spec_ticks += 1
-                if self.kv == "paged":
-                    tables, active = self._paged_batch()
-                    (toks, logps, finites, counts, accepts, last, npos,
-                     self._pools, self._draft_pools) = self._decode_fn(
-                        self._state, self._dstate, self._pools,
-                        self._draft_pools, tables, active,
-                        self._dev_tokens, self._dev_pos, keys, temp, top_k,
-                        top_p, greedy, spec_on, poison,
-                        jnp.asarray(diverge))
-                else:
-                    (toks, logps, finites, counts, accepts, last, npos,
-                     self._pools, self._draft_pools) = self._decode_fn(
-                        self._state, self._dstate, self._pools,
-                        self._draft_pools, self._dev_tokens, self._dev_pos,
-                        keys, temp, top_k, top_p, greedy, spec_on, poison,
-                        jnp.asarray(diverge))
-                self._dev_tokens, self._dev_pos = last, npos
+                out = self._run(self._decode_fn, self._decode_inputs(
+                    self._dev, self._slots,
+                    spec and self._diverge_every is not None
+                    and tick_no % self._diverge_every == 0))
+                self._dev["tokens"], self._dev["pos"] = (out["tokens"],
+                                                         out["pos"])
             with _span("serving_token_pull"):
-                # one device->host pull for the whole (slots, K+1) tick
-                toks, logps, finites, counts, accepts = jax.device_get(
-                    (toks, logps, finites, counts, accepts))
+                # one device->host pull for the whole call's burst (and a
+                # batched model's routed counts, a tick's commits with it)
+                host = jax.device_get({k: v for k, v in out.items()
+                                       if k not in _RESIDENT})
             stat_add("STAT_serving_decode_steps")
-            stat_add("STAT_spec_ticks")
-            k_spec = self.spec_tokens
-            with _span("serving_deliver") as deliver:
-                emitted = proposed = accepted_n = 0
-                seated = len(self._slots)
-                for slot in list(self._slots):
-                    run = self._slots[slot]
-                    if not finites[slot]:
-                        self._fail_slot(slot, run.resp, "verify")
-                        continue
-                    if run.req.spec:
-                        proposed += k_spec
-                        accepted_n += int(accepts[slot])
-                        self._h_accept.observe(int(accepts[slot]) / k_spec)
-                    for j in range(int(counts[slot])):
-                        # deadline enforcement on the tick itself (PR-6
-                        # rule): a speculative tick may hold K+1 ready
-                        # tokens, but a budget that expired mid-tick
-                        # delivers none of the remainder — the slot
-                        # recycles now (regression: deadline shorter than
-                        # one speculative tick)
-                        if (run.req.deadline is not None
-                                and run.req.deadline.expired()):
-                            stat_add("STAT_serving_deadline_expired")
-                            run.resp._fail(DeadlineExceededError(
-                                f"request {run.req.id} deadline "
-                                f"({run.req.deadline.seconds}s) expired "
-                                "mid-decode"))
-                            self._release(slot)
-                            break
-                        t = int(toks[slot, j])
-                        run.pos += 1
-                        run.produced += 1
-                        run.last_token = t
-                        self._emit(run, t, float(logps[slot, j]))
-                        emitted += 1
-                        self._maybe_finish(slot, run, t)
-                        if slot not in self._slots:
-                            # finished mid-tick: the tail commits are
-                            # discarded (their KV garbage dies with the
-                            # slot's next prefill)
-                            break
-                self._count_tokens(emitted)
-                if proposed:
-                    stat_add("STAT_spec_proposed", proposed)
-                    stat_add("STAT_spec_accepted", accepted_n)
-                    with self._m_lock:
-                        self._spec_proposed += proposed
-                        self._spec_accepted += accepted_n
-                deliver.args = {"tokens": emitted,
-                                "finished": seated - len(self._slots)}
+            if spec:
+                self._spec_ticks += 1
+                stat_add("STAT_spec_ticks")
+                self._count_accepts(host["accepts"], host["finites"])
+                # a tick holds commits[slot] ready tokens a slot, and one
+                # non-finite flag a slot covers them all
+                self._deliver(
+                    host["toks"], host["logps"],
+                    np.where(host["finites"], host["commits"], 1),
+                    np.broadcast_to(host["finites"][:, None],
+                                    host["toks"].shape), "verify")
+                return
+            if self._batched:
+                self._count_routed(sp.args, host["counts"])
+                self._gauge_kv_rows()
+            # the chunk's (step, slot) burst, a slot a row
+            self._deliver(host["toks"].T, host["logps"].T,
+                          np.full(self.max_slots, host["toks"].shape[0]),
+                          host["finites"].T, "decode")
+
+    def _count_accepts(self, accepts, finites):
+        """The accept-rate accounts of a verify tick, over the slots that
+        speculate and came back finite."""
+        proposed = accepted = 0
+        for slot, run in self._slots.items():
+            if run.req.spec and finites[slot]:
+                proposed += self.spec_tokens
+                accepted += int(accepts[slot])
+                self._h_accept.observe(int(accepts[slot]) / self.spec_tokens)
+        if proposed:
+            stat_add("STAT_spec_proposed", proposed)
+            stat_add("STAT_spec_accepted", accepted)
+            with self._m_lock:
+                self._spec_proposed += proposed
+                self._spec_accepted += accepted
+
+    def _deliver(self, toks, logps, ready, finite, phase: str):
+        """Emit every seated slot's ready tokens (the first `ready[slot]`
+        of row `slot`), fail a slot at its first non-finite step, finish
+        or release — the one loop behind the decode chunk and the
+        speculative tick."""
+        with _span("serving_deliver") as deliver:
+            emitted, seated = 0, len(self._slots)
+            for slot in list(self._slots):
+                run = self._slots[slot]
+                for j in range(int(ready[slot])):
+                    # deadline enforcement on the tick itself, not only at
+                    # the next sweep: a call may hold several ready tokens
+                    # a slot (a chunk, or a tick's K+1 commits), but a
+                    # budget that expired while it was computing stops the
+                    # stream here — no post-expiry token is delivered, the
+                    # slot recycles now (regression: deadline shorter than
+                    # one chunk / one speculative tick)
+                    if self._expired(slot, run):
+                        break
+                    if not finite[slot, j]:
+                        self._fail_slot(slot, run.resp, phase)
+                        break
+                    t = int(toks[slot, j])
+                    run.pos += 1
+                    run.produced += 1
+                    run.last_token = t
+                    self._emit(run, t, float(logps[slot, j]))
+                    emitted += 1
+                    self._maybe_finish(slot, run, t)
+                    if slot not in self._slots:
+                        # finished mid-call: the slot's later tokens are
+                        # discarded (their KV garbage dies with the slot's
+                        # next prefill)
+                        break
+            self._count_tokens(emitted)
+            deliver.args = {"tokens": emitted,
+                            "finished": seated - len(self._slots)}
 
     def _fail_slot(self, slot: int, resp: Response, phase: str):
         stat_add("STAT_serving_nonfinite")
@@ -2822,13 +2265,8 @@ class ServingEngine:
         """Fail every in-flight and queued request (engine death/close):
         a consumer blocked in Response.__iter__ / tokens() must get an
         error, never hang."""
-        for slot in list(self._slots):
-            run = self._slots.pop(slot)
-            self.scheduler.release(slot)
-            if self.kv == "paged":
-                self.kv_pool.free(slot)
-            if self._lora_reg is not None and run.aid:
-                self._lora_reg.release(run.aid)
+        for slot, run in list(self._slots.items()):
+            self._release(slot)
             run.resp._fail(make_exc(run.req))
         for req, resp in self.scheduler.drain_pending():
             resp._fail(make_exc(req))
@@ -2894,94 +2332,44 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # program lifecycle: example args, warmup, AOT program sets
     # ------------------------------------------------------------------
-    def _example_prefill_args(self, bucket: int):
-        """The exact argument tuple a live admission passes to this
-        bucket's prefill program (same avals, CURRENT pools) — one
-        builder shared by warmup and the program-set exporter so their
-        signatures can never drift.  Fixed pools target slot 0 (warmup
-        junk dies at the slot's next prefill); paged args route every
-        write through the allocator's sentinel table (dropped)."""
-        if self.kv == "paged":
-            slot_arg = jnp.asarray(self.kv_pool.sentinel_table())
-        else:
-            slot_arg = jnp.int32(0)
-        ids = np.full((1, bucket), self.pad_token_id, np.int32)
-        zero_key = jnp.asarray(np.zeros(self._key_width, np.uint32))
-        plen_args = ((jnp.int32(1), jnp.int32(0))   # plen, cached_len
-                     if self.prefix_cache is not None
-                     else (jnp.int32(1),))
-        if self.lora is not None:
-            # adapter id 0 = base: warmup decodes under the all-zero
-            # slot-0 factors, same avals as any live adapter id
-            plen_args = plen_args + (jnp.int32(0),)
-        common = (jnp.asarray(ids), slot_arg) + plen_args + (
-            zero_key, jnp.float32(1.0), jnp.int32(0), jnp.float32(1.0),
-            jnp.asarray(True))
-        if self.draft_model is not None:
-            return (self._state, self._dstate, self._pools,
-                    self._draft_pools) + common
-        if self.lora is not None:
-            return (self._state, self._pools,
-                    self._lora_reg.device_args()) + common
-        return (self._state, self._pools) + common
-
-    def _example_decode_args(self):
-        """The exact argument tuple a live tick passes to the decode (or
-        speculative verify) program — shared by warmup and the exporter."""
-        s = self.max_slots
-        pre = []
-        if self.kv == "paged":
-            pre = [jnp.asarray(np.tile(self.kv_pool.sentinel_table(),
-                                       (s, 1))),
-                   jnp.zeros((s,), bool)]
-        base = [jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.int32),
-                jnp.zeros((s, self._key_width), jnp.uint32),
-                jnp.ones((s,), jnp.float32), jnp.zeros((s,), jnp.int32),
-                jnp.ones((s,), jnp.float32), jnp.ones((s,), bool)]
-        if self.lora is not None:
-            # per-slot adapter ids slide in right after `pos`
-            base.insert(2, jnp.zeros((s,), jnp.int32))
-        if self._batched:
-            # so does the batched program's mask of occupied slots
-            base.insert(2, jnp.zeros((s,), bool))
-        if self.draft_model is not None:
-            args = pre + base + [jnp.ones((s,), bool),
-                                 jnp.zeros((s,), bool), jnp.asarray(False)]
-            return (self._state, self._dstate, self._pools,
-                    self._draft_pools, *args)
-        args = pre + base + [jnp.zeros((s,), bool)]
-        if self.lora is not None:
-            return (self._state, self._pools,
-                    self._lora_reg.device_args(), *args)
-        return (self._state, self._pools, *args)
+    def _programs(self):
+        """[(name, fn, inputs)]: every compiled program this engine
+        configuration will ever run, each with the `inputs` of a call
+        that touches no run — a one-token prompt through the cache view's
+        sentinel slot, a batch over no runs — at the avals of a live
+        call.  Warm-up and the program-set exporter both read it, so
+        neither keeps a signature of its own."""
+        probe = Request(0, [self.pad_token_id], 1, seed=0)
+        programs = [(f"prefill_b{b}", self._prefill_fns[b],
+                     self._prefill_inputs(probe, self._request_key(probe),
+                                          b, None))
+                    for b in self.buckets]
+        programs.append(("decode", self._decode_fn,
+                         self._decode_inputs(self._batch_inputs({}), {})))
+        return programs
 
     def _program_family(self):
-        """[(name, fn, example_args, donate_argnums)] for every compiled
-        program this engine configuration will ever run — the unit the
-        program store and AOT program sets operate on.  Names are
-        layout-agnostic (`prefill_b{bucket}`, `decode`) so a paged
-        artifact can never be confused with a fixed one except through
-        the manifest, which records the layout explicitly.  The donation
-        indices ride along because `jax.export` does not preserve
-        donation — the program-set loader re-applies them (losing them
-        silently would turn every tick into a full KV-pool copy)."""
-        donate = (2, 3) if self.draft_model is not None else (1,)
-        family = [(f"prefill_b{b}", self._prefill_fns[b],
-                   self._example_prefill_args(b), donate)
-                  for b in self.buckets]
-        family.append(("decode", self._decode_fn,
-                       self._example_decode_args(), donate))
-        return family
+        """[(name, fn, example_args, donate_argnums)] for `_programs()`,
+        with the CURRENT weights and pools — the unit the program store
+        and AOT program sets operate on.  Names are layout-agnostic
+        (`prefill_b{bucket}`, `decode`) so a paged artifact can never be
+        confused with a fixed one except through the manifest, which
+        records the layout explicitly.  The donation indices ride along
+        because `jax.export` does not preserve donation — the program-set
+        loader re-applies them (losing them silently would turn every
+        tick into a full KV-pool copy)."""
+        return [(name, fn, self._program_args(inputs), (1,))
+                for name, fn, inputs in self._programs()]
 
     def warmup(self) -> Dict:
         """Compile every program the engine will ever run (one prefill per
         bucket + the decode/verify step — on speculative engines the
         verify program and the draft halves of each bucket prefill ride
-        the same calls; paged variants route writes through the sentinel
-        table) so no request pays a trace — the program-lifecycle warmup
-        the gateway calls before admitting traffic.  After it returns,
-        `post_warmup_compiles()` must stay 0 under ANY traffic mix —
-        spec on/off, greedy/sampling, preempt/restore.
+        the same calls; a paged view routes the writes through the
+        sentinel table) so no request pays a trace — the program-lifecycle
+        warmup the gateway calls before admitting traffic.  After it
+        returns, `post_warmup_compiles()` must stay 0 under ANY traffic
+        mix — spec on/off, greedy/sampling, preempt/restore.
 
         Programs preloaded from an AOT program set in the native 'exe'
         representation are already compiled and are NOT executed here
@@ -2992,31 +2380,13 @@ class ServingEngine:
         from ..programs.program_set import LoadedProgram
         t0 = time.perf_counter()
         sources = {}
-        for b in self.buckets:
-            fn = self._prefill_fns[b]
+        for name, fn, inputs in self._programs():
             if isinstance(fn, LoadedProgram) and fn.kind == "exe":
-                sources[f"prefill_b{b}"] = "program_set:exe"
+                sources[name] = "program_set:exe"
                 continue
-            out = fn(*self._example_prefill_args(b))
-            if self.draft_model is not None:
-                self._pools, self._draft_pools = out[3], out[4]
-            else:
-                self._pools = out[3]
-            sources[f"prefill_b{b}"] = (
-                "program_set:stablehlo" if isinstance(fn, LoadedProgram)
-                else "traced")
-        fn = self._decode_fn
-        if isinstance(fn, LoadedProgram) and fn.kind == "exe":
-            sources["decode"] = "program_set:exe"
-        else:
-            out = fn(*self._example_decode_args())
-            if self.draft_model is not None:
-                self._pools, self._draft_pools = out[-2], out[-1]
-            else:
-                self._pools = out[-1]
-            sources["decode"] = (
-                "program_set:stablehlo" if isinstance(fn, LoadedProgram)
-                else "traced")
+            self._run(fn, inputs)
+            sources[name] = ("program_set:stablehlo"
+                             if isinstance(fn, LoadedProgram) else "traced")
         if self._cow_fn is not None:
             # precompile the COW block copy with the sentinel dst (mode=
             # "drop" makes it a no-op) so the first real COW pays no trace

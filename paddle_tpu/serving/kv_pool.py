@@ -19,8 +19,16 @@ Split of responsibilities:
   slot -> block-table indirection, alloc/append/free, capacity
   accounting (including the ``PDTPU_FAULT_KV_EXHAUST`` forced-exhaustion
   cap), and construction of the device pools from any model speaking the
-  ``gen_fixed_cache`` protocol.  Pure host bookkeeping: nothing here is
+  ``gen_fixed_cache`` protocol.  Pure host bookkeeping: nothing of it is
   ever traced.
+- **FixedKVView / PagedKVView** (here): what the engine's three program
+  bodies know of a layout.  Each has the same trace-time entries —
+  `open` (pools + the call's tables -> the contiguous view the model
+  runs against), `publish` (the rows a call wrote, back into the pools),
+  `prompt_cache` / `write_prompt` (what a prompt's forward runs against,
+  and where its rows go) — and the host-side inputs that go with them
+  (`prompt_inputs`, `batch_inputs`; over no slot they are the sentinel
+  forms warm-up uses).  The engine holds one and never asks which.
 - **ops/paged_attention.py** (device side): the gather/scatter/scrub
   primitives the compiled serving programs use against the pool, plus
   the standalone paged-attention op (jnp gather fallback on CPU, pallas
@@ -56,12 +64,15 @@ import threading
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.errors import InvalidArgumentError, ResourceExhaustedError
 from ..utils import faults
 
-__all__ = ["PagedKVPool", "KVPoolExhaustedError"]
+__all__ = ["PagedKVPool", "KVPoolExhaustedError", "FixedKVView",
+           "PagedKVView"]
 
 
 class KVPoolExhaustedError(ResourceExhaustedError):
@@ -426,7 +437,6 @@ class PagedKVPool:
         shape (B, T, *rest), one zero pool of shape
         (num_blocks, block_size, *rest).  `put` (optional) places each
         leaf — the mesh engine passes a heads-sharded device_put."""
-        import jax.numpy as jnp
         pools = []
         for (ks, kdt), (vs, vdt) in self.leaf_shapes(model, dtype):
             k = jnp.zeros((self.num_blocks, self.block_size) + ks, kdt)
@@ -439,3 +449,215 @@ class PagedKVPool:
     def pool_bytes(self, pools) -> int:
         return int(sum(k.size * k.dtype.itemsize + v.size * v.dtype.itemsize
                        for k, v in pools))
+
+
+class FixedKVView:
+    """The fixed layout as the engine's programs see it: every leaf holds
+    one row of cache per slot, `pool_len` long or, for a window layer, a
+    ring written at ``pos % rows``.  The pool IS the contiguous view the
+    model runs against, so `open` and `publish` are the identity; a
+    prompt's rows go to its slot's row."""
+
+    # -- host side: what a call's `inputs` say of the layout -----------------
+    def prompt_inputs(self, slot: Optional[int] = None) -> Dict:
+        """The slot a prefill writes (None: slot 0, whose warm-up junk dies
+        at the slot's next prefill)."""
+        return {"slot": jnp.int32(slot or 0)}
+
+    def batch_inputs(self, slots) -> Dict:
+        return {}
+
+    # -- trace time ------------------------------------------------------------
+    def open(self, pools, inputs):
+        return pools
+
+    def publish(self, pools, view, inputs, pos, n_rows):
+        return view
+
+    def prompt_cache(self, pools, inputs, scratch):
+        """(the caches a prompt's forward runs against, its position)."""
+        return scratch(), 0
+
+    def write_prompt(self, pools, kv, inputs):
+        slot, prompt_len = inputs["slot"], inputs["prompt_len"]
+        new_pools = []
+        for (kp, vp), (kc, vc) in zip(pools, kv):
+            # full-range overwrite: bucket KV + zeros to the leaf's own
+            # length (pool_len, or a window layer's ring), so a
+            # recycled slot keeps no stale KV from its previous tenant
+            rows = kp.shape[1]
+            if kc.shape[1] > rows:
+                # a bucket longer than the ring leaves the prompt's
+                # last `rows` positions in it: row r holds the one
+                # position p in [plen - rows, plen) with p % rows == r
+                first = prompt_len - rows
+                p = first + (jnp.arange(rows) - first) % rows
+                held = (p >= 0)[None, :, None, None]
+                at = jnp.maximum(p, 0)
+                krow = jnp.where(held, jnp.take(kc, at, axis=1),
+                                 0).astype(kp.dtype)
+                vrow = jnp.where(held, jnp.take(vc, at, axis=1),
+                                 0).astype(vp.dtype)
+            else:
+                krow = jnp.zeros((1, rows) + kp.shape[2:], kp.dtype)
+                vrow = jnp.zeros((1, rows) + vp.shape[2:], vp.dtype)
+                krow = jax.lax.dynamic_update_slice(
+                    krow, kc.astype(kp.dtype), (0, 0, 0, 0))
+                vrow = jax.lax.dynamic_update_slice(
+                    vrow, vc.astype(vp.dtype), (0, 0, 0, 0))
+            new_pools.append((
+                jax.lax.dynamic_update_slice(kp, krow, (slot, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(vp, vrow, (slot, 0, 0, 0))))
+        return new_pools
+
+
+class PagedKVView:
+    """The paged layout as the engine's programs see it: one block pool
+    per leaf and a block table per slot.  `open` gathers every slot's
+    table into its contiguous view ONCE per call — value-identical to the
+    fixed slot row, to the block boundary, so streams stay bit-identical
+    and attention pays nothing extra — and `publish` scatters the rows
+    the call wrote back through the tables in one pass, zeroing every
+    block a slot ENTERS first (scrub-on-recycle, module docstring).  One
+    gather and one scatter per call amortize the indirection over the
+    call's rows.  A draft's pool pages through the SAME tables."""
+
+    def __init__(self, pool: PagedKVPool, max_slots: int):
+        self.pool = pool
+        self.max_slots = int(max_slots)
+        self._batch = None   # (allocator version, slots) -> its inputs
+
+    # -- host side -------------------------------------------------------------
+    def prompt_inputs(self, slot: Optional[int] = None) -> Dict:
+        """The slot's block table (None: the all-sentinel table, through
+        which warm-up writes nothing)."""
+        return {"table": jnp.asarray(
+            self.pool.sentinel_table() if slot is None
+            else self.pool.table_array(slot))}
+
+    def batch_inputs(self, slots) -> Dict:
+        """Per-slot block tables (sentinel everywhere a slot is
+        unoccupied, so its writes drop) + the occupancy mask.  Cached
+        against the allocator's mutation version — tables only change
+        when a slot crosses a block boundary or membership churns, so
+        steady-state ticks re-upload nothing."""
+        key = (self.pool.version, tuple(slots))
+        if self._batch is None or self._batch[0] != key:
+            tables = np.tile(self.pool.sentinel_table(),
+                             (self.max_slots, 1))
+            active = np.zeros((self.max_slots,), bool)
+            for slot in slots:
+                tables[slot] = self.pool.table_array(slot)
+                active[slot] = True
+            self._batch = (key, {"tables": jnp.asarray(tables),
+                                 "active": jnp.asarray(active)})
+        return self._batch[1]
+
+    # -- trace time ------------------------------------------------------------
+    def open(self, pools, inputs):
+        """Batched `ops.paged_attention.gather_block_rows` (ONE
+        implementation site for the clip/sentinel contract): (S, nb_max)
+        tables over (num_blocks, block_size, ...) pools -> every slot's
+        contiguous (T, ...) view."""
+        from ..ops.paged_attention import gather_block_rows
+        gather = jax.vmap(gather_block_rows, in_axes=(None, 0))
+        tables = inputs["tables"]
+        return [(gather(kp, tables), gather(vp, tables)) for kp, vp in pools]
+
+    def publish(self, pools, view, inputs, pos, n_rows):
+        """Scatter rows pos..pos+n_rows-1 of every slot's view back (near
+        the view's end: `_window`)."""
+        start = self._window(pos, n_rows, view)
+        cut = jax.vmap(
+            lambda c, p: jax.lax.dynamic_slice_in_dim(c, p, n_rows))
+        return self._rows_back(
+            pools, [(cut(kc, start), cut(vc, start)) for kc, vc in view],
+            inputs["tables"], start, inputs["active"])
+
+    def prompt_cache(self, pools, inputs, scratch):
+        """A cold prompt runs against a bucket-sized scratch cache at 0.
+        With a prefix cache (`cached_len` among the inputs) it runs at
+        `cached_len` against the slot's own gathered view, whose rows
+        [0, cached_len) the adopted blocks already hold: only the
+        uncached SUFFIX is computed, and cached_len=0 IS the cold path."""
+        if "cached_len" not in inputs:
+            return scratch(), 0
+        from ..ops.paged_attention import gather_block_rows
+        table = inputs["table"]
+        return ([(gather_block_rows(kp, table)[None],
+                  gather_block_rows(vp, table)[None]) for kp, vp in pools],
+                inputs["cached_len"])
+
+    def write_prompt(self, pools, kv, inputs):
+        bucket = inputs["ids"].shape[1]
+        table = inputs["table"]
+        if "cached_len" in inputs:
+            # the suffix rows alone go back.  They start at cached_len — a
+            # block boundary for non-COW admissions, so shared blocks are
+            # never entered; any block the write scrubs lies entirely
+            # inside the window (fully rewritten), so shared content is
+            # preserved bit-exactly
+            start = self._window(inputs["cached_len"], bucket, kv)
+            rows = [(jax.lax.dynamic_slice_in_dim(kc[0], start, bucket)[None],
+                     jax.lax.dynamic_slice_in_dim(vc[0], start, bucket)[None])
+                    for kc, vc in kv]
+            return self._rows_back(pools, rows, table[None], start[None],
+                                   jnp.ones((1,), bool))
+        # every block the table covers for the bucket is overwritten
+        # END-TO-END (prompt KV + zeros to the block boundary): scrub-on-
+        # recycle for prompt blocks is the overwrite itself.  Sentinel
+        # entries (warm-up) drop the write.
+        bs = self.pool.block_size
+        nb = -(-bucket // bs)
+        ids = table[:nb]
+
+        def as_blocks(chunk, pool):
+            rows = chunk[0].astype(pool.dtype)               # (bucket, ...)
+            if nb * bs > bucket:
+                rows = jnp.concatenate(
+                    [rows, jnp.zeros((nb * bs - bucket,) + rows.shape[1:],
+                                     pool.dtype)])
+            return rows.reshape((nb, bs) + rows.shape[1:])
+
+        return [(kp.at[ids].set(as_blocks(kc, kp), mode="drop"),
+                 vp.at[ids].set(as_blocks(vc, vp), mode="drop"))
+                for (kp, vp), (kc, vc) in zip(pools, kv)]
+
+    @staticmethod
+    def _window(pos, n_rows, view):
+        """Where the `n_rows` rows a call wrote from `pos` on are cut out
+        of a (*, T, ...) view: `pos` clamped so the window never runs off
+        the view's end.  A clamped window re-writes up to (pos - start)
+        rows BELOW pos with the values the gather read for them —
+        idempotent by construction — instead of paying a permanently
+        longer view just to keep dynamic_slice from clamping."""
+        return jnp.maximum(0, jnp.minimum(pos, view[0][0].shape[1] - n_rows))
+
+    def _rows_back(self, pools, rows, tables, start, active):
+        """Per slot, the (S, R, ...) rows of positions start..start+R-1
+        through the tables into the block pools.  Every block a slot
+        ENTERS (write offset 0) is zeroed before the rows land; inactive
+        slots and rows past pool_len route through the sentinel id and
+        are dropped."""
+        from ..ops.paged_attention import scatter_block_rows, scrub_blocks
+        bs, sentinel = self.pool.block_size, self.pool.num_blocks
+        n_rows = rows[0][0].shape[1]
+        pvals = start[:, None] + jnp.arange(n_rows)[None, :]     # (S, R)
+        bidx = jnp.clip(pvals // bs, 0, tables.shape[1] - 1)
+        blk = jnp.take_along_axis(tables, bidx, axis=1)
+        off = (pvals % bs).reshape(-1)
+        ok = active[:, None] & (pvals < self.pool.pool_len)
+        blk_w = jnp.where(ok, blk, sentinel).reshape(-1)
+        # a block's first row IS the entering position, so every already
+        # committed row of the entering slot lives in earlier blocks —
+        # zeroing here can only erase recycled/stale speculative rows
+        scrub = jnp.where(ok & (pvals % bs == 0), blk, sentinel).reshape(-1)
+        new_pools = []
+        for (kp, vp), (kr, vr) in zip(pools, rows):
+            kr = kr.reshape((-1,) + kr.shape[2:])                # (S*R, ...)
+            vr = vr.reshape((-1,) + vr.shape[2:])
+            kp = scrub_blocks(kp, scrub)
+            vp = scrub_blocks(vp, scrub)
+            new_pools.append((scatter_block_rows(kp, blk_w, off, kr),
+                              scatter_block_rows(vp, blk_w, off, vr)))
+        return new_pools

@@ -306,8 +306,11 @@ def test_adapter_corrupt_fault_is_typed_and_clean_on_retry(adapters):
 # serving engine: adapter id 0 bit-identity, mixed batches, zero compiles
 # ---------------------------------------------------------------------------
 
-def test_engine_base_bit_identity_mixed_batch_and_swap_survival(adapters):
-    """The lora engine's adapter-id-0 streams are bit-identical to a
+@pytest.mark.parametrize("kv", [dict(), dict(kv="paged", block_size=4)],
+                         ids=["fixed", "paged"])
+def test_engine_base_bit_identity_mixed_batch_and_swap_survival(adapters, kv):
+    """Over both cache views (the oracle engine stays fixed and plain):
+    the lora engine's adapter-id-0 streams are bit-identical to a
     separately built no-LoRA engine; a heterogeneous batch (base + two
     adapters on four slots IN ONE TICK) reproduces each stream's solo
     single-adapter oracle bit-for-bit; nothing compiles after warmup —
@@ -322,7 +325,7 @@ def test_engine_base_bit_identity_mixed_batch_and_swap_survival(adapters):
     from paddle_tpu.jit import state_arrays
     plain = ServingEngine(tiny_model(), **ENGINE_KW)
     eng = ServingEngine(tiny_model(), lora=LoRAConfig(**LORA_CFG),
-                        **ENGINE_KW)
+                        **ENGINE_KW, **kv)
     plain.warmup()
     eng.warmup()
     eng.load_adapter("a1", adapters["a1"][0])

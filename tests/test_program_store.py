@@ -314,7 +314,7 @@ def test_program_set_stablehlo_fallback_path(tmp_path):
     blob = pickle.dumps(body, protocol=pickle.HIGHEST_PROTOCOL)
     hlo_only = str(tmp_path / "hlo_only.pdprograms")
     with open(hlo_only, "wb") as f:
-        pickle.dump({"format": 1,
+        pickle.dump({"format": envelope["format"],
                      "sha256": hashlib.sha256(blob).hexdigest(),
                      "body": blob}, f)
     eng2 = ServingEngine(m, max_slots=2, max_len=48, prefill_buckets=(8,),
@@ -359,6 +359,27 @@ def test_program_set_mismatch_and_corruption_are_typed(tmp_path):
     open(junk, "wb").write(b"not a program set")
     with pytest.raises(ProgramSetError):
         programs.read_manifest(junk)
+
+
+def test_program_set_of_an_older_format_is_refused_typed(tmp_path):
+    """A format-1 artifact holds programs with positional signatures; this
+    build calls every program as `(weights, pools, inputs)`, so the older
+    artifact must fail `ProgramSetError` at load (the predictor's
+    fallback) and never be called with the wrong arguments."""
+    import pickle
+    from paddle_tpu.programs.program_set import PROGRAM_SET_FORMAT
+    m = tiny_gpt()
+    eng = ServingEngine(m, max_slots=2, max_len=24, prefill_buckets=(8,))
+    path = eng.save_program_set(str(tmp_path / "a"))
+    with open(path, "rb") as f:
+        envelope = pickle.load(f)
+    assert envelope["format"] == PROGRAM_SET_FORMAT >= 2
+    old = str(tmp_path / "old.pdprograms")
+    with open(old, "wb") as f:
+        pickle.dump(dict(envelope, format=1), f)
+    with pytest.raises(ProgramSetError, match="format 1 unsupported"):
+        ServingEngine(m, max_slots=2, max_len=24, prefill_buckets=(8,),
+                      program_set=old)
 
 
 def test_predictor_falls_back_on_bad_program_set(tmp_path):

@@ -71,16 +71,20 @@ def test_spec_greedy_parity_random_draft(spec_engine):
         assert r.finish_reason == "length"
 
 
-def test_spec_identical_draft_accepts_everything():
+@pytest.mark.parametrize("kv", [dict(), dict(kv="paged", block_size=4)],
+                         ids=["fixed", "paged"])
+def test_spec_identical_draft_accepts_everything(kv):
     """Draft == target (weight-identical clone): every proposal matches
     the target argmax, so accept rate is exactly 1.0 — this also proves
     the K+1-token verify forward is row-for-row bit-identical to the
-    draft's sequential single-token forwards."""
+    draft's sequential single-token forwards.  Over both cache views: the
+    paged one gathers and publishes the draft's and the target's rows
+    through one set of block tables."""
     target = tiny_gpt(layers=2, seed=7)
     clone = tiny_gpt(layers=2, seed=7)
     eng = ServingEngine(target, max_slots=2, max_len=48,
                         prefill_buckets=(8,), draft_model=clone,
-                        spec_tokens=3)
+                        spec_tokens=3, **kv)
     r = eng.submit([1, 2, 3, 4], max_new_tokens=9)
     eng.run_until_drained(timeout=120)
     assert r.tokens() == solo(target, [1, 2, 3, 4], 9)

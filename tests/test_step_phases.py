@@ -293,6 +293,55 @@ def _module_name(fn, args):
     return re.search(r"module @(\S+)", text).group(1)
 
 
+def _tiny_routed():
+    m = models.CohereMoEForCausalLM(models.CohereMoEConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=16, sliding_window=8, num_experts=8, num_experts_per_tok=2,
+        num_shared_experts=2, dtype="float32", experts_held=(1, 4, 6)))
+    m.eval()
+    return m
+
+
+def _engine_of(kind):
+    """The five kinds of engine the three program bodies are built for:
+    three of `ENGINES`, one with adapters, one over a batched model."""
+    if kind in ENGINES:
+        return _engine(kind)
+    from paddle_tpu.lora import LoRAConfig
+    model, extra = ((_tiny_routed(), dict()) if kind == "batched" else
+                    (tiny_gpt(), dict(lora=LoRAConfig(
+                        rank=4, max_adapters=3, targets=("qkv",)))))
+    return ServingEngine(model, max_slots=2, max_len=32,
+                         prefill_buckets=(8,), decode_chunk=2, **extra)
+
+
+@pytest.mark.parametrize("kind", ["fixed", "paged", "adapters",
+                                  "speculative", "batched"])
+def test_every_kind_of_engine_lowers_the_three_named_programs(kind):
+    """One body each for prefill, decode and verify, whatever the engine
+    is configured with: the jitted functions keep the names the trace's
+    "XLA Modules" line shows, and every program takes the one signature
+    `(weights, pools, inputs)` with the pools donated."""
+    eng = _engine_of(kind)
+    try:
+        family = eng._program_family()
+        names = {name: _module_name(fn, args) for name, fn, args, _ in family}
+        for _, _, args, donate in family:
+            weights, pools, inputs = args
+            assert donate == (1,)
+            assert set(weights) == {"model"} | (
+                {"draft"} if kind == "speculative" else set()) | (
+                {"lora"} if kind == "adapters" else set())
+            assert set(pools) == set(weights) - {"lora"}
+            assert isinstance(inputs, dict)
+    finally:
+        eng.close()
+    assert names == {"prefill_b8": "jit_prefill",
+                     "decode": "jit_verify" if kind == "speculative"
+                     else "jit_decode"}
+
+
 def test_program_patterns_in_the_metrics_match_the_programs_they_name():
     """`decode_*_roofline` finds the decode program's executions in the
     trace's "XLA Modules" line by `^jit_decode\\(`: a refactor that renames
