@@ -30,10 +30,12 @@ import jax.numpy as jnp
 from ..core.errors import InvalidArgumentError
 from ..core.tensor import Tensor, unwrap
 from ..nn import initializer as I
+from ..nn.functional.attention import _PATH_TAKEN as _ATTENTION_PATH
 from ..nn.functional.moe import moe_ffn_held
 from ..nn.layer.container import LayerList
 from ..nn.layer.moe import HeldExperts
 from ..nn.layer_base import Layer
+from ..ops.flash_attention import flash_attention_grouped
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 _QUERY_BLOCK = 256      # prefill attention: queries a block (scores fit)
@@ -175,53 +177,70 @@ class CohereMoEBlock(Layer):
                 + shared / cfg.num_shared_experts), counts
 
     def _attend_seq(self, q, k, v):
-        """One sequence against itself, a block of queries at a time: the
-        float32 scores of 128 heads x 8192 x 8192 do not fit whole.  Each
-        group's keys are read once for its heads; a block reads only the
-        keys its mask can keep, `_KEY_CHUNK` of them at a time under a
-        running maximum and sum (one softmax over more is slow on the
-        chip: PERF.md, PR 30)."""
+        """One sequence against itself under the causal mask, a window
+        layer's queries over their last `sliding_window` keys: q (S, Hq,
+        hd), k, v (S, Hkv, hd) -> (S, Hq * hd).  On the chip the flash
+        kernel's grouped forward (`ops/flash_attention.py`: a KV head read
+        once where it lies for its 16 query heads, the walk started at the
+        window's edge; a full layer at 8192 rows 20.2 ms, a window layer
+        16.6, where the XLA form took 75 and 56: PERF.md, PR 33); where the
+        kernel refuses (not a TPU, a length that is no multiple of 128)
+        the chunked XLA form."""
+        window = self.cfg.sliding_window if self.kind == SLIDING else None
+        with jax.named_scope("window_attention" if window
+                             else "full_attention"):
+            out = flash_attention_grouped(q[None], k[None], v[None],
+                                          window=window)
+            if out is not None:
+                _ATTENTION_PATH.labels(path="flash").inc()
+                return out.reshape(q.shape[0], -1)
+            _ATTENTION_PATH.labels(path="xla").inc()
+            return self._attend_in_chunks(q, k, v, window)
+
+    def _attend_in_chunks(self, q, k, v, window):
+        """The XLA form, a block of queries at a time: the float32 scores
+        of 128 heads x 8192 x 8192 do not fit whole.  Each group's keys are
+        read once for its heads; a block reads only the keys its mask can
+        keep, `_KEY_CHUNK` of them at a time under a running maximum and
+        sum (one softmax over more is slow on the chip: PERF.md, PR 30)."""
         cfg = self.cfg
         s, nkv = q.shape[0], k.shape[1]
         q = q.reshape(s, nkv, -1, cfg.head_dim)
-        window = cfg.sliding_window if self.kind == SLIDING else None
         scale = 1.0 / math.sqrt(cfg.head_dim)
         per_query = lambda a: jnp.transpose(a, (2, 0, 1))[..., None]  # noqa
         out = []
-        with jax.named_scope("window_attention" if window
-                             else "full_attention"):
-            for i0 in range(0, s, _QUERY_BLOCK):
-                i1 = min(i0 + _QUERY_BLOCK, s)
-                lo = max(0, i0 - window + 1) if window else 0
-                i = jnp.arange(i0, i1)[:, None]
-                m = total = acc = None
-                for c0 in range(lo, i1, _KEY_CHUNK):
-                    c1 = min(c0 + _KEY_CHUNK, i1)
-                    scores = jnp.einsum(
-                        "qgrd,kgd->grqk", q[i0:i1], k[c0:c1],
-                        preferred_element_type=jnp.float32) * scale
-                    j = jnp.arange(c0, c1)[None, :]
-                    keep = j <= i
-                    if window:
-                        keep = keep & (i - j < window)
-                    scores = jnp.where(keep, scores, -1e30)
-                    # a chunk that is all masked for a row leaves that
-                    # row's garbage under a maximum of -1e30, which the
-                    # first real maximum wipes (every row keeps j = i)
-                    top = jnp.max(scores, axis=-1)
-                    new_m = top if m is None else jnp.maximum(m, top)
-                    p = jnp.exp(scores - new_m[..., None])
-                    pv = jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype),
-                                    v[c0:c1],
-                                    preferred_element_type=jnp.float32)
-                    if m is None:
-                        total, acc = jnp.sum(p, axis=-1), pv
-                    else:
-                        fade = jnp.exp(m - new_m)
-                        total = total * fade + jnp.sum(p, axis=-1)
-                        acc = acc * per_query(fade) + pv
-                    m = new_m
-                out.append(acc / per_query(total))
+        for i0 in range(0, s, _QUERY_BLOCK):
+            i1 = min(i0 + _QUERY_BLOCK, s)
+            lo = max(0, i0 - window + 1) if window else 0
+            i = jnp.arange(i0, i1)[:, None]
+            m = total = acc = None
+            for c0 in range(lo, i1, _KEY_CHUNK):
+                c1 = min(c0 + _KEY_CHUNK, i1)
+                scores = jnp.einsum(
+                    "qgrd,kgd->grqk", q[i0:i1], k[c0:c1],
+                    preferred_element_type=jnp.float32) * scale
+                j = jnp.arange(c0, c1)[None, :]
+                keep = j <= i
+                if window:
+                    keep = keep & (i - j < window)
+                scores = jnp.where(keep, scores, -1e30)
+                # a chunk that is all masked for a row leaves that row's
+                # garbage under a maximum of -1e30, which the first real
+                # maximum wipes (every row keeps j = i)
+                top = jnp.max(scores, axis=-1)
+                new_m = top if m is None else jnp.maximum(m, top)
+                p = jnp.exp(scores - new_m[..., None])
+                pv = jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype),
+                                v[c0:c1],
+                                preferred_element_type=jnp.float32)
+                if m is None:
+                    total, acc = jnp.sum(p, axis=-1), pv
+                else:
+                    fade = jnp.exp(m - new_m)
+                    total = total * fade + jnp.sum(p, axis=-1)
+                    acc = acc * per_query(fade) + pv
+                m = new_m
+            out.append(acc / per_query(total))
         return jnp.concatenate(out, axis=0).reshape(s, -1)
 
     def _out(self, x, attn, ffn):

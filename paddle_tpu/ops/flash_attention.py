@@ -34,6 +34,21 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   stands between a block's products and its neighbour's softmax; longer
   sequences are walked by `fori_loop`s.  A sequence of one block is the
   same code with nothing to walk and nothing to rescale.
+- **Grouped KV heads and a window, forward only** (`flash_attention_grouped`:
+  a prompt of a served model, causal).  With `r` query heads to a KV head a
+  grid step owns `hg` query heads (`hg` divides `r`) that share ONE KV head:
+  K's and V's block is that head's `d` lanes where it lies in the (B, S,
+  Hkv*D) tensor, found from the step's index, and the `r / hg` steps of a KV
+  head follow each other, so its K and V stay resident and nothing is
+  repeated through HBM.  A `window` gives the walk a lower bound as the
+  diagonal gives it the upper: key blocks wholly older than the window of
+  the q block's first row are neither loaded into the products nor
+  computed, the blocks the window's far edge crosses pay a second compare,
+  the blocks between go unmasked.  A window that holds the whole sequence is
+  the causal form, decided at trace time.  With `r` = 1 and no window every
+  one of these branches is decided at trace time and the kernel is the
+  training calls' kernel, equation for equation.  No backward is built for
+  either form: a gradient raises.
 - The backward is the FlashAttention-2 recompute scheme: the forward saves
   only O and the per-row logsumexp; one merged backward kernel owns a k
   block per grid step, holds Q, dO, lse and delta of the head group
@@ -62,9 +77,19 @@ bound that applies is the MXU at half rate, not the vector unit and not HBM.
 What is left above it is area: the forward computes 3/4 and the backward 5/8
 of the full product where the mask keeps 1/2 plus the diagonal.
 
-`flash_attention_bshd` returns None when the kernel doesn't apply (wrong
-platform/shape, or a sequence too long for its resident blocks); callers
-fall back to the XLA-fused naive path.
+At command-a-plus's prefill, (b1, s8192, 128 query heads on 8 KV heads of
+128) in bfloat16 (PERF.md section 6, PR 33): a full layer 20.2 ms, a layer
+with a window of 4096 16.6 ms (the XLA form in query blocks and key chunks
+75 and 56), 55% of the 11.2 ms its causal products take at 197 TFLOP/s:
+0.072 us a 128 x 128 tile of scores where the two products need 0.043, the
+heads of 128 filling the array.  Blocks of 512 (256: 39.0 ms; 1024: 19.1),
+2 query heads a step (1: 20.6; 4: 19.9).
+
+`flash_attention_bshd` and `flash_attention_grouped` return None when the
+kernel doesn't apply (wrong platform/shape, or a sequence too long for its
+resident blocks); callers fall back to an XLA form.  The first refuses
+grouped heads (the caller expands them, or takes the XLA form): it is the
+trainable entry, and the backward knows one KV head a query head.
 """
 from __future__ import annotations
 
@@ -93,7 +118,10 @@ _VMEM_MOST = 96 << 20
 # which form each traced kernel call took, chosen from the shapes at trace
 # time like `attention_path_total`: `one_block` (the keys are one block: no
 # loop, no rescale), `blocks` (a loop over all k sub-blocks), `causal_blocks`
-# (the loop ends at the diagonal and only its blocks are masked)
+# (the loop ends at the diagonal and only its blocks are masked); from
+# `flash_attention_grouped`: `grouped` (query heads share a KV head, causal),
+# `grouped_window` (and the walk starts at a window's far edge inside the
+# sequence), `window_blocks` (a window, one KV head a query head)
 _FORM_TAKEN = counter(
     "flash_attention_form_total",
     "flash kernel calls traced, by the form the shapes chose", ("form",))
@@ -169,6 +197,17 @@ def _resident_bytes(sq, sk, gd, itemsize):
     return max(bwd, fwd)
 
 
+def _forward_bytes(sq, sk, d, hg, itemsize):
+    """VMEM of the forward kernel alone over ONE shared KV head: K and V of
+    the whole sequence and the step's q and o blocks (double-buffered), and
+    for each of its `hg` heads a step's float32 scores and probabilities
+    (a written-out walk takes the whole blocks as one) and accumulator."""
+    blk_q, blk_k = _block(sq), _block(sk)
+    rows_k = sk if max(sq, sk) <= _WRITTEN_OUT else blk_k
+    return (2 * 2 * sk * d * itemsize + 2 * 2 * blk_q * hg * d * itemsize
+            + hg * blk_q * 4 * (2 * rows_k + d))
+
+
 def _compiler_params(semantics, resident):
     limit = None if resident <= _VMEM_DEFAULT else resident + (16 << 20)
     return pltpu.CompilerParams(dimension_semantics=semantics,
@@ -208,14 +247,57 @@ def flash_attention_bshd(q, k, v, causal=False, bias=None, q_segment_ids=None,
     local = functools.partial(_flash_bshd, causal=bool(causal),
                               dropout_p=float(dropout_p))
     args = (q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed)
-    # jax's own mesh context (`jax.set_mesh`): the axes of it that are not
-    # yet manual are the ones GSPMD would partition this program over
-    mesh = jax.sharding.get_abstract_mesh()
-    free = {a: mesh.shape[a] for a in mesh.axis_names
-            if a not in mesh.manual_axes}
+    free = _free_mesh_axes()
     if all(n == 1 for n in free.values()):
         return local(*args)
     return _per_shard(local, free, *args)
+
+
+def _free_mesh_axes():
+    """{axis: size} of jax's own mesh context (`jax.set_mesh`) that are not
+    yet manual: the ones GSPMD would partition this program over."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {a: mesh.shape[a] for a in mesh.axis_names
+            if a not in mesh.manual_axes}
+
+
+def flash_attention_grouped(q, k, v, window=None):
+    """Causal self-attention of a prompt, FORWARD ONLY: q (batch, seq, Hq,
+    head_dim) against k, v (batch, seq, Hkv, head_dim), Hq a multiple of
+    Hkv, each KV head read where it lies for the query heads that share it.
+    `window` (static): a query sees its own position and the `window - 1`
+    before it; None, or a window that holds the whole sequence, is the
+    causal form.  Returns q's layout, or None where the kernel does not
+    apply (not a TPU, a length that is no multiple of 128, grouped heads
+    narrower than 128 lanes, a multi-device program).  A gradient asked of
+    it raises: the backward kernel knows neither form."""
+    b, sq, h, d = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    if not _available() or d not in (64, 128, 256):
+        return None
+    if sq % 128 != 0 or sk % 128 != 0 or h % hkv != 0:
+        return None
+    r = h // hkv
+    if r > 1 and d % 128 != 0:    # a KV head's block is d lanes wide
+        return None
+    if any(n > 1 for n in _free_mesh_axes().values()):
+        return None
+    hg = _head_group(r, d) if r > 1 else _head_group(h, d)
+    if window is not None and window >= sk:
+        window = None
+    most = (_forward_bytes(sq, sk, d, hg, q.dtype.itemsize) if r > 1
+            else _resident_bytes(sq, sk, hg * d, q.dtype.itemsize))
+    if most > _VMEM_MOST:
+        return None
+    if r > 1:
+        form = "grouped" if window is None else "grouped_window"
+    else:
+        form = _form(True, sk) if window is None else "window_blocks"
+    _FORM_TAKEN.labels(form=form).inc()
+    out = _flash_forward_only(
+        q.reshape(b, sq, h * d), k.reshape(b, sk, hkv * d),
+        v.reshape(b, sk, hkv * d), h, hg, hkv, window)
+    return out.reshape(b, sq, h, d)
 
 
 def _flash_bshd(q, k, v, bias, q_segment_ids, kv_segment_ids, dropout_seed,
@@ -324,17 +406,21 @@ def _coords(q0, k0, blk_q, blk_k):
 
 
 def _scores(k_hd, q_hd, bias, qseg, kseg, q0, k0, scale, diagonal,
-            causal_off):
+            causal_off, edge=None):
     """One transposed (blk_k, blk_q) score block of one head (f32) with its
     masks.  bias, kseg: (blk_k, 1) or None; qseg: (1, blk_q) or None;
-    `diagonal`: the causal diagonal may cross this block."""
+    `diagonal`: the causal diagonal may cross this block; `edge`: a window
+    of that many keys, whose far edge may cross it."""
     s = jax.lax.dot_general(k_hd, q_hd, _NT,
                             preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias
-    if diagonal:
+    if diagonal or edge is not None:
         kpos, qpos = _coords(q0, k0, q_hd.shape[0], k_hd.shape[0])
+    if diagonal:
         s = jnp.where(qpos + causal_off >= kpos, s, _NEG_INF)
+    if edge is not None:
+        s = jnp.where(qpos + causal_off - kpos < edge, s, _NEG_INF)
     if qseg is not None:
         s = jnp.where(kseg == qseg, s, _NEG_INF)
     return s
@@ -361,6 +447,17 @@ def _diagonal_span(first, reach, blk, n):
     position the edge keeps for the nearest row, `reach` for the farthest."""
     hi = _clip(reach // blk + 1, 1, n)
     lo = _clip((first + 1) // blk, 0, hi)
+    return lo, hi
+
+
+def _window_span(first, reach, window, blk, n_whole):
+    """The `n_whole` blocks under the diagonal against a window's far edge,
+    the counterpart of `_diagonal_span`: those below index `lo` are wholly
+    older than the window of the nearest row (`first` its position among
+    the keys), [lo, hi) are crossed by the edge, those from `hi` on lie
+    inside every row's window (`reach`: the farthest row's position)."""
+    lo = _clip((first - window + 1) // blk, 0, n_whole)
+    hi = _clip((reach - window + blk) // blk, lo, n_whole)
     return lo, hi
 
 
@@ -423,7 +520,7 @@ def _traced_once(n_arrays):
 
 def _fwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
                 drop_tile, written_out, blk_q, blk_k, n_q, n_k, scale,
-                causal_off, heads, hg):
+                causal_off, heads, hg, shared_kv=False, window=None):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     bias_ref = next(it) if has_bias else None
@@ -435,15 +532,18 @@ def _fwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
     d = q_ref.shape[-1] // hg
     qseg = qseg_ref[0, 0] if has_seg else None
 
-    def block(h, qi, ki, n, carry, diagonal):
+    def block(h, qi, ki, n, carry, diagonal, edge=None):
         """Online-softmax step of head h over the k sub-blocks [ki, ki+n);
         `carry` None: the first step, nothing to rescale."""
         sl = slice(h * d, (h + 1) * d)
+        # grouped heads: the step's heads share the one KV head it was given
+        kv = slice(0, d) if shared_kv else sl
         ks = _rows(ki, blk_k, n)
-        s = _scores(k_ref[0, ks, sl], q_ref[0, :, sl],
+        s = _scores(k_ref[0, ks, kv], q_ref[0, :, sl],
                     bias_ref[0, ks, :] if has_bias else None,
                     qseg, kseg_ref[0, ks, :] if has_seg else None,
-                    qi * blk_q, ki * blk_k, scale, diagonal, causal_off)
+                    qi * blk_q, ki * blk_k, scale, diagonal, causal_off,
+                    edge)
         m = jnp.max(s, axis=0, keepdims=True)          # (1, blk_q)
         if carry is not None:
             m_prev, l_prev, acc = carry
@@ -456,7 +556,7 @@ def _fwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
                               n * blk_k, dropout_p, drop_tile)
             p = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
         pv = jax.lax.dot_general(                       # (d, blk_q)
-            v_ref[0, ks, sl], p.astype(v_ref.dtype), _TN,
+            v_ref[0, ks, kv], p.astype(v_ref.dtype), _TN,
             preferred_element_type=jnp.float32)
         if carry is None:
             return m, l, pv
@@ -465,31 +565,48 @@ def _fwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
 
     def q_block(qi):
         """q block qi (static or the grid's index) against its k blocks:
-        the whole ones, then those the diagonal crosses.  The heads go
-        side by side: their chains (product, softmax, product) are
-        independent, so one's latency hides behind the others' work."""
+        those a window's far edge crosses (the older ones are skipped), the
+        whole ones, then those the diagonal crosses.  The heads go side by
+        side: their chains (product, softmax, product) are independent, so
+        one's latency hides behind the others' work."""
         if causal:
-            n_whole, n_seen = _diagonal_span(
-                qi * blk_q + causal_off, qi * blk_q + blk_q - 1 + causal_off,
-                blk_k, n_k)
+            # the keys the block's first and last row see last
+            first = qi * blk_q + causal_off
+            reach = qi * blk_q + blk_q - 1 + causal_off
+            n_whole, n_seen = _diagonal_span(first, reach, blk_k, n_k)
         else:
             n_whole = n_seen = n_k
 
-        def blocks(ki, n, carry, diagonal):
-            return tuple(block(h, qi, ki, n, c, diagonal)
+        def blocks(ki, n, carry, diagonal, edge=None):
+            return tuple(block(h, qi, ki, n, c, diagonal, edge)
                          for h, c in enumerate(carry))
         if written_out:
-            # the whole blocks as ONE tall block: no chain of rescales
             carry = (None,) * hg
-            if n_whole:
-                carry = blocks(0, n_whole, carry, False)
         else:
             carry = ((jnp.full((1, blk_q), _NEG_INF, jnp.float32),
                       jnp.zeros((1, blk_q), jnp.float32),
                       jnp.zeros((d, blk_q), jnp.float32)),) * hg
+        start = 0
+        if window is not None:
+            # a row of an edge block may keep none of its keys: what that
+            # leaves under a maximum of -1e30 the first real maximum wipes
+            # (every row keeps its own position, in a later block)
+            lo, start = _window_span(first, reach, window, blk_k, n_whole)
+            carry = _loop(lo, start,
+                          lambda ki, c: blocks(ki, 1, c, False, window),
+                          carry, written_out)
+        if written_out:
+            # the whole blocks as ONE tall block: no chain of rescales
+            if n_whole - start:
+                carry = blocks(start, n_whole - start, carry, False)
+        else:
             carry = jax.lax.fori_loop(
-                0, n_whole, lambda ki, c: blocks(ki, 1, c, False), carry)
-        carry = _loop(n_whole, n_seen, lambda ki, c: blocks(ki, 1, c, True),
+                start, n_whole, lambda ki, c: blocks(ki, 1, c, False), carry)
+        # a window narrower than two blocks can cross a diagonal block too
+        both = window if window is not None and window < blk_q + blk_k \
+            else None
+        carry = _loop(n_whole, n_seen,
+                      lambda ki, c: blocks(ki, 1, c, True, both),
                       carry, written_out)
         outs, lses = [], []
         for m, l, acc in carry:
@@ -534,7 +651,8 @@ def _mask_inputs(bias, qseg, kseg, blk_q, blk_k, q_index, k_index):
 
 
 @_traced_once(7)
-def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
+def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg,
+              kv_heads=None, window=None):
     b, sq, hd = q.shape
     sk = k.shape[1]
     d = hd // heads
@@ -542,13 +660,26 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
     n_hg = heads // hg
     blk_q, blk_k = _block(sq), _block(sk)
     n_q, n_k = sq // blk_q, sk // blk_k
+    written_out = max(sq, sk) <= _WRITTEN_OUT
 
     # grid (b, head group, q block); K and V whole: their block does not
-    # move with the q block, so they are fetched once per (b, head group)
+    # move with the q block, so they are fetched once per (b, head group).
+    # Grouped heads (`kv_heads` < heads, hg dividing their ratio): the block
+    # is the ONE KV head the step's query heads share, d lanes where it lies,
+    # and the steps of one KV head follow each other, so it stays resident
+    # for all of them and nothing is repeated through HBM
+    shared_kv = kv_heads not in (None, heads)
+    if shared_kv:
+        steps = heads // kv_heads // hg      # grid steps a KV head
+        kv_lanes, kv_at = d, lambda g: jax.lax.div(g, steps)
+        resident = _forward_bytes(sq, sk, d, hg, q.dtype.itemsize)
+    else:
+        kv_lanes, kv_at = gd, lambda g: g
+        resident = _resident_bytes(sq, sk, gd, q.dtype.itemsize)
     in_specs = [
         pl.BlockSpec((1, blk_q, gd), lambda b, g, i, s: (b, i, g)),
-        pl.BlockSpec((1, sk, gd), lambda b, g, i, s: (b, 0, g)),
-        pl.BlockSpec((1, sk, gd), lambda b, g, i, s: (b, 0, g)),
+        pl.BlockSpec((1, sk, kv_lanes), lambda b, g, i, s: (b, 0, kv_at(g))),
+        pl.BlockSpec((1, sk, kv_lanes), lambda b, g, i, s: (b, 0, kv_at(g))),
     ]
     extra, extra_specs = _mask_inputs(bias, qseg, kseg, blk_q, sk,
                                       q_index=lambda i: i,
@@ -557,8 +688,9 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
         _fwd_kernel, has_bias=bias is not None, has_seg=qseg is not None,
         causal=causal, dropout_p=dropout_p,
         drop_tile=(_block(sq, fine=causal), _block(sk, fine=causal)),
-        written_out=max(sq, sk) <= _WRITTEN_OUT, blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k, scale=1.0 / math.sqrt(d),
-        causal_off=sk - sq, heads=heads, hg=hg)
+        written_out=written_out, blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
+        scale=1.0 / math.sqrt(d), causal_off=sk - sq, heads=heads, hg=hg,
+        shared_kv=shared_kv, window=window)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -579,8 +711,7 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg):
             jax.ShapeDtypeStruct((b, n_hg, hg, sq), jnp.float32),
         ],
         compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel"),
-            _resident_bytes(sq, sk, gd, q.dtype.itemsize)),
+            ("parallel", "parallel", "parallel"), resident),
         interpret=_INTERPRET,
     )(seed, q, k, v, *extra)
     return o, lse
@@ -790,3 +921,24 @@ def _flash_bwd(causal, dropout_p, heads, hg, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# the grouped and windowed forward: no backward is built for either form
+# (training a routed layer: ROADMAP C3), and a gradient says so by name
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_forward_only(q, k, v, heads, hg, kv_heads, window):
+    o, _ = _fwd_impl(q, k, v, None, None, None, jnp.zeros((1,), jnp.int32),
+                     True, 0.0, heads, hg, kv_heads, window)
+    return o
+
+
+def _forward_only_fwd(q, k, v, heads, hg, kv_heads, window):
+    raise NotImplementedError(
+        "flash_attention_grouped is forward only: the flash backward kernel "
+        "takes neither grouped KV heads nor a window (expand the KV heads "
+        "and call flash_attention_bshd, or take the XLA form)")
+
+
+_flash_forward_only.defvjp(_forward_only_fwd, lambda *_: None)

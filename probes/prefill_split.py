@@ -11,7 +11,12 @@ of the traced calls to the compiled module's own metadata: an event is
 named like its instruction, and the instruction's `op_name` holds the
 `jax.named_scope` path down to the primitive.  `F.moe_ffn_held` is wrapped
 in a scope of the probe's (`routed_ffn`), which adds nothing to the
-program but the name.
+program but the name.  A Pallas kernel is one custom call named by the
+scope it stands in (`%window_attention.1`, op_name
+`.../window_attention/pallas_call`), so the flash kernel of ISSUE 33 falls
+under the same `window_attention` / `full_attention` scopes as the XLA form
+of the parent: the split of both reads the same three scopes
+(`tests/test_chip_compile.py::test_flash_grouped_forward` holds the name).
 
     chiprun -- python3 probes/prefill_split.py --out chiprun_out/split/x.json
 

@@ -111,6 +111,34 @@ def test_flash_forward_backward_causal(chip_compile, b, s):
     assert sum(bool(backward.search(c)) for c in calls) == 1
 
 
+@pytest.mark.parametrize("s,window", [(8192, None), (8192, 4096),
+                                      (1024, 4096), (256, None)],
+                         ids=["full_8192", "window_4096_of_8192",
+                              "written_out_1024", "one_block_256"])
+def test_flash_grouped_forward(chip_compile, monkeypatch, s, window):
+    """command-a-plus's prefill attention (128 query heads on 8 KV heads of
+    128, one prompt, bfloat16) through `flash_attention_grouped`: a full
+    layer and a window layer of the longest bucket (the `fori_loop` walk,
+    started at the window's edge), and the written-out and one-block walks
+    of the short buckets.  One kernel, named by the scope the model calls
+    it under (the prefill probe joins on it), fed K and V as they lie:
+    8 heads wide, nothing repeated."""
+    monkeypatch.setattr(fa, "_available", lambda: True)
+    scope = "window_attention" if window else "full_attention"
+
+    def attend(q, k, v):
+        with jax.named_scope(scope):
+            return fa.flash_attention_grouped(q, k, v, window=window)
+    kv = ((1, s, 8, 128), jnp.bfloat16)
+    text = chip_compile(attend, ((1, s, 128, 128), jnp.bfloat16), kv, kv)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].startswith(f"%{scope}")
+    assert f'/{scope}/pallas_call"' in calls[0]
+    assert calls[0].count(f"bf16[1,{s},1024]") == 2      # K and V
+
+
 @pytest.mark.parametrize("hw_prng", [True, False],
                          ids=["hardware_prng", "hash_bits"])
 def test_flash_dropout_forward_backward(chip_compile, monkeypatch, hw_prng):

@@ -39,8 +39,8 @@ TINY = dict(vocab_size=128, hidden_size=64, intermediate_size=32,
 NAME, T0, DUR, TID, ID, PARENT, ARGS = range(7)
 
 
-def dims(held):
-    cfg = dict(TINY, layer_types=["sliding_attention"] * 3
+def dims(held, tiny=None):
+    cfg = dict(tiny or TINY, layer_types=["sliding_attention"] * 3
                + ["full_attention"], rope_theta=50000, layer_norm_eps=1e-5,
                logit_scale=1, initializer_range=0.125,
                experts_held=list(held))
@@ -53,10 +53,10 @@ def weights(d, seed):
             [dict(A.make_leaves(W.make, d, seed, i)) for i in range(d["L"])])
 
 
-def build(held=(1, 4, 6), seed=2147483659):
-    d = dims(held)
+def build(held=(1, 4, 6), seed=2147483659, tiny=None):
+    d = dims(held, tiny)
     model = models.CohereMoEForCausalLM(models.CohereMoEConfig(
-        **TINY, experts_held=held))
+        **(tiny or TINY), experts_held=held))
     model.eval()
     top, layers = weights(d, seed)
     state = model.state_dict()
@@ -144,6 +144,56 @@ def test_prefill_attention_in_blocks_and_chunks_is_the_references(
                          else None, "float32")
     np.testing.assert_allclose(jax.jit(blk._attend_seq)(q, k, v), want,
                                atol=2e-6)
+
+
+def test_a_kernel_sized_prompt_goes_through_the_flash_kernel(monkeypatch):
+    """Heads of 128 lanes, 4 query heads on 2 KV heads, a prompt bucket of
+    128 rows with a window of 48 inside it: every layer's prefill attention
+    takes `flash_attention_grouped` (interpreter), the logits are the
+    reference's, and the engine's prefill then decode follow the reference
+    at every position."""
+    from paddle_tpu.observability.metrics import get_registry
+    from paddle_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+
+    def counts(name):
+        return {k[0]: v for k, v in get_registry().get(name).samples()}
+    wide = dict(TINY, num_attention_heads=4, head_dim=128, sliding_window=48)
+    model, d, top, layers = build(tiny=wide)
+    bucket, max_len, plen, out = 128, 144, 120, 12
+    ref = jax.jit(lambda ids: REF.logits(top, layers, ids, d))
+    rng = np.random.RandomState(7)
+
+    paths, forms = counts("attention_path_total"), counts(
+        "flash_attention_form_total")
+    ids = rng.randint(0, 128, (1, bucket)).astype(np.int32)
+    got = np.asarray(model(paddle.to_tensor(ids)).numpy())[0]
+    assert counts("attention_path_total").get("flash", 0) \
+        - paths.get("flash", 0) == 4
+    assert counts("attention_path_total").get("xla", 0) == paths.get("xla", 0)
+    moved = {k: v - forms.get(k, 0)
+             for k, v in counts("flash_attention_form_total").items()}
+    assert moved.get("grouped_window") == 3 and moved.get("grouped") == 1
+    assert np.max(np.abs(got - np.asarray(ref(jnp.asarray(ids[0]))))) < 2e-5
+
+    e = ServingEngine(model, max_slots=2, max_len=max_len,
+                      prefill_buckets=(bucket,), decode_chunk=4,
+                      max_queue_depth=4)
+    try:
+        e.warmup()
+        prompt = rng.randint(0, 128, plen).astype(np.int32)
+        resp = e.submit(prompt, out)
+        e.run_until_drained(timeout=120)
+        toks = resp.tokens()
+        assert len(toks) == out and e.post_warmup_compiles() == 0
+    finally:
+        e.close()
+    row = np.zeros((max_len,), np.int32)
+    row[:plen + out] = np.concatenate([prompt, toks])
+    want = np.asarray(jax.nn.log_softmax(
+        ref(jnp.asarray(row)), axis=-1))[plen - 1:plen + out - 1]
+    assert [int(np.argmax(r)) for r in want] == list(toks)
+    assert abs(resp.logprob - want[np.arange(out), toks].sum()) < 1e-4
 
 
 def test_rope_turns_adjacent_pairs_and_leaves_position_0():

@@ -53,6 +53,19 @@ def rand_qkv(b=2, sq=256, sk=256, h=2, d=64, dtype=jnp.float32, seed=0):
     return mk(sq), mk(sq if sq == sk else sk), mk(sq if sq == sk else sk)
 
 
+def counter_moves(name, trace):
+    """{label: by how much} the registry's counter `name` moved over the
+    call `trace()`, and what the call returned."""
+    from paddle_tpu.observability.metrics import get_registry
+
+    def counts():
+        return {k[0]: v for k, v in get_registry().get(name).samples()}
+    before = counts()
+    out = trace()
+    return {k: v - before.get(k, 0) for k, v in counts().items()
+            if v != before.get(k, 0)}, out
+
+
 def test_fwd_matches_naive():
     q, k, v = rand_qkv()
     out = fa.flash_attention_bshd(q, k, v)
@@ -234,19 +247,131 @@ def test_dropout_grad_consistency(walk, causal, s):
 def test_form_counter_says_which_form_the_shapes_chose(s, causal, form):
     """`flash_attention_form_total{form}` is counted where the wrapper
     chooses, at trace time: tracing alone moves it, by one, for one form."""
-    from paddle_tpu.observability.metrics import get_registry
-
-    def counts():
-        m = get_registry().get("flash_attention_form_total")
-        return {k[0]: v for k, v in m.samples()}
-    before = counts()
     qkv = jax.ShapeDtypeStruct((4, s, 16, 64), jnp.bfloat16)
-    out = jax.eval_shape(lambda q, k, v: fa.flash_attention_bshd(
-        q, k, v, causal=causal), qkv, qkv, qkv)
+    moved, out = counter_moves(
+        "flash_attention_form_total",
+        lambda: jax.eval_shape(lambda q, k, v: fa.flash_attention_bshd(
+            q, k, v, causal=causal), qkv, qkv, qkv))
     assert out.shape == qkv.shape
-    moved = {k: v - before.get(k, 0) for k, v in counts().items()
-             if v != before.get(k, 0)}
     assert moved == {form: 1}
+
+
+def plain_grouped(q, k, v, window=None):
+    """Float32 softmax over each query's kept keys, the KV heads repeated:
+    a key is kept at or before the query and, under a window, less than
+    `window` positions before it."""
+    r = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x.astype(jnp.float32), r, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    i = jnp.arange(q.shape[1])[:, None]
+    j = jnp.arange(k.shape[1])[None, :]
+    keep = j <= i
+    if window is not None:
+        keep = keep & (i - j < window)
+    p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+
+def grouped_qkv(s, r, hkv=1, d=128, dtype=jnp.float32, seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.standard_normal((1, s, h, d)), dtype)
+                 for h in (hkv * r, hkv, hkv))
+
+
+# S = 128 is one block; 384 is three blocks of 128, walked written out as
+# `_WRITTEN_OUT` stands and by `fori_loop`s with it patched under S
+REGIMES = {"one_block": (128, None), "written_out": (384, None),
+           "fori_loop": (384, 0)}
+# a window absent, no multiple of a block, under, equal to and over S
+WINDOWS = {"none": lambda s: None, "ragged": lambda s: 100,
+           "under": lambda s: max(s - 128, 128) if s > 128 else 64,
+           "equal": lambda s: s, "over": lambda s: s + 640}
+
+
+@pytest.mark.parametrize("window", list(WINDOWS))
+@pytest.mark.parametrize("regime", list(REGIMES))
+@pytest.mark.parametrize("r", [1, 4, 16])
+def test_grouped_windowed_forward_is_the_plain_softmax(monkeypatch, r,
+                                                        regime, window):
+    """`flash_attention_grouped`: `r` query heads share each of 2 KV heads
+    (one for r = 16), read where they lie; under a window the walk starts
+    at its far edge.  Against a float32 softmax over the kept keys."""
+    s, written_out = REGIMES[regime]
+    if written_out is not None:
+        monkeypatch.setattr(fa, "_WRITTEN_OUT", written_out)
+    w = WINDOWS[window](s)
+    q, k, v = grouped_qkv(s, r, hkv=1 if r == 16 else 2, seed=r)
+    out = fa.flash_attention_grouped(q, k, v, window=w)
+    assert out is not None and out.shape == q.shape
+    np.testing.assert_allclose(out, plain_grouped(q, k, v, w),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_grouped_forward_in_bfloat16_keeps_float32_softmax_state():
+    q, k, v = grouped_qkv(384, 16, dtype=jnp.bfloat16)
+    out = fa.flash_attention_grouped(q, k, v, window=200)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               plain_grouped(q, k, v, 200),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("r,s,window,form", [
+    (16, 8192, None, "grouped"),            # a full layer, any bucket
+    (16, 4096, 4096, "grouped"),            # the window holds the bucket
+    (16, 8192, 4096, "grouped_window"),     # the 8192 bucket's window layers
+    (1, 1024, 256, "window_blocks"),
+    (1, 1024, None, "causal_blocks"),
+])
+def test_form_counter_names_the_grouped_and_windowed_forms(r, s, window,
+                                                           form):
+    """The routed model's prefill shapes (128 / 8 heads of 128) and what a
+    window does to the form, counted at trace time."""
+    q = jax.ShapeDtypeStruct((1, s, 8 * r, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, s, 8, 128), jnp.bfloat16)
+    moved, out = counter_moves(
+        "flash_attention_form_total",
+        lambda: jax.eval_shape(lambda q, k, v: fa.flash_attention_grouped(
+            q, k, v, window=window), q, kv, kv))
+    assert out.shape == q.shape
+    assert moved == {form: 1}
+
+
+def test_grouped_forward_refuses_what_it_cannot_do():
+    """Not a multiple of 128, query heads that do not divide, grouped heads
+    narrower than a lane tile: None, so the caller keeps its XLA form; a
+    gradient asked of the forward-only form fails by name."""
+    q, k, v = grouped_qkv(128, 4, hkv=2)
+    assert fa.flash_attention_grouped(q[:, :100], k[:, :100],
+                                      v[:, :100]) is None
+    assert fa.flash_attention_grouped(q[:, :, :3], k, v) is None
+    assert fa.flash_attention_grouped(q[..., :64], k[..., :64],
+                                      v[..., :64]) is None
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: fa.flash_attention_grouped(q, k, v).sum())(q)
+
+
+def test_sdpa_keeps_refusing_grouped_heads_and_differentiates():
+    """`F.scaled_dot_product_attention` is the trainable call: with fewer
+    KV heads than query heads it takes the XLA form as before (the kernel's
+    grouped form has no backward), and its gradient flows."""
+    import paddle_tpu as paddle
+    from paddle_tpu.nn import functional as F
+    q, k, v = (paddle.to_tensor(np.asarray(x), stop_gradient=False)
+               for x in grouped_qkv(128, 4, hkv=1))
+    moved, out = counter_moves(
+        "attention_path_total",
+        lambda: F.scaled_dot_product_attention(q, k, v, is_causal=True))
+    assert moved == {"xla": 1}
+    np.testing.assert_allclose(
+        out.numpy(), plain_grouped(*(jnp.asarray(t.numpy())
+                                     for t in (q, k, v))),
+        rtol=2e-5, atol=2e-5)
+    out.sum().backward()
+    assert q.grad.shape == [1, 128, 4, 128]
+    assert k.grad.shape == [1, 128, 1, 128]
+    assert float(np.abs(k.grad.numpy()).sum()) > 0
 
 
 def test_sdpa_routes_through_flash():
