@@ -16,3 +16,5 @@ from .dlrm import (DLRMConfig, DLRM, DLRMCriterion,  # noqa: F401
                    dlrm_tiny_config)
 from .cohere_moe import (CohereMoEConfig, CohereMoEBlock,  # noqa: F401
                          CohereMoEForCausalLM)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Block,  # noqa: F401
+                          DeepseekV3ForCausalLM)

@@ -114,6 +114,52 @@ def _softmax_pv(scores, keep, v, spec):
                       preferred_element_type=jnp.float32)
 
 
+def attend_in_chunks(q, k, v, scale, window=None):
+    """Causal attention of one sequence in XLA, a block of queries at a
+    time: q (S, Hkv, r, hd) (`r` query heads a KV head), k (S, Hkv, hd), v
+    (S, Hkv, dv) -> (S, Hkv * r * dv) float32; with `window` a query sees
+    its last `window` keys.  The float32 scores of 128 heads x 8192 x 8192
+    do not fit whole.  Each group's keys are read once for its heads; a
+    block reads only the keys its mask can keep, `_KEY_CHUNK` of them at a
+    time under a running maximum and sum (one softmax over more is slow on
+    the chip: PERF.md, PR 30)."""
+    s = q.shape[0]
+    per_query = lambda a: jnp.transpose(a, (2, 0, 1))[..., None]  # noqa
+    out = []
+    for i0 in range(0, s, _QUERY_BLOCK):
+        i1 = min(i0 + _QUERY_BLOCK, s)
+        lo = max(0, i0 - window + 1) if window else 0
+        i = jnp.arange(i0, i1)[:, None]
+        m = total = acc = None
+        for c0 in range(lo, i1, _KEY_CHUNK):
+            c1 = min(c0 + _KEY_CHUNK, i1)
+            scores = jnp.einsum(
+                "qgrd,kgd->grqk", q[i0:i1], k[c0:c1],
+                preferred_element_type=jnp.float32) * scale
+            j = jnp.arange(c0, c1)[None, :]
+            keep = j <= i
+            if window:
+                keep = keep & (i - j < window)
+            scores = jnp.where(keep, scores, -1e30)
+            # a chunk that is all masked for a row leaves that row's
+            # garbage under a maximum of -1e30, which the first real
+            # maximum wipes (every row keeps j = i)
+            top = jnp.max(scores, axis=-1)
+            new_m = top if m is None else jnp.maximum(m, top)
+            p = jnp.exp(scores - new_m[..., None])
+            pv = jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v[c0:c1],
+                            preferred_element_type=jnp.float32)
+            if m is None:
+                total, acc = jnp.sum(p, axis=-1), pv
+            else:
+                fade = jnp.exp(m - new_m)
+                total = total * fade + jnp.sum(p, axis=-1)
+                acc = acc * per_query(fade) + pv
+            m = new_m
+        out.append(acc / per_query(total))
+    return jnp.concatenate(out, axis=0).reshape(s, -1)
+
+
 class CohereMoEBlock(Layer):
     def __init__(self, cfg: CohereMoEConfig, kind: str):
         super().__init__()
@@ -198,50 +244,10 @@ class CohereMoEBlock(Layer):
             return self._attend_in_chunks(q, k, v, window)
 
     def _attend_in_chunks(self, q, k, v, window):
-        """The XLA form, a block of queries at a time: the float32 scores
-        of 128 heads x 8192 x 8192 do not fit whole.  Each group's keys are
-        read once for its heads; a block reads only the keys its mask can
-        keep, `_KEY_CHUNK` of them at a time under a running maximum and
-        sum (one softmax over more is slow on the chip: PERF.md, PR 30)."""
         cfg = self.cfg
-        s, nkv = q.shape[0], k.shape[1]
-        q = q.reshape(s, nkv, -1, cfg.head_dim)
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-        per_query = lambda a: jnp.transpose(a, (2, 0, 1))[..., None]  # noqa
-        out = []
-        for i0 in range(0, s, _QUERY_BLOCK):
-            i1 = min(i0 + _QUERY_BLOCK, s)
-            lo = max(0, i0 - window + 1) if window else 0
-            i = jnp.arange(i0, i1)[:, None]
-            m = total = acc = None
-            for c0 in range(lo, i1, _KEY_CHUNK):
-                c1 = min(c0 + _KEY_CHUNK, i1)
-                scores = jnp.einsum(
-                    "qgrd,kgd->grqk", q[i0:i1], k[c0:c1],
-                    preferred_element_type=jnp.float32) * scale
-                j = jnp.arange(c0, c1)[None, :]
-                keep = j <= i
-                if window:
-                    keep = keep & (i - j < window)
-                scores = jnp.where(keep, scores, -1e30)
-                # a chunk that is all masked for a row leaves that row's
-                # garbage under a maximum of -1e30, which the first real
-                # maximum wipes (every row keeps j = i)
-                top = jnp.max(scores, axis=-1)
-                new_m = top if m is None else jnp.maximum(m, top)
-                p = jnp.exp(scores - new_m[..., None])
-                pv = jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype),
-                                v[c0:c1],
-                                preferred_element_type=jnp.float32)
-                if m is None:
-                    total, acc = jnp.sum(p, axis=-1), pv
-                else:
-                    fade = jnp.exp(m - new_m)
-                    total = total * fade + jnp.sum(p, axis=-1)
-                    acc = acc * per_query(fade) + pv
-                m = new_m
-            out.append(acc / per_query(total))
-        return jnp.concatenate(out, axis=0).reshape(s, -1)
+        q = q.reshape(q.shape[0], k.shape[1], -1, cfg.head_dim)
+        return attend_in_chunks(q, k, v, 1.0 / math.sqrt(cfg.head_dim),
+                                window)
 
     def _out(self, x, attn, ffn):
         o = jnp.matmul(attn.astype(x.dtype), unwrap(self.o_proj),

@@ -106,7 +106,7 @@ _CHUNK_ROWS = 2048
 
 
 def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
-                      top_k=8, valid=None):
+                      top_k=8, valid=None, select_bias=None, scale=None):
     """The part of a routed FFN that the experts HELD HERE give; nothing is
     dropped.  Returns (y, picks_here, experts_hit, products, rows).
 
@@ -115,8 +115,11 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     the weights of the experts whose ids `experts_held` lists (a tuple, in
     the leaves' order), gated form `(act(x Wg) * (x Wu)) Wd` with silu.
     Every token picks its `top_k` experts among all E by the sigmoid of the
-    router's score (float32) and weighs them by it over the sum of the k
-    (the one form a configuration and a reference ask for so far).  Picks
+    router's score (float32) and weighs them by it over the sum of the k.
+    `select_bias` (E,), float32: added to the scores for the CHOICE alone
+    (`noaux_tc`'s `e_score_correction_bias`); the weights stay the scores
+    without it.  `scale`: every weight times this (`routed_scaling_factor`).
+    With neither the trace is the one it was.  Picks
     that fall on an expert held elsewhere add nothing here (that chip adds
     them; on one chip the layer runs without its exchange), and nothing as
     wide as a row of `x` is made for them: a stable sort of the T x top_k
@@ -141,8 +144,16 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     n_held = len(experts_held)
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)        # (T, K)
+    scores = jax.nn.sigmoid(logits)
+    if select_bias is None:
+        top, idx = jax.lax.top_k(scores, top_k)                    # (T, K)
+    else:
+        _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
+                               top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     share = (top / jnp.sum(top, axis=-1, keepdims=True)).reshape(-1)
+    if scale is not None:
+        share = share * scale
     # expert id -> its place among the held leaves, n_held = held elsewhere
     lut = [n_held] * n_experts
     for place, e in enumerate(experts_held):

@@ -70,11 +70,15 @@ class HeldExperts(Layer):
     grouped products made and the rows they went over) are what a serving
     engine's spans and counters report.  Expert weights are created in
     `dtype`; the router stays float32 (its scores pick the experts).
+    `selection_bias=True` adds the float32 leaf `e_score_correction_bias`
+    (num_experts,), zeros: it moves which experts a token picks and not
+    their weights; `scale` multiplies every weight.
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  top_k: int, experts_held=None, dtype=None,
-                 std: float = 0.02):
+                 std: float = 0.02, selection_bias: bool = False,
+                 scale: float = None):
         super().__init__()
         self.experts_held = tuple(range(num_experts) if experts_held is None
                                   else (int(e) for e in experts_held))
@@ -82,7 +86,7 @@ class HeldExperts(Layer):
                 or not all(0 <= e < num_experts for e in self.experts_held)):
             raise ValueError(f"experts_held {self.experts_held} must be "
                              f"distinct ids below {num_experts}")
-        self.num_experts, self.top_k = num_experts, top_k
+        self.num_experts, self.top_k, self.scale = num_experts, top_k, scale
         init, n = I.Normal(std=std), len(self.experts_held)
 
         def leaf(shape, dtype):
@@ -98,10 +102,15 @@ class HeldExperts(Layer):
         self.gate = leaf((n, d_model, d_hidden), dtype)
         self.up = leaf((n, d_model, d_hidden), dtype)
         self.down = leaf((n, d_hidden, d_model), dtype)
+        self.e_score_correction_bias = self.create_parameter(
+            (num_experts,), dtype="float32",
+            default_initializer=I.Constant(0.0)) if selection_bias else None
 
     def forward(self, x, valid=None):
         return moe_ffn_held(x, self.router, self.gate, self.up, self.down,
-                            self.experts_held, top_k=self.top_k, valid=valid)
+                            self.experts_held, top_k=self.top_k, valid=valid,
+                            select_bias=self.e_score_correction_bias,
+                            scale=self.scale)
 
     def extra_repr(self):
         return (f"num_experts={self.num_experts}, top_k={self.top_k}, "

@@ -112,24 +112,39 @@ prompt_len)` (the last position's logits alone) and the decode step
 hands `model.forward_decode(tokens, pools, pos, active)` ALL slots at
 once instead of vmapping a batch of one — a routed layer routes once a
 step and its experts see every slot's token in one grouped product;
-rows of empty slots are routed nowhere.  `gen_fixed_cache` may give
-layers different lengths: a leaf shorter than the pool is a window
-layer's RING, written at ``pos % rows``; the fixed view's `write_prompt`
-overwrites each leaf over its own length, and a bucket longer than the
-ring leaves the prompt's last ``rows`` positions in it.  Both programs
+rows of empty slots are routed nowhere.  **The cache protocol is a tuple
+of leaves a layer:** `gen_fixed_cache(B, rows)` returns, a layer, a tuple
+of arrays ``(B, rows', *rest)``, each with its own `rest` and dtype and
+one ``rows'`` a layer.  `(k, v)` of ``(…, heads, head_dim)`` is one case
+(GPT-2, Cohere); a latent-attention layer holds two leaves that are no
+pair, ``(…, 512)`` the normalised latent and ``(…, 64)`` the rotated key
+numbers of a row (`models.DeepseekV3ForCausalLM`; one leaf of 576 ran
+slower on the chip); a layer of one leaf or of three serves alike.  The fixed view, `build_pools`,
+`pool_bytes`, `_leaf_rows` and `_gauge_kv_rows` go over a layer's leaves
+without knowing their number or rank.  Layers may differ in length: a
+leaf shorter than the pool is a window layer's RING, written at ``pos %
+rows``; the fixed view's `write_prompt` overwrites each leaf over its own
+length, and a bucket longer than the ring leaves the prompt's last
+``rows`` positions in it.  Both programs
 return the model's int32 counts ``[picks on held experts, picks in all,
 held experts hit, grouped products made, rows those products went
 over]`` with the tokens (``out["counts"]``), summed over layers (and a
 decode call's steps) on the device: a prompt's routed layer walks the
 picks held here in chunks, so how many products it made is known only
-there.  They ride in the `serving_admit` / `serving_decode` spans' args
+there.  A model may count its cache behind them: ``[rows the call's
+requests hold, rows its attention went over]``.  They ride in the
+`serving_admit` / `serving_decode` spans' args
 (`routed_here`, `routed_all`, `experts_hit`, `expert_products`,
-`expert_rows`) and the counters `moe_routed_picks_total{where}`,
+`expert_rows`; `kv_rows_live`, `kv_rows_pool`) and the counters
+`moe_routed_picks_total{where}`,
 `moe_experts_hit_total` and `moe_expert_rows_total` (rows through the
 grouped products, to set against the picks held here and in all), and
-`serving_kv_rows{kind}` gauges the rows held.  ``kv="paged"``,
+`serving_kv_rows{kind}` gauges the rows held (`window`, `full`, or the
+model's own word: `latent`).  ``kv="paged"``,
 ``prefix_cache``, ``draft_model``, ``mesh``, ``lora``, `preempt_slot`
-and `restore_run` raise for such a model.
+and `restore_run` raise for such a model, naming the piece that is
+missing (the paged view, snapshots, transfer and the prefix cache still
+take equal `(k, v)` pairs).
 
 Greedy requests are bit-identical to a solo
 `generation.generate(decode_strategy='greedy_search')` run of the same
@@ -390,11 +405,14 @@ class ServingEngine:
         if self._batched:
             for given, what, missing in (
                     (kv == "paged", "kv='paged'",
-                     "the paged pool has one block table for every layer "
-                     "(kv_pool.py) and no ring of a window's rows"),
+                     "the paged view (kv_pool.PagedKVView) gathers and "
+                     "scatters `(k, v)` pairs through one block table for "
+                     "every layer: it has no ring of a window's rows and "
+                     "takes no layer of other leaves (a latent layer's)"),
                     (prefix_cache, "prefix_cache=True",
-                     "prefix reuse shares blocks of the paged pool, and a "
-                     "ring overwrites the rows a later request would share"),
+                     "prefix reuse shares blocks of the paged pool (which "
+                     "holds `(k, v)` pairs alone), and a ring overwrites "
+                     "the rows a later request would share"),
                     (draft_model is not None, "draft_model=",
                      "the verify program scores K+1 positions a slot "
                      "through `forward_fixed`, which this model has not"),
@@ -991,8 +1009,15 @@ class ServingEngine:
                                    training=False, method="forward_decode")
 
         self._apply_prefill, self._apply_decode = apply_prefill, apply_decode
-        # rows of each layer's leaf: shorter than the pool = a window's ring
-        self._leaf_rows = [int(k.shape[1]) for k, _ in self._pools]
+        # rows of each layer's leaves (one number a layer, however many
+        # leaves it has): shorter than the pool = a window's ring
+        self._leaf_rows = [int(layer[0].shape[1]) for layer in self._pools]
+        # what `serving_kv_rows{kind}` calls them: the model's word for its
+        # cache (`serving_cache_kind`, e.g. "latent"), else by length
+        named = getattr(model, "serving_cache_kind", None)
+        self._leaf_kinds = [
+            named or ("window" if rows < self._pool_len else "full")
+            for rows in self._leaf_rows]
         self._c_picks = _obs_m.counter(
             "moe_routed_picks_total",
             "picks of the routed layers (tokens x experts a token x "
@@ -1012,11 +1037,16 @@ class ServingEngine:
     def _count_routed(self, span_args: dict, counts):
         """A program's routed counts [here, all, experts hit, grouped
         products made, rows they went over] into its span's args and the
-        counters."""
-        here, total, hit, products, rows = (int(c) for c in counts)
+        counters; a model that counts its cache too (two more: rows the
+        call's requests hold, rows its attention went over) gets those
+        into the args as `kv_rows_live` and `kv_rows_pool`."""
+        here, total, hit, products, rows = (int(c) for c in counts[:5])
         span_args.update(routed_here=here, routed_all=total,
                          experts_hit=hit, expert_products=products,
                          expert_rows=rows)
+        if len(counts) > 5:
+            span_args.update(kv_rows_live=int(counts[5]),
+                             kv_rows_pool=int(counts[6]))
         self._c_picks.labels(where="here").inc(here)
         self._c_picks.labels(where="elsewhere").inc(total - here)
         self._c_hit.inc(hit)
@@ -1027,16 +1057,17 @@ class ServingEngine:
             raise InvalidArgumentError(
                 f"{what} is not built for a model that decodes the whole "
                 f"batch ({type(self.model).__name__}): a snapshot takes "
-                "rows [0, pos) of every leaf, and a window layer's ring "
-                "holds positions pos - rows .. pos at pos % rows")
+                "rows [0, pos) of every layer's `(k, v)` pair; a window "
+                "layer's ring holds positions pos - rows .. pos at pos % "
+                "rows, and a latent layer's leaves (512 and 64 wide) are "
+                "no `(k, v)` pair")
 
     def _gauge_kv_rows(self):
         """Rows the running requests hold as the decode call read them,
         summed over layers: a ring holds at most its own length."""
-        held = {"window": 0, "full": 0}
+        held = dict.fromkeys(self._leaf_kinds, 0)
         for run in self._slots.values():
-            for rows in self._leaf_rows:
-                kind = "window" if rows < self._pool_len else "full"
+            for rows, kind in zip(self._leaf_rows, self._leaf_kinds):
                 held[kind] += min(run.pos, rows)
         for kind, n in held.items():
             self._g_kv_rows.labels(kind=kind).set(n)

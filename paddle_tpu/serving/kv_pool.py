@@ -110,7 +110,7 @@ class PagedKVPool:
     """Host-side block allocator over a device-resident block pool.
 
     ``build_pools(model, ...)`` constructs the per-layer device pools —
-    each KV leaf of ``model.gen_fixed_cache(1, block_size)`` becomes a
+    each leaf of a layer's tuple in ``model.gen_fixed_cache(1, 1)`` becomes a
     ``(num_blocks, block_size, *leaf.shape[2:])`` zero pool — and the
     allocator hands out block ids: ``alloc``/``ensure`` grow a slot's
     table to cover a row count, ``free`` recycles the slot's blocks,
@@ -426,11 +426,13 @@ class PagedKVPool:
     # -- device pool construction -------------------------------------------
     @staticmethod
     def leaf_shapes(model, dtype=None):
-        """Per-layer (k, v) leaf shapes/dtypes from one block's worth of
-        the model's own fixed-cache protocol."""
+        """Per layer, a tuple of (shape past the rows, dtype), one a leaf of
+        the model's own fixed-cache protocol: `(k, v)` pairs of 4-D leaves,
+        or whatever a layer holds (a latent layer: two 3-D leaves of
+        different widths)."""
         template = model.gen_fixed_cache(1, 1, dtype)
-        return [((tuple(k.shape[2:]), k.dtype), (tuple(v.shape[2:]), v.dtype))
-                for k, v in template]
+        return [tuple((tuple(leaf.shape[2:]), leaf.dtype) for leaf in layer)
+                for layer in template]
 
     def build_pools(self, model, dtype=None, put=None):
         """The device-resident block pool: for each model KV leaf of
@@ -438,25 +440,29 @@ class PagedKVPool:
         (num_blocks, block_size, *rest).  `put` (optional) places each
         leaf — the mesh engine passes a heads-sharded device_put."""
         pools = []
-        for (ks, kdt), (vs, vdt) in self.leaf_shapes(model, dtype):
-            k = jnp.zeros((self.num_blocks, self.block_size) + ks, kdt)
-            v = jnp.zeros((self.num_blocks, self.block_size) + vs, vdt)
+        for layer in self.leaf_shapes(model, dtype):
+            leaves = [jnp.zeros((self.num_blocks, self.block_size) + rest, dt)
+                      for rest, dt in layer]
             if put is not None:
-                k, v = put(k), put(v)
-            pools.append((k, v))
+                leaves = [put(leaf) for leaf in leaves]
+            pools.append(tuple(leaves))
         return pools
 
     def pool_bytes(self, pools) -> int:
-        return int(sum(k.size * k.dtype.itemsize + v.size * v.dtype.itemsize
-                       for k, v in pools))
+        return int(sum(leaf.size * leaf.dtype.itemsize
+                       for layer in pools for leaf in layer))
 
 
 class FixedKVView:
-    """The fixed layout as the engine's programs see it: every leaf holds
-    one row of cache per slot, `pool_len` long or, for a window layer, a
-    ring written at ``pos % rows``.  The pool IS the contiguous view the
-    model runs against, so `open` and `publish` are the identity; a
-    prompt's rows go to its slot's row."""
+    """The fixed layout as the engine's programs see it.  A layer's cache
+    is a TUPLE OF LEAVES, each `(slots, rows, *rest)` with its own `rest`
+    and dtype and one `rows` a layer: `(k, v)` of `(…, heads, head_dim)` is
+    one case, a latent layer's `(…, 512)` beside `(…, 64)` another.
+    Every leaf holds one row of cache per slot, `pool_len` long or, for a
+    window layer, a ring written at ``pos % rows``.  The pool IS the
+    contiguous view the model runs against, so `open` and `publish` are the
+    identity; a prompt's rows go to its slot's row.  Nothing here knows how
+    many leaves a layer has or their rank."""
 
     # -- host side: what a call's `inputs` say of the layout -----------------
     def prompt_inputs(self, slot: Optional[int] = None) -> Dict:
@@ -481,33 +487,36 @@ class FixedKVView:
     def write_prompt(self, pools, kv, inputs):
         slot, prompt_len = inputs["slot"], inputs["prompt_len"]
         new_pools = []
-        for (kp, vp), (kc, vc) in zip(pools, kv):
+        for layer_pool, layer_kv in zip(pools, kv):
             # full-range overwrite: bucket KV + zeros to the leaf's own
             # length (pool_len, or a window layer's ring), so a
             # recycled slot keeps no stale KV from its previous tenant
-            rows = kp.shape[1]
-            if kc.shape[1] > rows:
+            rows = layer_pool[0].shape[1]
+            if layer_kv[0].shape[1] > rows:
                 # a bucket longer than the ring leaves the prompt's
                 # last `rows` positions in it: row r holds the one
                 # position p in [plen - rows, plen) with p % rows == r
                 first = prompt_len - rows
                 p = first + (jnp.arange(rows) - first) % rows
-                held = (p >= 0)[None, :, None, None]
+                held = {}       # which rows hold a position, by rank
+                for c in layer_kv:
+                    if c.ndim not in held:
+                        held[c.ndim] = (p >= 0)[
+                            (None, slice(None)) + (None,) * (c.ndim - 2)]
                 at = jnp.maximum(p, 0)
-                krow = jnp.where(held, jnp.take(kc, at, axis=1),
-                                 0).astype(kp.dtype)
-                vrow = jnp.where(held, jnp.take(vc, at, axis=1),
-                                 0).astype(vp.dtype)
+                row = [jnp.where(held[c.ndim], jnp.take(c, at, axis=1),
+                                 0).astype(leaf.dtype)
+                       for leaf, c in zip(layer_pool, layer_kv)]
             else:
-                krow = jnp.zeros((1, rows) + kp.shape[2:], kp.dtype)
-                vrow = jnp.zeros((1, rows) + vp.shape[2:], vp.dtype)
-                krow = jax.lax.dynamic_update_slice(
-                    krow, kc.astype(kp.dtype), (0, 0, 0, 0))
-                vrow = jax.lax.dynamic_update_slice(
-                    vrow, vc.astype(vp.dtype), (0, 0, 0, 0))
-            new_pools.append((
-                jax.lax.dynamic_update_slice(kp, krow, (slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(vp, vrow, (slot, 0, 0, 0))))
+                row = [jnp.zeros((1, rows) + leaf.shape[2:], leaf.dtype)
+                       for leaf in layer_pool]
+                row = [jax.lax.dynamic_update_slice(
+                    r, c.astype(r.dtype), (0,) * r.ndim)
+                    for r, c in zip(row, layer_kv)]
+            new_pools.append(tuple(
+                jax.lax.dynamic_update_slice(
+                    leaf, r, (slot,) + (0,) * (leaf.ndim - 1))
+                for leaf, r in zip(layer_pool, row)))
         return new_pools
 
 

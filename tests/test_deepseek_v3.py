@@ -1,0 +1,369 @@
+"""The DeepSeek-V3-family decoder (`models/deepseek_v3.py`: latent attention
+expanded for a prompt and absorbed for a decode step, a dense leading layer,
+`noaux_tc`-routed experts beside a summed shared MLP) and the serving
+engine's cache of two unequal leaves a layer, against the benchmark's plain reference
+(`benchmark/reference/deepseek_v3.py`), at a tiny size on the CPU in float32
+with seeded weights.
+
+No share test: every expert of a layer is held here (`experts_held` is all of
+them), so there are no parts held elsewhere to add up.
+
+Tolerances, each with its reason:
+  * 2e-5 on logits between the float32 program and the float32 reference:
+    the same products in another order (logits are of size 1; float32 sums
+    over 64 to 128 terms differ by a few 1e-6).  The absorbed step multiplies
+    `kv_b_proj` into the query before the rows instead of into the rows, a
+    re-association of the same float32 products, inside the same 2e-5.
+  * a bfloat16 program reads 1e-2 or more on the same comparison (asserted
+    above 50 x the tolerance): computing below the stated precision fails.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import models, observability as obs
+from paddle_tpu.core.errors import InvalidArgumentError
+from paddle_tpu.serving import ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import weights as W  # noqa: E402
+from benchmark.arch import deepseek_v3 as A  # noqa: E402
+
+pytestmark = [pytest.mark.serving]
+
+REF = A.reference
+TOL = 2e-5
+TINY = dict(vocab_size=128, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, num_hidden_layers=3,
+            first_k_dense_replace=1, num_attention_heads=4,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+            kv_lora_rank=24, n_routed_experts=8, num_experts_per_tok=3,
+            n_shared_experts=2, routed_scaling_factor=2.446,
+            rope_theta=50000, rms_norm_eps=1e-5)
+MAX_LEN = 40
+NAME, T0, DUR, TID, ID, PARENT, ARGS = range(7)
+
+
+def dims(**over):
+    return A.dims(dict(TINY, max_position_embeddings=MAX_LEN,
+                       initializer_range=0.125,
+                       e_score_correction_bias_std=0.2,
+                       kv_a_proj_with_mqa_std=0.5, n_group=1,
+                       topk_group=1, **over))
+
+
+def build(dtype="float32", seed=2147483659):
+    d = dims()
+    model = models.DeepseekV3ForCausalLM(models.DeepseekV3Config(
+        **TINY, dtype=dtype))
+    model.eval()
+    top = dict(A.make_leaves(W.make, d, seed, -1))
+    layers = [dict(A.make_leaves(W.make, d, seed, i)) for i in range(d["L"])]
+    state = model.state_dict()
+    for i, leaves in enumerate([top] + layers):
+        for name, leaf in leaves.items():
+            p = state[A.program_name(name, i - 1)]
+            assert tuple(p.shape) == tuple(leaf.shape), name
+            p._set_data(leaf.astype(p._data.dtype))
+    return model, d, top, layers
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return build()
+
+
+def reference_logits(tiny, d=None):
+    """The reference's logits over MAX_LEN positions, compiled once a set of
+    sizes: under the causal mask what follows a position does not move it."""
+    _, d0, top, layers = tiny
+    d = d or d0
+    fn = jax.jit(lambda ids: REF.logits(top, layers, ids, d))
+
+    def padded(ids):
+        row = np.zeros((MAX_LEN,), np.int32)
+        row[:len(ids)] = np.asarray(ids, np.int32)
+        return np.asarray(fn(jnp.asarray(row)))[:len(ids)]
+
+    return padded
+
+
+@pytest.fixture(scope="module")
+def ref_logits(tiny):
+    return reference_logits(tiny)
+
+
+@pytest.fixture(scope="module")
+def eng(tiny):
+    e = ServingEngine(tiny[0], max_slots=3, max_len=MAX_LEN,
+                      prefill_buckets=(4, 16), decode_chunk=4,
+                      max_queue_depth=16)
+    e.warmup()
+    yield e
+    e.close()
+
+
+IDS = np.random.RandomState(0).randint(0, 128, (24,)).astype(np.int32)
+
+
+# ------------------------------------------------------------------ model
+
+def test_full_forward_logits_agree_with_the_reference(tiny, ref_logits):
+    model, d, _, _ = tiny
+    assert d["kinds"] == ["dense", "moe", "moe"]
+    assert [blk.routed for blk in model.layers] == [False, True, True]
+    got = np.asarray(model(paddle.to_tensor(IDS[None])).numpy())[0]
+    assert got.dtype == np.float32
+    assert np.max(np.abs(got - ref_logits(IDS))) < TOL
+    names = set(model.state_dict())
+    # leaves as the source names them
+    assert {"embed_tokens", "norm", "lm_head",
+            "layers.0.self_attn.kv_a_proj_with_mqa",
+            "layers.0.self_attn.kv_a_layernorm",
+            "layers.0.self_attn.kv_b_proj", "layers.0.mlp.down_proj",
+            "layers.1.mlp.shared_experts.gate_proj",
+            "layers.1.mlp.experts.e_score_correction_bias",
+            "layers.2.post_attention_layernorm"} <= names
+    assert "layers.0.mlp.experts.router" not in names
+
+
+# what the reference reads when one detail of the layer is left out: each
+# must move its logits by far more than the tolerance, so that the agreement
+# above shows the program has the detail
+DETAILS = {
+    "selection_bias": lambda d, layers: (d, [
+        dict(l, bias=jnp.zeros_like(l["bias"])) if "bias" in l else l
+        for l in layers]),
+    "routed_scaling_factor": lambda d, layers: (dict(d, scale=1.0), layers),
+    "shared_expert_summed": lambda d, layers: (d, [
+        dict(l, sd=l["sd"] / 2) if "sd" in l else l for l in layers]),
+    "dense_first_layer": lambda d, layers: (d, [
+        dict(l, wd=jnp.zeros_like(l["wd"])) if "wd" in l else l
+        for l in layers]),
+    "kv_a_layernorm": lambda d, layers: (d, [
+        dict(l, kva_g=3.0 * l["kva_g"]) for l in layers]),
+    "k_pe_rotated_once_for_all_heads": lambda d, layers: (
+        dict(d, theta=1e30), layers),
+}
+
+
+@pytest.mark.parametrize("detail", sorted(DETAILS))
+def test_each_detail_of_the_layer_moves_the_reference(tiny, ref_logits,
+                                                      detail):
+    _, d, top, layers = tiny
+    d2, layers2 = DETAILS[detail](d, layers)
+    moved = np.asarray(jax.jit(lambda ids: REF.logits(
+        top, layers2, ids, d2))(jnp.asarray(IDS)))
+    assert np.max(np.abs(moved - ref_logits(IDS))) > 100 * TOL
+
+
+def test_the_bias_moves_a_pick_and_not_its_weight():
+    """`F.moe_ffn_held` with a selection bias: the experts are the top k of
+    score + bias, their weights the scores WITHOUT it over their sum, times
+    the scale; `norm_topk_prob`: a token's weights sum to the scale."""
+    from paddle_tpu.nn.functional import moe
+    rng = np.random.RandomState(3)
+    t, h, i, e, k = 6, 16, 8, 5, 2
+    x = jnp.asarray(rng.randn(t, h), jnp.float32)
+    router = jnp.asarray(rng.randn(h, e), jnp.float32)
+    bias = jnp.asarray([0.0, 5.0, 0.0, 0.0, -5.0], jnp.float32)
+    # experts whose output is their own id in every place: y reads the
+    # weights straight off
+    ones = jnp.ones((e, h, i), jnp.float32)
+    down = jnp.ones((e, i, h), jnp.float32) * jnp.arange(
+        1, e + 1, dtype=jnp.float32)[:, None, None]
+    gate = ones * 1e3                   # silu(1e3 * sum x) ~ 1e3 * sum x
+    held = tuple(range(e))
+
+    def weights_of(**kw):
+        """(T, E) weights read back from the layer's output."""
+        out = []
+        for only in range(e):
+            mask = jnp.zeros((e, 1, 1)).at[only].set(1.0)
+            y = moe.moe_ffn_held.raw(jnp.abs(x), router, gate * mask + 1e-9,
+                                     ones * mask, down * mask, held, k,
+                                     **kw)[0]
+            u = jnp.abs(x).sum(-1)
+            out.append(np.asarray(y[:, 0]) / np.asarray(
+                1e3 * u * u * i * (only + 1)))
+        return np.stack(out, axis=1)
+
+    sa = np.asarray(jax.nn.sigmoid(jnp.abs(x) @ router))
+    plain = weights_of()
+    biased = weights_of(select_bias=bias, scale=2.5)
+    for row in range(t):
+        want = np.argsort(-(sa[row] + np.asarray(bias)))[:k]
+        assert set(np.nonzero(biased[row] > 1e-6)[0]) == set(want)
+        assert 1 in want and 4 not in want          # the bias chose
+        np.testing.assert_allclose(
+            biased[row][want], 2.5 * sa[row][want] / sa[row][want].sum(),
+            rtol=2e-3)
+        np.testing.assert_allclose(biased[row].sum(), 2.5, rtol=2e-3)
+        np.testing.assert_allclose(plain[row].sum(), 1.0, rtol=2e-3)
+
+
+def test_with_neither_bias_nor_scale_the_routed_call_traces_as_before():
+    """`command-a-plus-1of8`'s call passes neither: its jaxpr holds no
+    addition of a bias and no second multiplication of the shares."""
+    from paddle_tpu.nn.functional import moe
+    x = jnp.ones((4, 8), jnp.float32)
+    r = jnp.ones((8, 4), jnp.float32)
+    w = jnp.ones((2, 8, 4), jnp.float32)
+    wd = jnp.ones((2, 4, 8), jnp.float32)
+    plain = str(jax.make_jaxpr(lambda *a: moe.moe_ffn_held.raw(
+        *a, (0, 1), 2))(x, r, w, w, wd))
+    again = str(jax.make_jaxpr(lambda *a: moe.moe_ffn_held.raw(
+        *a, (0, 1), 2, select_bias=None, scale=None))(x, r, w, w, wd))
+    both = str(jax.make_jaxpr(lambda *a: moe.moe_ffn_held.raw(
+        *a[:5], (0, 1), 2, select_bias=a[5], scale=2.0))(
+            x, r, w, w, wd, jnp.zeros((4,))))
+    assert plain == again != both
+
+
+def test_absorbed_attention_equals_expanded(tiny):
+    """One layer's attention: a sequence through the expanded path, then the
+    same positions one at a time through the absorbed step against the rows
+    the expanded path would cache."""
+    model, d, _, _ = tiny
+    attn = model.layers[1].self_attn
+    rng = np.random.RandomState(5)
+    s = 12
+    h = jnp.asarray(rng.randn(s, d["H"]), jnp.float32)
+    want, rows = attn.forward_seq(h)
+    assert [r.shape for r in rows] == [(s, d["latent"]), (s, d["rope"])]
+    bufs = [jnp.zeros((2, MAX_LEN, r.shape[1]), jnp.float32) for r in rows]
+    for p in range(s):
+        # slot 0 walks the sequence; slot 1 stays at position 0
+        pos = jnp.asarray([p, 0], jnp.int32)
+        got, *bufs = attn.forward_decode(jnp.stack([h[p], h[0]]), *bufs,
+                                         pos)
+        assert np.max(np.abs(np.asarray(got[0] - want[p]))) < TOL
+        assert np.max(np.abs(np.asarray(got[1] - want[0]))) < TOL
+    for buf, r in zip(bufs, rows):
+        assert np.max(np.abs(np.asarray(buf[0, :s] - r))) < 1e-6
+        assert not np.any(np.asarray(buf[0, s:]))
+
+
+def test_prompt_attention_in_blocks_and_chunks_is_the_references(
+        tiny, monkeypatch):
+    """Off the chip the kernel refuses and `_attend_seq` takes the XLA form
+    it shares with the Cohere model, here with a value narrower than a key
+    and blocks and chunks that do not divide the length."""
+    from paddle_tpu.models import cohere_moe
+    monkeypatch.setattr(cohere_moe, "_QUERY_BLOCK", 8)
+    monkeypatch.setattr(cohere_moe, "_KEY_CHUNK", 5)
+    attn = tiny[0].layers[0].self_attn
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(21, 4, 24), jnp.float32)
+    k = jnp.asarray(rng.randn(21, 4, 24), jnp.float32)
+    v = jnp.asarray(rng.randn(21, 4, 12), jnp.float32)
+    got = attn._attend_seq(q, k, v).reshape(21, -1)
+    want = REF.attention(q, k, v, "float32")
+    assert np.max(np.abs(np.asarray(got - want))) < TOL
+
+
+def test_a_bfloat16_program_fails_the_float32_tolerance(ref_logits):
+    model = build(dtype="bfloat16")[0]
+    got = np.asarray(model(paddle.to_tensor(IDS[None])).numpy())[0]
+    assert np.max(np.abs(got - ref_logits(IDS))) > 50 * TOL
+
+
+def test_a_form_the_model_has_not_is_refused():
+    for key, value in (("q_lora_rank", 1536), ("n_group", 8),
+                       ("topk_method", "greedy"), ("norm_topk_prob", False)):
+        with pytest.raises(InvalidArgumentError, match=key):
+            models.DeepseekV3Config(**dict(TINY, **{key: value}))
+
+
+# ------------------------------------------------------------- the engine
+
+def test_prefill_then_decode_through_the_cache_agrees_at_every_position(
+        tiny, eng, ref_logits):
+    """Logits, not tokens: the prompt's last position from the prefill
+    program, every later one from the absorbed decode step over the cache
+    the engine holds, against the reference's full forward."""
+    model = tiny[0]
+    cache = model.gen_fixed_cache(2, MAX_LEN)
+    assert [[leaf.shape for leaf in layer] for layer in cache] == [
+        [(2, MAX_LEN, 24), (2, MAX_LEN, 8)]] * 3
+    served = {}
+    for prompt, n in ((IDS[:11], 9), (IDS[5:8], 14), (IDS[2:18], 6)):
+        served[tuple(prompt)] = eng.submit(list(prompt), n)
+    while eng.has_work():
+        eng.step()
+    for prompt, resp in served.items():
+        toks = list(resp.tokens(5))
+        want = ref_logits(list(prompt) + toks)
+        # the served tokens are the reference's own choices...
+        assert toks == list(np.argmax(want[len(prompt) - 1:-1], axis=-1))
+    # ...and the logits agree position by position: the model's two entries
+    # by hand over the same cache protocol
+    prompt = IDS[:11]
+    padded = np.zeros((1, 16), np.int32)
+    padded[0, :11] = prompt
+    logits, rows, counts = model.forward_prefill(
+        paddle.to_tensor(padded), paddle.to_tensor(np.int32(11)))
+    seq = list(prompt) + list(served[tuple(prompt)].tokens(5))
+    want = ref_logits(seq)
+    assert np.max(np.abs(np.asarray(logits)[0, 0] - want[10])) < TOL
+    # 11 tokens x 3 picks x 2 routed layers; the cache's two counts: the
+    # prompt's rows and the bucket's, a layer
+    counts = np.asarray(counts)
+    assert counts.shape == (7,) and counts[1] == 11 * 3 * 2
+    assert counts[0] == counts[1] and list(counts[5:]) == [11 * 3, 16 * 3]
+    cache = [tuple(jnp.zeros((1, MAX_LEN, r.shape[2]), jnp.float32).at[
+        :, :16].set(jnp.where(jnp.arange(16)[None, :, None] < 11, r, 0))
+        for r in layer) for layer in rows]
+    for p in range(11, len(seq)):
+        logits, cache, counts = model.forward_decode(
+            jnp.asarray([seq[p]]), cache, jnp.asarray([p], jnp.int32),
+            jnp.asarray([True]))
+        assert np.max(np.abs(np.asarray(logits)[0] - want[p])) < TOL
+    assert list(np.asarray(counts)[5:]) == [len(seq) * 3, MAX_LEN * 3]
+
+
+def test_the_engine_gauges_latent_rows_and_records_the_cache_counts(
+        tiny, eng):
+    obs.get_tracer().clear()
+    resp = eng.submit(list(IDS[:9]), 7)
+    while eng.has_work():
+        eng.step()
+    assert len(resp.tokens(5)) == 7
+    events = obs.get_tracer().events()
+    admit = [ev[ARGS] for ev in events if ev[NAME] == "serving_admit"][-1]
+    assert (admit["kv_rows_live"], admit["kv_rows_pool"]) == (9 * 3, 16 * 3)
+    decodes = [ev[ARGS] for ev in events if ev[NAME] == "serving_decode"]
+    assert decodes and all(
+        a["kv_rows_pool"] == 4 * 3 * 3 * MAX_LEN for a in decodes)
+    # the first call: rows 10, 11, 12, 13 seen by its four steps, 3 layers
+    assert decodes[0]["kv_rows_live"] == 3 * (10 + 11 + 12 + 13)
+    assert decodes[0]["routed_all"] == 4 * 3 * 2
+    # the last decode call read its one request's rows, three layers of them
+    rows = obs.metrics.get_registry().get("serving_kv_rows")
+    assert 3 * 9 < rows.value(kind="latent") <= 3 * 16
+    assert eng._leaf_kinds == ["latent"] * 3
+
+
+@pytest.mark.parametrize("what, kw", [
+    ("kv='paged'", dict(kv="paged")),
+    ("draft_model=", dict(draft_model=object())),
+    ("mesh=", dict(mesh=object())),
+    ("lora=", dict(lora=object()))])
+def test_what_is_not_built_for_a_batched_model_keeps_raising(tiny, what, kw):
+    with pytest.raises(InvalidArgumentError) as err:
+        ServingEngine(tiny[0], max_slots=2, max_len=MAX_LEN, **kw)
+    assert what.split("=")[0] in str(err.value)
+
+
+def test_snapshots_refuse_naming_what_is_no_pair(eng):
+    for call in (lambda: eng.preempt_slot(0), lambda: eng.restore_run(None)):
+        with pytest.raises(InvalidArgumentError, match="no `.k, v.` pair"):
+            call()

@@ -625,6 +625,49 @@ def test_legacy_profiler_and_stat_parity():
 
 
 @obsmark
+def test_a_batched_models_cache_counts_ride_in_its_spans_and_the_gauge():
+    """A model that counts its cache behind its routed counts (`[…, rows the
+    call's requests hold, rows its attention went over]`): the engine puts
+    them into `serving_admit` / `serving_decode` as `kv_rows_live` and
+    `kv_rows_pool`, and gauges `serving_kv_rows` under the model's own word
+    for its cache (`latent`).  No span is added for it."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.serving import ServingEngine
+    from test_serving import LeavesProtocolModel
+
+    paddle.seed(3)
+    m = LeavesProtocolModel()
+    m.eval()
+    eng = ServingEngine(m, max_slots=2, max_len=32, prefill_buckets=(8,),
+                        decode_chunk=2)
+    eng.warmup()
+    tracer = obs.get_tracer()
+    tracer.clear()
+    rs = [eng.submit(np.arange(n), max_new_tokens=5) for n in (6, 3)]
+    eng.run_until_drained(timeout=60)
+    assert all(len(r.tokens()) == 5 for r in rs)
+    args = {}
+    for ev in tracer.events():
+        if ev[6] and "kv_rows_live" in ev[6]:
+            args.setdefault(ev[0], []).append(ev[6])
+    assert set(args) == {"serving_admit", "serving_decode"}
+    layers = 2
+    assert [(a["kv_rows_live"], a["kv_rows_pool"]) for a in args[
+        "serving_admit"]] == [(6 * layers, 8 * layers), (3 * layers,
+                                                          8 * layers)]
+    first = args["serving_decode"][0]
+    # both slots, two steps: rows 7 + 4, then 8 + 5, a layer; the whole
+    # pool of 2 slots x 32 rows is what each step went over
+    assert first["active"] == 2
+    assert first["kv_rows_live"] == layers * (7 + 4 + 8 + 5)
+    assert first["kv_rows_pool"] == layers * 2 * 2 * 32
+    assert first["routed_all"] == 0
+    rows = obs.metrics.get_registry().get("serving_kv_rows")
+    assert 0 < rows.value(kind="latent") <= layers * (6 + 5 + 3 + 5)
+    eng.close()
+
+
+@obsmark
 @pytest.mark.slow
 def test_observability_probe_smoke():
     """probes/observability_probe.py --steps 3: machinery end-to-end in a

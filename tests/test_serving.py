@@ -61,6 +61,63 @@ class ProtocolModel(Layer):
         return logits, [(k, v)]
 
 
+class LeavesProtocolModel(Layer):
+    """Minimal `serving_batch_decode` protocol model whose cache is NOT
+    `(k, v)` pairs: layer 0 holds ONE leaf `(B, rows, 3)` (a latent layer's
+    form), layer 1 THREE of different rank and dtype, `(B, rows, 1, 2)`
+    float32, `(B, rows, 2)` bfloat16 and `(B, rows)` int32.  Logits are an
+    embedding of the current token; a prompt's rows are marked 1 and a
+    decode step's 2, so stale rows are directly visible in the pool.  Its
+    counts are the five routed ones (zeros) and the cache's two."""
+
+    serving_batch_decode = True
+    serving_cache_kind = "latent"
+    RESTS = (((3,),), ((1, 2), (2,), ()))
+
+    def __init__(self, vocab=24):
+        super().__init__()
+        self.emb = Embedding(vocab, vocab)
+
+    def _dtypes(self, dtype):
+        import jax.numpy as jnp
+        return ((dtype or jnp.float32,),
+                (dtype or jnp.float32, jnp.bfloat16, jnp.int32))
+
+    def gen_fixed_cache(self, batch_size, max_length, dtype=None):
+        import jax.numpy as jnp
+        return [tuple(jnp.zeros((batch_size, max_length) + rest, dt)
+                      for rest, dt in zip(rests, dts))
+                for rests, dts in zip(self.RESTS, self._dtypes(dtype))]
+
+    def _counts(self, live, went_over):
+        import jax.numpy as jnp
+        return jnp.concatenate([jnp.zeros((5,), jnp.int32), jnp.stack(
+            [live, went_over]).astype(jnp.int32) * len(self.RESTS)])
+
+    def forward_prefill(self, input_ids, prompt_len):
+        import jax.numpy as jnp
+        from paddle_tpu.core.tensor import unwrap
+        ids, plen = unwrap(input_ids), unwrap(prompt_len)
+        logits = unwrap(self.emb(input_ids)).astype(jnp.float32)
+        last = jnp.take(logits, plen - 1, axis=1)[:, None]
+        rows = [tuple(jnp.ones((1, ids.shape[1]) + rest, dt)
+                      for rest, dt in zip(rests, dts))
+                for rests, dts in zip(self.RESTS, self._dtypes(None))]
+        return last, rows, self._counts(plen, ids.shape[1])
+
+    def forward_decode(self, tokens, caches, pos, active):
+        import jax.numpy as jnp
+        from paddle_tpu.core.tensor import unwrap
+        pos, active = unwrap(pos), unwrap(active)
+        logits = unwrap(self.emb(tokens)).astype(jnp.float32)
+        slot = jnp.arange(pos.shape[0])
+        new = [tuple(unwrap(leaf).at[slot, pos].set(2) for leaf in layer)
+               for layer in caches]
+        b, rows = new[0][0].shape[:2]
+        return logits, new, self._counts(
+            jnp.sum(jnp.where(active, pos + 1, 0)), b * rows)
+
+
 def tiny_gpt():
     cfg = models.GPTConfig(vocab_size=13, hidden_size=16,
                            num_hidden_layers=2, num_attention_heads=2,
@@ -172,6 +229,105 @@ def test_prefill_overwrites_full_slot_range():
     k = np.asarray(eng._pools[0][0])
     assert np.all(k[0, :8] == 1), "prefill chunk written"
     assert np.all(k[0, 8:] == 0), "tail beyond the bucket must be scrubbed"
+
+
+def _leaves(shape_of, fill, layers):
+    """A cache in `layers`' form (a tuple of (rest, dtype) a layer), every
+    leaf `shape_of(rest)` full of `fill`."""
+    import jax.numpy as jnp
+    return [tuple(jnp.full(shape_of(rest), fill, dt) for rest, dt in layer)
+            for layer in layers]
+
+
+_F32, _BF16, _I32 = "float32", "bfloat16", "int32"
+LAYER_FORMS = {
+    "pair": ((((2, 4), _F32), ((2, 4), _F32)),),
+    "one_leaf": ((((6,), _BF16),),),
+    "three_leaves_of_three_ranks": ((((1, 2), _F32), ((5,), _BF16),
+                                     ((), _I32)),),
+    "mixed_layers": ((((6,), _BF16),), (((2, 4), _F32), ((2, 4), _F32))),
+}
+
+
+@pytest.mark.parametrize("form", sorted(LAYER_FORMS))
+@pytest.mark.parametrize("rows", [16, 5], ids=["pool_len", "ring_of_5"])
+def test_fixed_view_writes_a_prompt_into_layers_of_any_leaves(form, rows):
+    """`FixedKVView.write_prompt` over a layer's leaves whatever their
+    number, rank and dtype: the slot's row is overwritten over its whole
+    length (the bucket's rows, then zeros; a ring keeps the prompt's last
+    `rows` positions at p % rows), the other slots are left alone."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.kv_pool import FixedKVView
+    layers, bucket, plen, slot = LAYER_FORMS[form], 8, 7, 1
+    pools = _leaves(lambda rest: (3, rows) + rest, 9, layers)
+    # a prompt's row p holds p + 1 in every place
+    kv = [tuple((jnp.arange(1, bucket + 1).reshape(
+        (1, bucket) + (1,) * len(rest)) * jnp.ones((1, bucket) + rest)
+                 ).astype(dt) for rest, dt in layer) for layer in layers]
+    new = FixedKVView().write_prompt(pools, kv, {
+        "slot": jnp.int32(slot), "prompt_len": jnp.int32(plen)})
+    assert [len(layer) for layer in new] == [len(l) for l in layers]
+    for layer, old in zip(new, pools):
+        for leaf, was in zip(layer, old):
+            assert leaf.shape == was.shape and leaf.dtype == was.dtype
+            got = np.asarray(leaf.astype(jnp.float32))
+            assert np.all(got[[0, 2]] == 9), "other slots untouched"
+            flat = got[slot].reshape(rows, -1)
+            assert np.all(flat == flat[:, :1]), "a row is one number wide"
+            if rows >= bucket:
+                want = list(range(1, bucket + 1)) + [0] * (rows - bucket)
+            else:       # positions plen - rows .. plen - 1 at p % rows
+                want = [0] * rows
+                for p in range(plen - rows, plen):
+                    want[p % rows] = p + 1
+            assert flat[:, 0].tolist() == want
+
+
+def test_build_pools_and_pool_bytes_follow_the_models_leaves():
+    import jax.numpy as jnp
+    from paddle_tpu.serving.kv_pool import PagedKVPool
+    m = LeavesProtocolModel()
+    pool = PagedKVPool(num_blocks=6, block_size=4, pool_len=16)
+    assert PagedKVPool.leaf_shapes(m) == [
+        (((3,), jnp.float32),),
+        (((1, 2), jnp.float32), ((2,), jnp.bfloat16), ((), jnp.int32))]
+    pools = pool.build_pools(m, put=lambda leaf: leaf + 0)
+    assert [[leaf.shape for leaf in layer] for layer in pools] == [
+        [(6, 4, 3)], [(6, 4, 1, 2), (6, 4, 2), (6, 4)]]
+    assert all(isinstance(layer, tuple) for layer in pools)
+    assert pool.pool_bytes(pools) == 6 * 4 * (3 * 4 + 2 * 4 + 2 * 2 + 4)
+    # the pair the other models give is the two-leaf case
+    pair = pool.build_pools(ProtocolModel())
+    assert [leaf.shape for leaf in pair[0]] == [(6, 4, 1, 2)] * 2
+    assert pool.pool_bytes(pair) == 2 * 6 * 4 * 2 * 4
+
+
+def test_recycled_slot_keeps_no_stale_rows_in_any_leaf():
+    """The engine over a model of one-leaf and three-leaf layers: after a
+    long tenant, a short prompt's prefill leaves nothing of it beyond the
+    bucket, in every leaf of every layer."""
+    paddle.seed(3)
+    m = LeavesProtocolModel()
+    m.eval()
+    eng = ServingEngine(m, max_slots=1, max_len=32, prefill_buckets=(8,),
+                        decode_chunk=2)
+    assert eng._leaf_rows == [32, 32] and eng._leaf_kinds == ["latent"] * 2
+    r = eng.submit(np.arange(6), max_new_tokens=20)
+    eng.run_until_drained(timeout=60)
+    assert r.done() and len(r.tokens()) == 20
+    for layer in eng._pools:
+        for leaf in layer:
+            assert np.any(np.asarray(leaf, np.float32)[0, 8:] == 2), \
+                "sanity: the long tenant's decode rows lie beyond the bucket"
+    r2 = eng.submit(np.arange(4), max_new_tokens=1)
+    eng.run_until_drained(timeout=60)
+    assert r2.done()
+    for layer in eng._pools:
+        for leaf in layer:
+            got = np.asarray(leaf, np.float32)[0]
+            assert np.all(got[:8] == 1), "prefill chunk written"
+            assert not np.any(got[8:]), "tail beyond the bucket scrubbed"
+    eng.close()
 
 
 # ---------------------------------------------------------------------------
