@@ -12,7 +12,10 @@ expert-parallel deployment: no capacity and nothing dropped; it sorts the
 picks, walks those that fell on the experts held here in chunks through
 grouped products, and says what that cost (picks here, experts hit,
 grouped products made, rows they went over) as device values a serving
-program returns with its tokens.
+program returns with its tokens.  Where a few rows' picks cover the held
+experts anyway (a decode step of 48 slots over 64 experts), it takes every
+row through every held expert in one batched product a matrix instead
+(`_batched_form`): the same bytes, no groups.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.op import defop
+from ...observability.metrics import counter
 
 _ACTS = {
     "gelu": jax.nn.gelu,
@@ -103,6 +107,52 @@ GROUPED_PRODUCTS = 3
 # picks (tokens x top_k) fit makes one chunk, a longer one walks the picks
 # held here in chunks of this many (chosen on the chip: PERF.md, PR 31)
 _CHUNK_ROWS = 2048
+# where the held experts' products are ONE batched product a matrix over
+# every row and every held expert (`_batched_form`), both chosen on the chip
+# (`probes/moe_decode_forms.py`, ms a layer; PERF.md, PR 35).  The share of
+# the experts that the call's picks are expected to reach, at least: 16 of
+# 128 experts of 4096 x 4096 take the batched form in 2.19 ms whatever the
+# rows, the grouped one in 1.72 at an expected 0.63 (16 rows x 8 picks),
+# 1.87 at 0.78 and 2.71 at 0.87; 64 experts of 2048 x 1408 are faster
+# batched from 0.53 on (1.51 against 1.84, and 3.53 at 48 rows x 6 picks).
+_BATCHED_COVER = 0.9
+# The rows of the call, at most: up to here the batched product takes the
+# time of the experts' bytes (1.51 ms at 48 rows, 1.52 at 128, 1.54 at 192,
+# where 1.35 is the bytes' at 819 GB/s); at 256 rows it takes 1.95: rows x
+# experts of operations, not bytes, bound it from about 240 rows on.
+_BATCHED_ROWS = 192
+
+# which form of the held experts' products each traced call took, chosen
+# from the shapes at trace time like `flash_attention_form_total`
+_FORM_TAKEN = counter(
+    "moe_expert_form_total",
+    "moe_ffn_held calls traced, by the form of the experts' products the "
+    "shapes chose", ("form",))
+
+
+def _batched_form(t, top_k, n_experts):
+    """Whether `t` rows that each pick `top_k` of `n_experts` go through
+    every held expert in one batched product a matrix: where the picks are
+    expected to reach nearly every expert, so that the grouped product
+    would read them all too, and the rows are few enough that the experts'
+    bytes and not rows x experts of operations bound the product."""
+    cover = 1.0 - (1.0 - 1.0 / n_experts) ** (t * top_k)
+    return cover >= _BATCHED_COVER and t <= _BATCHED_ROWS
+
+
+def _batched_products(x, w_gate, w_up, w_down, weight):
+    """Every row of x (T, d_model) through every held expert, the expert
+    axis the batch of each product and the weights read where they lie;
+    `weight` (T, n_held) float32, a row's share at each held expert it
+    picked and 0 elsewhere, weighs the float32 results.  -> (T, d_model)
+    float32."""
+    xe = jnp.broadcast_to(x, (w_gate.shape[0],) + x.shape)
+    dot = lambda a, w: jax.lax.dot_general(  # noqa: E731
+        a, w.astype(a.dtype), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    with jax.named_scope("moe_expert_product"):
+        h = (jax.nn.silu(dot(xe, w_gate)) * dot(xe, w_up)).astype(x.dtype)
+        return jnp.sum(dot(h, w_down) * weight.T[:, :, None], axis=0)
 
 
 def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
@@ -134,6 +184,11 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     one that straddles two chunks is read twice.  Where T x top_k fits one
     chunk (a decode step) there is no loop.  `valid` (T,) bool: rows that
     are routed nowhere (an empty serving slot, a prompt's padding).
+    Where `_batched_form` says so (from T, top_k and E alone: a decode
+    step whose picks reach nearly every expert), there is no sort, gather
+    or grouped product: every row goes through every held expert in one
+    batched product a matrix, weighed in float32 by its share there (0
+    where it did not pick the expert); `products` and `rows` are then 0.
     int32 counts: picks_here, picks that fell on held experts;
     experts_hit, held experts with at least one token; products, grouped
     products made (`GROUPED_PRODUCTS` a chunk); rows, rows they went over
@@ -162,8 +217,17 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     if valid is not None:
         place = jnp.where(valid[:, None], place, n_held)
     place = place.reshape(-1)                                      # (T*K,)
-    sizes = jnp.sum(place[:, None] == jnp.arange(n_held)[None, :],
-                    axis=0, dtype=jnp.int32)                       # (n_held,)
+    held_at = place[:, None] == jnp.arange(n_held)[None, :]   # (T*K, n_held)
+    sizes = jnp.sum(held_at, axis=0, dtype=jnp.int32)              # (n_held,)
+    batched = _batched_form(t, top_k, n_experts)
+    _FORM_TAKEN.labels(form="batched" if batched else "grouped").inc()
+    if batched:
+        weight = jnp.sum(jnp.where(held_at, share[:, None], 0.0).reshape(
+            t, top_k, n_held), axis=1)
+        y = _batched_products(x, w_gate, w_up, w_down, weight)
+        return (y.astype(x.dtype), jnp.sum(sizes),
+                jnp.sum(sizes > 0, dtype=jnp.int32), jnp.int32(0),
+                jnp.int32(0))
     ends = jnp.cumsum(sizes)
     here = ends[-1]
     rows = min(_CHUNK_ROWS, t * top_k)

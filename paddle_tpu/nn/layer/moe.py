@@ -67,8 +67,9 @@ class HeldExperts(Layer):
 
     `y` is the part of the routed sum that the held experts give
     (`functional.moe_ffn_held`); the four int32 counts (the last two: the
-    grouped products made and the rows they went over) are what a serving
-    engine's spans and counters report.  Expert weights are created in
+    grouped products made and the rows they went over, both 0 where the
+    call took the batched form) are what a serving engine's spans and
+    counters report.  Expert weights are created in
     `dtype`; the router stays float32 (its scores pick the experts).
     `selection_bias=True` adds the float32 leaf `e_score_correction_bias`
     (num_experts,), zeros: it moves which experts a token picks and not

@@ -134,8 +134,10 @@ picks held here in chunks, so how many products it made is known only
 there.  A model may count its cache behind them: ``[rows the call's
 requests hold, rows its attention went over]``.  They ride in the
 `serving_admit` / `serving_decode` spans' args
-(`routed_here`, `routed_all`, `experts_hit`, `expert_products`,
-`expert_rows`; `kv_rows_live`, `kv_rows_pool`) and the counters
+(`routed_here`, `routed_all`, `experts_hit`; `expert_products` and
+`expert_rows` where the call made a grouped product, absent where its
+routed layers took the batched form; `kv_rows_live`, `kv_rows_pool`) and
+the counters
 `moe_routed_picks_total{where}`,
 `moe_experts_hit_total` and `moe_expert_rows_total` (rows through the
 grouped products, to set against the picks held here and in all), and
@@ -1039,11 +1041,16 @@ class ServingEngine:
         products made, rows they went over] into its span's args and the
         counters; a model that counts its cache too (two more: rows the
         call's requests hold, rows its attention went over) gets those
-        into the args as `kv_rows_live` and `kv_rows_pool`."""
+        into the args as `kv_rows_live` and `kv_rows_pool`.  A call that
+        made no grouped product (its routed layers took the batched form)
+        leaves `expert_products` and `expert_rows` out: a reader of the
+        device trace takes every span that carries them as a call whose
+        grouped products' events it must find."""
         here, total, hit, products, rows = (int(c) for c in counts[:5])
         span_args.update(routed_here=here, routed_all=total,
-                         experts_hit=hit, expert_products=products,
-                         expert_rows=rows)
+                         experts_hit=hit)
+        if products:
+            span_args.update(expert_products=products, expert_rows=rows)
         if len(counts) > 5:
             span_args.update(kv_rows_live=int(counts[5]),
                              kv_rows_pool=int(counts[6]))

@@ -215,6 +215,54 @@ def test_held_experts_grouped_product_keeps_its_name(chip_compile):
     assert len(calls) > GROUPED_PRODUCTS   # the group metadata: other names
 
 
+def _moonlight_routed_layer(chip_compile, tokens):
+    """(the compiled text of Moonlight's routed layer over `tokens` rows: 64
+    experts of 2048 x 1408 all held, 6 a token, the selection bias and the
+    scale, bfloat16; the pattern by which the cell's
+    `moe_expert_product_roofline` finds a grouped product's events)."""
+    from paddle_tpu.nn.functional.moe import moe_ffn_held
+    path = os.path.join(
+        os.path.dirname(__file__), os.pardir, "benchmark", "metrics",
+        "moe_expert_product_roofline.serve_flood_longgen.json")
+    with open(path) as f:
+        kernel = re.compile(json.load(f)["params"]["kernel"])
+    held, h, i = tuple(range(64)), 2048, 1408
+    text = chip_compile(
+        lambda x, r, g, u, d, valid, bias: moe_ffn_held.raw(
+            x, r, g, u, d, held, top_k=6, valid=valid, select_bias=bias,
+            scale=2.446),
+        ((tokens, h), jnp.bfloat16), ((h, 64), jnp.float32),
+        ((64, h, i), jnp.bfloat16), ((64, h, i), jnp.bfloat16),
+        ((64, i, h), jnp.bfloat16), ((tokens,), jnp.bool_),
+        ((64,), jnp.float32))
+    return text, kernel
+
+
+def test_a_decode_step_that_hits_every_expert_makes_no_grouped_product(
+        chip_compile):
+    """Moonlight's decode step (48 slots x 6 picks over 64 experts): the
+    batched form.  No instruction carries the grouped product's name, and
+    the three batched products read each expert stack where it lies (no
+    copy or transpose as large as one)."""
+    text, kernel = _moonlight_routed_layer(chip_compile, 48)
+    lines = [line.strip() for line in text.splitlines()]
+    assert not [line for line in lines if kernel.search(line)]
+    assert not [line for line in lines if re.search(
+        r"\[64,(2048,1408|1408,2048)\]\S* (copy|transpose)\(", line)]
+    assert sum("convolution(" in line and "moe_expert_product" in line
+               for line in lines) == 3
+
+
+def test_a_prompt_of_256_rows_keeps_the_grouped_products(chip_compile):
+    """The same layer over the smallest prompt bucket: 256 rows are more
+    than the batched form takes, so the three grouped products stand, under
+    the name the metric reads."""
+    from paddle_tpu.nn.functional.moe import GROUPED_PRODUCTS
+    text, kernel = _moonlight_routed_layer(chip_compile, 256)
+    assert sum(bool(kernel.search(line.strip()))
+               for line in text.splitlines()) == GROUPED_PRODUCTS
+
+
 def test_held_experts_walk_a_prompts_picks_in_chunks(chip_compile):
     """The same layer over a prompt's 2048 tokens (16,384 picks, an eighth
     of them held here): one `while` walks the held picks a chunk at a time,

@@ -105,13 +105,17 @@ def eng(tiny):
 
 # ------------------------------------------------------------------ model
 
-@pytest.mark.parametrize("chunk_rows", [None, 16],
-                         ids=["one_chunk", "chunks_of_16"])
+@pytest.mark.parametrize("chunk_rows", [0, None, 16],
+                         ids=["batched", "one_chunk", "chunks_of_16"])
 def test_logits_agree_with_the_reference(tiny, ref_logits, monkeypatch,
                                          chunk_rows):
-    """24 tokens x 2 picks a layer: one chunk as `_CHUNK_ROWS` stands, and
-    the walk in chunks (a trip count the device computes) with it patched
-    under the picks."""
+    """24 tokens x 2 picks a layer cover the 8 experts, so the rule takes
+    the batched form; with the grouped form in its place: one chunk as
+    `_CHUNK_ROWS` stands, and the walk in chunks (a trip count the device
+    computes) with it patched under the picks."""
+    assert moe._batched_form(24, 2, 8)
+    if chunk_rows != 0:
+        monkeypatch.setattr(moe, "_batched_form", lambda *a: False)
     if chunk_rows:
         monkeypatch.setattr(moe, "_CHUNK_ROWS", chunk_rows)
     model, d, top, layers = tiny
@@ -256,8 +260,10 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
         p._set_data(v)
     y, here, hit, products, rows = layer(paddle.to_tensor(x))
     assert (int(here.numpy()), int(hit.numpy())) == (t * 2, 2)
-    assert (int(products.numpy()), int(rows.numpy())) == (
-        moe.GROUPED_PRODUCTS, t * 2)
+    # 96 picks are expected to cover 6 experts: the batched form, whose
+    # products are not grouped ones
+    assert moe._batched_form(t, 2, 6)
+    assert (int(products.numpy()), int(rows.numpy())) == (0, 0)
     s = jax.nn.sigmoid(x @ router)
     w = s[:, [5, 3]] / jnp.sum(s[:, [5, 3]], -1, keepdims=True)
     want = sum(w[:, k:k + 1] * ((jax.nn.silu(x @ gate[k]) * (x @ up[k]))
@@ -327,8 +333,10 @@ def test_the_picks_held_here_in_chunks_are_a_loop_over_each_tokens_picks(
     """The compacted form (sort, walk the held prefix a chunk at a time,
     add rows to their tokens) against the plain loop, with what it says of
     itself: `GROUPED_PRODUCTS` a chunk walked and a chunk's rows a chunk,
-    so rows that are held elsewhere or padding cost no row."""
+    so rows that are held elsewhere or padding cost no row.  (The grouped
+    form in the rule's place: 8 tokens' picks already cover 6 experts.)"""
     t, held, chunk_rows, steer, n_valid = HELD_CASES[case]
+    monkeypatch.setattr(moe, "_batched_form", lambda *a: False)
     monkeypatch.setattr(moe, "_CHUNK_ROWS", chunk_rows)
     rng = np.random.RandomState(len(case))
     h, i, top_k = 16, 8, 2
@@ -460,6 +468,11 @@ def test_the_spans_a_step_are_unchanged_and_carry_the_routed_counts(served):
         # three experts held: each hit counts once a layer a step
         assert 0 <= args["experts_hit"] <= min(
             args["routed_here"], 3 * layers * chunk)
+        if args.get("bucket") == 16:
+            # 32 picks cover the 8 experts: the batched form, no grouped
+            # product and so neither count
+            assert not {"expert_products", "expert_rows"} & set(args)
+            continue
         # three grouped products a layer a step over all the picks of the
         # batch of 3 slots or of the prompt's bucket: one chunk each here
         steps = chunk if ev[NAME] == "serving_decode" else 1
@@ -492,5 +505,9 @@ def test_the_counters_add_up(eng):
     assert picks.value(where="here") - before[0] == here > 0
     assert picks.value(where="elsewhere") - before[1] == total - here > 0
     assert hit.value() - before[2] == sum(a["experts_hit"] for a in spans)
+    # a bucket of 16 takes the batched form: its spans carry no rows
+    grouped = [a for a in spans if "expert_rows" in a]
+    assert 0 < len(grouped) < len(spans)
     assert rows_through.value() - before[3] == sum(
-        a["expert_rows"] for a in spans) >= here
+        a["expert_rows"] for a in grouped) >= sum(
+            a["routed_here"] for a in grouped)

@@ -49,6 +49,7 @@ TINY = dict(vocab_size=128, hidden_size=64, intermediate_size=96,
             n_shared_experts=2, routed_scaling_factor=2.446,
             rope_theta=50000, rms_norm_eps=1e-5)
 MAX_LEN = 40
+ROWS_MOST = 192     # `nn/functional/moe.py::_BATCHED_ROWS`
 NAME, T0, DUR, TID, ID, PARENT, ARGS = range(7)
 
 
@@ -228,6 +229,95 @@ def test_with_neither_bias_nor_scale_the_routed_call_traces_as_before():
     assert plain == again != both
 
 
+# of 8 experts, 3 a token: those held, whether a bias and a scale are given,
+# valid rows of the 12 (None: all), an expert the bias keeps every token
+# from (None: none)
+FORM_CASES = {
+    "all_held_with_bias_and_scale": (tuple(range(8)), True, None, None),
+    "a_held_subset": ((6, 1, 4), False, None, None),
+    "rows_masked": (tuple(range(8)), True, 7, None),
+    "an_expert_no_token_picked": ((0, 2, 5, 7), True, None, 5),
+}
+
+
+@pytest.mark.parametrize("case", list(FORM_CASES))
+def test_the_batched_form_is_the_grouped_form(monkeypatch, case):
+    """Every row through every held expert, weighed by its share where it
+    picked the expert and by 0 elsewhere, against the picks sorted into
+    grouped products: the same `y` within the file's tolerance (the same
+    float32 products summed in another order), the same picks here and
+    experts hit, and no grouped product or row in the batched one."""
+    from paddle_tpu.nn.functional import moe
+    held, biased, n_valid, shunned = FORM_CASES[case]
+    rng = np.random.RandomState(len(case))
+    t, e, k, h, i = 12, 8, 3, 16, 8
+    x = jnp.asarray(rng.randn(t, h), jnp.float32)
+    router = jnp.asarray(rng.randn(h, e) * 0.5, jnp.float32)
+    gate, up, down = (jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+                      for shape in ((len(held), h, i), (len(held), h, i),
+                                    (len(held), i, h)))
+    kw = {}
+    if biased:
+        bias = rng.randn(e) * 0.2
+        if shunned is not None:
+            bias[shunned] = -5.0
+        kw = dict(select_bias=jnp.asarray(bias, jnp.float32), scale=2.446)
+    if n_valid is not None:
+        kw["valid"] = jnp.arange(t) < n_valid
+    got = {}
+    for form in ("grouped", "batched"):
+        monkeypatch.setattr(moe, "_batched_form",
+                            lambda *a, f=form: f == "batched")
+        y, *counts = moe.moe_ffn_held.raw(x, router, gate, up, down, held, k,
+                                          **kw)
+        got[form] = np.asarray(y), [int(c) for c in counts]
+    (y_g, (here_g, hit_g, products_g, rows_g)) = got["grouped"]
+    (y_b, (here_b, hit_b, products_b, rows_b)) = got["batched"]
+    assert np.max(np.abs(y_g)) > 0.1 and np.max(np.abs(y_b - y_g)) < TOL
+    assert (here_b, hit_b) == (here_g, hit_g) and here_g > 0
+    assert (products_g, rows_g) == (moe.GROUPED_PRODUCTS, t * k)
+    assert (products_b, rows_b) == (0, 0)
+    if len(held) == e:
+        assert here_g == (t if n_valid is None else n_valid) * k
+    else:                       # picks held elsewhere add nothing here
+        assert here_g < t * k
+    if n_valid is not None:
+        assert not np.any(y_b[n_valid:]) and not np.any(y_g[n_valid:])
+    if shunned is not None:
+        assert hit_g == len(held) - 1
+
+
+@pytest.mark.parametrize("t, top_k, n_experts, batched", [
+    (48, 6, 64, True),        # Moonlight's decode step
+    (16, 8, 128, False),      # command-a-plus's decode step
+    (256, 6, 64, False),      # Moonlight's smallest prompt bucket
+    (256, 8, 128, False),     # command-a-plus's smallest prompt bucket
+    (ROWS_MOST, 6, 64, True), (ROWS_MOST + 1, 6, 64, False),
+    # 64 experts are covered to 0.9 by 147 picks and not by 146
+    (49, 3, 64, True), (73, 2, 64, False)])
+def test_the_rule_that_chooses_the_form(t, top_k, n_experts, batched):
+    """`_batched_form` at the shapes of the two routed cells' programs and
+    at the edge of each of its two constants."""
+    from paddle_tpu.nn.functional import moe
+    assert (moe._BATCHED_COVER, moe._BATCHED_ROWS) == (0.9, ROWS_MOST)
+    assert moe._batched_form(t, top_k, n_experts) is batched
+
+
+def test_the_form_counts_itself_once_a_traced_call():
+    from paddle_tpu.nn.functional import moe
+    taken = obs.metrics.get_registry().get("moe_expert_form_total")
+    before = {f: taken.value(form=f) for f in ("grouped", "batched")}
+    x, r = jnp.ones((4, 8), jnp.float32), jnp.ones((8, 4), jnp.float32)
+    w, wd = jnp.ones((2, 8, 4), jnp.float32), jnp.ones((2, 4, 8), jnp.float32)
+    call = jax.jit(lambda x: moe.moe_ffn_held.raw(x, r, w, w, wd, (0, 1), 2))
+    assert not moe._batched_form(4, 2, 4) and moe._batched_form(8, 2, 4)
+    for _ in range(3):          # traced once, run three times
+        call(x)
+    call(jnp.ones((8, 8), jnp.float32))
+    assert {f: taken.value(form=f) - before[f] for f in before} == {
+        "grouped": 1, "batched": 1}
+
+
 def test_absorbed_attention_equals_expanded(tiny):
     """One layer's attention: a sequence through the expanded path, then the
     same positions one at a time through the absorbed step against the rows
@@ -350,6 +440,52 @@ def test_the_engine_gauges_latent_rows_and_records_the_cache_counts(
     rows = obs.metrics.get_registry().get("serving_kv_rows")
     assert 3 * 9 < rows.value(kind="latent") <= 3 * 16
     assert eng._leaf_kinds == ["latent"] * 3
+
+
+def test_a_call_that_made_no_grouped_product_leaves_its_counts_out(
+        tiny, eng):
+    """Twelve slots x 3 picks cover the 8 experts, as a prompt's 16 rows
+    do: that engine's programs take the batched form, its spans carry the
+    routed and the cache counts but neither `expert_products` nor
+    `expert_rows`, and `moe_expert_rows_total` stands still; the module's
+    engine of three slots makes grouped products and says so."""
+    from paddle_tpu.nn.functional import moe
+    assert moe._batched_form(12, 3, 8) and not moe._batched_form(3, 3, 8)
+    always = {"routed_here", "routed_all", "experts_hit", "kv_rows_live",
+              "kv_rows_pool"}
+    grouped_only = {"expert_products", "expert_rows"}
+    rows = obs.metrics.get_registry().get("moe_expert_rows_total")
+
+    def spans_of(engine):
+        obs.get_tracer().clear()
+        resp = engine.submit(list(IDS[:9]), 6)
+        while engine.has_work():
+            engine.step()
+        assert len(resp.tokens(5)) == 6
+        events = obs.get_tracer().events()
+        return [[set(ev[ARGS]) for ev in events if ev[NAME] == name]
+                for name in ("serving_admit", "serving_decode")]
+
+    wide = ServingEngine(tiny[0], max_slots=12, max_len=MAX_LEN,
+                         prefill_buckets=(16,), decode_chunk=4,
+                         max_queue_depth=4)
+    try:
+        wide.warmup()
+        before = rows.value()
+        admits, decodes = spans_of(wide)
+        assert rows.value() == before
+    finally:
+        wide.close()
+    assert admits and decodes
+    for args in admits + decodes:
+        assert always <= args and not grouped_only & args
+    before = rows.value()
+    admits, decodes = spans_of(eng)
+    assert rows.value() > before
+    # the prompt of 9 rows fills the bucket of 16 (batched); a decode step
+    # of three slots is grouped
+    assert all(always <= a and not grouped_only & a for a in admits)
+    assert decodes and all(always | grouped_only <= a for a in decodes)
 
 
 @pytest.mark.parametrize("what, kw", [
