@@ -1,6 +1,7 @@
 """Flagship model zoo: BERT / GPT-2 / ERNIE pretraining models for the
 BASELINE.md benchmark configs (#3 BERT DP, #4 ERNIE sharding, #5 GPT-2 PP),
-and the Cohere2-MoE decoder the serving benchmark runs."""
+and the decoders the serving benchmark runs (Cohere2-MoE, DeepSeek-V3's
+family, EvaByte)."""
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertPretrainingCriterion,
                    BertForSequenceClassification,
@@ -18,3 +19,5 @@ from .cohere_moe import (CohereMoEConfig, CohereMoEBlock,  # noqa: F401
                          CohereMoEForCausalLM)
 from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Block,  # noqa: F401
                           DeepseekV3ForCausalLM)
+from .evabyte import (EvaByteConfig, EvaByteBlock,  # noqa: F401
+                      EvaByteForCausalLM)

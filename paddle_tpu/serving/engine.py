@@ -114,39 +114,52 @@ once instead of vmapping a batch of one — a routed layer routes once a
 step and its experts see every slot's token in one grouped product;
 rows of empty slots are routed nowhere.  **The cache protocol is a tuple
 of leaves a layer:** `gen_fixed_cache(B, rows)` returns, a layer, a tuple
-of arrays ``(B, rows', *rest)``, each with its own `rest` and dtype and
-one ``rows'`` a layer.  `(k, v)` of ``(…, heads, head_dim)`` is one case
-(GPT-2, Cohere); a latent-attention layer holds two leaves that are no
-pair, ``(…, 512)`` the normalised latent and ``(…, 64)`` the rotated key
-numbers of a row (`models.DeepseekV3ForCausalLM`; one leaf of 576 ran
-slower on the chip); a layer of one leaf or of three serves alike.  The fixed view, `build_pools`,
+of arrays ``(B, rows', *rest)``, each with its OWN ``rows'``, `rest` and
+dtype.  `(k, v)` of ``(…, heads, head_dim)`` is one case (GPT-2, Cohere); a
+latent-attention layer holds two leaves that are no pair, ``(…, 512)`` the
+normalised latent and ``(…, 64)`` the rotated key numbers of a row
+(`models.DeepseekV3ForCausalLM`; one leaf of 576 ran slower on the chip); a
+layer of one leaf or of three serves alike.  The fixed view, `build_pools`,
 `pool_bytes`, `_leaf_rows` and `_gauge_kv_rows` go over a layer's leaves
-without knowing their number or rank.  Layers may differ in length: a
-leaf shorter than the pool is a window layer's RING, written at ``pos %
-rows``; the fixed view's `write_prompt` overwrites each leaf over its own
-length, and a bucket longer than the ring leaves the prompt's last
-``rows`` positions in it.  Both programs
-return the model's int32 counts ``[picks on held experts, picks in all,
-held experts hit, grouped products made, rows those products went
-over]`` with the tokens (``out["counts"]``), summed over layers (and a
-decode call's steps) on the device: a prompt's routed layer walks the
-picks held here in chunks, so how many products it made is known only
-there.  A model may count its cache behind them: ``[rows the call's
-requests hold, rows its attention went over]``.  They ride in the
-`serving_admit` / `serving_decode` spans' args
-(`routed_here`, `routed_all`, `experts_hit`; `expert_products` and
-`expert_rows` where the call made a grouped product, absent where its
-routed layers took the batched form; `kv_rows_live`, `kv_rows_pool`) and
-the counters
-`moe_routed_picks_total{where}`,
+without knowing their number or rank.  **Leaves may differ in length and
+in clock, within a layer too.**  A leaf as long as the pool holds a row a
+token at ``pos``.  A shorter one is its model's: a window layer's RING,
+written at ``pos % rows`` (Cohere's window layers), or a SUMMARY LEAF, one
+pooled row a chunk of tokens written at ``pos // chunk`` beside a ring of
+exact rows (`models.EvaByteForCausalLM`: four leaves a layer, ring keys and
+values of 2048 rows and summary keys and values of ``max_len / 16``).  Who
+writes where in a decode step is the model's `forward_decode`; the fixed
+view's `write_prompt` overwrites each leaf over ITS OWN length with what
+the prompt's forward hands back for it: a leaf no longer than the pool's is
+taken as it lies (the model has laid a ring out as a ring, by `prompt_len`),
+a token-indexed leaf longer than the pool's is a bucket longer than a ring
+and leaves the prompt's last ``rows`` positions in it.  Both programs
+return the model's int32 counts with the tokens (``out["counts"]``), summed
+over layers (and a decode call's steps) on the device.  **A model names
+them** (`serving_count_names`, e.g. ``("kv_rows_live", "kv_rows_pool",
+"kv_rows_summary")``) and the engine carries each into the `serving_admit`
+/ `serving_decode` span's args under its name, unread.  A model that names
+nothing is of the routed family and its counts are, by position, ``[picks
+on held experts, picks in all, held experts hit, grouped products made,
+rows those products went over]`` (a prompt's routed layer walks the picks
+held here in chunks, so how many products it made is known only on the
+device), which a model may follow with two of its cache: ``[rows the
+call's requests hold, rows its attention went over]``.  Those ride in the
+spans' args as `routed_here`, `routed_all`, `experts_hit`;
+`expert_products` and `expert_rows` where the call made a grouped product,
+absent where its routed layers took the batched form; `kv_rows_live`,
+`kv_rows_pool`; and feed the counters `moe_routed_picks_total{where}`,
 `moe_experts_hit_total` and `moe_expert_rows_total` (rows through the
-grouped products, to set against the picks held here and in all), and
-`serving_kv_rows{kind}` gauges the rows held (`window`, `full`, or the
-model's own word: `latent`).  ``kv="paged"``,
+grouped products, to set against the picks held here and in all).
+`serving_kv_rows{kind}` gauges the rows held: what a slot holds at a
+position is the model's to say (`serving_rows_held(pos)`: `window`, the
+ring's live rows, and `summary`), else a row a token a layer, a ring at
+most its length (`window`, `full`, or the model's own word,
+`serving_cache_kind`: `latent`).  ``kv="paged"``,
 ``prefix_cache``, ``draft_model``, ``mesh``, ``lora``, `preempt_slot`
 and `restore_run` raise for such a model, naming the piece that is
 missing (the paged view, snapshots, transfer and the prefix cache still
-take equal `(k, v)` pairs).
+take equal `(k, v)` pairs: no ring, no latent leaf, no summary leaf).
 
 Greedy requests are bit-identical to a solo
 `generation.generate(decode_strategy='greedy_search')` run of the same
@@ -401,8 +414,9 @@ class ServingEngine:
         self._dtype = dtype
         # a model that asks for it (`serving_batch_decode`) gets the WHOLE
         # batch of slots in one `forward_decode` call, a position a slot,
-        # and a pool whose leaves differ by layer (`gen_fixed_cache` gives a
-        # window layer a ring); what is not built for such a model refuses
+        # and a pool whose leaves differ by layer and within one
+        # (`gen_fixed_cache` gives a window layer a ring, a summary leaf a
+        # row a chunk); what is not built for such a model refuses
         self._batched = bool(getattr(model, "serving_batch_decode", False))
         if self._batched:
             for given, what, missing in (
@@ -410,11 +424,13 @@ class ServingEngine:
                      "the paged view (kv_pool.PagedKVView) gathers and "
                      "scatters `(k, v)` pairs through one block table for "
                      "every layer: it has no ring of a window's rows and "
-                     "takes no layer of other leaves (a latent layer's)"),
+                     "takes no layer of other leaves (a latent layer's, or "
+                     "a summary leaf's row a chunk)"),
                     (prefix_cache, "prefix_cache=True",
                      "prefix reuse shares blocks of the paged pool (which "
-                     "holds `(k, v)` pairs alone), and a ring overwrites "
-                     "the rows a later request would share"),
+                     "holds `(k, v)` pairs alone), a ring overwrites "
+                     "the rows a later request would share, and a summary "
+                     "leaf could be shared at a window's boundary alone"),
                     (draft_model is not None, "draft_model=",
                      "the verify program scores K+1 positions a slot "
                      "through `forward_fixed`, which this model has not"),
@@ -1011,15 +1027,23 @@ class ServingEngine:
                                    training=False, method="forward_decode")
 
         self._apply_prefill, self._apply_decode = apply_prefill, apply_decode
-        # rows of each layer's leaves (one number a layer, however many
-        # leaves it has): shorter than the pool = a window's ring
-        self._leaf_rows = [int(layer[0].shape[1]) for layer in self._pools]
+        # what the programs' int32 counts are: a model may name them
+        # (`serving_count_names`) and they ride into the spans' args under
+        # those names, unread; else they are the routed family's five, with
+        # two of the cache behind them (`_count_routed`)
+        self._count_names = getattr(model, "serving_count_names", None)
+        # and what a slot holds at a position, where the model says it
+        self._rows_held = getattr(model, "serving_rows_held", None)
+        # rows of each leaf of each layer: shorter than the pool = a
+        # window's ring, or rows on a clock of the model's own
+        self._leaf_rows = [tuple(int(leaf.shape[1]) for leaf in layer)
+                           for layer in self._pools]
         # what `serving_kv_rows{kind}` calls them: the model's word for its
         # cache (`serving_cache_kind`, e.g. "latent"), else by length
         named = getattr(model, "serving_cache_kind", None)
         self._leaf_kinds = [
-            named or ("window" if rows < self._pool_len else "full")
-            for rows in self._leaf_rows]
+            tuple(named or ("window" if rows < self._pool_len else "full")
+                  for rows in layer) for layer in self._leaf_rows]
         self._c_picks = _obs_m.counter(
             "moe_routed_picks_total",
             "picks of the routed layers (tokens x experts a token x "
@@ -1035,6 +1059,15 @@ class ServingEngine:
             "serving_kv_rows",
             "cache rows the running requests hold, summed over layers, by "
             "kind of layer", ("kind",))
+
+    def _count_model(self, span_args: dict, counts):
+        """A program's int32 counts into its span's args.  A model that
+        names them (`serving_count_names`) is carried unread: each count
+        under its name, nothing else known of it.  A model that does not
+        is of the routed family, whose counts feed counters too."""
+        if self._count_names is None:
+            return self._count_routed(span_args, counts)
+        span_args.update(zip(self._count_names, (int(c) for c in counts)))
 
     def _count_routed(self, span_args: dict, counts):
         """A program's routed counts [here, all, experts hit, grouped
@@ -1066,16 +1099,28 @@ class ServingEngine:
                 f"batch ({type(self.model).__name__}): a snapshot takes "
                 "rows [0, pos) of every layer's `(k, v)` pair; a window "
                 "layer's ring holds positions pos - rows .. pos at pos % "
-                "rows, and a latent layer's leaves (512 and 64 wide) are "
-                "no `(k, v)` pair")
+                "rows, a latent layer's leaves (512 and 64 wide) are "
+                "no `(k, v)` pair, and a summary leaf holds a pooled row a "
+                "chunk at pos // chunk, not a token's")
 
     def _gauge_kv_rows(self):
         """Rows the running requests hold as the decode call read them,
-        summed over layers: a ring holds at most its own length."""
-        held = dict.fromkeys(self._leaf_kinds, 0)
-        for run in self._slots.values():
-            for rows, kind in zip(self._leaf_rows, self._leaf_kinds):
-                held[kind] += min(run.pos, rows)
+        summed over layers, by kind.  What a slot holds at a position is
+        the model's to say (`serving_rows_held(pos) -> {kind: rows}`: a
+        ring wraps, a summary row stands for a chunk); a model that says
+        nothing holds a row a token in every layer, a ring at most its own
+        length (a layer's leaves of one length are the parts of one row)."""
+        if self._rows_held is not None:
+            held = {}
+            for run in self._slots.values():
+                for kind, n in self._rows_held(run.pos).items():
+                    held[kind] = held.get(kind, 0) + n
+        else:
+            held = {kind: 0 for layer in self._leaf_kinds for kind in layer}
+            for run in self._slots.values():
+                for rows, kinds in zip(self._leaf_rows, self._leaf_kinds):
+                    for n, kind in dict(zip(rows, kinds)).items():
+                        held[kind] += min(run.pos, n)
         for kind, n in held.items():
             self._g_kv_rows.labels(kind=kind).set(n)
 
@@ -1571,7 +1616,7 @@ class ServingEngine:
                 if ok:
                     tok = int(out["tok"])
                 if self._batched:   # the program has ended: no new wait
-                    self._count_routed(sp.args, np.asarray(out["counts"]))
+                    self._count_model(sp.args, np.asarray(out["counts"]))
             if not ok:
                 # the run is not in _slots yet — _release won't see the
                 # pin, drop it here
@@ -2181,7 +2226,7 @@ class ServingEngine:
                                     host["toks"].shape), "verify")
                 return
             if self._batched:
-                self._count_routed(sp.args, host["counts"])
+                self._count_model(sp.args, host["counts"])
                 self._gauge_kv_rows()
             # the chunk's (step, slot) burst, a slot a row
             self._deliver(host["toks"].T, host["logps"].T,

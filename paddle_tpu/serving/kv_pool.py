@@ -455,11 +455,15 @@ class PagedKVPool:
 
 class FixedKVView:
     """The fixed layout as the engine's programs see it.  A layer's cache
-    is a TUPLE OF LEAVES, each `(slots, rows, *rest)` with its own `rest`
-    and dtype and one `rows` a layer: `(k, v)` of `(…, heads, head_dim)` is
-    one case, a latent layer's `(…, 512)` beside `(…, 64)` another.
-    Every leaf holds one row of cache per slot, `pool_len` long or, for a
-    window layer, a ring written at ``pos % rows``.  The pool IS the
+    is a TUPLE OF LEAVES, each `(slots, rows, *rest)` with its own `rows`,
+    `rest` and dtype: `(k, v)` of `(…, heads, head_dim)` is one case, a
+    latent layer's `(…, 512)` beside `(…, 64)` another, a ring's `(k, v)`
+    beside a pair of pooled summaries, a row a chunk, a third.  A leaf
+    holds a slot's rows `pool_len` long, or as a window's ring written at
+    ``pos % rows``, or on whatever clock its model keeps (`write_prompt`
+    takes a prompt's leaf as the model hands it back: no longer than the
+    pool's and already in the pool's layout, or longer and token-indexed,
+    which is a ring's case).  The pool IS the
     contiguous view the model runs against, so `open` and `publish` are the
     identity; a prompt's rows go to its slot's row.  Nothing here knows how
     many leaves a layer has or their rank."""
@@ -488,36 +492,52 @@ class FixedKVView:
         slot, prompt_len = inputs["slot"], inputs["prompt_len"]
         new_pools = []
         for layer_pool, layer_kv in zip(pools, kv):
-            # full-range overwrite: bucket KV + zeros to the leaf's own
-            # length (pool_len, or a window layer's ring), so a
-            # recycled slot keeps no stale KV from its previous tenant
-            rows = layer_pool[0].shape[1]
-            if layer_kv[0].shape[1] > rows:
-                # a bucket longer than the ring leaves the prompt's
-                # last `rows` positions in it: row r holds the one
-                # position p in [plen - rows, plen) with p % rows == r
-                first = prompt_len - rows
-                p = first + (jnp.arange(rows) - first) % rows
-                held = {}       # which rows hold a position, by rank
-                for c in layer_kv:
-                    if c.ndim not in held:
-                        held[c.ndim] = (p >= 0)[
-                            (None, slice(None)) + (None,) * (c.ndim - 2)]
-                at = jnp.maximum(p, 0)
-                row = [jnp.where(held[c.ndim], jnp.take(c, at, axis=1),
-                                 0).astype(leaf.dtype)
-                       for leaf, c in zip(layer_pool, layer_kv)]
-            else:
-                row = [jnp.zeros((1, rows) + leaf.shape[2:], leaf.dtype)
-                       for leaf in layer_pool]
-                row = [jax.lax.dynamic_update_slice(
-                    r, c.astype(r.dtype), (0,) * r.ndim)
-                    for r, c in zip(row, layer_kv)]
-            new_pools.append(tuple(
-                jax.lax.dynamic_update_slice(
-                    leaf, r, (slot,) + (0,) * (leaf.ndim - 1))
-                for leaf, r in zip(layer_pool, row)))
+            # each leaf by its OWN length; a layer's leaves of one length
+            # (a `(k, v)` pair, a ring's pair beside a summary pair) are
+            # written together
+            by_rows = {}
+            for i, leaf in enumerate(layer_pool):
+                by_rows.setdefault(leaf.shape[1], []).append(i)
+            new = [None] * len(layer_pool)
+            for rows, which in by_rows.items():
+                written = self._write_rows(
+                    [layer_pool[i] for i in which],
+                    [layer_kv[i] for i in which], rows, slot, prompt_len)
+                for i, leaf in zip(which, written):
+                    new[i] = leaf
+            new_pools.append(tuple(new))
         return new_pools
+
+    @staticmethod
+    def _write_rows(leaves, kv, rows, slot, prompt_len):
+        """Pool leaves `rows` long with a prompt's rows in `slot`."""
+        # full-range overwrite: the prompt's rows + zeros to the leaf's
+        # own length (pool_len, a window layer's ring, a row a chunk), so
+        # a recycled slot keeps no stale KV from its previous tenant
+        if kv[0].shape[1] > rows:
+            # a bucket longer than the ring leaves the prompt's
+            # last `rows` positions in it: row r holds the one
+            # position p in [plen - rows, plen) with p % rows == r
+            first = prompt_len - rows
+            p = first + (jnp.arange(rows) - first) % rows
+            held = {}       # which rows hold a position, by rank
+            for c in kv:
+                if c.ndim not in held:
+                    held[c.ndim] = (p >= 0)[
+                        (None, slice(None)) + (None,) * (c.ndim - 2)]
+            at = jnp.maximum(p, 0)
+            row = [jnp.where(held[c.ndim], jnp.take(c, at, axis=1),
+                             0).astype(leaf.dtype)
+                   for leaf, c in zip(leaves, kv)]
+        else:
+            row = [jnp.zeros((1, rows) + leaf.shape[2:], leaf.dtype)
+                   for leaf in leaves]
+            row = [jax.lax.dynamic_update_slice(
+                r, c.astype(r.dtype), (0,) * r.ndim)
+                for r, c in zip(row, kv)]
+        return [jax.lax.dynamic_update_slice(
+            leaf, r, (slot,) + (0,) * (leaf.ndim - 1))
+            for leaf, r in zip(leaves, row)]
 
 
 class PagedKVView:

@@ -8,8 +8,10 @@ The only file that describes the chip.  The topology is described inside a
 module-scoped fixture, never at import: one process at a time may load the
 TPU's library, and every xdist worker imports every test file.
 """
+import contextlib
 import functools
 import json
+import math
 import os
 import re
 
@@ -42,24 +44,32 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def chip_compile(one_chip):
-    """compile(fn, *(shape, dtype)) for the described chip, with the
-    persistent compilation cache off: such an executable is written to it
-    but cannot be read back without a chip."""
+@contextlib.contextmanager
+def _no_compilation_cache():
+    """The persistent compilation cache off: an executable for a described
+    chip is written to it but cannot be read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
+
+@pytest.fixture()
+def chip_compile(one_chip):
+    """compile(fn, *(shape, dtype)) for the described chip, with the
+    persistent compilation cache off."""
     def compile_(fn, *avals):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape, dtype in avals]
         return jax.jit(fn).lower(*args).compile().as_text()
 
-    yield compile_
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+    with _no_compilation_cache():
+        yield compile_
 
 
 QKV = ((B, S, H, D), jnp.bfloat16)
@@ -292,3 +302,121 @@ def test_held_experts_walk_a_prompts_picks_in_chunks(chip_compile):
                for line in text.splitlines()) == GROUPED_PRODUCTS
     assert f"[{tokens * top_k},{h}]" not in text
     assert f"[{tokens},{top_k},{h}]" not in text
+
+
+@pytest.fixture(scope="module")
+def evabyte_programs(one_chip):
+    """compile(name) -> the compiled form of the serving engine's OWN program
+    (`decode`, `prefill_b32768`) over EvaByte at the PUBLISHED widths and the
+    cell's slots, for the described chip.  Nothing of that size exists here:
+    the engine is built over a model of 8 layers two numbers a head wide,
+    the model's config is then set to the published one (a forward reads
+    its sizes from it, and `functional_call` swaps the leaves), and the
+    programs are lowered on shapes alone: the benchmark's layout gives the
+    weights', the cell's mix the pool's."""
+    import importlib
+    import sys
+    from paddle_tpu import models
+    from paddle_tpu.serving import ServingEngine
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    arch = importlib.import_module("benchmark.arch.evabyte")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "evabyte-6.5b-8of32.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "flood_longctx_32k.json")) as f:
+        mix = json.load(f)["engine"]
+    kwargs = cfg["program"]["kwargs"]
+    model = models.EvaByteForCausalLM(models.EvaByteConfig(
+        **dict(kwargs, hidden_size=64, intermediate_size=64)))
+    model.eval()
+    eng = ServingEngine(model, max_slots=mix["max_slots"],
+                        max_len=mix["max_len"],
+                        prefill_buckets=(mix["prefill_buckets"][-1],),
+                        decode_chunk=mix["decode_chunk"])
+    vars(model.config).update(vars(models.EvaByteConfig(**kwargs)))
+    d = arch.dims(cfg)
+    shape = lambda s, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        tuple(s), dt, sharding=one_chip)
+    state = {arch.program_name(name, layer): shape(leaf[0], jnp.bfloat16)
+             for layer in range(-1, d["L"])
+             for name, leaf in (arch.top_layout(d) if layer < 0
+                                else arch.layer_layout(d)).items()}
+    assert set(state) == set(eng._state)
+    row = (mix["max_slots"], d["window"], d["heads"], d["hd"])
+    summary = row[:1] + (mix["max_len"] // d["chunk"],) + row[2:]
+    pools = [tuple(shape(s, jnp.bfloat16) for s in (row, row, summary,
+                                                    summary))] * d["L"]
+    programs = {name: (fn, inputs) for name, fn, inputs in eng._programs()}
+    cache_bytes = sum(math.prod(leaf.shape) * 2 for layer in pools
+                      for leaf in layer)
+    held = cache_bytes + arch.param_count(d) * 2
+
+    def compile_(name):
+        fn, inputs = programs[name]
+        with _no_compilation_cache():
+            return fn.lower(
+                {"model": state}, {"model": pools},
+                jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       inputs)).compile()
+
+    yield compile_, held
+    eng.close()
+
+
+def _metric_pattern(name, key):
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", name + ".json")
+    with open(path) as f:
+        return re.compile(json.load(f)["params"][key])
+
+
+HBM = 16 * 1024 ** 3 * 0.985        # what the compiler grants a program
+
+
+def test_evabyte_decode_program_compiles_and_fits(evabyte_programs,
+                                                  record_property):
+    """16 slots x (2048 ring + 2048 summary rows) x 8 layers beside 3.26 GB
+    of weights: the decode program's temporaries beside what the engine
+    holds stay inside the chip, the rings and summaries are read where they
+    lie (no copy or transpose as large as a leaf: a gather of a chunk's 16
+    rows once re-laid the whole ring, 268 MB a leaf a step), and the module
+    carries the name `decode_flood_longctx_roofline` finds it by."""
+    compile_, held = evabyte_programs
+    compiled = compile_("decode")
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert 11.8e9 < held < 11.9e9
+    assert held + mem.temp_size_in_bytes < HBM
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    assert _metric_pattern("decode_flood_longctx_roofline",
+                           "program").search(
+        text.split(",", 1)[0].replace("HloModule ", "") + "(")
+    assert not [line for line in text.splitlines() if re.search(
+        r"bf16\[16,2048,32,128\]\S* (copy|transpose)\(", line.strip())]
+
+
+def test_evabyte_longest_prefill_program_compiles_and_fits(
+        evabyte_programs, monkeypatch, record_property):
+    """The 32768 bucket: 16 windows a layer through the flash kernel's
+    forward, each ONE custom call named by its scope (what
+    `eva_prefill_attention_roofline.serve_flood_longctx` reads), 2048 + 128
+    w rows long; the program hands back leaves no longer than the pool's
+    and fits beside it."""
+    monkeypatch.setattr(fa, "_available", lambda: True)
+    compile_, held = evabyte_programs
+    compiled = compile_("prefill_b32768")
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert held + mem.temp_size_in_bytes < HBM
+    kernel = _metric_pattern(
+        "eva_prefill_attention_roofline.serve_flood_longctx", "kernel")
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 16 * 8 and all(kernel.search(c) for c in calls)
+    for w in range(16):
+        assert sum(f"bf16[1,{2048 + 128 * w},4096]" in c
+                   for c in calls) == 8

@@ -396,7 +396,7 @@ def served(eng):
 
 def test_prefill_then_decode_follow_the_reference_at_every_position(
         eng, served, ref_logits):
-    assert eng._leaf_rows == [WINDOW] * 3 + [MAX_LEN]
+    assert eng._leaf_rows == [(WINDOW, WINDOW)] * 3 + [(MAX_LEN, MAX_LEN)]
     assert eng.post_warmup_compiles() == 0
     assert eng.compile_counts()["total"] == eng.compile_counts()["bound"] == 3
     for prompt, resp, (_, out) in zip(served["prompts"], served["resps"],

@@ -439,7 +439,7 @@ def test_the_engine_gauges_latent_rows_and_records_the_cache_counts(
     # the last decode call read its one request's rows, three layers of them
     rows = obs.metrics.get_registry().get("serving_kv_rows")
     assert 3 * 9 < rows.value(kind="latent") <= 3 * 16
-    assert eng._leaf_kinds == ["latent"] * 3
+    assert eng._leaf_kinds == [("latent", "latent")] * 3
 
 
 def test_a_call_that_made_no_grouped_product_leaves_its_counts_out(

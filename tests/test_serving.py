@@ -283,6 +283,34 @@ def test_fixed_view_writes_a_prompt_into_layers_of_any_leaves(form, rows):
             assert flat[:, 0].tolist() == want
 
 
+def test_fixed_view_writes_each_leaf_of_a_layer_by_its_own_length():
+    """A layer of UNEQUAL leaves (`write_prompt` took the first leaf's length
+    for the whole layer): a ring pair of 5 rows handed a token-indexed
+    bucket of 8 (the prompt's last 5 positions at p % 5), a pair as long as
+    the pool, and a summary pair of 3 rows that the model hands back as it
+    lies, 2 rows long (a row a chunk: not a token's, not wrapped)."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.kv_pool import FixedKVView
+    bucket, plen, slot = 8, 7, 1
+    lengths = (5, 5, 16, 16, 3, 3)
+    pools = [tuple(jnp.full((3, rows, 2), 9.0) for rows in lengths)]
+    token_rows = (jnp.arange(1, bucket + 1, dtype=jnp.float32)[None, :, None]
+                  * jnp.ones((1, bucket, 2)))
+    chunk_rows = jnp.asarray([[[70.0, 70.0], [80.0, 80.0]]])
+    kv = [(token_rows, token_rows, token_rows, token_rows, chunk_rows,
+           chunk_rows)]
+    new = FixedKVView().write_prompt(pools, kv, {
+        "slot": jnp.int32(slot), "prompt_len": jnp.int32(plen)})
+    assert [leaf.shape for leaf in new[0]] == [(3, n, 2) for n in lengths]
+    want = {5: [6, 7, 3, 4, 5], 16: list(range(1, 9)) + [0] * 8,
+            3: [70, 80, 0]}
+    for leaf, rows in zip(new[0], lengths):
+        got = np.asarray(leaf)
+        assert np.all(got[[0, 2]] == 9), "other slots untouched"
+        assert got[slot, :, 0].tolist() == want[rows], rows
+        assert np.all(got[slot, :, 0] == got[slot, :, 1])
+
+
 def test_build_pools_and_pool_bytes_follow_the_models_leaves():
     import jax.numpy as jnp
     from paddle_tpu.serving.kv_pool import PagedKVPool
@@ -311,7 +339,9 @@ def test_recycled_slot_keeps_no_stale_rows_in_any_leaf():
     m.eval()
     eng = ServingEngine(m, max_slots=1, max_len=32, prefill_buckets=(8,),
                         decode_chunk=2)
-    assert eng._leaf_rows == [32, 32] and eng._leaf_kinds == ["latent"] * 2
+    # a number a leaf: one leaf in layer 0, three in layer 1
+    assert eng._leaf_rows == [(32,), (32, 32, 32)]
+    assert eng._leaf_kinds == [("latent",), ("latent",) * 3]
     r = eng.submit(np.arange(6), max_new_tokens=20)
     eng.run_until_drained(timeout=60)
     assert r.done() and len(r.tokens()) == 20
