@@ -20,7 +20,10 @@ Two attention paths over ONE set of weights:
 * a decode step (`forward_decode`) ABSORBS `kv_b_proj` into the query and
   the output: `q_lat[h] = q_nope[h] Wk[h]`, the score is `q_lat . c +
   q_pe . k_pe` over the cached rows as they lie, `o[h] = (p c) Wv[h]`.
-  `Wk` and `Wv` are views of the one leaf; no second copy is held.
+  `Wk` and `Wv` are views of the one leaf; no second copy is held.  On
+  the chip score, softmax and output are ONE kernel that walks each slot's
+  rows up to its own position (`ops/latent_decode_attention.py`); where
+  that refuses, two products over the whole pool behind a mask.
 
 Serving: `gen_fixed_cache` gives a layer TWO leaves that are no `(k, v)`
 pair: `(B, rows, kv_lora_rank)`, the normalised latent, and `(B, rows,
@@ -57,6 +60,7 @@ from ..nn.layer.container import LayerList
 from ..nn.layer.moe import HeldExperts
 from ..nn.layer_base import Layer
 from ..ops.flash_attention import flash_attention_grouped
+from ..ops.latent_decode_attention import mla_decode_attention
 from .cohere_moe import attend_in_chunks
 
 _FLASH_LANES = 256      # the kernel's head width that holds 192 and 128
@@ -181,6 +185,28 @@ class RoutedMLP(Layer):
                 + self.shared_experts.forward(h)), counts
 
 
+def masked_latent_attention(q_lat, q_pe, cbuf, pbuf, pos, scale, dtype):
+    """A decode step's absorbed attention as two products over the WHOLE
+    pool behind a mask: q_lat (B, heads, latent), q_pe (B, heads, rope)
+    float32, cbuf (B, rows, latent), pbuf (B, rows, rope), pos (B,) ->
+    `softmax(scale (q_lat . c + q_pe . k_pe)) c` over rows 0..pos, (B, heads,
+    latent) float32.  Operands in `dtype`, float32 sums and softmax.  What
+    `ops/latent_decode_attention.py` is held to, and what runs where that
+    kernel refuses."""
+    rows = cbuf.shape[1]
+    latent = cbuf.astype(dtype)
+    scores = (jnp.einsum("bhc,brc->bhr", q_lat.astype(dtype), latent,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhc,brc->bhr", q_pe.astype(dtype),
+                           pbuf.astype(dtype),
+                           preferred_element_type=jnp.float32)
+              ) * scale
+    keep = jnp.arange(rows)[None, None, :] <= pos[:, None, None]
+    probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), axis=-1)
+    return jnp.einsum("bhr,brc->bhc", probs.astype(dtype), latent,
+                      preferred_element_type=jnp.float32)
+
+
 class LatentAttention(Layer):
     """MLA's five leaves and its two paths."""
 
@@ -274,14 +300,23 @@ class LatentAttention(Layer):
                                 self._scale).reshape(q.shape[0], -1, dv)
 
     # ------------------------------------------------------- a decode step
-    def forward_decode(self, h, cbuf, pbuf, pos):
+    def forward_decode(self, h, cbuf, pbuf, pos, active=None):
         """h (B, H): one token a slot at positions pos (B,); cbuf (B, rows,
-        latent), pbuf (B, rows, rope) -> (attention's output (B, H)
-        float32, the two buffers with the step's row written at pos).
-        ABSORBED: nothing a head wide is made of a cached row."""
+        latent), pbuf (B, rows, rope); active (B,): the slots that hold a
+        request (None: all) -> (attention's output (B, H) float32, the two
+        buffers with the step's row written at pos, the rows of them the
+        attention went over).  ABSORBED: nothing a head wide is made of a
+        cached row.  On the chip one kernel walks each slot's rows up to
+        its own `pos` and reads a row once for score and output
+        (`ops/latent_decode_attention.py`); where it refuses (not a TPU,
+        leaves that are not bfloat16, rows or widths that are no whole
+        blocks and lanes) the products go over the whole pool behind a
+        mask."""
         cfg = self.cfg
         b, rows = h.shape[0], cbuf.shape[1]
         nope = cfg.qk_nope_head_dim
+        if active is None:
+            active = jnp.ones((b,), bool)
         with jax.named_scope("mla_absorbed_attention"):
             q_nope, q_pe, c, k_pe = self._query_and_row(h, pos)
             w = self._kv_b()
@@ -291,21 +326,21 @@ class LatentAttention(Layer):
             slot, at = jnp.arange(b), jnp.minimum(pos, rows - 1)
             cbuf = cbuf.at[slot, at].set(c.astype(cbuf.dtype))
             pbuf = pbuf.at[slot, at].set(k_pe.astype(pbuf.dtype))
-            latent = cbuf.astype(h.dtype)
-            scores = (jnp.einsum("bhc,brc->bhr", q_lat.astype(h.dtype),
-                                 latent, preferred_element_type=jnp.float32)
-                      + jnp.einsum("bhc,brc->bhr", q_pe.astype(h.dtype),
-                                   pbuf.astype(h.dtype),
-                                   preferred_element_type=jnp.float32)
-                      ) * self._scale
-            keep = jnp.arange(rows)[None, None, :] <= pos[:, None, None]
-            probs = jax.nn.softmax(jnp.where(keep, scores, -1e30), axis=-1)
-            o_lat = jnp.einsum("bhr,brc->bhc", probs.astype(h.dtype), latent,
-                               preferred_element_type=jnp.float32)
+            walked = mla_decode_attention(q_lat * self._scale,
+                                          q_pe * self._scale, cbuf, pbuf,
+                                          pos, active)
+            if walked is not None:
+                _ATTENTION_PATH.labels(path="decode_kernel").inc()
+                o_lat, went_over = walked
+            else:
+                _ATTENTION_PATH.labels(path="decode_xla").inc()
+                o_lat, went_over = masked_latent_attention(
+                    q_lat, q_pe, cbuf, pbuf, pos, self._scale,
+                    h.dtype), jnp.int32(b * rows)
             attn = jnp.einsum("bhc,chd->bhd", o_lat.astype(h.dtype),
                               w[..., nope:],
                               preferred_element_type=jnp.float32)
-        return self._out(attn.astype(h.dtype)), cbuf, pbuf
+        return self._out(attn.astype(h.dtype)), cbuf, pbuf, went_over
 
 
 class DeepseekV3Block(Layer):
@@ -347,10 +382,12 @@ class DeepseekV3Block(Layer):
         return x, row, counts
 
     def forward_decode(self, x, cbuf, pbuf, pos, active):
-        attn, cbuf, pbuf = self.self_attn.forward_decode(
-            self._normed(x, self.input_layernorm), cbuf, pbuf, pos)
+        """-> (x', the two buffers, the routed counts, the rows of the
+        buffers the attention went over)."""
+        attn, cbuf, pbuf, went_over = self.self_attn.forward_decode(
+            self._normed(x, self.input_layernorm), cbuf, pbuf, pos, active)
         x, counts = self._mlp(self._add(x, attn), active)
-        return x, cbuf, pbuf, counts
+        return x, cbuf, pbuf, counts, went_over
 
 
 class DeepseekV3ForCausalLM(Layer):
@@ -429,17 +466,18 @@ class DeepseekV3ForCausalLM(Layer):
 
     def forward_decode(self, tokens, caches, pos, active):
         """tokens, pos, active (B,): every slot's last token at its own
-        position -> (logits (B, V) float32, caches, counts).  The whole
-        leaves are read: the rows attention went over are all of them."""
+        position -> (logits (B, V) float32, caches, counts).  The rows
+        attention went over are what each layer's form really read: the
+        whole leaves behind a mask, or each slot's blocks up to `pos`."""
         pos, active = unwrap(pos), unwrap(active)
         x = unwrap(self.embed_tokens)[unwrap(tokens)]
-        new, counts = [], 0
+        new, counts, went_over = [], 0, 0
         for blk, (cbuf, pbuf) in zip(self.layers, caches):
-            x, cbuf, pbuf, c = blk.forward_decode(
+            x, cbuf, pbuf, c, rows = blk.forward_decode(
                 x, unwrap(cbuf), unwrap(pbuf), pos, active)
             new.append((cbuf, pbuf))
-            counts = counts + c
-        live = jnp.sum(jnp.where(active, pos + 1, 0))
-        counts = jnp.concatenate([counts, self._cache_counts(
-            live, x.shape[0] * new[0][0].shape[1])])
+            counts, went_over = counts + c, went_over + rows
+        live = jnp.sum(jnp.where(active, pos + 1, 0)) * len(self.layers)
+        counts = jnp.concatenate(
+            [counts, jnp.stack([live, went_over]).astype(jnp.int32)])
         return self._head(x), new, counts

@@ -12,7 +12,19 @@
            `lax.scan` carry `mla_absorbed_attention` in any of their stats
            (the reader of `mla_decode_attention_roofline` finds them so).
 
+and what ISSUE 37 asked of one (`--live 256,512,1024`, and nothing else of
+the above then):
+
+  live     the decode step's attention with the slots at positions drawn
+           from `flood_longgen_8k`'s lengths (a prompt plus a part of its
+           output): the kernel that walks each slot's live rows
+           (`ops/latent_decode_attention.py`) at each block size given
+           against the masked products over the whole pool, the layer
+           whole and the attention's core alone.
+
     chiprun -- python3 probes/mla_probe.py --out chiprun_out/mla_probe.json
+    chiprun -- python3 probes/mla_probe.py --live 256,512,1024 \
+        --out chiprun_out/mla_live.json
 
 Prints one `MLA{json}` line a measurement.  Needs the chip.
 """
@@ -41,16 +53,189 @@ def timed(fn, *args, calls=10):
     return (time.perf_counter() - t0) / calls
 
 
+def with_state(attn, body):
+    """`body(*arrays)` as `call(state, *arrays)`, run with `state` swapped
+    into the layer."""
+    from paddle_tpu.core.tensor import unwrap
+    from paddle_tpu.jit import functional_call
+
+    def call(state, *arrays):
+        attn.probe_body = lambda *a: body(*(unwrap(x) for x in a))
+        try:
+            return functional_call(attn, state, *arrays, method="probe_body")
+        finally:
+            del attn.probe_body
+    return call
+
+
+def drawn_positions(slots, rows, seed):
+    """A position a slot as the cell's mix leaves them in a steady state: a
+    prompt's length plus a uniform part of its output's, the request drawn
+    by how long it holds its slot (its output)."""
+    import numpy as np
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           "flood_longgen_8k.json")) as f:
+        mix = json.load(f)
+    rng = np.random.RandomState(seed)
+
+    def lengths(spec, n):
+        x = spec["median"] * np.exp(spec["sigma"] * rng.randn(n))
+        return np.clip(np.round(x), spec["min"], spec["max"])
+
+    n = 64 * slots
+    prompts, outs = lengths(mix["prompt"], n), lengths(mix["output"], n)
+    took = rng.choice(n, size=slots, p=outs / outs.sum())
+    pos = prompts[took] + np.floor(rng.rand(slots) * outs[took])
+    return np.minimum(pos, rows - 1).astype(np.int32)
+
+
+def live(args, note):
+    """The kernel against the masked products; weights, pools and positions
+    are ARGUMENTS of every jitted call (a closure makes them constants of
+    the program: PR 31's 40 chip-minutes)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models import deepseek_v3 as M
+    from paddle_tpu.ops import latent_decode_attention as K
+    cfg = M.DeepseekV3Config(num_hidden_layers=1)
+    attn = M.LatentAttention(cfg)
+    state = {k: v._data for k, v in attn.state_dict().items()}
+    b, rows = args.slots, args.rows
+    lat, rope, heads = cfg.kv_lora_rank, cfg.qk_rope_head_dim, \
+        cfg.num_attention_heads
+    pos = jnp.asarray(drawn_positions(b, rows, args.seed))
+    active = jnp.ones((b,), bool)
+    ks = jax.random.split(jax.random.PRNGKey(args.seed), 5)
+    h = jax.random.normal(ks[0], (b, cfg.hidden_size), jnp.bfloat16)
+    q_lat = jax.random.normal(ks[1], (b, heads, lat), jnp.float32) * 0.05
+    q_pe = jax.random.normal(ks[2], (b, heads, rope), jnp.float32) * 0.05
+    steps = args.steps
+
+    def layer(state, h, cbuf, pbuf, pos, active):
+        """`steps` dependent decode steps of the layer in one program."""
+        def one(_, carry):
+            h, cbuf, pbuf = carry[:3]
+            o, cbuf, pbuf, went_over = with_state(attn, attn.forward_decode)(
+                state, h, cbuf, pbuf, pos, active)
+            return (h + (o * 1e-3).astype(h.dtype), cbuf, pbuf, o,
+                    went_over.astype(jnp.int32))
+        return jax.lax.fori_loop(
+            0, steps, one, (h, cbuf, pbuf, h.astype(jnp.float32),
+                            jnp.int32(0)))
+
+    def core_kernel(q_lat, q_pe, cbuf, pbuf, pos, active):
+        def one(_, q):
+            o, _ = K.mla_decode_attention(q, q_pe, cbuf, pbuf, pos, active)
+            return q + o * 1e-3
+        return jax.lax.fori_loop(0, steps, one, q_lat)
+
+    def core_masked(q_lat, q_pe, cbuf, pbuf, pos, active):
+        def one(_, q):
+            return q + M.masked_latent_attention(
+                q, q_pe, cbuf, pbuf, pos, 1.0, cbuf.dtype) * 1e-3
+        return jax.lax.fori_loop(0, steps, one, q_lat)
+
+    def pools():
+        return (jax.random.normal(ks[3], (b, rows, lat), jnp.bfloat16),
+                jax.random.normal(ks[4], (b, rows, rope), jnp.bfloat16))
+
+    # which form a trace takes is read from the kernel's module, which is no
+    # part of jit's cache key: every timing traces anew
+    def time_layer():
+        jax.clear_caches()
+        fn = jax.jit(layer, donate_argnums=(2, 3))
+        out = fn(state, h, *pools(), pos, active)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        out = fn(state, h, out[1], out[2], pos, active)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / steps, out[3], int(out[4])
+
+    def time_core(fn):
+        jax.clear_caches()
+        fn, bufs = jax.jit(fn), pools()
+        jax.block_until_ready(fn(q_lat, q_pe, *bufs, pos, active))
+        t0 = time.perf_counter()
+        out = fn(q_lat, q_pe, *bufs, pos, active)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / steps, out
+
+    row_bytes = (lat + rope) * 2
+    live_rows = int(np.sum(np.asarray(pos) + 1))
+    available, K._available = K._available, lambda: False
+    try:
+        t_layer, want, went_over = time_layer()
+        t_core, want_core = time_core(core_masked)
+    finally:
+        K._available = available
+    note(what="live_masked", slots=b, rows=rows, steps=steps,
+         mean_pos=float(np.mean(np.asarray(pos))), live_rows=live_rows,
+         rows_went_over=went_over, layer_ms=t_layer * 1e3,
+         core_ms=t_core * 1e3, live_bytes=live_rows * row_bytes,
+         live_read_at_819GBs_ms=live_rows * row_bytes / 819e9 * 1e3,
+         pool_read_at_819GBs_ms=b * rows * row_bytes / 819e9 * 1e3)
+    block = K.BLOCK_ROWS
+    try:
+        for r in (int(x) for x in args.live.split(",")):
+            K.BLOCK_ROWS = r
+            t_layer, got, went_over = time_layer()
+            t_core, got_core = time_core(core_kernel)
+            diff = lambda a, c: float(jnp.max(jnp.abs(  # noqa: E731
+                a.astype(jnp.float32) - c.astype(jnp.float32))))
+            note(what="live_kernel", block_rows=r, layer_ms=t_layer * 1e3,
+                 core_ms=t_core * 1e3, rows_went_over=went_over,
+                 live_pct=100.0 * live_rows / went_over,
+                 walked_read_at_819GBs_ms=went_over * row_bytes / 819e9
+                 * 1e3, layer_differs_by=diff(got, want),
+                 core_differs_by=diff(got_core, want_core),
+                 core_size=float(jnp.max(jnp.abs(want_core))))
+    finally:
+        K.BLOCK_ROWS = block
+
+
+def written(args, recs):
+    if args.out:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(recs, f, indent=1)
+    return 0
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
     ap.add_argument("--slots", type=int, default=48)
     ap.add_argument("--rows", type=int, default=8192)
     ap.add_argument("--prefill", default="2048,8192")
+    ap.add_argument("--live", default=None,
+                    help="block sizes of the live-rows kernel to time, "
+                         "e.g. 256,512,1024; the other measurements are "
+                         "left out then")
+    ap.add_argument("--steps", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=37)
+    ap.add_argument("--any-device", type=int, default=0,
+                    help="1: rehearse --live off the chip (the kernel "
+                         "through the interpreter)")
     args = ap.parse_args(argv)
+    if args.live:
+        import jax
+        from paddle_tpu.ops import latent_decode_attention as K
+        if jax.default_backend() != "tpu":
+            if not args.any_device:
+                print("no TPU", file=sys.stderr)
+                return 2
+            K._INTERPRET = True
+        recs = []
+
+        def note(**rec):
+            recs.append(rec)
+            print("MLA" + json.dumps(rec), flush=True)
+
+        live(args, note)
+        return written(args, recs)
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.jit import functional_call
     from paddle_tpu.models import deepseek_v3 as M
     if jax.default_backend() != "tpu":
         print("no TPU", file=sys.stderr)
@@ -68,17 +253,6 @@ def main(argv=None):
     def note(**rec):
         recs.append(rec)
         print("MLA" + json.dumps(rec), flush=True)
-
-    def with_state(body):
-        """`body(*arrays)` run with `state` swapped into the layer."""
-        def call(state, *arrays):
-            attn.probe_body = lambda *a: body(*(M.unwrap(x) for x in a))
-            try:
-                return functional_call(attn, state, *arrays,
-                                       method="probe_body")
-            finally:
-                del attn.probe_body
-        return call
 
     # ---- decode: two leaves (the model's) against one
     two_leaves = attn.forward_decode
@@ -103,7 +277,7 @@ def main(argv=None):
         return attn._out(o.astype(h.dtype)), buf
 
     buf = jax.random.normal(key, (b, rows, lat + rope), jnp.bfloat16)
-    f1 = jax.jit(with_state(one_leaf), donate_argnums=(2,))
+    f1 = jax.jit(with_state(attn, one_leaf), donate_argnums=(2,))
 
     def loop1(buf):
         for _ in range(10):
@@ -117,12 +291,12 @@ def main(argv=None):
     jax.block_until_ready(o1)
     t1 = (time.perf_counter() - t0) / 10
     cbuf, pbuf = buf[..., :lat] + 0, buf[..., lat:] + 0
-    f2 = jax.jit(with_state(two_leaves), donate_argnums=(2, 3))
-    o2, cbuf, pbuf = f2(state, h, cbuf, pbuf, pos)
+    f2 = jax.jit(with_state(attn, two_leaves), donate_argnums=(2, 3))
+    o2, cbuf, pbuf, _ = f2(state, h, cbuf, pbuf, pos)
     jax.block_until_ready(o2)
     t0 = time.perf_counter()
     for _ in range(10):
-        o2, cbuf, pbuf = f2(state, h, cbuf, pbuf, pos)
+        o2, cbuf, pbuf, _ = f2(state, h, cbuf, pbuf, pos)
     jax.block_until_ready(o2)
     t2 = (time.perf_counter() - t0) / 10
     pool = b * rows * (lat + rope) * 2
@@ -137,7 +311,7 @@ def main(argv=None):
     def chunk(state, h, buf, pos):
         def step(carry, _):
             buf, pos = carry
-            o, *buf = with_state(two_leaves)(state, h, *buf, pos)
+            o, *buf, _ = with_state(attn, two_leaves)(state, h, *buf, pos)
             return (tuple(buf), pos + 1), jnp.sum(o)
         (buf, _), s = jax.lax.scan(step, (tuple(buf), pos), None, length=4)
         return s, buf
@@ -198,11 +372,7 @@ def main(argv=None):
                  a.astype(jnp.float32) - c.astype(jnp.float32)))),
              products_at_peak_ms=4.0 * 16 * 160 * s_len * (s_len + 1) / 2
              / 197e12 * 1e3)
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(recs, f, indent=1)
-    return 0
+    return written(args, recs)
 
 
 if __name__ == "__main__":

@@ -62,11 +62,14 @@ def _no_compilation_cache():
 @pytest.fixture()
 def chip_compile(one_chip):
     """compile(fn, *(shape, dtype)) for the described chip, with the
-    persistent compilation cache off."""
-    def compile_(fn, *avals):
-        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-                for shape, dtype in avals]
-        return jax.jit(fn).lower(*args).compile().as_text()
+    persistent compilation cache off; an argument may be a dict of such
+    pairs, `donate` the arguments the program may write into."""
+    def compile_(fn, *avals, donate=()):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a[0], a[1], sharding=one_chip),
+            list(avals), is_leaf=lambda a: isinstance(a, tuple))
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().as_text()
 
     with _no_compilation_cache():
         yield compile_
@@ -304,55 +307,93 @@ def test_held_experts_walk_a_prompts_picks_in_chunks(chip_compile):
     assert f"[{tokens},{top_k},{h}]" not in text
 
 
-@pytest.fixture(scope="module")
-def evabyte_programs(one_chip):
-    """compile(name) -> the compiled form of the serving engine's OWN program
-    (`decode`, `prefill_b32768`) over EvaByte at the PUBLISHED widths and the
-    cell's slots, for the described chip.  Nothing of that size exists here:
-    the engine is built over a model of 8 layers two numbers a head wide,
-    the model's config is then set to the published one (a forward reads
-    its sizes from it, and `functional_call` swaps the leaves), and the
-    programs are lowered on shapes alone: the benchmark's layout gives the
-    weights', the cell's mix the pool's."""
+def test_moonlight_decode_layer_walks_the_live_rows(chip_compile,
+                                                    monkeypatch):
+    """One layer of Moonlight's decode step at 48 slots x 8192 rows (the
+    attention at its published widths: 16 heads, a latent of 512 beside 64
+    rotated numbers; a narrow MLP and vocabulary): the attention is ONE
+    kernel under the name a trace shows it by, fed both leaves as they lie
+    in the pool: no copy or transpose as large as the latent leaf."""
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.models import deepseek_v3 as M
+    from paddle_tpu.ops import latent_decode_attention as K
+    monkeypatch.setattr(K, "_available", lambda: True)
+    model = M.DeepseekV3ForCausalLM(M.DeepseekV3Config(
+        num_hidden_layers=1, vocab_size=256, intermediate_size=64))
+    model.eval()
+    state = {k: (tuple(v.shape), v._data.dtype)
+             for k, v in model.state_dict().items()}
+
+    def step(state, tokens, cbuf, pbuf, pos, active):
+        logits, cache, counts = functional_call(
+            model, state, tokens, [(cbuf, pbuf)], pos, active,
+            training=False, method="forward_decode")
+        return logits, cache, counts
+
+    text = chip_compile(step, state, ((48,), jnp.int32),
+                        ((48, 8192, 512), jnp.bfloat16),
+                        ((48, 8192, 64), jnp.bfloat16), ((48,), jnp.int32),
+                        ((48,), jnp.bool_), donate=(2, 3))
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1
+    assert calls[0].startswith("%mla_decode_attention")
+    assert '/mla_decode_attention/' in calls[0]
+    assert "bf16[48,8192,512]" in calls[0] and "bf16[48,8192,64]" in calls[0]
+    assert not [line for line in text.splitlines() if re.search(
+        r"bf16\[48,8192,512\]\S* (copy|transpose)\(", line.strip())]
+
+
+@contextlib.contextmanager
+def _engine_programs(one_chip, arch_name, config, traffic, narrow, bucket,
+                     pool_leaves):
+    """compile(name) -> the compiled form of the serving engine's OWN
+    program (`decode`, `prefill_b<bucket>`) over a configuration of the
+    benchmark at its PUBLISHED widths and its cell's slots, for the
+    described chip, and the bytes the engine would hold (weights and cache).
+    Nothing of that size exists here: the engine is built over a model cut
+    by `narrow`, the model's config is then set to the published one (a
+    forward reads its sizes from it, and `functional_call` swaps the
+    leaves), and the programs are lowered on shapes alone: the benchmark's
+    layout gives the weights', `pool_leaves(d, mix)` a cache layer's."""
     import importlib
     import sys
-    from paddle_tpu import models
     from paddle_tpu.serving import ServingEngine
     root = os.path.join(os.path.dirname(__file__), os.pardir)
     if root not in sys.path:
         sys.path.insert(0, root)
-    arch = importlib.import_module("benchmark.arch.evabyte")
-    with open(os.path.join(root, "benchmark", "configs",
-                           "evabyte-6.5b-8of32.json")) as f:
+    from benchmark import harness
+    arch = importlib.import_module("benchmark.arch." + arch_name)
+    with open(os.path.join(root, "benchmark", "configs", config)) as f:
         cfg = json.load(f)
-    with open(os.path.join(root, "benchmark", "traffic",
-                           "flood_longctx_32k.json")) as f:
+    with open(os.path.join(root, "benchmark", "traffic", traffic)) as f:
         mix = json.load(f)["engine"]
-    kwargs = cfg["program"]["kwargs"]
-    model = models.EvaByteForCausalLM(models.EvaByteConfig(
-        **dict(kwargs, hidden_size=64, intermediate_size=64)))
+    program = cfg["program"]
+    factory = harness.resolve(program["factory"])
+    model = harness.resolve(program["model"])(
+        factory(**dict(program["kwargs"], **narrow)))
     model.eval()
     eng = ServingEngine(model, max_slots=mix["max_slots"],
                         max_len=mix["max_len"],
-                        prefill_buckets=(mix["prefill_buckets"][-1],),
+                        prefill_buckets=(mix["prefill_buckets"][bucket],),
                         decode_chunk=mix["decode_chunk"])
-    vars(model.config).update(vars(models.EvaByteConfig(**kwargs)))
+    vars(model.config).update(vars(factory(**program["kwargs"])))
     d = arch.dims(cfg)
     shape = lambda s, dt: jax.ShapeDtypeStruct(  # noqa: E731
         tuple(s), dt, sharding=one_chip)
-    state = {arch.program_name(name, layer): shape(leaf[0], jnp.bfloat16)
-             for layer in range(-1, d["L"])
-             for name, leaf in (arch.top_layout(d) if layer < 0
-                                else arch.layer_layout(d)).items()}
+    state = {}
+    for layer in range(-1, d["L"]):
+        layout = (arch.top_layout(d) if layer < 0
+                  else arch.layer_layout(d, d["kinds"][layer]))
+        for name, leaf in layout.items():
+            name = arch.program_name(name, layer)
+            state[name] = shape(leaf[0], eng._state[name].dtype)
     assert set(state) == set(eng._state)
-    row = (mix["max_slots"], d["window"], d["heads"], d["hd"])
-    summary = row[:1] + (mix["max_len"] // d["chunk"],) + row[2:]
-    pools = [tuple(shape(s, jnp.bfloat16) for s in (row, row, summary,
-                                                    summary))] * d["L"]
+    pools = [tuple(shape(s, jnp.bfloat16)
+                   for s in pool_leaves(d, mix))] * d["L"]
     programs = {name: (fn, inputs) for name, fn, inputs in eng._programs()}
-    cache_bytes = sum(math.prod(leaf.shape) * 2 for layer in pools
-                      for leaf in layer)
-    held = cache_bytes + arch.param_count(d) * 2
+    held = (sum(math.prod(leaf.shape) * 2 for layer in pools
+                for leaf in layer) + arch.param_count(d) * 2)
 
     def compile_(name):
         fn, inputs = programs[name]
@@ -362,8 +403,73 @@ def evabyte_programs(one_chip):
                 jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        inputs)).compile()
 
-    yield compile_, held
-    eng.close()
+    try:
+        yield compile_, held
+    finally:
+        eng.close()
+
+
+@pytest.fixture(scope="module")
+def moonlight_decode(one_chip):
+    """(the compiled decode program of Moonlight's seven layers at 48 slots
+    x 8192 rows with the decode kernel taken, which the CPU this runs on
+    would refuse; the bytes held)."""
+    from paddle_tpu.ops import latent_decode_attention as K
+    at = lambda d, mix, w: (mix["max_slots"], mix["max_len"], w)  # noqa: E731
+    available, K._available = K._available, lambda: True
+    try:
+        with _engine_programs(
+                one_chip, "deepseek_v3", "moonlight-16b-a3b-7of27.json",
+                "flood_longgen_8k.json",
+                dict(hidden_size=64, intermediate_size=64,
+                     moe_intermediate_size=32, vocab_size=256), 0,
+                lambda d, mix: (at(d, mix, d["latent"]),
+                                at(d, mix, d["rope"]))) as (compile_, held):
+            return compile_("decode"), held
+    finally:
+        K._available = available
+
+
+def test_moonlight_decode_program_compiles_and_fits(moonlight_decode,
+                                                    record_property):
+    """48 slots x 8192 latent rows x 7 layers beside 8.53 GB of weights:
+    seven kernels a step under their name inside the decode loop, no copy
+    or transpose of a latent leaf anywhere in the program, its temporaries
+    beside what the engine holds inside the chip, and the module under the
+    name `decode_flood_longgen_roofline` finds it by."""
+    compiled, held = moonlight_decode
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert 11.6e9 < held < 11.8e9
+    assert held + mem.temp_size_in_bytes < HBM
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    assert _metric_pattern("decode_flood_longgen_roofline",
+                           "program").search(
+        text.split(",", 1)[0].replace("HloModule ", "") + "(")
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 7
+    assert all(c.startswith("%mla_decode_attention") for c in calls)
+    assert not [line for line in text.splitlines() if re.search(
+        r"bf16\[48,8192,512\]\S* (copy|transpose)\(", line.strip())]
+
+
+@pytest.fixture(scope="module")
+def evabyte_programs(one_chip):
+    """compile(name) of EvaByte's `decode` and `prefill_b32768` at 16 slots
+    x 32768 positions, and the bytes held."""
+    def leaves(d, mix):
+        row = (mix["max_slots"], d["window"], d["heads"], d["hd"])
+        summary = row[:1] + (mix["max_len"] // d["chunk"],) + row[2:]
+        return row, row, summary, summary
+
+    with _engine_programs(
+            one_chip, "evabyte", "evabyte-6.5b-8of32.json",
+            "flood_longctx_32k.json",
+            dict(hidden_size=64, intermediate_size=64), -1,
+            leaves) as programs:
+        yield programs
 
 
 def _metric_pattern(name, key):

@@ -16,6 +16,14 @@ Tolerances, each with its reason:
     re-association of the same float32 products, inside the same 2e-5.
   * a bfloat16 program reads 1e-2 or more on the same comparison (asserted
     above 50 x the tolerance): computing below the stated precision fails.
+  * the decode kernel (`ops/latent_decode_attention.py`, through the
+    interpreter) against the masked products it replaces, both with
+    bfloat16 operands and float32 sums: the query is scaled before it is
+    rounded where the products scale the score, and the kernel rounds a
+    row's weight before the sum of weights divides, so an output of size 1
+    differs by bfloat16's step, 2 ** -8: 2e-2 on the attention's output
+    (readings to 5e-3), 0.15 on logits three dense layers later, each of
+    which rounds the residual stream again (readings 0.05 to 0.09).
 """
 import os
 import sys
@@ -61,10 +69,20 @@ def dims(**over):
                        topk_group=1, **over))
 
 
-def build(dtype="float32", seed=2147483659):
-    d = dims()
+# the least widths the decode kernel takes: lanes of 128 and 64, 8 heads;
+# every layer dense, because two bfloat16 programs that differ by rounding
+# flip a routed layer's pick at a near tie and then differ by 0.4 of a logit
+LANES = dict(num_attention_heads=8, kv_lora_rank=128, qk_rope_head_dim=64,
+             first_k_dense_replace=3)
+BLOCK = 16          # rows a block of the kernel in these tests
+LANES_LEN = 48      # three blocks a slot
+KERNEL_TOL, KERNEL_LOGIT_TOL = 2e-2, 0.15
+
+
+def build(dtype="float32", seed=2147483659, **over):
+    d = dims(**over)
     model = models.DeepseekV3ForCausalLM(models.DeepseekV3Config(
-        **TINY, dtype=dtype))
+        **dict(TINY, **over), dtype=dtype))
     model.eval()
     top = dict(A.make_leaves(W.make, d, seed, -1))
     layers = [dict(A.make_leaves(W.make, d, seed, i)) for i in range(d["L"])]
@@ -333,8 +351,9 @@ def test_absorbed_attention_equals_expanded(tiny):
     for p in range(s):
         # slot 0 walks the sequence; slot 1 stays at position 0
         pos = jnp.asarray([p, 0], jnp.int32)
-        got, *bufs = attn.forward_decode(jnp.stack([h[p], h[0]]), *bufs,
-                                         pos)
+        got, *bufs, went_over = attn.forward_decode(
+            jnp.stack([h[p], h[0]]), *bufs, pos)
+        assert int(went_over) == 2 * MAX_LEN    # the masked products: all
         assert np.max(np.abs(np.asarray(got[0] - want[p]))) < TOL
         assert np.max(np.abs(np.asarray(got[1] - want[0]))) < TOL
     for buf, r in zip(bufs, rows):
@@ -375,49 +394,242 @@ def test_a_form_the_model_has_not_is_refused():
 
 # ------------------------------------------------------------- the engine
 
-def test_prefill_then_decode_through_the_cache_agrees_at_every_position(
-        tiny, eng, ref_logits):
-    """Logits, not tokens: the prompt's last position from the prefill
-    program, every later one from the absorbed decode step over the cache
-    the engine holds, against the reference's full forward."""
-    model = tiny[0]
-    cache = model.gen_fixed_cache(2, MAX_LEN)
-    assert [[leaf.shape for leaf in layer] for layer in cache] == [
-        [(2, MAX_LEN, 24), (2, MAX_LEN, 8)]] * 3
-    served = {}
-    for prompt, n in ((IDS[:11], 9), (IDS[5:8], 14), (IDS[2:18], 6)):
-        served[tuple(prompt)] = eng.submit(list(prompt), n)
-    while eng.has_work():
-        eng.step()
-    for prompt, resp in served.items():
-        toks = list(resp.tokens(5))
-        want = ref_logits(list(prompt) + toks)
-        # the served tokens are the reference's own choices...
-        assert toks == list(np.argmax(want[len(prompt) - 1:-1], axis=-1))
-    # ...and the logits agree position by position: the model's two entries
-    # by hand over the same cache protocol
-    prompt = IDS[:11]
+@pytest.fixture(scope="module")
+def lanes():
+    """A bfloat16 model at the least widths the decode kernel takes."""
+    return build("bfloat16", **LANES)
+
+
+@pytest.fixture()
+def kernel(monkeypatch):
+    """The decode kernel through the interpreter, in blocks of BLOCK rows."""
+    from paddle_tpu.ops import latent_decode_attention as K
+    monkeypatch.setattr(K, "_INTERPRET", True)
+    monkeypatch.setattr(K, "BLOCK_ROWS", BLOCK)
+    return K
+
+
+def decode_paths():
+    taken = obs.metrics.get_registry().get("attention_path_total")
+    return {p: taken.value(path="decode_" + p) for p in ("kernel", "xla")}
+
+
+def by_hand(model, prompt, seq, max_len):
+    """The model's two entries by hand over the cache protocol: the logits
+    at the prompt's last position and at every later one of `seq`, and the
+    counts of the prefill and of the last decode step."""
     padded = np.zeros((1, 16), np.int32)
-    padded[0, :11] = prompt
-    logits, rows, counts = model.forward_prefill(
-        paddle.to_tensor(padded), paddle.to_tensor(np.int32(11)))
-    seq = list(prompt) + list(served[tuple(prompt)].tokens(5))
-    want = ref_logits(seq)
-    assert np.max(np.abs(np.asarray(logits)[0, 0] - want[10])) < TOL
-    # 11 tokens x 3 picks x 2 routed layers; the cache's two counts: the
-    # prompt's rows and the bucket's, a layer
-    counts = np.asarray(counts)
-    assert counts.shape == (7,) and counts[1] == 11 * 3 * 2
-    assert counts[0] == counts[1] and list(counts[5:]) == [11 * 3, 16 * 3]
-    cache = [tuple(jnp.zeros((1, MAX_LEN, r.shape[2]), jnp.float32).at[
-        :, :16].set(jnp.where(jnp.arange(16)[None, :, None] < 11, r, 0))
-        for r in layer) for layer in rows]
-    for p in range(11, len(seq)):
+    padded[0, :len(prompt)] = prompt
+    logits, rows, first = model.forward_prefill(
+        paddle.to_tensor(padded), paddle.to_tensor(np.int32(len(prompt))))
+    out = [np.asarray(logits, np.float32)[0, 0]]
+    cache = [tuple(jnp.zeros((1, max_len, r.shape[2]), r.dtype).at[
+        :, :16].set(jnp.where(jnp.arange(16)[None, :, None] < len(prompt),
+                              r, 0)) for r in layer) for layer in rows]
+    for p in range(len(prompt), len(seq)):
         logits, cache, counts = model.forward_decode(
             jnp.asarray([seq[p]]), cache, jnp.asarray([p], jnp.int32),
             jnp.asarray([True]))
-        assert np.max(np.abs(np.asarray(logits)[0] - want[p])) < TOL
-    assert list(np.asarray(counts)[5:]) == [len(seq) * 3, MAX_LEN * 3]
+        out.append(np.asarray(logits, np.float32)[0])
+    return np.stack(out), np.asarray(first), np.asarray(counts)
+
+
+REQUESTS = ((IDS[:11], 9), (IDS[5:8], 14), (IDS[2:18], 6))
+
+
+def serve(engine):
+    served = {tuple(prompt): engine.submit(list(prompt), n)
+              for prompt, n in REQUESTS}
+    while engine.has_work():
+        engine.step()
+    return {prompt: list(resp.tokens(5)) for prompt, resp in served.items()}
+
+
+@pytest.mark.parametrize("path", ["masked_products", "kernel"])
+def test_prefill_then_decode_through_the_cache_agrees_at_every_position(
+        path, request):
+    """Logits, not tokens: the prompt's last position from the prefill
+    program, every later one from the absorbed decode step over the cache
+    the engine holds.  `masked_products`: the float32 model, whose decode
+    step the kernel refuses, against the reference's full forward.
+    `kernel`: a bfloat16 model at lane-whole widths with the kernel run by
+    the interpreter, against the SAME model with the kernel refused."""
+    prompt = IDS[:11]
+    if path == "masked_products":
+        model = request.getfixturevalue("tiny")[0]
+        ref_logits = request.getfixturevalue("ref_logits")
+        cache = model.gen_fixed_cache(2, MAX_LEN)
+        assert [[leaf.shape for leaf in layer] for layer in cache] == [
+            [(2, MAX_LEN, 24), (2, MAX_LEN, 8)]] * 3
+        before = decode_paths()
+        served = serve(request.getfixturevalue("eng"))
+        for p, toks in served.items():
+            want = ref_logits(list(p) + toks)
+            # the served tokens are the reference's own choices...
+            assert toks == list(np.argmax(want[len(p) - 1:-1], axis=-1))
+        # ...and the logits agree position by position
+        seq = list(prompt) + served[tuple(prompt)]
+        got, first, counts = by_hand(model, prompt, seq, MAX_LEN)
+        assert np.max(np.abs(got - ref_logits(seq)[10:])) < TOL
+        # 11 tokens x 3 picks x 2 routed layers; the cache's two counts:
+        # the prompt's rows and the bucket's, a layer
+        assert first.shape == (7,) and first[1] == 11 * 3 * 2
+        assert first[0] == first[1] and list(first[5:]) == [11 * 3, 16 * 3]
+        # a decode step's: the rows the slot holds, and the WHOLE pool
+        assert list(counts[5:]) == [len(seq) * 3, MAX_LEN * 3]
+        took = decode_paths()
+        assert took["kernel"] == before["kernel"]
+        assert took["xla"] > before["xla"]
+        return
+    model = request.getfixturevalue("lanes")[0]
+    K = request.getfixturevalue("kernel")
+    before = decode_paths()
+    engine = ServingEngine(model, max_slots=3, max_len=LANES_LEN,
+                           prefill_buckets=(4, 16), decode_chunk=4,
+                           max_queue_depth=16)
+    try:
+        served = serve(engine)
+    finally:
+        engine.close()
+    took = decode_paths()
+    assert took["kernel"] - before["kernel"] == 3      # a layer, traced once
+    assert took["xla"] == before["xla"]
+    for p, toks in served.items():
+        seq = list(p) + toks
+        got, _, counts = by_hand(model, p, seq, LANES_LEN)
+        K._INTERPRET = False         # the kernel refuses: masked products
+        want, _, pool = by_hand(model, p, seq, LANES_LEN)
+        K._INTERPRET = True
+        assert np.max(np.abs(got - want)) < KERNEL_LOGIT_TOL
+        # a served token is the masked products' choice, or a near tie
+        assert all(want[i, t] > want[i].max() - KERNEL_LOGIT_TOL
+                   for i, t in enumerate(toks))
+        # what the last step went over: the slot's blocks up to its row on
+        # the one path, the pool on the other; the rows it holds on both
+        walked = -(-len(seq) // BLOCK) * BLOCK
+        assert list(counts[5:]) == [len(seq) * 3, walked * 3]
+        assert list(pool[5:]) == [len(seq) * 3, LANES_LEN * 3]
+
+
+# ------------------------------------------------- the decode kernel alone
+
+ROWS = 4 * BLOCK
+RAGGED = {      # (pos, active) a slot
+    "first_row": ([0] * 4, [True] * 4),
+    "a_blocks_last_row": ([BLOCK - 1] * 4, [True] * 4),
+    "a_blocks_first_row": ([BLOCK, 2 * BLOCK, 3 * BLOCK, BLOCK], [True] * 4),
+    "the_pools_last_row": ([ROWS - 1] * 4, [True] * 4),
+    "an_inactive_slot": ([2 * BLOCK + 3, ROWS - 1, 5, 7],
+                         [True, False, True, False]),
+    "every_slot_in_another_block": ([3, BLOCK + 5, 2 * BLOCK + 9,
+                                     3 * BLOCK + 2], [True] * 4),
+}
+
+
+def masked_products(q_lat, q_pe, cbuf, pbuf, pos):
+    """The model's own masked products over queries already scaled."""
+    from paddle_tpu.models.deepseek_v3 import masked_latent_attention
+    return masked_latent_attention(q_lat, q_pe, cbuf, pbuf, pos, 1.0,
+                                   cbuf.dtype)
+
+
+def kernel_inputs(rows=ROWS, heads=8, latent=128, rope=64,
+                  dtype=jnp.bfloat16):
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    return (jax.random.normal(ks[0], (4, heads, latent), jnp.float32) * 0.3,
+            jax.random.normal(ks[1], (4, heads, rope), jnp.float32) * 0.3,
+            jax.random.normal(ks[2], (4, rows, latent), dtype),
+            jax.random.normal(ks[3], (4, rows, rope), dtype))
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_the_decode_kernel_is_the_masked_products_over_ragged_positions(
+        kernel, case):
+    """Scores sharp enough that a row left out, a row too many or a block
+    skipped would show (the weights are far from flat), each slot's walk
+    ending in its own block."""
+    pos, active = (jnp.asarray(x) for x in RAGGED[case])
+    q_lat, q_pe, cbuf, pbuf = kernel_inputs()
+    got, went_over = kernel.mla_decode_attention(q_lat, q_pe, cbuf, pbuf,
+                                                 pos, active)
+    want = masked_products(q_lat, q_pe, cbuf, pbuf, pos)
+    live = np.asarray(active)
+    assert float(jnp.max(jnp.abs(want))) > 0.5
+    assert np.max(np.abs(np.asarray(got - want))[live]) < KERNEL_TOL
+    assert np.all(np.isfinite(np.asarray(got)))
+    # an inactive slot costs one block, an active one its blocks up to pos
+    blocks = np.where(live, np.asarray(pos) // BLOCK + 1, 1)
+    assert int(went_over) == int(blocks.sum()) * BLOCK
+    # a row past `pos` weighs nothing: garbage there changes no output
+    dirty = jnp.where(jnp.arange(ROWS)[None, :, None] > pos[:, None, None],
+                      jnp.asarray(1e4, cbuf.dtype), cbuf)
+    again, _ = kernel.mla_decode_attention(q_lat, q_pe, dirty, pbuf, pos,
+                                           active)
+    assert np.array_equal(np.asarray(again)[live], np.asarray(got)[live])
+
+
+REFUSED = {
+    "float32_leaves": dict(dtype=jnp.float32),
+    "rows_that_are_no_whole_blocks": dict(rows=ROWS + 8),
+    "a_latent_that_fills_no_lanes": dict(latent=96),
+    "a_rope_that_fills_no_half_lane": dict(rope=24),
+    "heads_that_fill_no_sublanes": dict(heads=4),
+}
+
+
+@pytest.mark.parametrize("why", sorted(REFUSED))
+def test_a_shape_the_decode_kernel_refuses_takes_the_masked_products(
+        kernel, why):
+    pos, active = jnp.asarray([3, 9, 20, 40]), jnp.ones((4,), bool)
+    assert kernel.mla_decode_attention(
+        *kernel_inputs(**REFUSED[why]), pos, active) is None
+    # and not on a TPU with no interpreter: whatever the shape
+    kernel._INTERPRET = False
+    assert kernel.mla_decode_attention(*kernel_inputs(), pos, active) is None
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_the_layer_counts_the_decode_form_it_took_and_what_it_went_over(
+        lanes, kernel, path):
+    """One layer's `forward_decode` at lane-whole widths: with the kernel,
+    `attention_path_total{path="decode_kernel"}` and the walked blocks;
+    over a pool of rows that are no whole blocks, `decode_xla` and the
+    pool; the same output either way."""
+    attn = lanes[0].layers[1].self_attn
+    rows = ROWS if path == "kernel" else ROWS + 8
+    rng = np.random.RandomState(3)
+    h = jnp.asarray(rng.randn(4, 64), jnp.bfloat16)
+    bufs = [jnp.asarray(rng.randn(4, rows, w), jnp.bfloat16)
+            for w in (128, 64)]
+    pos = jnp.asarray([3, BLOCK + 5, 2 * BLOCK + 9, 3 * BLOCK + 2])
+    active = jnp.asarray([True, True, False, True])
+    before = decode_paths()
+    got, _, _, went_over = attn.forward_decode(h, *bufs, pos, active)
+    took = decode_paths()
+    assert {p: took[p] - before[p] for p in took} == {
+        "kernel": int(path == "kernel"), "xla": int(path == "xla")}
+    assert int(went_over) == ((1 + 2 + 1 + 4) * BLOCK if path == "kernel"
+                              else 4 * rows)
+    kernel._INTERPRET = False
+    want = attn.forward_decode(h, *bufs, pos, active)[0]
+    live = np.asarray(active)
+    assert np.max(np.abs(np.asarray(got - want))[live]) < KERNEL_TOL
+
+
+def test_the_decode_kernels_body_is_traced_once_for_all_layers(
+        lanes, kernel, monkeypatch):
+    """Three layers call the kernel with one signature: its body is traced
+    for the first and bound again for the others (pallas traces a body at
+    every call; `flash_attention._traced_once` says what that cost)."""
+    traced, body = [], kernel._kernel
+    monkeypatch.setattr(kernel, "_kernel", lambda *a, **kw: (
+        traced.append(1), body(*a, **kw))[1])
+    model, slots, rows = lanes[0], 5, 5 * BLOCK   # a signature of its own
+    jax.make_jaxpr(lambda tok, cache, pos, act: model.forward_decode(
+        tok, cache, pos, act))(
+        jnp.zeros((slots,), jnp.int32), model.gen_fixed_cache(slots, rows),
+        jnp.arange(slots, dtype=jnp.int32) * 7, jnp.ones((slots,), bool))
+    assert len(traced) == 1
 
 
 def test_the_engine_gauges_latent_rows_and_records_the_cache_counts(
