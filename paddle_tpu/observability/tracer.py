@@ -19,9 +19,16 @@ one flag (a few hundred nanoseconds: PERF.md, PR 27), so there is no
 switch to remember.  `Tracer.record` takes a span whose start lies in the
 past (a queue wait is known only when it ends); such a span is in the ring
 only, an annotation cannot be back-dated.
+
+Python's collector pauses every thread that holds the interpreter, so its
+longer pauses are spans too: one `gc.callbacks` hook, installed when this
+module is imported, records `python_gc {generation, collected}` for every
+collection of the oldest generation and for any collection of
+`GC_SPAN_MIN_S` or more (the others, hundreds a second, record nothing).
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -38,6 +45,8 @@ except ImportError:  # pragma: no cover
 __all__ = ["Span", "Tracer", "get_tracer", "span"]
 
 DEFAULT_MAX_EVENTS = 200_000  # bound host memory (same cap profiler.py had)
+GC_SPAN_MIN_S = 1e-3  # a younger generation's collection shorter than this
+                      # records nothing
 
 
 class Span:
@@ -149,6 +158,8 @@ class Tracer:
             rec[0] += 1
             rec[1] += dur
             self._ring.append((name, t0, dur, tid, span_id, parent_id, args))
+        if _gc_late and self is _default_tracer:
+            self.record("python_gc", *_gc_late.popleft())
 
     def _record(self, sp: Span):
         self._append(sp.name, sp.t0, sp.dur, sp.tid, sp.span_id,
@@ -232,6 +243,34 @@ class Tracer:
 
 
 _default_tracer = Tracer()
+
+_gc_t0 = None          # start of the collection running now (one at a time)
+_gc_late: deque = deque()  # collections that ended inside the ring's lock
+
+
+def _on_gc(phase, info):
+    """The collector's hook: a `python_gc` span on the default tracer for a
+    collection of generation 2 or of `GC_SPAN_MIN_S` or more.  A collection
+    can start at any bytecode, also inside the ring's own lock, which is
+    not reentrant: one that ends while the lock is held waits in `_gc_late`
+    for the next span recorded."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    t1 = time.perf_counter()
+    gen = info["generation"]
+    if t0 is None or (gen < 2 and t1 - t0 < GC_SPAN_MIN_S):
+        return
+    args = {"generation": gen, "collected": info["collected"]}
+    if _default_tracer._lock.locked():
+        _gc_late.append((t0, t1, None, args))
+    else:
+        _default_tracer.record("python_gc", t0, t1, args=args)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def get_tracer() -> Tracer:

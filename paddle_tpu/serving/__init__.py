@@ -131,8 +131,10 @@ Metrics (all live under `metrics()`, the STAT_serving_* monitor counters,
 and the predictor's profile report): ttft_p50_ms,
 inter_token_ms, tokens_per_sec, queue_depth, slot_occupancy,
 requests_completed/errored, STAT_serving_{requests,rejects,tokens,
-prefills,decode_steps,compiles,queue_depth,slots_active,cancelled,
-deadline_expired,nonfinite}.
+compiles,cancelled,deadline_expired,nonfinite}.  Queue depth and slots
+seated are the gauges `serving_queue_depth` / `serving_slot_occupancy`;
+prefills and decode calls are the tracer's `serving_admit` /
+`serving_decode` span counts (`get_tracer().aggregates()`).
 """
 from __future__ import annotations
 

@@ -1601,8 +1601,11 @@ class ServingEngine:
         """One admission: the bucketed prefill enqueued, its first token
         pulled (the pull blocks on the chip), the run seated in its slot."""
         plen = int(req.prompt.shape[0])
+        # `seated`: the runs that cannot advance while this one's prompt
+        # is prepared, run and its first token pulled
         with _span("serving_admit", args={"request": req.id, "slot": slot,
-                                          "plen": plen}) as sp:
+                                          "plen": plen,
+                                          "seated": len(self._slots)}) as sp:
             with _span("serving_prefill_dispatch"):
                 called = (self._prefill_cached(req, resp, slot, sp.args)
                           if self.prefix_cache is not None
@@ -1610,7 +1613,6 @@ class ServingEngine:
             if called is None:  # failed terminally before any program ran
                 return
             out, key, aid = called
-            stat_add("STAT_serving_prefills")
             with _span("serving_prefill_wait"):
                 ok = bool(out["finite"])
                 if ok:
@@ -2212,7 +2214,6 @@ class ServingEngine:
                 # batched model's routed counts, a tick's commits with it)
                 host = jax.device_get({k: v for k, v in out.items()
                                        if k not in _RESIDENT})
-            stat_add("STAT_serving_decode_steps")
             if spec:
                 self._spec_ticks += 1
                 stat_add("STAT_spec_ticks")

@@ -94,7 +94,6 @@ class RequestScheduler:
                     f"serving queue full ({self.max_queue_depth} waiting); "
                     "request rejected")
             self._pending.append((req, resp))
-            stat_add("STAT_serving_queue_depth")
             depth_g.set(len(self._pending))
 
     # -- engine side --------------------------------------------------------
@@ -140,7 +139,6 @@ class RequestScheduler:
                     return None
                 self._pending.popleft()
                 self._space.notify()
-                stat_add("STAT_serving_queue_depth", -1)
                 depth_g.set(len(self._pending))
                 if resp.cancelled:
                     stat_add("STAT_serving_cancelled")
@@ -155,7 +153,6 @@ class RequestScheduler:
                     continue
                 slot = self._free.pop()
                 self._active[slot] = (req, resp)
-                stat_add("STAT_serving_slots_active")
                 occ_g.set(len(self._active))
                 _note_admitted(req, resp)
                 return req, resp, slot
@@ -171,7 +168,6 @@ class RequestScheduler:
                 return None
             slot = self._free.pop()
             self._active[slot] = (req, resp)
-            stat_add("STAT_serving_slots_active")
             _obs()[0].set(len(self._active))
             if resp.admitted_at is None:  # not a preempted run's return
                 _note_admitted(req, resp)
@@ -185,7 +181,6 @@ class RequestScheduler:
             if slot in self._active:
                 del self._active[slot]
                 self._free.append(slot)
-                stat_add("STAT_serving_slots_active", -1)
                 _obs()[0].set(len(self._active))
 
     def drain_pending(self):
@@ -193,8 +188,6 @@ class RequestScheduler:
         shutdown/death path; the caller fails the responses."""
         with self._space:
             drained = list(self._pending)
-            if drained:
-                stat_add("STAT_serving_queue_depth", -len(drained))
             self._pending = deque()
             _obs()[1].set(0)
             self._space.notify_all()
@@ -230,7 +223,6 @@ class RequestScheduler:
                 else:
                     keep.append((req, resp))
                     continue
-                stat_add("STAT_serving_queue_depth", -1)
                 self._space.notify()
             self._pending = keep
             _obs()[1].set(len(self._pending))

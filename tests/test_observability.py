@@ -687,3 +687,30 @@ def test_observability_probe_smoke():
     assert "failures" not in rec
     assert rec["spans_exported"] == 200
     assert rec["export_ms"] > 0
+
+
+@obsmark
+def test_a_full_collection_is_one_python_gc_span():
+    import gc
+    from paddle_tpu import observability as obs
+    tracer = obs.get_tracer()
+    gc.disable()                  # no collection but the one asked for
+    try:
+        tracer.clear()
+        gc.collect(2)
+        spans = [ev for ev in tracer.events() if ev[0] == "python_gc"]
+        # the ring's lock is not reentrant: a collection that ends while
+        # it is held is recorded after the next span
+        with tracer._lock:
+            gc.collect(2)
+        assert [ev[0] for ev in tracer.events()] == ["python_gc"]
+        tracer.record("after", 1.0, 2.0)
+        late = [ev for ev in tracer.events() if ev[0] == "python_gc"]
+    finally:
+        gc.enable()
+    assert len(spans) == 1 and spans[0][6]["generation"] == 2
+    assert spans[0][6]["collected"] >= 0 and spans[0][2] > 0
+    assert spans[0][4] is not None and spans[0][5] is None
+    assert len(late) == 2 and late[1][6]["generation"] == 2
+    assert late[1][1] > spans[0][1] and late[1][4] != spans[0][4]
+    assert tracer.aggregates()["python_gc"][0] == 2

@@ -451,15 +451,21 @@ def test_deadline_expiry_while_queued(stub_engine):
         r.tokens(timeout=5)
 
 
+def _admissions():
+    """Prefills reached: the tracer's count of `serving_admit` spans."""
+    from paddle_tpu.observability import get_tracer
+    return get_tracer().aggregates().get("serving_admit", [0, 0.0])[0]
+
+
 def test_cancel_before_prefill(stub_engine):
     _, eng = stub_engine
-    prefills0 = stat_get("STAT_serving_prefills")
+    prefills0 = _admissions()
     r = eng.submit(np.arange(4), max_new_tokens=5)
     r.cancel()
     eng.step()
     with pytest.raises(RequestCancelled):
         r.tokens(timeout=5)
-    assert stat_get("STAT_serving_prefills") == prefills0, \
+    assert _admissions() == prefills0, \
         "cancelled-before-prefill must never reach the device"
     assert eng.scheduler.free_slot_count() == eng.max_slots
 

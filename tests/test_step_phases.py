@@ -52,12 +52,18 @@ PER_REQUEST = ("serving_queue_wait", "serving_admit",
                "serving_request")
 
 
-def _engine(kind):
+def _engine(kind, slots=2):
     kw = dict(ENGINES[kind])
     if kind == "speculative":
         kw["draft_model"] = tiny_gpt()
-    return ServingEngine(tiny_gpt(), max_slots=2, max_len=32,
+    return ServingEngine(tiny_gpt(), max_slots=slots, max_len=32,
                          prefill_buckets=(8,), decode_chunk=2, **kw)
+
+
+def _program(events):
+    """The program's own spans: the collector's pauses (`python_gc`) land
+    in the ring whenever Python collects, in any test."""
+    return [ev for ev in events if ev[NAME] != "python_gc"]
 
 
 def _drive(eng, tracer):
@@ -69,7 +75,7 @@ def _drive(eng, tracer):
     while eng.has_work():
         before = len(tracer)
         eng.step()
-        steps.append(tracer.events()[before:])
+        steps.append(_program(tracer.events()[before:]))
     return resps, steps
 
 
@@ -94,7 +100,8 @@ def test_engine_steps_leave_the_phase_spans_and_nothing_per_token(kind):
     try:
         tracer.clear()
         resps, steps = _drive(eng, tracer)
-        assert eng.step() is False and len(tracer) == sum(map(len, steps))
+        assert eng.step() is False
+        assert len(_program(tracer.events())) == sum(map(len, steps))
     finally:
         eng.close()
     assert [len(r.tokens()) for r in resps] == [11, 3, 2]
@@ -158,6 +165,27 @@ def test_engine_steps_leave_the_phase_spans_and_nothing_per_token(kind):
     assert quiet >= 1
     assert sum(ev[ARGS]["tokens"] for ev in events
                if ev[NAME] == "serving_deliver") == 16 - 3  # less the firsts
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_an_admission_records_the_runs_already_seated(kind):
+    """`seated` on `serving_admit`: the runs that wait while the prompt
+    runs, the one being admitted not counted."""
+    tracer = obs.get_tracer()
+    eng = _engine(kind, slots=3)
+    try:
+        tracer.clear()
+        first = eng.submit([1, 2, 3], 11)
+        eng.step()                       # into an empty engine
+        rest = [eng.submit([1, 2, 3, 4], 3), eng.submit([2, 2], 2)]
+        eng.step()                       # two more beside the first
+        while eng.has_work():
+            eng.step()
+    finally:
+        eng.close()
+    assert [len(r.tokens()) for r in [first] + rest] == [11, 3, 2]
+    assert [ev[ARGS]["seated"] for ev in tracer.events()
+            if ev[NAME] == "serving_admit"] == [0, 1, 2]
 
 
 def test_a_failed_request_ends_its_timeline_too():
