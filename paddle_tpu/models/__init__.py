@@ -1,7 +1,7 @@
 """Flagship model zoo: BERT / GPT-2 / ERNIE pretraining models for the
 BASELINE.md benchmark configs (#3 BERT DP, #4 ERNIE sharding, #5 GPT-2 PP),
 and the decoders the serving benchmark runs (Cohere2-MoE, DeepSeek-V3's
-family, EvaByte)."""
+family, EvaByte, Nemotron-H)."""
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertPretrainingCriterion,
                    BertForSequenceClassification,
@@ -21,3 +21,5 @@ from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Block,  # noqa: F401
                           DeepseekV3ForCausalLM)
 from .evabyte import (EvaByteConfig, EvaByteBlock,  # noqa: F401
                       EvaByteForCausalLM)
+from .nemotron_h import (NemotronHConfig, NemotronHBlock,  # noqa: F401
+                         NemotronHForCausalLM)

@@ -15,7 +15,10 @@ grouped products made, rows they went over) as device values a serving
 program returns with its tokens.  Where a few rows' picks cover the held
 experts anyway (a decode step of 48 slots over 64 experts), it takes every
 row through every held expert in one batched product a matrix instead
-(`_batched_form`): the same bytes, no groups.
+(`_batched_form`): the same bytes, no groups.  Its experts are gated
+(`(silu(x Wg) * (x Wu)) Wd`) or, with no gate, relu squared (`relu(x W1)^2
+W2`), and they may take another input than the router reads (a latent the
+layer projects its tokens into).
 """
 from __future__ import annotations
 
@@ -101,7 +104,7 @@ moe_ffn = defop("moe_ffn")(_raw_moe_ffn)
 # grouped products one chunk of `moe_ffn_held` makes (gate, up, down): a call
 # returns how many it made, a serving span reports them as `expert_products`,
 # and a reader of the device trace knows how many events of the product a
-# program call holds
+# program call holds.  Experts with no gate make one fewer.
 GROUPED_PRODUCTS = 3
 # rows of sorted picks a chunk's grouped products go over: a call whose
 # picks (tokens x top_k) fit makes one chunk, a longer one walks the picks
@@ -140,30 +143,43 @@ def _batched_form(t, top_k, n_experts):
     return cover >= _BATCHED_COVER and t <= _BATCHED_ROWS
 
 
+def _hidden(dot, x, w_gate, w_up):
+    """An expert's hidden row, float32: `silu(x Wg) * (x Wu)`, or with no
+    gate `relu(x W1)^2`."""
+    if w_gate is None:
+        return jnp.square(jax.nn.relu(dot(x, w_up)))
+    return jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
+
+
 def _batched_products(x, w_gate, w_up, w_down, weight):
     """Every row of x (T, d_model) through every held expert, the expert
     axis the batch of each product and the weights read where they lie;
     `weight` (T, n_held) float32, a row's share at each held expert it
     picked and 0 elsewhere, weighs the float32 results.  -> (T, d_model)
     float32."""
-    xe = jnp.broadcast_to(x, (w_gate.shape[0],) + x.shape)
+    xe = jnp.broadcast_to(x, (w_up.shape[0],) + x.shape)
     dot = lambda a, w: jax.lax.dot_general(  # noqa: E731
         a, w.astype(a.dtype), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     with jax.named_scope("moe_expert_product"):
-        h = (jax.nn.silu(dot(xe, w_gate)) * dot(xe, w_up)).astype(x.dtype)
+        h = _hidden(dot, xe, w_gate, w_up).astype(x.dtype)
         return jnp.sum(dot(h, w_down) * weight.T[:, :, None], axis=0)
 
 
 def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
-                      top_k=8, valid=None, select_bias=None, scale=None):
+                      top_k=8, valid=None, select_bias=None, scale=None,
+                      expert_x=None):
     """The part of a routed FFN that the experts HELD HERE give; nothing is
     dropped.  Returns (y, picks_here, experts_hit, products, rows).
 
     x: (T, d_model); router_w: (d_model, E) over ALL E experts; w_gate,
     w_up: (n_held, d_model, d_hidden), w_down: (n_held, d_hidden, d_model):
     the weights of the experts whose ids `experts_held` lists (a tuple, in
-    the leaves' order), gated form `(act(x Wg) * (x Wu)) Wd` with silu.
+    the leaves' order), gated form `(act(x Wg) * (x Wu)) Wd` with silu;
+    `w_gate` None: the experts have no gate and take `relu(x Wu)^2 Wd`
+    (two products a chunk, not three).  `expert_x` (T, d_expert): what the
+    experts take where it is not what the router reads (a latent the
+    caller projected x into; `y` is then d_expert wide).
     Every token picks its `top_k` experts among all E by the sigmoid of the
     router's score (float32) and weighs them by it over the sum of the k.
     `select_bias` (E,), float32: added to the scores for the CHOICE alone
@@ -194,7 +210,7 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     products made (`GROUPED_PRODUCTS` a chunk); rows, rows they went over
     (a chunk's rows x chunks).
     """
-    t, d_model = x.shape
+    t = x.shape[0]
     n_experts = router_w.shape[-1]
     n_held = len(experts_held)
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -217,6 +233,8 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     if valid is not None:
         place = jnp.where(valid[:, None], place, n_held)
     place = place.reshape(-1)                                      # (T*K,)
+    if expert_x is not None:
+        x = expert_x
     held_at = place[:, None] == jnp.arange(n_held)[None, :]   # (T*K, n_held)
     sizes = jnp.sum(held_at, axis=0, dtype=jnp.int32)              # (n_held,)
     batched = _batched_form(t, top_k, n_experts)
@@ -249,12 +267,14 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
             dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
                 a, w.astype(a.dtype), part,
                 preferred_element_type=jnp.float32)
-            h = jnp.where(live, jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up),
+            h = jnp.where(live, _hidden(dot, xs, w_gate, w_up),
                           0.0).astype(x.dtype)
             out = jnp.where(live, dot(h, w_down) * share[picks][:, None], 0.0)
         return y.at[token].add(out)
 
-    y = jnp.zeros((t, d_model), jnp.float32)
+    # products a chunk: gate, up and down, or up and down with no gate
+    made = GROUPED_PRODUCTS if w_gate is not None else GROUPED_PRODUCTS - 1
+    y = jnp.zeros((t, x.shape[1]), jnp.float32)
     if chunks == 1:
         y, walked = add_chunk(0, y), jnp.int32(1)
     else:
@@ -262,7 +282,7 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
         y = jax.lax.fori_loop(0, walked, add_chunk, y)
     return (y.astype(x.dtype), here.astype(jnp.int32),
             jnp.sum(sizes > 0, dtype=jnp.int32),
-            (GROUPED_PRODUCTS * walked).astype(jnp.int32),
+            (made * walked).astype(jnp.int32),
             (rows * walked).astype(jnp.int32))
 
 

@@ -73,13 +73,17 @@ class HeldExperts(Layer):
     `dtype`; the router stays float32 (its scores pick the experts).
     `selection_bias=True` adds the float32 leaf `e_score_correction_bias`
     (num_experts,), zeros: it moves which experts a token picks and not
-    their weights; `scale` multiplies every weight.
+    their weights; `scale` multiplies every weight.  `gated=False`: experts
+    of two leaves, `relu(x up)^2 down`, and no `gate`.  `d_expert`: the
+    width the experts take and give where it is not the router's
+    `d_model` (a latent: `functional.moe_ffn_held`'s `expert_x`).
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  top_k: int, experts_held=None, dtype=None,
                  std: float = 0.02, selection_bias: bool = False,
-                 scale: float = None):
+                 scale: float = None, gated: bool = True,
+                 d_expert: int = None):
         super().__init__()
         self.experts_held = tuple(range(num_experts) if experts_held is None
                                   else (int(e) for e in experts_held))
@@ -100,9 +104,10 @@ class HeldExperts(Layer):
             return p
 
         self.router = leaf((d_model, num_experts), "float32")
-        self.gate = leaf((n, d_model, d_hidden), dtype)
-        self.up = leaf((n, d_model, d_hidden), dtype)
-        self.down = leaf((n, d_hidden, d_model), dtype)
+        width = d_model if d_expert is None else d_expert
+        self.gate = leaf((n, width, d_hidden), dtype) if gated else None
+        self.up = leaf((n, width, d_hidden), dtype)
+        self.down = leaf((n, d_hidden, width), dtype)
         self.e_score_correction_bias = self.create_parameter(
             (num_experts,), dtype="float32",
             default_initializer=I.Constant(0.0)) if selection_bias else None

@@ -127,7 +127,11 @@ token at ``pos``.  A shorter one is its model's: a window layer's RING,
 written at ``pos % rows`` (Cohere's window layers), or a SUMMARY LEAF, one
 pooled row a chunk of tokens written at ``pos // chunk`` beside a ring of
 exact rows (`models.EvaByteForCausalLM`: four leaves a layer, ring keys and
-values of 2048 rows and summary keys and values of ``max_len / 16``).  Who
+values of 2048 rows and summary keys and values of ``max_len / 16``).  A
+leaf may have NO row axis: ``(B, *state)``, a state of fixed size that a
+prompt writes whole and a decode step rewrites in place
+(`models.NemotronHForCausalLM`'s Mamba layers: the SSM state and the conv
+state; a layer that holds nothing has no entry).  Who
 writes where in a decode step is the model's `forward_decode`; the fixed
 view's `write_prompt` overwrites each leaf over ITS OWN length with what
 the prompt's forward hands back for it: a leaf no longer than the pool's is
@@ -138,7 +142,9 @@ return the model's int32 counts with the tokens (``out["counts"]``), summed
 over layers (and a decode call's steps) on the device.  **A model names
 them** (`serving_count_names`, e.g. ``("kv_rows_live", "kv_rows_pool",
 "kv_rows_summary")``) and the engine carries each into the `serving_admit`
-/ `serving_decode` span's args under its name, unread.  A model that names
+/ `serving_decode` span's args under its name, unread; a model that names
+the routed family's five first (`_ROUTED_COUNTS`) has those read as the
+routed family's are, and the rest carried.  A model that names
 nothing is of the routed family and its counts are, by position, ``[picks
 on held experts, picks in all, held experts hit, grouped products made,
 rows those products went over]`` (a prompt's routed layer walks the picks
@@ -307,6 +313,12 @@ def _default_buckets(max_len: int):
         b *= 2
     buckets.append(max_len)
     return tuple(buckets)
+
+
+# the routed family's counts, in the order a program returns them: a model
+# that names its counts may put these first (`_count_model`)
+_ROUTED_COUNTS = ("routed_here", "routed_all", "experts_hit",
+                  "expert_products", "expert_rows")
 
 
 class _SlotRun:
@@ -1035,7 +1047,9 @@ class ServingEngine:
         # and what a slot holds at a position, where the model says it
         self._rows_held = getattr(model, "serving_rows_held", None)
         # rows of each leaf of each layer: shorter than the pool = a
-        # window's ring, or rows on a clock of the model's own
+        # window's ring, or rows on a clock of the model's own (read only
+        # where the model does not say what a slot holds: a model with a
+        # leaf that has no row axis, a state of fixed size, says it)
         self._leaf_rows = [tuple(int(leaf.shape[1]) for leaf in layer)
                            for layer in self._pools]
         # what `serving_kv_rows{kind}` calls them: the model's word for its
@@ -1063,11 +1077,17 @@ class ServingEngine:
     def _count_model(self, span_args: dict, counts):
         """A program's int32 counts into its span's args.  A model that
         names them (`serving_count_names`) is carried unread: each count
-        under its name, nothing else known of it.  A model that does not
-        is of the routed family, whose counts feed counters too."""
+        under its name, nothing else known of it, but for the routed
+        family's five where it names them first (`_ROUTED_COUNTS`), which
+        feed the counters too.  A model that names nothing is of the routed
+        family, whose counts feed counters too."""
         if self._count_names is None:
             return self._count_routed(span_args, counts)
-        span_args.update(zip(self._count_names, (int(c) for c in counts)))
+        named = dict(zip(self._count_names, (int(c) for c in counts)))
+        if self._count_names[:5] == _ROUTED_COUNTS:
+            self._count_routed(span_args, [named.pop(k)
+                                           for k in _ROUTED_COUNTS])
+        span_args.update(named)
 
     def _count_routed(self, span_args: dict, counts):
         """A program's routed counts [here, all, experts hit, grouped

@@ -460,13 +460,14 @@ class FixedKVView:
     latent layer's `(…, 512)` beside `(…, 64)` another, a ring's `(k, v)`
     beside a pair of pooled summaries, a row a chunk, a third.  A leaf
     holds a slot's rows `pool_len` long, or as a window's ring written at
-    ``pos % rows``, or on whatever clock its model keeps (`write_prompt`
+    ``pos % rows``, or on whatever clock its model keeps, or is a state of
+    fixed size with no row axis at all, `(slots, *state)` (`write_prompt`
     takes a prompt's leaf as the model hands it back: no longer than the
-    pool's and already in the pool's layout, or longer and token-indexed,
-    which is a ring's case).  The pool IS the
-    contiguous view the model runs against, so `open` and `publish` are the
-    identity; a prompt's rows go to its slot's row.  Nothing here knows how
-    many leaves a layer has or their rank."""
+    pool's and already in the pool's layout, which a state's leaf always
+    is, or longer and token-indexed, which is a ring's case).  The pool IS
+    the contiguous view the model runs against, so `open` and `publish` are
+    the identity; a prompt's rows go to its slot's row.  Nothing here knows
+    how many leaves a layer has or their rank."""
 
     # -- host side: what a call's `inputs` say of the layout -----------------
     def prompt_inputs(self, slot: Optional[int] = None) -> Dict:
@@ -494,7 +495,8 @@ class FixedKVView:
         for layer_pool, layer_kv in zip(pools, kv):
             # each leaf by its OWN length; a layer's leaves of one length
             # (a `(k, v)` pair, a ring's pair beside a summary pair) are
-            # written together
+            # written together.  A state's leaf (no row axis) comes back in
+            # the pool's own shape a slot and is written whole
             by_rows = {}
             for i, leaf in enumerate(layer_pool):
                 by_rows.setdefault(leaf.shape[1], []).append(i)

@@ -355,7 +355,9 @@ def _engine_programs(one_chip, arch_name, config, traffic, narrow, bucket,
     by `narrow`, the model's config is then set to the published one (a
     forward reads its sizes from it, and `functional_call` swaps the
     leaves), and the programs are lowered on shapes alone: the benchmark's
-    layout gives the weights', `pool_leaves(d, mix)` a cache layer's."""
+    layout gives the weights', `pool_leaves(d, mix)` a cache layer's (None:
+    the model's own `gen_fixed_cache` at the published sizes, a layer's
+    leaves of other shapes and dtypes)."""
     import importlib
     import sys
     from paddle_tpu.serving import ServingEngine
@@ -389,11 +391,18 @@ def _engine_programs(one_chip, arch_name, config, traffic, narrow, bucket,
             name = arch.program_name(name, layer)
             state[name] = shape(leaf[0], eng._state[name].dtype)
     assert set(state) == set(eng._state)
-    pools = [tuple(shape(s, jnp.bfloat16)
-                   for s in pool_leaves(d, mix))] * d["L"]
+    if pool_leaves is None:
+        pools = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, a.dtype), jax.eval_shape(
+                lambda: model.gen_fixed_cache(mix["max_slots"],
+                                              mix["max_len"])))
+    else:
+        pools = [tuple(shape(s, jnp.bfloat16)
+                       for s in pool_leaves(d, mix))] * d["L"]
     programs = {name: (fn, inputs) for name, fn, inputs in eng._programs()}
-    held = (sum(math.prod(leaf.shape) * 2 for layer in pools
-                for leaf in layer) + arch.param_count(d) * 2)
+    held = (sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                for layer in pools for leaf in layer)
+            + arch.param_count(d) * 2)
 
     def compile_(name):
         fn, inputs = programs[name]
@@ -536,3 +545,39 @@ def test_evabyte_longest_prefill_program_compiles_and_fits(
     for w in range(16):
         assert sum(f"bf16[1,{2048 + 128 * w},4096]" in c
                    for c in calls) == 8
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    """compile(name) of Nemotron-H's `decode` and `prefill_b8192` at 96
+    slots x 8192 positions (5 Mamba layers' state leaves, one attention
+    layer's rows), and the bytes held."""
+    with _engine_programs(
+            one_chip, "nemotron_h", "nemotron3-super-1of4-11of88.json",
+            "flood_reason_8k.json",
+            dict(hidden_size=64, mamba_num_heads=16, mamba_head_dim=8,
+                 n_groups=2, ssm_state_size=8, moe_intermediate_size=32,
+                 moe_latent_size=16, moe_shared_expert_intermediate_size=32,
+                 vocab_size=256, num_attention_heads=4, head_dim=16), -1,
+            None) as programs:
+        yield programs
+
+
+@pytest.mark.parametrize("name, temp_most", [("decode", 0.5e9),
+                                             ("prefill_b8192", 3.0e9)])
+def test_nemotron_programs_compile_and_fit(nemotron_programs, name,
+                                           temp_most, record_property):
+    """The decode step over 96 slots' float32 SSM state (2.01 GB, no row
+    axis) beside 9.30 GB of weights, and the longest prompt's chunked scan:
+    each program's temporaries beside what the engine holds stay inside
+    the chip, and no copy of a whole state leaf is made."""
+    compile_, held = nemotron_programs
+    compiled = compile_(name)
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert 12.0e9 < held < 12.3e9
+    assert held + mem.temp_size_in_bytes < HBM
+    assert mem.temp_size_in_bytes < temp_most
+    assert not [line for line in compiled.as_text().splitlines()
+                if re.search(r"f32\[96,128,64,128\]\S* copy\(",
+                             line.strip())]
