@@ -14,12 +14,14 @@ Two forms over one set of weights:
 
 * a prompt (`forward`, `forward_prefill`): windows are independent given
   the summaries, so a bucket of W windows is W causal problems.  Window w's
-  queries go in BEHIND `128 w` rows of padding and its keys behind its `128
-  w` summaries: under the plain causal mask a query then sees every
-  summary and its window's keys up to itself, which is the flash kernel's
-  forward as it is (`ops.flash_attention.flash_attention_grouped`; the
-  chunked XLA form, `cohere_moe.attend_in_chunks`, where it refuses).  The
-  summaries of all the prompt's chunks are computed once.
+  keys go in behind its `128 w` summaries and its queries are the last of
+  those positions: under the causal mask with the diagonal `128 w` to the
+  right a query then sees every summary and its window's keys up to
+  itself, which is the flash kernel's forward as it is
+  (`ops.flash_attention.flash_attention_grouped`; where it refuses, the
+  chunked XLA form, `cohere_moe.attend_in_chunks`, with the queries behind
+  `128 w` rows of zeros).  The summaries of all the prompt's chunks are
+  computed once.
 * a decode step (`forward_decode`): the token's key and value go into the
   ring at `n % window`, the query attends the ring's rows `r <= n %
   window` (rows beyond hold the window before) beside the summary rows `c
@@ -211,12 +213,12 @@ class EvaAttention(Layer):
             for w0 in range(0, s, win):
                 w1 = min(w0 + win, s)
                 seen = _summaries_seen(w0, win, chunk)
+                # a batch of one where the rows are joined: the kernel's
+                # zero rows at the tail then join them in the same pass
                 behind = lambda a, b: jnp.concatenate(  # noqa: E731
-                    [a[:seen], b[w0:w1]])
-                padding = jnp.zeros((seen,) + q.shape[1:], q.dtype)
+                    [a[None, :seen], b[None, w0:w1]], axis=1)
                 out.append(self._attend_window(
-                    behind(padding, q), behind(k_hat, k),
-                    behind(v_hat, v))[seen:])
+                    q[None, w0:w1], behind(k_hat, k), behind(v_hat, v)))
             attn = jnp.concatenate(out)
             rows = min(win, k.shape[0])
             at = (prompt_len - 1) // win * win
@@ -225,16 +227,22 @@ class EvaAttention(Layer):
         return self._out(attn.astype(h.dtype)), (*ring, k_hat, v_hat)
 
     def _attend_window(self, q, k, v):
-        """q, k, v (N, heads, head_dim) under the causal mask -> (N, heads,
-        head_dim): the flash kernel's forward on the chip, the chunked XLA
-        form where it refuses (not a TPU, N no multiple of 128)."""
-        out = flash_attention_grouped(q[None], k[None], v[None])
+        """A window's queries q (1, N, heads, head_dim), the last N of the
+        positions of k, v (1, M, heads, head_dim), under the causal mask ->
+        (N, heads, head_dim): query i sees the keys up to i + M - N.  The
+        flash kernel's forward on the chip; where it refuses (not a TPU, a
+        length no multiple of 128) the chunked XLA form, the queries behind
+        M - N rows of zeros."""
+        out = flash_attention_grouped(q, k, v)
         if out is not None:
             _ATTENTION_PATH.labels(path="flash").inc()
             return out[0]
         _ATTENTION_PATH.labels(path="xla").inc()
+        q, k, v = q[0], k[0], v[0]
+        front = k.shape[0] - q.shape[0]
+        q = jnp.concatenate([jnp.zeros((front,) + q.shape[1:], q.dtype), q])
         return attend_in_chunks(q[:, :, None], k, v,
-                                self._scale).reshape(q.shape)
+                                self._scale).reshape(q.shape)[front:]
 
     # ------------------------------------------------------- a decode step
     def forward_decode(self, h, ring_k, ring_v, sum_k, sum_v, pos,

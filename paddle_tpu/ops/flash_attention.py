@@ -47,8 +47,12 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   the blocks between go unmasked.  A window that holds the whole sequence is
   the causal form, decided at trace time.  With `r` = 1 and no window every
   one of these branches is decided at trace time and the kernel is the
-  training calls' kernel, equation for equation.  No backward is built for
-  either form: a gradient raises.
+  training calls' kernel, equation for equation.  Its queries may be the
+  last rows of a longer run of keys (the diagonal then starts to the right,
+  as in the training kernel), and a length past 512 that 512 does not
+  divide takes zero rows at its tail up to the next multiple, so that its
+  blocks are 512 all the same.  No backward is built for either form: a
+  gradient raises.
 - The backward is the FlashAttention-2 recompute scheme: the forward saves
   only O and the per-row logsumexp; one merged backward kernel owns a k
   block per grid step, holds Q, dO, lse and delta of the head group
@@ -121,7 +125,9 @@ _VMEM_MOST = 96 << 20
 # (the loop ends at the diagonal and only its blocks are masked); from
 # `flash_attention_grouped`: `grouped` (query heads share a KV head, causal),
 # `grouped_window` (and the walk starts at a window's far edge inside the
-# sequence), `window_blocks` (a window, one KV head a query head)
+# sequence), `window_blocks` (a window, one KV head a query head),
+# `padded_blocks` (a length past 512 that 512 does not divide, walked in
+# 512-row blocks behind zero rows at its tail, whatever else it is)
 _FORM_TAKEN = counter(
     "flash_attention_form_total",
     "flash kernel calls traced, by the form the shapes chose", ("form",))
@@ -261,16 +267,28 @@ def _free_mesh_axes():
             if a not in mesh.manual_axes}
 
 
+def _whole_blocks(size: int) -> int:
+    """`size` rows, or past one block of 512 the next multiple of 512: the
+    rows a forward-only call walks, so that its blocks are 512 whatever the
+    length (on v5e a tile of scores costs about twice as much in blocks of
+    128 rows as in blocks of 512: PERF.md section 5)."""
+    return size if size <= 512 else -(-size // 512) * 512
+
+
 def flash_attention_grouped(q, k, v, window=None):
-    """Causal self-attention of a prompt, FORWARD ONLY: q (batch, seq, Hq,
-    head_dim) against k, v (batch, seq, Hkv, head_dim), Hq a multiple of
+    """Causal attention of a prompt, FORWARD ONLY: q (batch, sq, Hq,
+    head_dim) against k, v (batch, sk, Hkv, head_dim), Hq a multiple of
     Hkv, each KV head read where it lies for the query heads that share it.
-    `window` (static): a query sees its own position and the `window - 1`
-    before it; None, or a window that holds the whole sequence, is the
-    causal form.  Returns q's layout, or None where the kernel does not
-    apply (not a TPU, a length that is no multiple of 128, grouped heads
-    narrower than 128 lanes, a multi-device program).  A gradient asked of
-    it raises: the backward kernel knows neither form."""
+    The queries are the LAST `sq` of the `sk` positions (sq <= sk): query i
+    sees keys up to `i + sk - sq`.  `window` (static): a query sees its own
+    position and the `window - 1` before it; None, or a window that holds
+    the whole sequence, is the causal form.  A length past 512 that 512
+    does not divide is walked in 512-row blocks: q, k and v take zero rows
+    at their tail, which lie past every real query's diagonal, and the
+    padded queries' rows are dropped.  Returns q's layout, or None where
+    the kernel does not apply (not a TPU, a length that is no multiple of
+    128, grouped heads narrower than 128 lanes, a multi-device program).  A
+    gradient asked of it raises: the backward kernel knows neither form."""
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     if not _available() or d not in (64, 128, 256):
@@ -285,18 +303,31 @@ def flash_attention_grouped(q, k, v, window=None):
     hg = _head_group(r, d) if r > 1 else _head_group(h, d)
     if window is not None and window >= sk:
         window = None
-    most = (_forward_bytes(sq, sk, d, hg, q.dtype.itemsize) if r > 1
-            else _resident_bytes(sq, sk, hg * d, q.dtype.itemsize))
+    rows_q, rows_k = _whole_blocks(sq), _whole_blocks(sk)
+    most = (_forward_bytes(rows_q, rows_k, d, hg, q.dtype.itemsize) if r > 1
+            else _resident_bytes(rows_q, rows_k, hg * d, q.dtype.itemsize))
     if most > _VMEM_MOST:
         return None
-    if r > 1:
+    if (rows_q, rows_k) != (sq, sk):
+        form = "padded_blocks"
+    elif r > 1:
         form = "grouped" if window is None else "grouped_window"
     else:
         form = _form(True, sk) if window is None else "window_blocks"
     _FORM_TAKEN.labels(form=form).inc()
-    out = _flash_forward_only(
-        q.reshape(b, sq, h * d), k.reshape(b, sk, hkv * d),
-        v.reshape(b, sk, hkv * d), h, hg, hkv, window)
+
+    def tail(x, rows):
+        # zero rows joined in the caller's layout, before the heads are
+        # folded into the lanes: where x is the caller's own concatenation
+        # XLA makes the two one, and the rows cost no pass of their own
+        if rows != x.shape[1]:
+            x = jnp.concatenate([x, jnp.zeros(
+                (b, rows - x.shape[1]) + x.shape[2:], x.dtype)], axis=1)
+        return x.reshape(b, rows, -1)
+    out = _flash_forward_only(tail(q, rows_q), tail(k, rows_k),
+                              tail(v, rows_k), h, hg, hkv, window, sk - sq)
+    if rows_q != sq:
+        out = out[:, :sq]
     return out.reshape(b, sq, h, d)
 
 
@@ -652,9 +683,14 @@ def _mask_inputs(bias, qseg, kseg, blk_q, blk_k, q_index, k_index):
 
 @_traced_once(7)
 def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg,
-              kv_heads=None, window=None):
+              kv_heads=None, window=None, causal_off=None):
+    """`causal_off`: how far the causal diagonal lies to the right of the
+    scores' main one, the keys' length less the queries' before either
+    took rows at its tail; None: `sk - sq`."""
     b, sq, hd = q.shape
     sk = k.shape[1]
+    if causal_off is None:
+        causal_off = sk - sq
     d = hd // heads
     gd = hg * d
     n_hg = heads // hg
@@ -689,7 +725,7 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg,
         causal=causal, dropout_p=dropout_p,
         drop_tile=(_block(sq, fine=causal), _block(sk, fine=causal)),
         written_out=written_out, blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
-        scale=1.0 / math.sqrt(d), causal_off=sk - sq, heads=heads, hg=hg,
+        scale=1.0 / math.sqrt(d), causal_off=causal_off, heads=heads, hg=hg,
         shared_kv=shared_kv, window=window)
 
     o, lse = pl.pallas_call(
@@ -927,14 +963,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # (training a routed layer: ROADMAP C3), and a gradient says so by name
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_forward_only(q, k, v, heads, hg, kv_heads, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_forward_only(q, k, v, heads, hg, kv_heads, window, causal_off):
     o, _ = _fwd_impl(q, k, v, None, None, None, jnp.zeros((1,), jnp.int32),
-                     True, 0.0, heads, hg, kv_heads, window)
+                     True, 0.0, heads, hg, kv_heads, window, causal_off)
     return o
 
 
-def _forward_only_fwd(q, k, v, heads, hg, kv_heads, window):
+def _forward_only_fwd(q, k, v, heads, hg, kv_heads, window, causal_off):
     raise NotImplementedError(
         "flash_attention_grouped is forward only: the flash backward kernel "
         "takes neither grouped KV heads nor a window (expand the KV heads "

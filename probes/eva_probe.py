@@ -9,9 +9,11 @@
            matrices beside them).  A decode loop's fusions carry no scope in
            a trace (PERF.md, PR 34), so this probe stands where a metric
            would never report.
-  prefill  one window's attention behind `128 w` summaries (w = 0, 7, 15:
-           2048, 2944 and 3968 rows under the causal mask) through the
-           flash kernel's forward against the chunked XLA form, a whole
+  prefill  one window's attention: its 2048 queries against its 2048 keys
+           behind `128 w` summaries (w = 0, 7, 15 by default: 2048, 2944 and
+           3968 keys under the causal mask, `--windows` to choose) through
+           the model's call into the flash kernel's forward against the
+           chunked XLA form, a whole
            layer's windowed attention for a bucket of 8192 and of 32768,
            and one whole BLOCK (attention and the gated MLP) over each of
            the cell's five buckets: a prompt is eight of them and the head.
@@ -280,17 +282,19 @@ def main(argv=None):
 
     # ---- prefill: one window behind its summaries, flash against XLA
     def window(q, k, v):
-        return attn._attend_window(q, k, v)
+        return attn._attend_window(q[None], k[None], v[None])
 
     def chunks(q, k, v):
-        return M.attend_in_chunks(q[:, :, None], k, v,
-                                  attn._scale).reshape(q.shape)
+        front = jnp.zeros((k.shape[0] - q.shape[0],) + q.shape[1:], q.dtype)
+        q = jnp.concatenate([front, q])
+        return M.attend_in_chunks(q[:, :, None], k, v, attn._scale).reshape(
+            q.shape)[front.shape[0]:]
 
     for w in (int(n) for n in args.windows.split(",")):
         n = win + w * (win // chunk)
         ks = jax.random.split(jax.random.PRNGKey(n), 3)
-        q, k, v = (jax.random.normal(kk, (n, heads, hd), jnp.bfloat16)
-                   for kk in ks)
+        q, k, v = (jax.random.normal(kk, (rows, heads, hd), jnp.bfloat16)
+                   for kk, rows in zip(ks, (win, n, n)))
         flash, xla = jax.jit(window), jax.jit(chunks)
         a, c = flash(q, k, v), xla(q, k, v)
         pairs = win * (win + 1) / 2 + win * (n - win)

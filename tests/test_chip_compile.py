@@ -528,9 +528,11 @@ def test_evabyte_longest_prefill_program_compiles_and_fits(
         evabyte_programs, monkeypatch, record_property):
     """The 32768 bucket: 16 windows a layer through the flash kernel's
     forward, each ONE custom call named by its scope (what
-    `eva_prefill_attention_roofline.serve_flood_longctx` reads), 2048 + 128
-    w rows long; the program hands back leaves no longer than the pool's
-    and fits beside it."""
+    `eva_prefill_attention_roofline.serve_flood_longctx` reads): the
+    window's own 2048 queries against its 128 w summaries and 2048 keys,
+    rounded up to 512 rows by zero rows that the concatenation of summaries
+    and keys writes (no pad of its own in the program); the program hands
+    back leaves no longer than the pool's and fits beside it."""
     monkeypatch.setattr(fa, "_available", lambda: True)
     compile_, held = evabyte_programs
     compiled = compile_("prefill_b32768")
@@ -539,12 +541,24 @@ def test_evabyte_longest_prefill_program_compiles_and_fits(
     assert held + mem.temp_size_in_bytes < HBM
     kernel = _metric_pattern(
         "eva_prefill_attention_roofline.serve_flood_longctx", "kernel")
-    calls = [line.strip() for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    calls = [line.strip() for line in text.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 16 * 8 and all(kernel.search(c) for c in calls)
-    for w in range(16):
-        assert sum(f"bf16[1,{2048 + 128 * w},4096]" in c
-                   for c in calls) == 8
+    # (queries, keys, values) as the kernel takes them
+    operands = [re.search(
+        r"operand_layout_constraints=\{s32\[1\]\S*, (bf16\[[\d,]+\])\S*, "
+        r"(bf16\[[\d,]+\])\S*, (bf16\[[\d,]+\])", c).groups() for c in calls]
+    assert all(c.startswith(c.split(" = ")[0] + " = (bf16[1,2048,4096]")
+               for c in calls)
+    assert all(q == "bf16[1,2048,4096]" and k == v for q, k, v in operands)
+    rows = lambda w: -(-(2048 + 128 * w) // 512) * 512  # noqa: E731
+    assert sorted(k for _, k, _ in operands) == sorted(
+        f"bf16[1,{rows(w)},4096]" for w in range(16) for _ in range(8))
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    assert not [line for line in entry.splitlines()
+                if " pad(" in line and "eva_prefill_attention" in line]
 
 
 @pytest.fixture(scope="module")

@@ -234,6 +234,40 @@ def test_below_one_window_the_layer_is_causal_softmax_attention(tiny):
     assert [r.shape[0] for r in rows] == [32, 32, 8, 8]
 
 
+def test_the_prompts_windows_through_the_kernel_equal_the_xla_form(
+        monkeypatch):
+    """One layer at heads of 64, window 512, chunk 4 (128 summaries a
+    window), three windows: each window's 512 queries go to the flash
+    kernel (the interpreter) against its 512, 640 and 768 keys, the last
+    two walked in 512-row blocks behind zero rows at their tail; the
+    chunked XLA form takes the queries behind zero rows.  The two agree."""
+    from paddle_tpu.models import evabyte as M
+    from paddle_tpu.ops import flash_attention as fa
+    cfg = models.EvaByteConfig(
+        vocab_size=48, hidden_size=128, intermediate_size=64,
+        num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=2,
+        window_size=512, chunk_size=4, initializer_range=0.1,
+        dtype="float32")
+    attn = M.EvaAttention(cfg)
+    s = 3 * cfg.window_size
+    h = jnp.asarray(np.random.RandomState(9).randn(s, 128), jnp.float32)
+    want, _ = attn.forward_seq(h, s - 5)
+    reg = obs.metrics.get_registry()
+    counts = lambda: (  # noqa: E731
+        dict((k[0], v) for k, v in reg.get(
+            "flash_attention_form_total").samples()),
+        reg.get("attention_path_total").value(path="flash"))
+    (forms, flash) = counts()
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    got, _ = attn.forward_seq(h, s - 5)
+    forms_after, flash_after = counts()
+    assert flash_after - flash == 3
+    assert {k: v - forms.get(k, 0) for k, v in forms_after.items()
+            if v != forms.get(k, 0)} == {"one_block": 1, "padded_blocks": 2}
+    assert np.max(np.abs(np.asarray(got) - np.asarray(want))) < TOL
+    assert np.max(np.abs(np.asarray(want))) > 100 * TOL
+
+
 def test_a_decode_step_equals_the_prompts_form(tiny):
     """One layer's attention: a sequence of three windows and a half
     through the windowed form, then the same positions one at a time

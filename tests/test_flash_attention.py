@@ -258,13 +258,14 @@ def test_form_counter_says_which_form_the_shapes_chose(s, causal, form):
 
 def plain_grouped(q, k, v, window=None):
     """Float32 softmax over each query's kept keys, the KV heads repeated:
-    a key is kept at or before the query and, under a window, less than
-    `window` positions before it."""
+    the queries are the last of the keys' positions, and a key is kept at
+    or before the query's position and, under a window, less than `window`
+    positions before it."""
     r = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(x.astype(jnp.float32), r, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
                    precision="highest") / np.sqrt(q.shape[-1])
-    i = jnp.arange(q.shape[1])[:, None]
+    i = jnp.arange(q.shape[1])[:, None] + k.shape[1] - q.shape[1]
     j = jnp.arange(k.shape[1])[None, :]
     keep = j <= i
     if window is not None:
@@ -273,10 +274,11 @@ def plain_grouped(q, k, v, window=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
 
 
-def grouped_qkv(s, r, hkv=1, d=128, dtype=jnp.float32, seed=0):
+def grouped_qkv(s, r, hkv=1, d=128, dtype=jnp.float32, seed=0, sk=None):
+    """q (1, s, hkv r, d) and k, v (1, sk, hkv, d); sk None: s."""
     rng = np.random.RandomState(seed)
-    return tuple(jnp.asarray(rng.standard_normal((1, s, h, d)), dtype)
-                 for h in (hkv * r, hkv, hkv))
+    return tuple(jnp.asarray(rng.standard_normal((1, n, h, d)), dtype)
+                 for n, h in ((s, hkv * r), (sk or s, hkv), (sk or s, hkv)))
 
 
 # S = 128 is one block; 384 is three blocks of 128, walked written out as
@@ -315,6 +317,61 @@ def test_grouped_forward_in_bfloat16_keeps_float32_softmax_state():
     np.testing.assert_allclose(out.astype(jnp.float32),
                                plain_grouped(q, k, v, 200),
                                rtol=2e-2, atol=2e-2)
+
+
+# (sq, sk, window): the queries are the last sq of sk positions.  512 rows
+# or a multiple of 512 are walked as they are; a length past 512 that 512
+# does not divide takes zero rows at its tail up to the next multiple
+OFFSET_CASES = {
+    "q512_k640": (512, 640, None),       # keys padded to 1024, diagonal 128
+    "q512_k896": (512, 896, None),       # keys padded to 1024, diagonal 384
+    "q1024_k1152": (1024, 1152, None),   # a window of EvaByte's in small
+    "q640_k640": (640, 640, None),       # both padded, the diagonal at 0
+    "q384_k640": (384, 640, None),       # 128-row q blocks, 512-row k blocks
+    "q512_k1024": (512, 1024, None),     # no padding: the offset alone
+    "q512_k896_window": (512, 896, 300),
+}
+
+
+@pytest.mark.parametrize("r", [1, 4])
+@pytest.mark.parametrize("case", list(OFFSET_CASES))
+def test_queries_at_the_end_of_longer_keys_are_the_plain_softmax(
+        walk, case, r):
+    """`flash_attention_grouped` with fewer queries than keys: query i sees
+    the keys up to i + sk - sq, whatever rows the wrapper padded at the
+    tail, on both walks.  Against a float32 softmax over the kept keys."""
+    sq, sk, window = OFFSET_CASES[case]
+    q, k, v = grouped_qkv(sq, r, hkv=1 if r == 1 else 2, seed=sk + r,
+                          sk=sk)
+    out = fa.flash_attention_grouped(q, k, v, window=window)
+    assert out is not None and out.shape == q.shape
+    np.testing.assert_allclose(out, plain_grouped(q, k, v, window),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("sq,sk,r,form", [
+    (2048, 2176, 1, "padded_blocks"),    # EvaByte's window 1
+    (2048, 2304, 1, "padded_blocks"),    # window 2
+    (2048, 2560, 1, "causal_blocks"),    # window 4: 512 divides
+    (640, 640, 1, "padded_blocks"),
+    (640, 640, 16, "padded_blocks"),
+    (512, 512, 1, "one_block"),
+    (256, 256, 16, "grouped"),           # the smallest prompt buckets
+    (2048, 2048, 16, "grouped"),
+    (8192, 8192, 16, "grouped"),
+])
+def test_form_counter_says_where_the_tail_padding_engages(sq, sk, r, form):
+    """A call whose lengths took rows at their tail counts `padded_blocks`
+    and nothing else; lengths that 512 divides, and 512 or under, count
+    their old forms alone."""
+    hkv = 32 if r == 1 else 8
+    q = jax.ShapeDtypeStruct((1, sq, hkv * r, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, sk, hkv, 128), jnp.bfloat16)
+    moved, out = counter_moves(
+        "flash_attention_form_total",
+        lambda: jax.eval_shape(fa.flash_attention_grouped, q, kv, kv))
+    assert out.shape == q.shape
+    assert moved == {form: 1}
 
 
 @pytest.mark.parametrize("r,s,window,form", [
