@@ -28,6 +28,7 @@ from ..core.layout import layout_policy  # noqa: F401  (public: jit.layout_polic
 from ..core.recompute import recompute_policy  # noqa: F401  (public: jit.recompute_policy)
 from ..core.tensor import Tensor, no_grad, unwrap
 from ..nn.layer_base import Layer
+from ..observability.tracer import defer_args as _defer_args
 from ..observability.tracer import span as _span
 
 
@@ -414,6 +415,16 @@ class TrainStep:
         # (grad_norm, ok) device scalars from the last guarded call; read
         # them together with the loss to avoid an extra host sync
         self.last_guard = None
+        # a model whose forward returns, as its last output, an int32 vector
+        # of counts names them (`train_count_names`): the step returns the
+        # vector beside the loss, and the `train_step` span carries them,
+        # read from the device only when the tracer's ring is read
+        self._count_names = getattr(model, "train_count_names", None)
+        if self._count_names is not None and self._sparse:
+            raise NotImplementedError(
+                "TrainStep does not carry train_count_names through the "
+                "sparse-gradient step")
+        self.last_counts = None
 
     def _forward_loss(self, state, batch, rng_key=None):
         return forward_loss(self.model, self.loss_fn, state, batch, rng_key,
@@ -497,6 +508,7 @@ class TrainStep:
         with_outputs = self._with_outputs
         guard = self._guard
         accum = self.accum_steps
+        counted = self._count_names is not None
         from ..utils import faults as _faults
 
         def accum_grads(params, step_no, lr, rng_key, batch):
@@ -546,6 +558,10 @@ class TrainStep:
             return jnp.mean(losses), grads, cur
 
         def step(params, opt_state, step_no, lr, rng_key, batch):
+            if accum > 1 and counted:
+                raise NotImplementedError(
+                    "TrainStep(accum_steps>1) does not carry a model's "
+                    "train_count_names out of its micro-batches")
             if accum > 1:
                 loss, grads, carried = accum_grads(
                     params, step_no, lr, rng_key, batch)
@@ -558,7 +574,7 @@ class TrainStep:
                     loss, outs, bufs = forward_loss(
                         self.model, self.loss_fn, full, batch, rng_key,
                         self.amp_level, self.amp_dtype,
-                        return_outputs=with_outputs,
+                        return_outputs=with_outputs or counted,
                         return_buffer_updates=True)
                     return loss, (outs, bufs)
 
@@ -567,6 +583,11 @@ class TrainStep:
                 loss_fn = _recompute.checkpoint(loss_of) if self._remat else loss_of
                 (loss, (outs, bufs)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(train_params)
+                if counted:
+                    # the model's counts, its last output, leave beside the
+                    # loss; its other outputs only where asked for
+                    counts = outs[-1]
+                    outs = outs if with_outputs else ()
             # trace-time gated: identity (zero compiled ops) unless armed
             grads = _faults.poison_grads(grads, step_no)
             new_params, new_opt = apply_updates(
@@ -574,11 +595,12 @@ class TrainStep:
             # running-stat (buffer) updates captured in the traced forward
             # ride the same compiled step — no eager _set_data round-trip
             new_params.update(bufs)
+            tail = (counts,) if counted else ()
             if guard:
                 new_params, new_opt, gnorm, ok = guard_select(
                     params, opt_state, new_params, new_opt, loss, grads)
-                return new_params, new_opt, loss, outs, gnorm, ok
-            return new_params, new_opt, loss, outs
+                return (new_params, new_opt, loss, outs, gnorm, ok) + tail
+            return (new_params, new_opt, loss, outs) + tail
 
         def step_sparse(params, opt_state, step_no, lr, rng_key, batch):
             from ..core import selected_rows as sr
@@ -848,9 +870,12 @@ class TrainStep:
 
     def __call__(self, *batch):
         with _span("train_step",
-                   args={"step": self.optimizer._step_count + 1}), \
+                   args={"step": self.optimizer._step_count + 1}) as sp, \
                 _step_hist().time():
-            return self._call_inner(*batch)
+            loss = self._call_inner(*batch)
+            if self._count_names is not None:
+                _defer_args(sp, self._count_names, self.last_counts)
+            return loss
 
     def _call_inner(self, *batch):
         with _span("train_step_gather_state"):
@@ -872,6 +897,8 @@ class TrainStep:
             out = self._compiled(
                 state, self._opt_state, step_no, lr, rng_key, raw_batch)
         with _span("train_step_write_back"):
+            if self._count_names is not None:
+                *out, self.last_counts = out
             if self._guard:
                 new_state, self._opt_state, loss, outs, gnorm, ok = out
                 self.last_guard = (gnorm, ok)

@@ -1,7 +1,7 @@
 """Flagship model zoo: BERT / GPT-2 / ERNIE pretraining models for the
 BASELINE.md benchmark configs (#3 BERT DP, #4 ERNIE sharding, #5 GPT-2 PP),
 and the decoders the serving benchmark runs (Cohere2-MoE, DeepSeek-V3's
-family, EvaByte, Nemotron-H)."""
+family, EvaByte, Nemotron-H), and Mellum 2, which trains routed."""
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertPretrainingCriterion,
                    BertForSequenceClassification,
@@ -23,3 +23,5 @@ from .evabyte import (EvaByteConfig, EvaByteBlock,  # noqa: F401
                       EvaByteForCausalLM)
 from .nemotron_h import (NemotronHConfig, NemotronHBlock,  # noqa: F401
                          NemotronHForCausalLM)
+from .mellum2 import (Mellum2Config, Mellum2Block,  # noqa: F401
+                      Mellum2ForCausalLM)

@@ -18,14 +18,20 @@ row through every held expert in one batched product a matrix instead
 (`_batched_form`): the same bytes, no groups.  Its experts are gated
 (`(silu(x Wg) * (x Wu)) Wd`) or, with no gate, relu squared (`relu(x W1)^2
 W2`), and they may take another input than the router reads (a latent the
-layer projects its tokens into).
+layer projects its tokens into).  Its router scores by the sigmoid or, as
+Mellum's does, the softmax.  A layer that TRAINS takes its grouped walk
+with a backward (`trainable=True`: `_held_products`, the same chunks walked
+again in reverse, nothing dropped and no capacity), which is what replaces
+`_raw_moe_ffn`'s capacity dispatch for a held share of the experts.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.op import defop
 from ...observability.metrics import counter
@@ -110,6 +116,17 @@ GROUPED_PRODUCTS = 3
 # picks (tokens x top_k) fit makes one chunk, a longer one walks the picks
 # held here in chunks of this many (chosen on the chip: PERF.md, PR 31)
 _CHUNK_ROWS = 2048
+# rows a chunk of the trainable form goes over: its backward adds each
+# chunk's float32 weight gradients (the held stacks' size) into the layer's,
+# so fewer, longer chunks read and write them fewer times, while the last
+# chunk's rows past the held picks are still gone over, so a step's work
+# steps up where a layer's held picks cross a multiple of the chunk.  Read
+# on the chip, ms a step of 8192 tokens x 8 picks (PERF.md, section 6): with
+# half the picks held, 2048 rows 498 to 503, 4096 407 to 411, 6144 366 to
+# 370, 8192 351 to 357; with a quarter held, as the cell holds them, 8192
+# 299 to 312 (two chunks a layer or three, by seed) and 6144 311 to 313
+# (three for any share from 18.75% to 28.1%)
+_TRAIN_CHUNK_ROWS = 3 * _CHUNK_ROWS
 # where the held experts' products are ONE batched product a matrix over
 # every row and every held expert (`_batched_form`), both chosen on the chip
 # (`probes/moe_decode_forms.py`, ms a layer; PERF.md, PR 35).  The share of
@@ -168,7 +185,7 @@ def _batched_products(x, w_gate, w_up, w_down, weight):
 
 def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
                       top_k=8, valid=None, select_bias=None, scale=None,
-                      expert_x=None):
+                      expert_x=None, score="sigmoid", trainable=False):
     """The part of a routed FFN that the experts HELD HERE give; nothing is
     dropped.  Returns (y, picks_here, experts_hit, products, rows).
 
@@ -180,8 +197,10 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     (two products a chunk, not three).  `expert_x` (T, d_expert): what the
     experts take where it is not what the router reads (a latent the
     caller projected x into; `y` is then d_expert wide).
-    Every token picks its `top_k` experts among all E by the sigmoid of the
-    router's score (float32) and weighs them by it over the sum of the k.
+    Every token picks its `top_k` experts among all E by the router's
+    scores (float32) and weighs them by the score over the sum of the k:
+    `score` "sigmoid", the sigmoid of each logit; "softmax", the softmax
+    over all E (renormalised over the k, the full sum cancels).
     `select_bias` (E,), float32: added to the scores for the CHOICE alone
     (`noaux_tc`'s `e_score_correction_bias`); the weights stay the scores
     without it.  `scale`: every weight times this (`routed_scaling_factor`).
@@ -205,6 +224,10 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     or grouped product: every row goes through every held expert in one
     batched product a matrix, weighed in float32 by its share there (0
     where it did not pick the expert); `products` and `rows` are then 0.
+    `trainable`: the grouped walk as a form that reverse mode can go
+    through (`_held_products`: chunks of `_TRAIN_CHUNK_ROWS` forward and,
+    recomputed, backward; the router's gradient comes through the picks'
+    shares), never the batched one; counted as `grouped_train`.
     int32 counts: picks_here, picks that fell on held experts;
     experts_hit, held experts with at least one token; products, grouped
     products made (`GROUPED_PRODUCTS` a chunk); rows, rows they went over
@@ -215,7 +238,12 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     n_held = len(experts_held)
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
     if select_bias is None:
         top, idx = jax.lax.top_k(scores, top_k)                    # (T, K)
     else:
@@ -237,8 +265,9 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
         x = expert_x
     held_at = place[:, None] == jnp.arange(n_held)[None, :]   # (T*K, n_held)
     sizes = jnp.sum(held_at, axis=0, dtype=jnp.int32)              # (n_held,)
-    batched = _batched_form(t, top_k, n_experts)
-    _FORM_TAKEN.labels(form="batched" if batched else "grouped").inc()
+    batched = not trainable and _batched_form(t, top_k, n_experts)
+    _FORM_TAKEN.labels(form="batched" if batched else "grouped_train"
+                       if trainable else "grouped").inc()
     if batched:
         weight = jnp.sum(jnp.where(held_at, share[:, None], 0.0).reshape(
             t, top_k, n_held), axis=1)
@@ -248,38 +277,22 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
                 jnp.int32(0))
     ends = jnp.cumsum(sizes)
     here = ends[-1]
-    rows = min(_CHUNK_ROWS, t * top_k)
+    rows = min(_TRAIN_CHUNK_ROWS if trainable else _CHUNK_ROWS, t * top_k)
     chunks = -(-(t * top_k) // rows)
     # the picks held here first, by expert; padded so that a chunk's slice
     # never runs off the end (rows past `here` add nothing)
     order = jnp.pad(jnp.argsort(place, stable=True).astype(jnp.int32),
                     (0, chunks * rows - t * top_k))
 
-    def add_chunk(c, y):
-        lo = c * rows
-        picks = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-        token = picks // top_k
-        live = (lo + jnp.arange(rows) < here)[:, None]
-        part = (jnp.clip(ends, lo, lo + rows)
-                - jnp.clip(ends - sizes, lo, lo + rows))
-        with jax.named_scope("moe_expert_product"):
-            xs = x[token]
-            dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
-                a, w.astype(a.dtype), part,
-                preferred_element_type=jnp.float32)
-            h = jnp.where(live, _hidden(dot, xs, w_gate, w_up),
-                          0.0).astype(x.dtype)
-            out = jnp.where(live, dot(h, w_down) * share[picks][:, None], 0.0)
-        return y.at[token].add(out)
-
     # products a chunk: gate, up and down, or up and down with no gate
     made = GROUPED_PRODUCTS if w_gate is not None else GROUPED_PRODUCTS - 1
-    y = jnp.zeros((t, x.shape[1]), jnp.float32)
-    if chunks == 1:
-        y, walked = add_chunk(0, y), jnp.int32(1)
+    if trainable:
+        y = _held_products(x, share, w_gate, w_up, w_down, order, sizes,
+                           top_k, rows)
+        walked = _walked(here, rows)
     else:
-        walked = (here + rows - 1) // rows
-        y = jax.lax.fori_loop(0, walked, add_chunk, y)
+        y, walked = _walk(x, share, w_gate, w_up, w_down, order, sizes,
+                          ends, here, top_k, rows, chunks == 1)
     return (y.astype(x.dtype), here.astype(jnp.int32),
             jnp.sum(sizes > 0, dtype=jnp.int32),
             (made * walked).astype(jnp.int32),
@@ -287,3 +300,123 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
 
 
 moe_ffn_held = defop("moe_ffn_held")(_raw_moe_ffn_held)
+
+
+# the names the held experts' products carry, by which a device trace
+# finds their events: the forward's (every form), and the trainable form's
+# backward's (each chunk's three products again, then the six that give dX
+# and dW)
+_SCOPE_FWD, _SCOPE_BWD = "moe_expert_product", "moe_expert_product_bwd"
+
+
+def _chunk(order, ends, here, sizes, top_k, rows, c):
+    """Chunk c of the picks sorted held-first (`order`; `ends`, the
+    cumulative `sizes` of the held experts; `here`, their sum): (its
+    picks, their tokens, which of its rows are held picks (rows, 1), each
+    held expert's rows in it)."""
+    lo = c * rows
+    picks = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+    token = picks // top_k
+    live = (lo + jnp.arange(rows) < here)[:, None]
+    part = (jnp.clip(ends, lo, lo + rows)
+            - jnp.clip(ends - sizes, lo, lo + rows))
+    return picks, token, live, part
+
+
+def _chunk_products(xs, w_gate, w_up, w_down, live, part, weigh):
+    """A chunk's rows of x through its experts' grouped products, float32,
+    each weighed by `weigh` (its pick's share).  A grouped product leaves
+    the rows past its last group undefined on the chip, in either
+    direction: rows that are not held picks give 0."""
+    dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+        a, w.astype(a.dtype), part, preferred_element_type=jnp.float32)
+    h = jnp.where(live, _hidden(dot, xs, w_gate, w_up), 0.0).astype(xs.dtype)
+    return jnp.where(live, weigh(dot(h, w_down)), 0.0)
+
+
+def _walked(here, rows):
+    """Chunks the held picks fill."""
+    return (here + rows - 1) // rows
+
+
+def _walk(x, share, w_gate, w_up, w_down, order, sizes, ends, here, top_k,
+          rows, one_chunk):
+    """The held picks' part of the layer, (T, d) float32, and the chunks
+    walked: a chunk at a time, as many as the held picks fill (a trip
+    count the device computes), or the one chunk with no loop."""
+    def add_chunk(c, y):
+        picks, token, live, part = _chunk(order, ends, here, sizes, top_k,
+                                          rows, c)
+        with jax.named_scope(_SCOPE_FWD):
+            out = _chunk_products(
+                x[token], w_gate, w_up, w_down, live, part,
+                lambda o: o * share[picks][:, None])
+        return y.at[token].add(out)
+
+    y = jnp.zeros((x.shape[0], x.shape[1]), jnp.float32)
+    if one_chunk:
+        return add_chunk(0, y), jnp.int32(1)
+    walked = _walked(here, rows)
+    return jax.lax.fori_loop(0, walked, add_chunk, y), walked
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_products(x, share, w_gate, w_up, w_down, order, sizes, top_k,
+                   rows):
+    """`_walk`'s loop as a form that reverse mode goes through: `order` the
+    picks sorted held-first by expert (padded to whole chunks), `sizes`
+    (n_held,) the picks of each held expert, `share` (T * top_k,) each
+    pick's weight.  Its backward walks the same chunks, recomputing each
+    one's products, and gives x, the shares and the three stacks their
+    gradients."""
+    ends = jnp.cumsum(sizes)
+    return _walk(x, share, w_gate, w_up, w_down, order, sizes, ends,
+                 ends[-1], top_k, rows, False)[0]
+
+
+def _held_fwd(x, share, w_gate, w_up, w_down, order, sizes, top_k, rows):
+    y = _held_products(x, share, w_gate, w_up, w_down, order, sizes, top_k,
+                       rows)
+    return y, (x, share, w_gate, w_up, w_down, order, sizes)
+
+
+def _held_bwd(top_k, rows, res, dy):
+    x, share, w_gate, w_up, w_down, order, sizes = res
+    dy = dy.astype(jnp.float32)
+    ends = jnp.cumsum(sizes)
+    here = ends[-1]
+    f32 = lambda w: None if w is None else jnp.zeros(  # noqa: E731
+        w.shape, jnp.float32)
+
+    def add(c, acc):
+        dx, dshare, dwg, dwu, dwd = acc
+        picks, token, live, part = _chunk(order, ends, here, sizes, top_k,
+                                          rows, c)
+
+        def products(xs, sh, wg, wu, wd):
+            return _chunk_products(xs, wg, wu, wd, live, part,
+                                   lambda o: o * sh[:, None])
+        with jax.named_scope(_SCOPE_BWD):
+            _, vjp = jax.vjp(products, x[token], share[picks], w_gate,
+                             w_up, w_down)
+            gx, gs, gg, gu, gd = vjp(dy[token])
+        gx = jnp.where(live, gx.astype(jnp.float32), 0.0)
+        gs = jnp.where(live[:, 0], gs.astype(jnp.float32), 0.0)
+        dwg = None if dwg is None else dwg + gg.astype(jnp.float32)
+        return (dx.at[token].add(gx), dshare.at[picks].add(gs), dwg,
+                dwu + gu.astype(jnp.float32), dwd + gd.astype(jnp.float32))
+
+    acc = (jnp.zeros(x.shape, jnp.float32),
+           jnp.zeros(share.shape, jnp.float32), f32(w_gate), f32(w_up),
+           f32(w_down))
+    dx, dshare, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, _walked(here, rows), add, acc)
+    cast = lambda g, like: None if like is None else g.astype(  # noqa: E731
+        like.dtype)
+    zero_int = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
+    return (cast(dx, x), cast(dshare, share), cast(dwg, w_gate),
+            cast(dwu, w_up), cast(dwd, w_down), zero_int(order),
+            zero_int(sizes))
+
+
+_held_products.defvjp(_held_fwd, _held_bwd)

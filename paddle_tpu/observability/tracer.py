@@ -42,7 +42,7 @@ try:  # the tracer itself is pure host; without jax it records the ring only
 except ImportError:  # pragma: no cover
     _TraceAnnotation = None
 
-__all__ = ["Span", "Tracer", "get_tracer", "span"]
+__all__ = ["Span", "Tracer", "defer_args", "get_tracer", "span"]
 
 DEFAULT_MAX_EVENTS = 200_000  # bound host memory (same cap profiler.py had)
 GC_SPAN_MIN_S = 1e-3  # a younger generation's collection shorter than this
@@ -139,6 +139,9 @@ class Tracer:
 
     def __init__(self, max_events: int = DEFAULT_MAX_EVENTS):
         self._lock = threading.Lock()
+        # readers resolving deferred args one at a time, without holding
+        # the ring's lock while the device is read
+        self._resolving = threading.Lock()
         self._ring: deque = deque(maxlen=int(max_events))
         self._agg: Dict[str, list] = {}  # name -> [count, total_s]
         self._ids = itertools.count(1)
@@ -197,9 +200,21 @@ class Tracer:
             return {k: list(v) for k, v in self._agg.items()}
 
     def events(self) -> List[tuple]:
-        """Snapshot of the ring: (name, t0, dur, tid, id, parent_id, args)."""
+        """Snapshot of the ring: (name, t0, dur, tid, id, parent_id, args).
+        Args a span deferred (`defer_args`) are read off the device here,
+        once, and stand in its args as numbers from then on."""
         with self._lock:
-            return list(self._ring)
+            ring = list(self._ring)
+        with self._resolving:
+            late = [ev[6] for ev in ring if ev[6] and DEFERRED in ev[6]]
+            if late:
+                import jax
+                values = jax.device_get([a[DEFERRED][1] for a in late])
+                for args, vals in zip(late, values):
+                    args.update(zip(args[DEFERRED][0],
+                                    (v.item() for v in vals)))
+                    del args[DEFERRED]
+        return ring
 
     def __len__(self):
         with self._lock:
@@ -240,6 +255,20 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return path
+
+
+# the key of a span's args under which (names, device vector) wait to be
+# read: a program's counts that come back beside its result, which the host
+# does not wait for while the span is open
+DEFERRED = "_deferred"
+
+
+def defer_args(sp: Span, names, values):
+    """`names` into the span's args, their values the device vector
+    `values` (one number a name), read when the ring is read."""
+    if sp.args is None:
+        sp.args = {}
+    sp.args[DEFERRED] = (tuple(names), values)
 
 
 _default_tracer = Tracer()

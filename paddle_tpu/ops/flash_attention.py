@@ -34,8 +34,8 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   stands between a block's products and its neighbour's softmax; longer
   sequences are walked by `fori_loop`s.  A sequence of one block is the
   same code with nothing to walk and nothing to rescale.
-- **Grouped KV heads and a window, forward only** (`flash_attention_grouped`:
-  a prompt of a served model, causal).  With `r` query heads to a KV head a
+- **Grouped KV heads and a window** (`flash_attention_grouped`: a prompt
+  of a served model, or a trained one's layer, causal).  With `r` query heads to a KV head a
   grid step owns `hg` query heads (`hg` divides `r`) that share ONE KV head:
   K's and V's block is that head's `d` lanes where it lies in the (B, S,
   Hkv*D) tensor, found from the step's index, and the `r / hg` steps of a KV
@@ -51,8 +51,13 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   last rows of a longer run of keys (the diagonal then starts to the right,
   as in the training kernel), and a length past 512 that 512 does not
   divide takes zero rows at its tail up to the next multiple, so that its
-  blocks are 512 all the same.  No backward is built for either form: a
-  gradient raises.
+  blocks are 512 all the same.  Its backward is the merged kernel below
+  walking the same blocks from the keys' side: a grid step owns one k block
+  of the one KV head its `hg` query heads share, the q blocks from the
+  diagonal down to the window's far edge (older ones are skipped, only
+  those the edge crosses pay its compare), and the step's dK and dV are
+  its head group's part, left in float32 and summed over the `r / hg`
+  groups of a KV head outside the kernel.
 - The backward is the FlashAttention-2 recompute scheme: the forward saves
   only O and the per-row logsumexp; one merged backward kernel owns a k
   block per grid step, holds Q, dO, lse and delta of the head group
@@ -92,8 +97,8 @@ heads of 128 filling the array.  Blocks of 512 (256: 39.0 ms; 1024: 19.1),
 `flash_attention_bshd` and `flash_attention_grouped` return None when the
 kernel doesn't apply (wrong platform/shape, or a sequence too long for its
 resident blocks); callers fall back to an XLA form.  The first refuses
-grouped heads (the caller expands them, or takes the XLA form): it is the
-trainable entry, and the backward knows one KV head a query head.
+grouped heads (the caller expands them, or takes the XLA form); the second
+is causal and takes neither bias, segments nor dropout.
 """
 from __future__ import annotations
 
@@ -131,6 +136,11 @@ _VMEM_MOST = 96 << 20
 _FORM_TAKEN = counter(
     "flash_attention_form_total",
     "flash kernel calls traced, by the form the shapes chose", ("form",))
+
+# the names a differentiated `flash_attention_grouped` call's two kernels
+# carry, by which a device trace finds their events (the forward of a call
+# that is not differentiated keeps its caller's name)
+_SCOPE_FWD, _SCOPE_BWD = "grouped_flash_fwd", "grouped_flash_bwd"
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _TN = (((0,), (0,)), ((), ()))   # a.T @ b
@@ -276,7 +286,7 @@ def _whole_blocks(size: int) -> int:
 
 
 def flash_attention_grouped(q, k, v, window=None):
-    """Causal attention of a prompt, FORWARD ONLY: q (batch, sq, Hq,
+    """Causal attention of a prompt or a trained layer: q (batch, sq, Hq,
     head_dim) against k, v (batch, sk, Hkv, head_dim), Hq a multiple of
     Hkv, each KV head read where it lies for the query heads that share it.
     The queries are the LAST `sq` of the `sk` positions (sq <= sk): query i
@@ -288,7 +298,9 @@ def flash_attention_grouped(q, k, v, window=None):
     padded queries' rows are dropped.  Returns q's layout, or None where
     the kernel does not apply (not a TPU, a length that is no multiple of
     128, grouped heads narrower than 128 lanes, a multi-device program).  A
-    gradient asked of it raises: the backward kernel knows neither form."""
+    gradient asked of it is the backward kernel's, whose two calls carry
+    the names `_SCOPE_FWD` and `_SCOPE_BWD`; one whose queries of a head
+    group over the whole length do not fit in VMEM raises."""
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     if not _available() or d not in (64, 128, 256):
@@ -324,8 +336,8 @@ def flash_attention_grouped(q, k, v, window=None):
             x = jnp.concatenate([x, jnp.zeros(
                 (b, rows - x.shape[1]) + x.shape[2:], x.dtype)], axis=1)
         return x.reshape(b, rows, -1)
-    out = _flash_forward_only(tail(q, rows_q), tail(k, rows_k),
-                              tail(v, rows_k), h, hg, hkv, window, sk - sq)
+    out = _flash_grouped(tail(q, rows_q), tail(k, rows_k), tail(v, rows_k),
+                         h, hg, hkv, window, sk - sq)
     if rows_q != sq:
         out = out[:, :sq]
     return out.reshape(b, sq, h, d)
@@ -767,7 +779,7 @@ def _fwd_impl(q, k, v, bias, qseg, kseg, seed, causal, dropout_p, heads, hg,
 
 def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
                 written_out, blk_q, blk_k, n_q, n_k, scale, causal_off, heads,
-                hg):
+                hg, shared_kv=False, window=None):
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
         next(it), next(it), next(it), next(it), next(it), next(it))
@@ -788,15 +800,18 @@ def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def block(h, qi, ki, diagonal):
+    def block(h, qi, ki, diagonal, edge=None):
         sl = slice(h * d, (h + 1) * d)
         hs = slice(h, h + 1)
-        k_hd, v_hd = k_ref[0, :, sl], v_ref[0, :, sl]
+        # grouped heads: the step's heads share the one KV head it was
+        # given, and their dK and dV add up in its d lanes
+        kv = slice(0, d) if shared_kv else sl
+        k_hd, v_hd = k_ref[0, :, kv], v_ref[0, :, kv]
         qs = _rows(qi, blk_q)
         q_hd, do_hd = q_ref[0, qs, sl], do_ref[0, qs, sl]
         s = _scores(k_hd, q_hd, bias, qseg_ref[0, qi] if has_seg else None,
                     kseg, qi * blk_q, ki * blk_k, scale, diagonal,
-                    causal_off)
+                    causal_off, edge)
         p = jnp.exp(s - lse_ref[0, 0, qi, hs, :])           # (blk_k, blk_q)
         dpd = jax.lax.dot_general(v_hd, do_hd, _NT,
                                   preferred_element_type=jnp.float32)
@@ -809,13 +824,13 @@ def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
             dp = jnp.where(keep, dpd * inv, 0.0)
         else:
             pd, dp = p, dpd
-        dv_acc[:, sl] += jax.lax.dot(pd.astype(do_ref.dtype), do_hd,
+        dv_acc[:, kv] += jax.lax.dot(pd.astype(do_ref.dtype), do_hd,
                                      preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[0, 0, qi, hs, :])
         if has_bias:  # d(bias_k) = sum over q rows of dS (heads summed)
             db_acc[...] += jnp.sum(ds, axis=1, keepdims=True)
         ds = ds.astype(q_ref.dtype)
-        dk_acc[:, sl] += jax.lax.dot(
+        dk_acc[:, kv] += jax.lax.dot(
             ds, q_hd, preferred_element_type=jnp.float32) * scale
         dq_acc[qi, sl, :] += jax.lax.dot_general(           # (d, blk_q)
             k_hd, ds, _TN, preferred_element_type=jnp.float32) * scale
@@ -823,7 +838,8 @@ def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
     def k_block(ki):
         """k block ki (static or the grid's index) against its q blocks,
         the heads side by side: first those the diagonal crosses, then the
-        whole ones below it."""
+        whole ones below it, then those a window's far edge crosses; the q
+        blocks past them see none of the block's keys and are skipped."""
         if causal:
             # seen from the keys' side: q blocks below `first_seen` are
             # wholly masked, those from `first_whole` on wholly kept
@@ -833,18 +849,34 @@ def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
                                 first_seen, n_q)
         else:
             first_seen = first_whole = 0
+        whole_end = seen_end = n_q
+        if window is not None:
+            # q blocks below `whole_end` keep every key of the block (their
+            # last row less than `window` after its first key); from
+            # `seen_end` on, a block's first row is past the last key's
+            # window
+            whole_end = _clip((edge + window) // blk_q, first_whole, n_q)
+            seen_end = _clip((edge + window + blk_k + blk_q - 2) // blk_q,
+                             whole_end, n_q)
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
         if has_bias:
             db_acc[...] = jnp.zeros_like(db_acc)
 
-        def blocks(qi, _, diagonal):
+        def blocks(qi, _, diagonal, edge=None):
             for h in range(hg):
-                block(h, qi, ki, diagonal)
-        _loop(first_seen, first_whole, lambda qi, c: blocks(qi, c, True),
+                block(h, qi, ki, diagonal, edge)
+        # a window narrower than two blocks can cross a diagonal block too
+        both = window if window is not None and window < blk_q + blk_k \
+            else None
+        _loop(first_seen, first_whole,
+              lambda qi, c: blocks(qi, c, True, both), None, written_out)
+        _loop(first_whole, whole_end, lambda qi, c: blocks(qi, c, False),
               None, written_out)
-        _loop(first_whole, n_q, lambda qi, c: blocks(qi, c, False), None,
-              written_out)
+        if window is not None:
+            _loop(whole_end, seen_end,
+                  lambda qi, c: blocks(qi, c, False, window), None,
+                  written_out)
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
         if has_bias:
@@ -864,14 +896,31 @@ def _bwd_kernel(seed_ref, *refs, has_bias, has_seg, causal, dropout_p,
 
 @_traced_once(10)
 def _bwd_impl(q, k, v, bias, qseg, kseg, seed, o, lse, do,
-              causal, dropout_p, heads, hg):
+              causal, dropout_p, heads, hg, kv_heads=None, window=None,
+              causal_off=None):
+    """`kv_heads` < heads: K and V hold that many heads, each shared by
+    heads / kv_heads query heads (`hg` dividing their ratio), and a grid
+    step's block of them is the one KV head its query heads share; the
+    step's dK and dV are that head group's part, left in float32 and summed
+    over the head groups of a KV head here.  `window`, `causal_off`: as the
+    forward's."""
     b, sq, hd = q.shape
     sk = k.shape[1]
+    if causal_off is None:
+        causal_off = sk - sq
     d = hd // heads
     gd = hg * d
     n_hg = heads // hg
     blk_q, blk_k = _block(sq, fine=causal), _block(sk, fine=causal)
     n_q, n_k = sq // blk_q, sk // blk_k
+    shared_kv = kv_heads not in (None, heads)
+    if shared_kv:
+        steps = heads // kv_heads // hg      # grid steps a KV head
+        kv_lanes, kv_at = d, lambda g: jax.lax.div(g, steps)
+        part = jnp.float32                   # dK, dV: a head group's part
+    else:
+        kv_lanes, kv_at = gd, lambda g: g
+        part = None
 
     # lse and delta[b, s, h] = sum_d do*o as (B, n_hg, n_q, hg, blk_q): a
     # (hg, blk_q) tile a q sub-block, found by a leading index in the loop
@@ -881,40 +930,49 @@ def _bwd_impl(q, k, v, bias, qseg, kseg, seed, o, lse, do,
 
     # grid (b, head group, k block); Q, dO, lse and delta whole
     whole = pl.BlockSpec((1, sq, gd), lambda b, g, j, s: (b, 0, g))
-    k_block = pl.BlockSpec((1, blk_k, gd), lambda b, g, j, s: (b, j, g))
+    k_block = pl.BlockSpec((1, blk_k, kv_lanes),
+                           lambda b, g, j, s: (b, j, kv_at(g)))
+    dkv_block = pl.BlockSpec((1, blk_k, kv_lanes),
+                             lambda b, g, j, s: (b, j, g))
     rows = pl.BlockSpec((1, 1, n_q, hg, blk_q),
                         lambda b, g, j, s: (b, g, 0, 0, 0))
     extra, extra_specs = _mask_inputs(bias, qseg, kseg, blk_q, blk_k,
                                       q_index=None, k_index=lambda j: j)
+    kernel = functools.partial(
+        _bwd_kernel, has_bias=bias is not None,
+        has_seg=qseg is not None, causal=causal, dropout_p=dropout_p,
+        written_out=max(sq, sk) <= _WRITTEN_OUT,
+        blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
+        scale=1.0 / math.sqrt(d), causal_off=causal_off, heads=heads,
+        hg=hg)
+    if shared_kv or window is not None:
+        kernel = functools.partial(kernel, shared_kv=shared_kv,
+                                   window=window)
 
     outs = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel, has_bias=bias is not None,
-            has_seg=qseg is not None, causal=causal, dropout_p=dropout_p,
-            written_out=max(sq, sk) <= _WRITTEN_OUT,
-            blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
-            scale=1.0 / math.sqrt(d), causal_off=sk - sq, heads=heads,
-            hg=hg),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, n_hg, n_k),
             in_specs=[whole, k_block, k_block, whole, rows, rows]
             + extra_specs,
-            out_specs=[whole, k_block, k_block]
+            out_specs=[whole, dkv_block, dkv_block]
             + ([pl.BlockSpec((1, 1, blk_k, 1),
                              lambda b, g, j, s: (b, g, j, 0))]
                if bias is not None else []),
             scratch_shapes=[
                 pltpu.VMEM((n_q, gd, blk_q), jnp.float32),
-                pltpu.VMEM((blk_k, gd), jnp.float32),
-                pltpu.VMEM((blk_k, gd), jnp.float32),
+                pltpu.VMEM((blk_k, kv_lanes), jnp.float32),
+                pltpu.VMEM((blk_k, kv_lanes), jnp.float32),
             ] + ([pltpu.VMEM((blk_k, 1), jnp.float32)]
                  if bias is not None else []),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, sk, hd), k.dtype),
-            jax.ShapeDtypeStruct((b, sk, hd), v.dtype),
+            jax.ShapeDtypeStruct((b, sk, n_hg * d if shared_kv else hd),
+                                 part or k.dtype),
+            jax.ShapeDtypeStruct((b, sk, n_hg * d if shared_kv else hd),
+                                 part or v.dtype),
         ] + ([jax.ShapeDtypeStruct((b, n_hg, sk, 1), jnp.float32)]
              if bias is not None else []),
         compiler_params=_compiler_params(
@@ -923,6 +981,10 @@ def _bwd_impl(q, k, v, bias, qseg, kseg, seed, o, lse, do,
         interpret=_INTERPRET,
     )(seed, q, k, v, do, lse, delta, *extra)
     dq, dk, dv = outs[:3]
+    if shared_kv:   # the head groups' parts of each KV head, summed
+        dk, dv = (x.reshape(b, sk, kv_heads, steps, d).sum(3).reshape(
+            b, sk, kv_heads * d).astype(y.dtype)
+            for x, y in ((dk, k), (dv, v)))
     dbias = None
     if bias is not None:  # per-(batch, head-group) key sums -> (B, Sk, 1)
         dbias = outs[3].sum(axis=1)
@@ -959,22 +1021,40 @@ def _flash_bwd(causal, dropout_p, heads, hg, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# the grouped and windowed forward: no backward is built for either form
-# (training a routed layer: ROADMAP C3), and a gradient says so by name
+# the grouped and windowed form, forward and backward: the backward walks
+# the same blocks from the keys' side, the window's far edge bounding it as
+# the diagonal does
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_forward_only(q, k, v, heads, hg, kv_heads, window, causal_off):
+def _flash_grouped(q, k, v, heads, hg, kv_heads, window, causal_off):
     o, _ = _fwd_impl(q, k, v, None, None, None, jnp.zeros((1,), jnp.int32),
                      True, 0.0, heads, hg, kv_heads, window, causal_off)
     return o
 
 
-def _forward_only_fwd(q, k, v, heads, hg, kv_heads, window, causal_off):
-    raise NotImplementedError(
-        "flash_attention_grouped is forward only: the flash backward kernel "
-        "takes neither grouped KV heads nor a window (expand the KV heads "
-        "and call flash_attention_bshd, or take the XLA form)")
+def _grouped_fwd(q, k, v, heads, hg, kv_heads, window, causal_off):
+    with jax.named_scope(_SCOPE_FWD):
+        o, lse = _fwd_impl(q, k, v, None, None, None,
+                           jnp.zeros((1,), jnp.int32), True, 0.0, heads, hg,
+                           kv_heads, window, causal_off)
+    return o, (q, k, v, o, lse)
 
 
-_flash_forward_only.defvjp(_forward_only_fwd, lambda *_: None)
+def _grouped_bwd(heads, hg, kv_heads, window, causal_off, res, g):
+    q, k, v, o, lse = res
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[2] // heads
+    if _resident_bytes(sq, sk, hg * d, q.dtype.itemsize) > _VMEM_MOST:
+        raise NotImplementedError(
+            f"flash_attention_grouped's backward holds the queries of a "
+            f"head group ({hg} x {d} lanes) over {sq} rows resident, more "
+            f"than {_VMEM_MOST >> 20} MiB of VMEM")
+    with jax.named_scope(_SCOPE_BWD):
+        dq, dk, dv, _ = _bwd_impl(q, k, v, None, None, None,
+                                  jnp.zeros((1,), jnp.int32), o, lse,
+                                  g.astype(q.dtype), True, 0.0, heads, hg,
+                                  kv_heads, window, causal_off)
+    return dq, dk, dv
+
+
+_flash_grouped.defvjp(_grouped_fwd, _grouped_bwd)

@@ -595,3 +595,207 @@ def test_nemotron_programs_compile_and_fit(nemotron_programs, name,
     assert not [line for line in compiled.as_text().splitlines()
                 if re.search(r"f32\[96,128,64,128\]\S* copy\(",
                              line.strip())]
+
+
+# ------------------------------------------------ Mellum 2's training step
+
+def _metric_params(name):
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", name + ".json")
+    with open(path) as f:
+        return json.load(f)["params"]
+
+
+@pytest.fixture(scope="module")
+def mellum2_step(one_chip):
+    """The compiled training step of `mellum2-train-s8k` (1 x 8192 tokens,
+    bfloat16 O1, AdamW) at the PUBLISHED widths for the described chip.
+    Nothing of that size exists here: the model is built cut to tiny
+    widths (the held experts' ids and the router's 64 outputs as
+    published), its config is then set to the published one, and the step
+    is lowered on the benchmark layout's shapes alone."""
+    import sys
+    import paddle_tpu as paddle
+    from paddle_tpu.core import rng as _rng
+    from paddle_tpu.jit import TrainStep
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import harness
+    from benchmark.arch import mellum2 as A
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b-1of4-4of28.json")) as f:
+        cfg = json.load(f)
+    program = cfg["program"]
+    factory = harness.resolve(program["factory"])
+    narrow = dict(hidden_size=64, num_attention_heads=2,
+                  num_key_value_heads=1, moe_intermediate_size=32,
+                  vocab_size=256)
+    model = harness.resolve(program["model"])(
+        factory(**dict(program["kwargs"], **narrow)))
+    vars(model.config).update(vars(factory(**program["kwargs"])))
+    h = cfg["training"]["optimizer"]
+    opt = paddle.optimizer.AdamW(learning_rate=h["lr"],
+                                 parameters=model.parameters())
+    step = TrainStep(model, A.train_loss_fn(), opt, amp_level="O1",
+                     amp_dtype="bfloat16")
+    d = A.dims(cfg)
+    shape = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        tuple(s), dt, sharding=one_chip)
+    state = {}
+    for prog, ref, layer in A.program_names(d):
+        leaf = A.layout(d)[ref] if layer is None \
+            else A.layout(d)["layers"][ref]
+        state[prog] = shape(leaf[0] if layer is None else leaf[0][1:])
+    opt_state = jax.tree_util.tree_map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(step.init_opt_state, state))
+    batch = (shape((1, 8192), jnp.int32),) * 2
+    key = _rng.example_key()
+    fn = step._build(state, None, batch)
+    available, fa._available = fa._available, lambda: True
+    try:
+        with _no_compilation_cache():
+            compiled = fn.lower(state, opt_state, shape((), jnp.int32),
+                                shape(()), shape(key.shape, key.dtype),
+                                batch).compile()
+    finally:
+        fa._available = available
+    return compiled, sum(a.size * 4 for a in jax.tree_util.tree_leaves(
+        state)) + sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(opt_state))
+
+
+def test_mellum2_train_step_compiles_and_fits(mellum2_step, record_property):
+    """595.2M parameters with AdamW's two moments (9.52 GB with the
+    gradients, 7.14 GB held between steps) beside one step's temporaries at
+    8192 tokens stay inside the chip; every layer's attention takes the
+    grouped flash kernel forward and backward, one call each, under the
+    names `window_flash_train_roofline.train_s8k` finds them by; the held
+    experts' products carry the name its sibling metric reads, 12 a chunk
+    (3 forward, and 9 backward: the 3 again and 6 for dX and dW)."""
+    compiled, held = mellum2_step
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert 7.0e9 < held < 7.3e9
+    assert held + mem.temp_size_in_bytes < HBM
+    lines = [line.strip() for line in compiled.as_text().splitlines()]
+    flash = _metric_params("window_flash_train_roofline.train_s8k")
+    kernels = [line for line in lines if "tpu_custom_call" in line
+               and " custom-call(" in line]
+    assert sum(bool(re.search(flash["forward"], k)) for k in kernels) == 4
+    assert sum(bool(re.search(flash["backward"], k)) for k in kernels) == 4
+    products = _metric_params("moe_expert_product_roofline.train_s8k")
+    made = [line for line in lines if re.search(products["kernel"], line)]
+    assert len(made) == 4 * 3 * products["events_per_product"]
+
+
+# the lowered text (Mosaic kernels' bodies without their source locations)
+# of the programs other cells run through the code this model shares: the
+# flash kernel's training calls of gpt2m-train and bertl-train, the grouped
+# forward of the served prompts (cmdap, Nemotron, EvaByte), and the held
+# experts' serving forms (cmdap, Moonlight, Nemotron), as the commit before
+# the grouped backward and the trainable held form lowered them
+SHARED_PROGRAMS = {
+    "gpt2_flash_fwd_bwd":
+        "2762280ec6da7d8f01fd524bfd3b9b5bf43bca5a2119c363a8660e751a184a95",
+    "bert_flash_fwd_bwd":
+        "2babc1d5941a9946b9ca9014c9ddc6a37d1fa794398ede47516b805903affefe",
+    "grouped_8192_None_128_8":
+        "3b2a001f813264eb41980fc161391340468825347aa1f6fb54ad6aa05a323f16",
+    "grouped_8192_4096_128_8":
+        "9c2b5a87b56d31e7f20db2314e1a5ede7864526959943f92d2bf2244349ae662",
+    "grouped_8192_None_32_2":
+        "1a138e82e2d92510a439ba19c36709172b01f762fe0300ee19f876ec647c2e45",
+    "grouped_2048_None_32_32":
+        "b9b3fc4e04af0c87374ff1258c0981ec5bd6b01f66a59b6e6dca1175a0ae1707",
+    "grouped_2176_None_32_32":
+        "fbe6a3da99d6b1c457bd3a31a7ebcc10f830e7e2b50167d59e7d53de6705843a",
+    "grouped_1024_256_16_16":
+        "468d061bcf1cb066be1fb52ec4586d44dddaad3d10ae9f9aed933fa681e2043b",
+    "cmdap_decode_moe":
+        "0e53add552b386933587f6be9ee8be70d69a36fe1fc872b93798d3e16cc8fff3",
+    "cmdap_prefill_moe":
+        "a9c94f034287d7203fe3f931acac58ca90f19886407b942a693d120a66c5be7c",
+    "moonlight_moe_48":
+        "c67cafa4cefac8b2f86f979c52b28ae6887d701d04a71cddb3afb2c9858385db",
+    "moonlight_moe_2048":
+        "073243a667365bb284346b172d919cf820388792bca25397f456dcfcb687e778",
+    "nemotron_moe_relu2":
+        "0875acd7df6f39bcf505caacc33e94a6a03c0b2f47303522442dddcf3c6b5c66",
+}
+
+
+def _shared_program(name, shape):
+    """(function, argument shapes) of one of `SHARED_PROGRAMS`."""
+    from paddle_tpu.nn.functional.moe import moe_ffn_held
+    bf, f32 = jnp.bfloat16, jnp.float32
+    seed = shape((1,), jnp.int32)
+
+    def flash_grads(causal):
+        def fn(q, k, v, s):
+            return jax.grad(lambda q, k, v: fa._flash_bshd(
+                q, k, v, None, None, None, s, causal=causal,
+                dropout_p=0.0).astype(f32).sum(), argnums=(0, 1, 2))(q, k, v)
+        return fn
+    if name == "gpt2_flash_fwd_bwd":
+        qkv = shape((4, 1024, 16, 64), bf)
+        return flash_grads(True), (qkv, qkv, qkv, seed)
+    if name == "bert_flash_fwd_bwd":
+        qkv = shape((8, 512, 16, 64), bf)
+        return flash_grads(False), (qkv, qkv, qkv, seed)
+    if name.startswith("grouped_"):
+        s, w, hq, hkv = name.split("_")[1:]
+        s, hq, hkv = int(s), int(hq), int(hkv)
+        w = None if w == "None" else int(w)
+        kv = shape((1, s, hkv, 128), bf)
+        return (lambda q, k, v: fa.flash_attention_grouped(q, k, v, window=w),
+                (shape((1, s, hq, 128), bf), kv, kv))
+    if name.startswith("cmdap_"):
+        t, h = (16 if "decode" in name else 2048), 4096
+        return (lambda x, r, g, u, d, valid: moe_ffn_held.raw(
+            x, r, g, u, d, tuple(range(16)), top_k=8, valid=valid),
+            (shape((t, h), bf), shape((h, 128), f32),
+             shape((16, h, h), bf), shape((16, h, h), bf),
+             shape((16, h, h), bf), shape((t,), jnp.bool_)))
+    if name.startswith("moonlight_"):
+        t, h, i = int(name.split("_")[-1]), 2048, 1408
+        return (lambda x, r, g, u, d, valid, bias: moe_ffn_held.raw(
+            x, r, g, u, d, tuple(range(64)), top_k=6, valid=valid,
+            select_bias=bias, scale=2.446),
+            (shape((t, h), bf), shape((h, 64), f32), shape((64, h, i), bf),
+             shape((64, h, i), bf), shape((64, i, h), bf),
+             shape((t,), jnp.bool_), shape((64,), f32)))
+    return (lambda x, r, u, d, valid, ex: moe_ffn_held.raw(
+        x, r, None, u, d, tuple(range(128)), top_k=22, valid=valid,
+        expert_x=ex),
+        (shape((96, 4096), bf), shape((4096, 512), f32),
+         shape((128, 1024, 2688), bf), shape((128, 2688, 1024), bf),
+         shape((96,), jnp.bool_), shape((96, 1024), bf)))
+
+
+@pytest.mark.parametrize("name", sorted(SHARED_PROGRAMS))
+def test_other_cells_programs_lower_to_the_texts_they_had(one_chip,
+                                                          monkeypatch, name):
+    """The grouped backward and the trainable held form are new paths: the
+    programs other cells run through the same code lower to the text they
+    had, sha256 for sha256 (the kernels' source locations, which move with
+    any edit of their file, stripped from their bodies)."""
+    import hashlib
+    from jax._src import tpu_custom_call as tcc
+    serialize = tcc._lower_mosaic_module_to_asm
+
+    def stripped(module, *args, **kwargs):
+        with module.context:
+            tcc.PassManager.parse(
+                "builtin.module(strip-debuginfo)").run(module.operation)
+        return serialize(module, *args, **kwargs)
+    monkeypatch.setattr(tcc, "_lower_mosaic_module_to_asm", stripped)
+    monkeypatch.setattr(fa, "_available", lambda: True)
+    shape = lambda s, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        tuple(s), dt, sharding=one_chip)
+    fn, args = _shared_program(name, shape)
+    text = jax.jit(fn).lower(*args).as_text()
+    # the module is named after the Python function; the program is not
+    text = re.sub(r"module @\S+", "module @m", text, count=1)
+    assert hashlib.sha256(text.encode()).hexdigest() == SHARED_PROGRAMS[name]

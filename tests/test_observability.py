@@ -272,6 +272,37 @@ def test_tracer_nesting_and_ring_bound():
     assert agg["outer"][0] == 1
 
 
+def test_deferred_args_are_read_once_by_readers_at_the_same_time():
+    """Spans whose args wait on the device (`defer_args`) hold numbers for
+    every reader of the ring, also readers that read it at once from
+    several threads: none sees the key that held the device vector."""
+    import jax.numpy as jnp
+    from paddle_tpu.observability.tracer import DEFERRED, Tracer, defer_args
+
+    tr = Tracer(max_events=1000)
+    for i in range(200):
+        with tr.span("step") as sp:
+            defer_args(sp, ("a", "b"), jnp.asarray([i, 2 * i], jnp.int32))
+    start, seen, errors = threading.Barrier(8), [], []
+
+    def read():
+        start.wait()
+        try:
+            seen.append([ev[6] for ev in tr.events()])
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+    threads = [threading.Thread(target=read) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors and len(seen) == 8
+    for args in seen:
+        assert [(a["a"], a["b"]) for a in args] == [
+            (i, 2 * i) for i in range(200)]
+        assert all(DEFERRED not in a for a in args)
+
+
 @obsmark
 def test_profiler_shim_thread_safety_hammer():
     """Regression for the pre-PR5 bug: profiler _records/_events were
