@@ -14,7 +14,8 @@ layer's walk started at the window's far edge in both passes); where the
 kernel refuses (not a TPU, a length that is no multiple of 128), the
 chunked XLA form.  The routed layer is `nn.HeldExperts`, told which experts
 this chip holds, in its trainable form (`F.moe_ffn_held(trainable=True)`:
-the held picks walked in chunks forward and backward, nothing dropped).
+the held picks in one pass a layer through the grouped-matmul kernels of
+`ops.grouped_matmul`, forward and backward, nothing dropped).
 
 `forward` returns, beside the logits, int32 counts summed over layers
 (`train_count_names`), which `jit.TrainStep` returns beside the loss and
@@ -258,7 +259,7 @@ class Mellum2ForCausalLM(Layer):
         """{kind: (cos, sin) (s, head_dim / 2)} at positions 0..s-1."""
         cfg, out = self.config, {}
         pos = jnp.arange(s, dtype=jnp.float32)[:, None]
-        for kind in set(cfg.layer_types):
+        for kind in dict.fromkeys(cfg.layer_types):  # a set's order varies
             inv, factor = rope_frequencies(cfg.rope_parameters[kind],
                                            cfg.head_dim)
             ang = pos * inv[None, :]

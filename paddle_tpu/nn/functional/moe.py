@@ -19,10 +19,11 @@ row through every held expert in one batched product a matrix instead
 (`(silu(x Wg) * (x Wu)) Wd`) or, with no gate, relu squared (`relu(x W1)^2
 W2`), and they may take another input than the router reads (a latent the
 layer projects its tokens into).  Its router scores by the sigmoid or, as
-Mellum's does, the softmax.  A layer that TRAINS takes its grouped walk
-with a backward (`trainable=True`: `_held_products`, the same chunks walked
-again in reverse, nothing dropped and no capacity), which is what replaces
-`_raw_moe_ffn`'s capacity dispatch for a held share of the experts.
+Mellum's does, the softmax.  A layer that TRAINS takes its held picks in
+one grouped pass with a backward (`trainable=True`: `_held_pass`, through
+the kernels of `ops.grouped_matmul`, nothing dropped and no capacity), which
+is what replaces `_raw_moe_ffn`'s capacity dispatch for a held share of the
+experts.
 """
 from __future__ import annotations
 
@@ -35,6 +36,7 @@ import numpy as np
 
 from ...core.op import defop
 from ...observability.metrics import counter
+from ...ops import grouped_matmul as gm
 
 _ACTS = {
     "gelu": jax.nn.gelu,
@@ -116,17 +118,15 @@ GROUPED_PRODUCTS = 3
 # picks (tokens x top_k) fit makes one chunk, a longer one walks the picks
 # held here in chunks of this many (chosen on the chip: PERF.md, PR 31)
 _CHUNK_ROWS = 2048
-# rows a chunk of the trainable form goes over: its backward adds each
-# chunk's float32 weight gradients (the held stacks' size) into the layer's,
-# so fewer, longer chunks read and write them fewer times, while the last
-# chunk's rows past the held picks are still gone over, so a step's work
-# steps up where a layer's held picks cross a multiple of the chunk.  Read
-# on the chip, ms a step of 8192 tokens x 8 picks (PERF.md, section 6): with
-# half the picks held, 2048 rows 498 to 503, 4096 407 to 411, 6144 366 to
-# 370, 8192 351 to 357; with a quarter held, as the cell holds them, 8192
-# 299 to 312 (two chunks a layer or three, by seed) and 6144 311 to 313
-# (three for any share from 18.75% to 28.1%)
-_TRAIN_CHUNK_ROWS = 3 * _CHUNK_ROWS
+# rows a tile of the trainable form's grouped pass (`ops.grouped_matmul`):
+# its kernels walk a layer's picks held here in tiles of this many, a tile
+# that straddles two experts once for each, so that a layer's products go
+# over about (picks here / tile + experts held) tiles.  At 16 of 64 experts
+# of 2304 x 896 and ~16k picks held a layer: 256 goes over ~79 tiles where
+# 512 goes over ~47 of twice the rows (a fifth more products), and a tile
+# of 256 rows takes about as long on the MXU as its expert's matrices take
+# to arrive, so that a change of expert stalls nothing
+_TRAIN_CHUNK_ROWS = 256
 # where the held experts' products are ONE batched product a matrix over
 # every row and every held expert (`_batched_form`), both chosen on the chip
 # (`probes/moe_decode_forms.py`, ms a layer; PERF.md, PR 35).  The share of
@@ -224,14 +224,17 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     or grouped product: every row goes through every held expert in one
     batched product a matrix, weighed in float32 by its share there (0
     where it did not pick the expert); `products` and `rows` are then 0.
-    `trainable`: the grouped walk as a form that reverse mode can go
-    through (`_held_products`: chunks of `_TRAIN_CHUNK_ROWS` forward and,
-    recomputed, backward; the router's gradient comes through the picks'
-    shares), never the batched one; counted as `grouped_train`.
+    `trainable`: the held picks in ONE grouped pass that reverse mode can go
+    through (`_held_pass`: the kernels of `ops.grouped_matmul` over tiles of
+    `_TRAIN_CHUNK_ROWS` sorted picks, forward and, recomputed, backward; the
+    router's gradient comes through the picks' shares), gated experts
+    alone, never the batched form; counted as `grouped_kernel`.
     int32 counts: picks_here, picks that fell on held experts;
     experts_hit, held experts with at least one token; products, grouped
-    products made (`GROUPED_PRODUCTS` a chunk); rows, rows they went over
-    (a chunk's rows x chunks).
+    products made (`GROUPED_PRODUCTS` a chunk; the trainable pass's forward
+    kernel calls, `gm.FORWARD_CALLS`); rows, rows they went over (a chunk's
+    rows x chunks; the trainable pass's, the distinct tiles' rows that hold
+    the held picks).
     """
     t = x.shape[0]
     n_experts = router_w.shape[-1]
@@ -266,7 +269,7 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
     held_at = place[:, None] == jnp.arange(n_held)[None, :]   # (T*K, n_held)
     sizes = jnp.sum(held_at, axis=0, dtype=jnp.int32)              # (n_held,)
     batched = not trainable and _batched_form(t, top_k, n_experts)
-    _FORM_TAKEN.labels(form="batched" if batched else "grouped_train"
+    _FORM_TAKEN.labels(form="batched" if batched else "grouped_kernel"
                        if trainable else "grouped").inc()
     if batched:
         weight = jnp.sum(jnp.where(held_at, share[:, None], 0.0).reshape(
@@ -277,22 +280,29 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
                 jnp.int32(0))
     ends = jnp.cumsum(sizes)
     here = ends[-1]
-    rows = min(_TRAIN_CHUNK_ROWS if trainable else _CHUNK_ROWS, t * top_k)
+    # the picks held here first, by expert
+    order = jnp.argsort(place, stable=True).astype(jnp.int32)
+    if trainable:
+        if w_gate is None:
+            raise ValueError("the trainable form takes gated experts")
+        # a tile a whole number of bfloat16 sublane tiles
+        tile = min(_TRAIN_CHUNK_ROWS, -(-(t * top_k) // 16) * 16)
+        y = _held_pass(x, share, w_gate, w_up, w_down, order, sizes, top_k,
+                       tile)
+        return (y.astype(x.dtype), here.astype(jnp.int32),
+                jnp.sum(sizes > 0, dtype=jnp.int32),
+                jnp.int32(gm.FORWARD_CALLS),
+                (_walked(here, tile) * tile).astype(jnp.int32))
+    rows = min(_CHUNK_ROWS, t * top_k)
     chunks = -(-(t * top_k) // rows)
-    # the picks held here first, by expert; padded so that a chunk's slice
-    # never runs off the end (rows past `here` add nothing)
-    order = jnp.pad(jnp.argsort(place, stable=True).astype(jnp.int32),
-                    (0, chunks * rows - t * top_k))
+    # padded so that a chunk's slice never runs off the end (rows past
+    # `here` add nothing)
+    order = jnp.pad(order, (0, chunks * rows - t * top_k))
 
     # products a chunk: gate, up and down, or up and down with no gate
     made = GROUPED_PRODUCTS if w_gate is not None else GROUPED_PRODUCTS - 1
-    if trainable:
-        y = _held_products(x, share, w_gate, w_up, w_down, order, sizes,
-                           top_k, rows)
-        walked = _walked(here, rows)
-    else:
-        y, walked = _walk(x, share, w_gate, w_up, w_down, order, sizes,
-                          ends, here, top_k, rows, chunks == 1)
+    y, walked = _walk(x, share, w_gate, w_up, w_down, order, sizes, ends,
+                      here, top_k, rows, chunks == 1)
     return (y.astype(x.dtype), here.astype(jnp.int32),
             jnp.sum(sizes > 0, dtype=jnp.int32),
             (made * walked).astype(jnp.int32),
@@ -302,11 +312,9 @@ def _raw_moe_ffn_held(x, router_w, w_gate, w_up, w_down, experts_held,
 moe_ffn_held = defop("moe_ffn_held")(_raw_moe_ffn_held)
 
 
-# the names the held experts' products carry, by which a device trace
-# finds their events: the forward's (every form), and the trainable form's
-# backward's (each chunk's three products again, then the six that give dX
-# and dW)
-_SCOPE_FWD, _SCOPE_BWD = "moe_expert_product", "moe_expert_product_bwd"
+# the name the serving forms' products carry, by which a device trace finds
+# their events
+_SCOPE_FWD = "moe_expert_product"
 
 
 def _chunk(order, ends, here, sizes, top_k, rows, c):
@@ -360,63 +368,66 @@ def _walk(x, share, w_gate, w_up, w_down, order, sizes, ends, here, top_k,
     return jax.lax.fori_loop(0, walked, add_chunk, y), walked
 
 
+def _rows_of(order, share, sizes, top_k, tile):
+    """The pass's rows, padded to whole tiles: the sorted picks' tokens,
+    their place among the rows (the inverse of `order`), their shares as a
+    (rows, 1) column, and the kernels' walk over them."""
+    picks = order.shape[0]
+    rows = -(-picks // tile) * tile
+    token = jnp.pad(order // top_k, (0, rows - picks))
+    place = jnp.zeros((picks,), jnp.int32).at[order].set(
+        jnp.arange(picks, dtype=jnp.int32))
+    weight = jnp.pad(share[order], (0, rows - picks))[:, None]
+    return token, place, weight, gm.group_tiles(sizes, rows, tile)
+
+
+def _to_tokens(rows, place, here, top_k):
+    """Each token's sum of its held picks' rows, float32: `rows` (pass
+    rows, w) in sorted order; a pick whose place is past `here` is held
+    elsewhere and the row it would read is undefined."""
+    picked = jnp.where((place < here)[:, None], rows[place], 0.0)
+    return jnp.sum(picked.reshape(-1, top_k, rows.shape[1]), axis=1)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_products(x, share, w_gate, w_up, w_down, order, sizes, top_k,
-                   rows):
-    """`_walk`'s loop as a form that reverse mode goes through: `order` the
-    picks sorted held-first by expert (padded to whole chunks), `sizes`
+def _held_pass(x, share, w_gate, w_up, w_down, order, sizes, top_k, tile):
+    """The held picks' part of the layer, (T, d) float32, in one grouped
+    pass: `order` the T * top_k picks sorted held-first by expert, `sizes`
     (n_held,) the picks of each held expert, `share` (T * top_k,) each
-    pick's weight.  Its backward walks the same chunks, recomputing each
-    one's products, and gives x, the shares and the three stacks their
-    gradients."""
-    ends = jnp.cumsum(sizes)
-    return _walk(x, share, w_gate, w_up, w_down, order, sizes, ends,
-                 ends[-1], top_k, rows, False)[0]
+    pick's weight.  The picks' rows of x are gathered in that order, go
+    through `gm.gate_up` and `gm.down` over tiles of `tile` rows, and each
+    token sums its held picks' weighted rows."""
+    return _held_fwd(x, share, w_gate, w_up, w_down, order, sizes, top_k,
+                     tile)[0]
 
 
-def _held_fwd(x, share, w_gate, w_up, w_down, order, sizes, top_k, rows):
-    y = _held_products(x, share, w_gate, w_up, w_down, order, sizes, top_k,
-                       rows)
+def _held_fwd(x, share, w_gate, w_up, w_down, order, sizes, top_k, tile):
+    token, place, weight, groups = _rows_of(order, share, sizes, top_k, tile)
+    h = gm.gate_up(groups, x[token], w_gate, w_up, tile)
+    y = _to_tokens(gm.down(groups, h, weight, w_down, tile), place,
+                   jnp.sum(sizes), top_k)
     return y, (x, share, w_gate, w_up, w_down, order, sizes)
 
 
-def _held_bwd(top_k, rows, res, dy):
+def _held_bwd(top_k, tile, res, dy):
+    """The same rows again: `gm.gate_up_bwd` gives each row's input's,
+    share's and hidden products' gradients in one visit, `gm.tgmm` each
+    stack's gradient over its expert's rows, and the rows' input gradients
+    go back to their tokens as the forward's outputs did."""
     x, share, w_gate, w_up, w_down, order, sizes = res
-    dy = dy.astype(jnp.float32)
-    ends = jnp.cumsum(sizes)
-    here = ends[-1]
-    f32 = lambda w: None if w is None else jnp.zeros(  # noqa: E731
-        w.shape, jnp.float32)
-
-    def add(c, acc):
-        dx, dshare, dwg, dwu, dwd = acc
-        picks, token, live, part = _chunk(order, ends, here, sizes, top_k,
-                                          rows, c)
-
-        def products(xs, sh, wg, wu, wd):
-            return _chunk_products(xs, wg, wu, wd, live, part,
-                                   lambda o: o * sh[:, None])
-        with jax.named_scope(_SCOPE_BWD):
-            _, vjp = jax.vjp(products, x[token], share[picks], w_gate,
-                             w_up, w_down)
-            gx, gs, gg, gu, gd = vjp(dy[token])
-        gx = jnp.where(live, gx.astype(jnp.float32), 0.0)
-        gs = jnp.where(live[:, 0], gs.astype(jnp.float32), 0.0)
-        dwg = None if dwg is None else dwg + gg.astype(jnp.float32)
-        return (dx.at[token].add(gx), dshare.at[picks].add(gs), dwg,
-                dwu + gu.astype(jnp.float32), dwd + gd.astype(jnp.float32))
-
-    acc = (jnp.zeros(x.shape, jnp.float32),
-           jnp.zeros(share.shape, jnp.float32), f32(w_gate), f32(w_up),
-           f32(w_down))
-    dx, dshare, dwg, dwu, dwd = jax.lax.fori_loop(
-        0, _walked(here, rows), add, acc)
-    cast = lambda g, like: None if like is None else g.astype(  # noqa: E731
-        like.dtype)
+    token, place, weight, groups = _rows_of(order, share, sizes, top_k, tile)
+    here = jnp.sum(sizes)
+    xs, dys = x[token], dy.astype(x.dtype)[token]
+    dxs, dg, du, hw, dw = gm.gate_up_bwd(groups, xs, dys, weight, w_gate,
+                                         w_up, w_down, tile)
+    dwg, dwu = gm.tgmm(groups, xs, dg, tile), gm.tgmm(groups, xs, du, tile)
+    dwd = gm.tgmm(groups, hw, dys, tile)
+    dx = _to_tokens(dxs, place, here, top_k)
+    dshare = jnp.where(place < here, dw[place, 0], 0.0)
     zero_int = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
-    return (cast(dx, x), cast(dshare, share), cast(dwg, w_gate),
-            cast(dwu, w_up), cast(dwd, w_down), zero_int(order),
-            zero_int(sizes))
+    return (dx.astype(x.dtype), dshare.astype(share.dtype),
+            dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
+            dwd.astype(w_down.dtype), zero_int(order), zero_int(sizes))
 
 
-_held_products.defvjp(_held_fwd, _held_bwd)
+_held_pass.defvjp(_held_fwd, _held_bwd)

@@ -1,15 +1,17 @@
 """Milliseconds a `mellum2-train-s8k` step takes with the held experts'
-trainable form walking its picks in chunks of each given size, on the
-cell's own weights and batches (one model, a step compiled a size):
+trainable form's grouped pass walking its picks in tiles of each given
+size, on the cell's own weights and batches (one model, a step compiled a
+size):
 
-    python3 probes/mellum2_chunks.py --chunks 2048,4096,6144,8192 \\
+    python3 probes/mellum2_chunks.py --tiles 256,512 \\
         --seeds 2600004177,3147483901 [--steps 20] \\
         [--workload mellum2-train-s8k]
 
 Per size and seed one JSON line: ms a step over `--steps` steps enqueued
 two in flight after three unmeasured ones, the share of the step's picks
-that fell on the held experts (`routed_here` / `routed_all`) and the rows
-the grouped products went over.  Needs the chip.
+that fell on the held experts (`routed_here` / `routed_all`), the rows of
+the tiles the grouped pass went over, and the seconds the first steps took
+(the step traced, lowered and compiled).  Needs the chip.
 """
 import argparse
 import gc
@@ -26,7 +28,7 @@ from benchmark import harness  # noqa: E402
 def main(argv=None, need_tpu=True, spec_path=None, data_dirs=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="mellum2-train-s8k")
-    ap.add_argument("--chunks", default="2048,4096,6144,8192")
+    ap.add_argument("--tiles", default="256,512")
     ap.add_argument("--seeds", default="2600004177,3147483901")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args(argv)
@@ -45,8 +47,8 @@ def main(argv=None, need_tpu=True, spec_path=None, data_dirs=None):
     system = train_steps.TrainSystem(run)
     amp = run.config["training"]["amp"]
     names = system.model.train_count_names
-    for chunk in (int(c) for c in args.chunks.split(",")):
-        moe._TRAIN_CHUNK_ROWS = chunk
+    for tile in (int(c) for c in args.tiles.split(",")):
+        moe._TRAIN_CHUNK_ROWS = tile
         system.step = None
         gc.collect()
         system.step = TrainStep(system.model, system.arch.train_loss_fn(),
@@ -72,7 +74,7 @@ def main(argv=None, need_tpu=True, spec_path=None, data_dirs=None):
             counts = dict(zip(names, (int(v) for v in jax.device_get(
                 system.step.last_counts))))
             print(json.dumps({
-                "chunk": chunk, "seed": seed, "ms_per_step": ms,
+                "tile": tile, "seed": seed, "ms_per_step": ms,
                 "tokens_per_s": system.tokens_per_step * 1e3 / ms,
                 "picks_here_pct": 100.0 * counts["routed_here"]
                 / counts["routed_all"],

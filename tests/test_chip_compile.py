@@ -228,6 +228,34 @@ def test_held_experts_grouped_product_keeps_its_name(chip_compile):
     assert len(calls) > GROUPED_PRODUCTS   # the group metadata: other names
 
 
+def test_trainable_held_experts_compile_to_the_grouped_kernels(
+        chip_compile, monkeypatch):
+    """Mellum 2's routed layer as it trains (8192 tokens x 8 picks, 16 of
+    64 experts of 2304 x 896 held, bfloat16), forward and backward: as many
+    instructions under the name `moe_gmm_train_roofline.train_s8k` reads as
+    its forward's kernel calls (`expert_products`) times the events a call
+    stands for, and no `ragged-dot`."""
+    from paddle_tpu.nn.functional.moe import moe_ffn_held
+    from paddle_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_on_chip", lambda: True)
+    params = _metric_params("moe_gmm_train_roofline.train_s8k")
+    held, h, i = tuple(range(16)), 2304, 896
+
+    def loss(x, r, g, u, d, ct):
+        y, _, _, products, _ = moe_ffn_held.raw(
+            x, r, g, u, d, held, top_k=8, score="softmax", trainable=True)
+        return jnp.sum(y.astype(jnp.float32) * ct), products
+    text = chip_compile(
+        jax.value_and_grad(loss, argnums=range(5), has_aux=True),
+        ((8192, h), jnp.bfloat16), ((h, 64), jnp.float32),
+        ((16, h, i), jnp.bfloat16), ((16, h, i), jnp.bfloat16),
+        ((16, i, h), jnp.bfloat16), ((8192, h), jnp.float32))
+    lines = [line.strip() for line in text.splitlines()]
+    made = [line for line in lines if re.search(params["kernel"], line)]
+    assert len(made) == gm.FORWARD_CALLS * params["events_per_product"]
+    assert not [line for line in lines if "ragged-dot" in line]
+
+
 def _moonlight_routed_layer(chip_compile, tokens):
     """(the compiled text of Moonlight's routed layer over `tokens` rows: 64
     experts of 2048 x 1408 all held, 6 a token, the selection bias and the
@@ -652,8 +680,10 @@ def mellum2_step(one_chip):
         jax.eval_shape(step.init_opt_state, state))
     batch = (shape((1, 8192), jnp.int32),) * 2
     key = _rng.example_key()
+    from paddle_tpu.ops import grouped_matmul as gm
     fn = step._build(state, None, batch)
     available, fa._available = fa._available, lambda: True
+    on_chip, gm._on_chip = gm._on_chip, lambda: True
     try:
         with _no_compilation_cache():
             compiled = fn.lower(state, opt_state, shape((), jnp.int32),
@@ -661,6 +691,7 @@ def mellum2_step(one_chip):
                                 batch).compile()
     finally:
         fa._available = available
+        gm._on_chip = on_chip
     return compiled, sum(a.size * 4 for a in jax.tree_util.tree_leaves(
         state)) + sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(opt_state))
@@ -672,8 +703,9 @@ def test_mellum2_train_step_compiles_and_fits(mellum2_step, record_property):
     8192 tokens stay inside the chip; every layer's attention takes the
     grouped flash kernel forward and backward, one call each, under the
     names `window_flash_train_roofline.train_s8k` finds them by; the held
-    experts' products carry the name its sibling metric reads, 12 a chunk
-    (3 forward, and 9 backward: the 3 again and 6 for dX and dW)."""
+    experts' grouped pass is the kernels `moe_gmm_train_roofline.train_s8k`
+    reads, six a layer (2 forward, 4 backward), and no `ragged-dot` is
+    left."""
     compiled, held = mellum2_step
     mem = compiled.memory_analysis()
     record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
@@ -685,9 +717,11 @@ def test_mellum2_train_step_compiles_and_fits(mellum2_step, record_property):
                and " custom-call(" in line]
     assert sum(bool(re.search(flash["forward"], k)) for k in kernels) == 4
     assert sum(bool(re.search(flash["backward"], k)) for k in kernels) == 4
-    products = _metric_params("moe_expert_product_roofline.train_s8k")
+    from paddle_tpu.ops.grouped_matmul import FORWARD_CALLS
+    products = _metric_params("moe_gmm_train_roofline.train_s8k")
     made = [line for line in lines if re.search(products["kernel"], line)]
-    assert len(made) == 4 * 3 * products["events_per_product"]
+    assert len(made) == 4 * FORWARD_CALLS * products["events_per_product"]
+    assert not [line for line in lines if "ragged-dot" in line]
 
 
 # the lowered text (Mosaic kernels' bodies without their source locations)

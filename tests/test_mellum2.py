@@ -4,13 +4,14 @@ backward; routed experts held a share at a time through the trainable form
 of `F.moe_ffn_held`) against the benchmark's plain reference
 (`benchmark/reference/mellum2.py`), at a tiny size on the CPU in float32
 with seeded weights; the kernel's new backward and the held experts' new
-backward alone; YaRN's frequencies; the shares of the experts.
+backward alone, its grouped kernels traced once for all layers; YaRN's
+frequencies; the shares of the experts.
 
 Tolerances, each with its reason:
   * 2e-5 relative on the loss and 2e-4 relative (of a leaf's largest
     element) on every leaf's gradient through `TrainStep` in float32: the
     same arithmetic in another order (the kernel's blocks, the held picks in
-    chunks, the reference dense over experts); gradients are of size 1e-3
+    tiles, the reference dense over experts); gradients are of size 1e-3
     to 1e-1 and their float32 sums over a few thousand terms differ by
     ~1e-7 of the largest.
   * 2e-5 of the largest element between the kernel's gradients and
@@ -20,6 +21,7 @@ Tolerances, each with its reason:
 """
 import math
 import os
+import re
 import sys
 
 import jax
@@ -34,6 +36,7 @@ from paddle_tpu.models import mellum2 as M
 from paddle_tpu.nn.functional import moe
 from paddle_tpu.observability import get_tracer
 from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu.ops import grouped_matmul as gm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -135,7 +138,10 @@ def test_the_counts_ride_on_the_train_step_span_unread_until_the_ring_is():
     assert last["routed_all"] == 2 * 32 * 2 * 4     # tokens x k x layers
     assert 0 < last["routed_here"] <= last["routed_all"]
     assert 0 < last["experts_hit"] <= 4 * 4
-    assert last["expert_products"] % 3 == 0
+    # the grouped pass's two forward kernels a layer, over the whole tiles
+    # that hold each layer's held picks (one tile of 128 rows here)
+    assert last["expert_products"] == gm.FORWARD_CALLS * 4
+    assert last["routed_here"] <= last["expert_rows"] <= 4 * 128
     assert all(isinstance(v, int) for v in last.values())
 
     gpt = models.GPTForPretraining(models.GPTConfig(
@@ -256,9 +262,10 @@ def test_held_experts_backward_is_the_gradient_of_the_dense_layer(
         monkeypatch, routing, chunk):
     """`moe_ffn_held(trainable=True)`: the output and the gradients of x,
     the router and the three stacks against `jax.grad` of the dense held
-    layer, walked in chunks of 16 picks (several) and in one; with every
-    token's first pick on expert 0 (its router column raised), so that one
-    expert takes every token and nothing is dropped."""
+    layer, its grouped pass over tiles of 16 picks (several, experts
+    straddling them) and over one; with every token's first pick on
+    expert 0 (its router column raised), so that one expert takes every
+    token and nothing is dropped."""
     monkeypatch.setattr(moe, "_TRAIN_CHUNK_ROWS", chunk)
     held, k = (0, 2, 3, 5), 3
     x, router, wg, wu, wd, g = experts(7)
@@ -293,10 +300,87 @@ def test_the_trainable_form_is_counted_by_its_own_label():
         *a, (0, 1, 2, 3), top_k=2, score="softmax", trainable=True),
         x, router, wg, wu, wd)
     after = {k[0]: v for k, v in m.samples()}
-    assert after["grouped_train"] - before.get("grouped_train", 0) == 1
+    assert after["grouped_kernel"] - before.get("grouped_kernel", 0) == 1
     assert {k: v - before.get(k, 0) for k, v in after.items()
-            if k != "grouped_train"} == {k: 0 for k in after
-                                         if k != "grouped_train"}
+            if k != "grouped_kernel"} == {k: 0 for k in after
+                                          if k != "grouped_kernel"}
+
+
+KERNELS = ("_gate_up_kernel", "_down_kernel", "_gate_up_bwd_kernel",
+           "_tgmm_kernel")
+
+
+def test_each_grouped_kernels_body_is_traced_and_lowered_once_for_all_layers(
+        monkeypatch):
+    """A two-layer model's train step calls the grouped pass's kernels in
+    every layer, forward and backward, with one signature a kernel: each
+    body is traced once for all the layers (`tgmm` once for each of its
+    two signatures, the gate's and up's gradients and the down's), as is
+    the pass of two layers alone, whose lowered text holds each kernel as
+    ONE function that both layers call."""
+    traced = {name: 0 for name in KERNELS}
+    for name in KERNELS:
+        body = getattr(gm, name)
+        monkeypatch.setattr(gm, name, lambda *a, _n=name, _b=body, **kw: (
+            traced.__setitem__(_n, traced[_n] + 1), _b(*a, **kw))[1])
+    # widths no other test traces the kernels at
+    cfg = dict(tiny_cfg(hidden=96), num_hidden_layers=2,
+               layer_types=["sliding_attention", "full_attention"])
+    model = program(cfg)
+    step = TrainStep(model, A.train_loss_fn(), paddle.optimizer.AdamW(
+        learning_rate=1e-3, parameters=model.parameters()))
+    ids = np.random.RandomState(2).randint(0, 128, (2, 33)).astype(np.int32)
+    step(paddle.to_tensor(ids[:, :-1]), paddle.to_tensor(ids[:, 1:]))
+    assert traced == {"_gate_up_kernel": 1, "_down_kernel": 1,
+                      "_gate_up_bwd_kernel": 1, "_tgmm_kernel": 2}
+
+    x, router, wg, wu, wd, g = experts(5, d=80)
+
+    def two_layers(x, *w):
+        for _ in range(2):
+            x = x + moe.moe_ffn_held.raw(x, *w, (0, 2, 3, 5), top_k=3,
+                                         score="softmax", trainable=True)[0]
+        return jnp.sum(x * g)
+    text = jax.jit(jax.value_and_grad(two_layers, argnums=range(5))).lower(
+        x, router, wg, wu, wd).as_text()
+    for fn in ("_gate_up", "_down", "_gate_up_bwd", "_tgmm"):
+        assert len(re.findall(rf"func\.func private @{fn}(_\d+)?\(",
+                              text)) == (2 if fn == "_tgmm" else 1), fn
+        assert len(re.findall(rf"call @{fn}(_\d+)?\(", text)) == (
+            6 if fn == "_tgmm" else 2), fn
+
+
+# lowers a tiny model's forward in a fresh process and prints its text's hash
+_LOWER_FORWARD = """
+import hashlib, json, sys
+import jax, jax.numpy as jnp
+from paddle_tpu import models
+cfg = models.Mellum2Config(**json.loads(sys.argv[1]))
+model = models.Mellum2ForCausalLM(cfg)
+text = jax.jit(lambda ids: model.forward(ids)[0]).lower(
+    jnp.zeros((1, 8), jnp.int32)).as_text()
+print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_the_forward_lowers_to_one_text_whatever_the_hash_seed():
+    """The rotary tables are made in the order the layer kinds first
+    appear: a set of them is ordered by string hashes, which differ from
+    process to process, and the step's text, the compile cache's key,
+    would then differ with them (a warm run compiles the step again)."""
+    import json
+    import subprocess
+    kw = {k: v for k, v in tiny_cfg().items()
+          if k not in ("num_experts_held", "rope_parameters")}
+    texts = set()
+    for seed in ("0", "1", "2", "3"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu")
+        out = subprocess.run(
+            [sys.executable, "-c", _LOWER_FORWARD, json.dumps(kw)], env=env,
+            cwd=ROOT, capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        texts.add(out.stdout.split()[-1])
+    assert len(texts) == 1
 
 
 def test_four_shares_of_the_experts_add_up_to_the_uncut_layer():
