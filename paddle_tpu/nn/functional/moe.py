@@ -125,7 +125,8 @@ _CHUNK_ROWS = 2048
 # of 2304 x 896 and ~16k picks held a layer: 256 goes over ~79 tiles where
 # 512 goes over ~47 of twice the rows (a fifth more products), and a tile
 # of 256 rows takes about as long on the MXU as its expert's matrices take
-# to arrive, so that a change of expert stalls nothing
+# to arrive, so that a change of expert stalls nothing.  Read on the chip,
+# ms a step of 8192 tokens (PERF.md, PR 44): 256 195.0, 512 199.6 to 199.8
 _TRAIN_CHUNK_ROWS = 256
 # where the held experts' products are ONE batched product a matrix over
 # every row and every held expert (`_batched_form`), both chosen on the chip
